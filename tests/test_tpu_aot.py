@@ -1,0 +1,158 @@
+"""Every Pallas kernel in ops/ still compiles for the chip — checked
+without one.
+
+`jax.experimental.topologies.get_topology_desc` describes a v5e host to the
+installed libtpu, and `jit(...).lower(...).compile()` against its devices
+runs the real Mosaic + XLA:TPU compile at production geometry (TILE_HI=512,
+BLK=4096, BLK_U=1024, FM_BLK=1024; 65,536 x 39 batches; bf16 and f32). A
+Mosaic rejection — a VMEM limit, a block shape, an op it no longer lowers —
+fails here, on the CPU, instead of on chip time.
+
+Kernel-only programs on purpose: a whole train step adds the AUC sort,
+which alone compiles for ~25-50 s (PERF.md §6, PR 21). There is one
+installation, so a topology that cannot be built is a failure, not a skip.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from wormhole_tpu.ops import coo_kernels as ck
+from wormhole_tpu.ops import fused_update as fu
+from wormhole_tpu.ops import hist
+
+ROWS, NNZ = 65536, 39
+CAP = ROWS * NNZ
+NB_DENSE = 1 << 22          # headline table: dense coo kernels
+NB_BIG = 1 << 26            # Criteo-1TB table: compacted kernels
+U_CAP = 1572864             # its auto compact_cap (24 tiles)
+DIM, VB = 8, 1 << 20        # DiFacto bench shape
+UW_CAP, UV_CAP = 6 * ck.TILE, 256 * ck.BLK_U
+DTYPES = [jnp.bfloat16, jnp.float32]
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    assert topo.devices[0].device_kind == "TPU v5 lite", topo.devices
+    return NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+
+
+@pytest.fixture(autouse=True)
+def compiled_not_interpreted(monkeypatch):
+    # the test process runs on CPU, where the kernels would pick interpret
+    # mode; the AOT target is the chip
+    for mod in (ck, fu, hist):
+        monkeypatch.setattr(mod, "_use_interpret", lambda: False)
+    assert (ck.TILE_HI, ck.BLK, ck.BLK_U, ck.FM_BLK) == (512, 4096, 1024,
+                                                         1024)
+
+
+def aot(fn, sharding, *shapes):
+    """Compile fn for the described chip; shapes are (shape, dtype)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    # Mosaic really ran: an interpreted kernel leaves no custom call
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def coo_stream(capacity, num_buckets, tile=None, blk=None):
+    """(idx, seg, val, tmap, first) shapes of a packed COO stream."""
+    p = ck.packed_size(capacity, num_buckets, tile, blk)
+    nblk = p // (blk or ck.BLK)
+    i32, f32 = jnp.int32, jnp.float32
+    return [((p,), i32), ((p,), i32), ((p,), f32), ((nblk,), i32),
+            ((nblk,), i32)]
+
+
+def slot_blocks(u_cap, n):
+    """n per-update-block int32 vectors (tmap_u, first_u, last_u)."""
+    return [((u_cap // ck.BLK_U,), jnp.int32)] * n
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_coo_pull_push_dense(v5e, dtype):
+    stream = coo_stream(CAP, NB_DENSE)
+    aot(lambda w, *s: ck.coo_spmv(w, *s, ROWS, dtype=dtype), v5e,
+        ((NB_DENSE,), jnp.float32), *stream)
+    aot(lambda d, *s: ck.coo_spmv_t(d, *s, NB_DENSE, dtype=dtype), v5e,
+        ((ROWS,), jnp.float32), *stream)
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_compacted_linear_kernels(v5e, dtype):
+    f32, i32 = jnp.float32, jnp.int32
+    table2 = ((NB_BIG // ck.LANES, ck.LANES), f32)
+    aot(lambda t, u, tm: ck.tile_gather(t, u, tm, dtype=dtype), v5e,
+        table2, ((U_CAP,), i32), *slot_blocks(U_CAP, 1))
+    aot(lambda d, *s: ck.coo_spmv_t(d, *s, U_CAP, dtype=dtype), v5e,
+        ((ROWS,), f32), *coo_stream(CAP, U_CAP))
+
+    def update(z, n, w, g, uniq, tm, fi, la):
+        return fu.scatter_update(
+            "ftrl", {"z": z, "n": n, "w": w}, g, uniq, tm, fi, la,
+            lr_eta=0.1, lr_beta=1.0, lambda_l1=1.0, lambda_l2=0.0,
+            dtype=dtype)
+
+    aot(update, v5e, *[((NB_BIG,), f32)] * 3, ((U_CAP,), f32),
+        ((U_CAP,), i32), *slot_blocks(U_CAP, 3))
+
+
+@pytest.mark.parametrize("algo,tables", [("adagrad", 2), ("sgd", 1)])
+def test_fused_update_other_handles(v5e, algo, tables):
+    f32, i32 = jnp.float32, jnp.int32
+    names = ("n", "w")[2 - tables:]
+
+    def update(*a):
+        state = dict(zip(names, a[:tables]))
+        return fu.scatter_update(algo, state, *a[tables:], lr_eta=0.1,
+                                 lr_beta=1.0, lambda_l1=1.0, lambda_l2=0.0,
+                                 fixed_bytes=1, dtype=jnp.bfloat16)
+
+    aot(update, v5e, *[((NB_BIG,), f32)] * tables, ((U_CAP,), f32),
+        ((U_CAP,), i32), *slot_blocks(U_CAP, 3))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_fm_kernels(v5e, dtype):
+    f32, i32 = jnp.float32, jnp.int32
+    vflat = ((VB * DIM,), f32)
+    v2 = ((VB * DIM // ck.LANES, ck.LANES), f32)
+    aot(lambda t, u, tm: fu.row_tile_gather(t, u, tm, DIM, dtype=dtype),
+        v5e, v2, ((UV_CAP,), i32), *slot_blocks(UV_CAP, 1))
+
+    idx, _, _, tmap, first = coo_stream(CAP, UV_CAP, ck.TILE_HI, ck.FM_BLK)
+    p = idx[0][0]
+    wire = dtype  # a/b arrive at the gather wire dtype (difacto._build_fm)
+    aot(lambda V, a, b, si, tm, fi: ck.fm_push_contrib(
+            V, a, b, si, tm, fi, dtype=dtype),
+        v5e, ((UV_CAP, DIM), f32), ((p, DIM), wire), ((p,), wire), idx,
+        tmap, first)
+
+    aot(lambda V, nV, g, tch, u, tm, fi, la: fu.v_scatter_update(
+            V, nV, g, tch, u, tm, fi, la, dim=DIM, V_lr_eta=0.01,
+            V_lr_beta=1.0, lambda_V=0.01, dtype=dtype),
+        v5e, vflat, vflat, ((UV_CAP, DIM), f32), ((UV_CAP,), f32),
+        ((UV_CAP,), i32), *slot_blocks(UV_CAP, 3))
+
+    # difacto's w update: FTRL with cnt riding as the additive table
+    def update(z, n, w, cnt, g, uniq, tm, fi, la, wcnts):
+        return fu.scatter_update(
+            "ftrl", {"z": z, "n": n, "w": w, "cnt": cnt}, g, uniq, tm, fi,
+            la, lr_eta=0.1, lr_beta=1.0, lambda_l1=1.0, lambda_l2=0.0,
+            dtype=dtype, add_table="cnt", add_values=wcnts)
+
+    aot(update, v5e, *[((NB_DENSE,), f32)] * 4, ((UW_CAP,), f32),
+        ((UW_CAP,), i32), *slot_blocks(UW_CAP, 3), ((UW_CAP,), f32))
+
+
+def test_gbdt_histogram(v5e):
+    rows, F, B, nodes = 1 << 21, 28, 256, 32   # HIGGS shape, a deep level
+    aot(lambda b, g, h, rel: hist.level_hist(b, g, h, rel, nodes, B), v5e,
+        ((rows, F), jnp.uint8), ((rows,), jnp.float32),
+        ((rows,), jnp.float32), ((rows,), jnp.int32))

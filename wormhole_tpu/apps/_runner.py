@@ -186,7 +186,9 @@ def _run_scheduler_global(env) -> dict:
         seen_any = sched.incarnation > 0
         while True:
             time.sleep(1.0)
-            seen_any = seen_any or bool(sched.live_workers())
+            # ever-seen, not live-now: a pure-predict job's workers can
+            # register and leave between two polls
+            seen_any = seen_any or sched.workers_ever_seen() > 0
             if seen_any and not sched.live_workers():
                 return {}
             if not seen_any and time.monotonic() > startup_deadline:
@@ -656,6 +658,9 @@ def _run_worker(cfg, env, make_learner, verbose: bool) -> dict:
     from wormhole_tpu.runtime.tracker import LivenessPinger
 
     learner = make_learner(cfg, env)
+    # the worker is the one role that opens the device; its solver runs
+    # quiet, so state here where it landed (the launcher prefixes the role)
+    print(learner.placement, flush=True)
     client = SchedulerClient(env.scheduler_uri, f"worker-{env.rank}")
     client.register()
     # background liveness pings: a worker streaming a large part (or in

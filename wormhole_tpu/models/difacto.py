@@ -45,7 +45,8 @@ from wormhole_tpu.ops.localizer import localize
 from wormhole_tpu.ops.penalty import l1l2_solve
 from wormhole_tpu.ops.spmv import row_squares, spmm, spmv, spmv_t
 from wormhole_tpu.parallel.kvstore import KVStore, TableSpec, quantize_push
-from wormhole_tpu.parallel.mesh import batch_sharding, make_mesh
+from wormhole_tpu.parallel.mesh import (batch_sharding, describe_placement,
+                                        make_mesh)
 
 
 @dataclasses.dataclass
@@ -210,22 +211,31 @@ class DifactoLearner:
         # collectives path
         D = self.mesh.shape.get("data", 1)
         M_ = self.mesh.shape.get("model", 1)
-        self._use_fm_pallas = (
-            cfg.kernel == "pallas"
-            or (cfg.kernel == "auto" and jax.default_backend() == "tpu")
-        ) and (not cfg.l1_shrk and D == 1 and M_ == 1
-               and cfg.minibatch % 128 == 0
-               # the fused in-place V update needs rows that tile cleanly:
-               # dim a power of two dividing 128, V table a whole number
-               # of (TILE_HI, 128) flat tiles
-               and cfg.dim & (cfg.dim - 1) == 0 and 128 % cfg.dim == 0
-               and (cfg.vb * cfg.dim) % ck.TILE == 0
-               # the fused w update streams whole (TILE_HI, 128) tiles
-               and cfg.num_buckets % ck.TILE == 0
-               # the row-gather kernels compute flat int32 offsets
-               # uniq * dim, so the flat V table must fit int32
-               # (ADVICE r2; pack_tile_coo asserts the same for w)
-               and cfg.vb * cfg.dim < 2**31)
+        want = cfg.kernel == "pallas" or (
+            cfg.kernel == "auto" and jax.default_backend() == "tpu")
+        # the fused in-place updates need tables that tile cleanly: dim a
+        # power of two dividing 128, V and w tables whole numbers of
+        # (TILE_HI, 128) flat tiles, lane-aligned rows; the row-gather
+        # kernels compute flat int32 offsets uniq * dim, so the flat V
+        # table must fit int32 (ADVICE r2; pack_tile_coo asserts the
+        # same for w)
+        blockers = [reason for bad, reason in (
+            (cfg.l1_shrk, "l1_shrk needs device-resident w"),
+            (D != 1 or M_ != 1, f"mesh {D}x{M_} has more than one device"),
+            (cfg.minibatch % 128 != 0, "minibatch % 128 != 0"),
+            (cfg.dim & (cfg.dim - 1) != 0 or 128 % cfg.dim != 0,
+             f"dim {cfg.dim} is not a power of two dividing 128"),
+            ((cfg.vb * cfg.dim) % ck.TILE != 0
+             or cfg.num_buckets % ck.TILE != 0,
+             f"tables are not whole {ck.TILE}-entry tiles"),
+            (cfg.vb * cfg.dim >= 2**31, "flat V table overflows int32"),
+        ) if bad]
+        self._use_fm_pallas = want and not blockers
+        #: start-up statement of where and how this learner runs
+        self.placement = describe_placement(
+            self.mesh, "difacto", self._use_fm_pallas,
+            "; ".join(blockers) if want else
+            "kernel=xla" if cfg.kernel == "xla" else "")
         self._fm_caps = None
         self._fm_steps = None
         self._fm_lock = threading.Lock()

@@ -32,15 +32,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from wormhole_tpu.data.rowblock import RowBlock
 from wormhole_tpu.parallel.mesh import (
     DATA_AXIS,
     batch_sharding,
+    describe_placement,
     make_mesh,
     replicated,
-    shard_map,
 )
 from wormhole_tpu.solver.workload import iter_rowblocks
 
@@ -235,6 +236,12 @@ class GbdtLearner:
         self._requested_rounds = cfg.num_round
         self.mesh = mesh if mesh is not None else make_mesh(num_model=1)
         self._n_data = self.mesh.shape[DATA_AXIS]
+        self._use_mxu_hist = cfg.hist_kernel == "mxu" or (
+            cfg.hist_kernel == "auto" and jax.default_backend() == "tpu")
+        #: start-up statement of where and how this learner runs
+        self.placement = describe_placement(
+            self.mesh, "gbdt", self._use_mxu_hist,
+            "hist_kernel=xla" if cfg.hist_kernel == "xla" else "")
         self.edges: Optional[np.ndarray] = None   # [dim, max_bin-1]
         # stacked per-round trees, each [T] where T = 2^(max_depth+1)-1
         self.trees: dict[str, np.ndarray] = _empty_trees(cfg)
@@ -347,13 +354,10 @@ class GbdtLearner:
         sibling = num_nodes > 1
         hist_nodes = num_nodes // 2 if sibling else num_nodes
 
-        use_mxu_hist = cfg.hist_kernel == "mxu" or (
-            cfg.hist_kernel == "auto" and jax.default_backend() == "tpu")
-
         def local_hist(binned, g, h, rel):
             """Per-shard (node, feature, bin) histograms + psum — the
             rabit::Allreduce of gradient histograms."""
-            if use_mxu_hist:
+            if self._use_mxu_hist:
                 # MXU one-hot-matmul histogram (ops/hist.py): the XLA
                 # scatter costs ~10ns per rows x F element on TPU
                 from wormhole_tpu.ops.hist import level_hist
@@ -683,6 +687,8 @@ class GbdtLearner:
         so a resumed worker's counter sequence lines up with the
         survivors')."""
         cfg = self.cfg
+        if verbose:
+            print(self.placement, flush=True)
         prior = self.trees
         self.trees = _empty_trees(cfg)
         for k in self.trees:

@@ -26,7 +26,8 @@ import numpy as np
 
 from wormhole_tpu.data import pack_cache as _pc
 from wormhole_tpu.data.rowblock import RowBlock, to_device_batch
-from wormhole_tpu.parallel.mesh import batch_sharding, make_mesh, replicated
+from wormhole_tpu.parallel.mesh import (batch_sharding, describe_placement,
+                                        make_mesh, replicated)
 from wormhole_tpu.solver.workload import iter_parts, iter_rowblocks
 
 
@@ -170,9 +171,18 @@ class KmeansLearner:
         # the kernel's dual vector wants lane-aligned rows (odd batch
         # sizes keep the scatter densify), and the raw pallas call has
         # no mesh variant — a data-sharded in-process mesh keeps the
-        # GSPMD-partitioned scatter path
-        self._use_packed = (not self._use_sparse and B % 128 == 0
-                            and self.mesh.shape.get("data", 1) == 1)
+        # GSPMD-partitioned scatter path. Off-TPU the kernel would only
+        # run interpreted, which nothing asks for here.
+        blockers = [reason for bad, reason in (
+            (self._use_sparse, "assign_kernel=sparse"),
+            (B % 128 != 0, "minibatch % 128 != 0"),
+            (self.mesh.shape.get("data", 1) != 1, "data-sharded mesh"),
+        ) if bad]
+        self._use_packed = (not blockers
+                            and jax.default_backend() == "tpu")
+        #: start-up statement of where and how this learner runs
+        self.placement = describe_placement(
+            self.mesh, "kmeans", self._use_packed, "; ".join(blockers))
         assert cfg.kernel_dtype in ("f32", "bf16"), (
             f"kernel_dtype must be 'f32' or 'bf16', got "
             f"{cfg.kernel_dtype!r}")
@@ -319,6 +329,8 @@ class KmeansLearner:
     # -- Lloyd loop (kmeans.cc:169-208) -------------------------------------
     def run(self, verbose: bool = True) -> float:
         cfg = self.cfg
+        if verbose:
+            print(self.placement, flush=True)
         if self.centroids is None and not self._try_resume():
             self.init_centroids()
         cost = float("nan")

@@ -35,7 +35,8 @@ from wormhole_tpu.ops import metrics as M
 from wormhole_tpu.ops.penalty import l1l2_solve
 from wormhole_tpu.ops.spmv import spmv, spmv_t
 from wormhole_tpu.parallel.kvstore import KVStore, TableSpec, quantize_push
-from wormhole_tpu.parallel.mesh import batch_sharding, make_mesh
+from wormhole_tpu.parallel.mesh import (batch_sharding, describe_placement,
+                                        make_mesh)
 
 
 @dataclasses.dataclass
@@ -234,6 +235,15 @@ class LinearLearner:
             and jax.default_backend() == "tpu"
             and shapes_ok
         )
+        why_not = ""
+        if cfg.kernel == "xla":
+            why_not = "kernel=xla"
+        elif jax.default_backend() == "tpu" and not shapes_ok:
+            why_not = (f"num_buckets % {M * ck.TILE} or minibatch % "
+                       f"{D * ck.LANES} != 0")
+        #: start-up statement of where and how this learner runs
+        self.placement = describe_placement(self.mesh, "linear",
+                                            self.use_pallas, why_not)
         # mesh layout (shard_map + psum collectives) whenever any axis > 1
         self._mesh_coo = self.use_pallas and (D > 1 or M > 1)
         self._shard_cap = ck.mesh_capacity(cfg.row_capacity, D, M)

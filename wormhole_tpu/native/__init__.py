@@ -7,8 +7,11 @@ ctypes (no pybind11 in the image). The Python parsers in
 wormhole_tpu/data/parsers.py stay the reference implementation and the
 fallback — `tests/test_native.py` cross-checks the two bit-for-bit.
 
-The library is built lazily on first use (`make -C wormhole_tpu/native`);
-set WORMHOLE_NO_NATIVE=1 to force the pure-Python path, or
+The library is built lazily on first use (`make -C wormhole_tpu/native`).
+A build that fails is reported once on stderr with the compiler's output
+and recorded in `status()`; the callers then run the Python reference
+paths, and the app's start-up line says so (`native=failed: ...`).
+Set WORMHOLE_NO_NATIVE=1 to choose the pure-Python path on purpose, or
 WORMHOLE_NATIVE_LIB=/path/to/lib.so to load a specific build — that is
 how the sanitizer CI job runs the suite against the asan/tsan/ubsan
 targets of the Makefile (the race/memory checking the reference never
@@ -31,6 +34,7 @@ _SO = os.path.join(_DIR, "libwormhole_native.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_failure = ""  # why the library is not loaded, once a load was tried
 
 
 def _stale() -> bool:
@@ -47,21 +51,23 @@ def _stale() -> bool:
     return False
 
 
-def _build() -> bool:
+def _build() -> str:
     """Compile to a per-process temp name, then os.replace into place, so
     concurrent first-use builds (multi-process launches on a shared
-    filesystem) can never dlopen a half-written .so."""
+    filesystem) can never dlopen a half-written .so. Returns "" on
+    success, else what went wrong (the compiler's stderr)."""
     tmp = f"libwormhole_native.{os.getpid()}.tmp.so"
     try:
         r = subprocess.run(
             ["make", "-C", _DIR, "-s", f"OUT={tmp}"],
             capture_output=True, timeout=120)
         if r.returncode != 0:
-            return False
+            return (f"make exited {r.returncode}\n"
+                    + r.stderr.decode("utf-8", "replace").strip())
         os.replace(os.path.join(_DIR, tmp), _SO)
-        return True
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+        return ""
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"{type(e).__name__}: {e}"
     finally:
         try:
             os.remove(os.path.join(_DIR, tmp))
@@ -92,7 +98,7 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 def get_lib() -> Optional[ctypes.CDLL]:
     """Load (building if needed) the native library; None if unavailable."""
-    global _lib, _tried
+    global _lib, _tried, _failure
     if _lib is not None:
         return _lib
     if os.environ.get("WORMHOLE_NO_NATIVE"):
@@ -113,13 +119,33 @@ def get_lib() -> Optional[ctypes.CDLL]:
                     f"WORMHOLE_NATIVE_LIB={override!r} failed to load or "
                     f"is missing symbols: {e}") from e
             return _lib
-        if _stale() and not _build():
-            return None
-        try:
-            _lib = _bind(ctypes.CDLL(_SO))
-        except OSError:
-            _lib = None
+        if _stale():
+            _failure = _build()
+        if not _failure:
+            try:
+                _lib = _bind(ctypes.CDLL(_SO))
+            except OSError as e:
+                _failure = f"dlopen failed: {e}"
+        if _failure:
+            import sys
+
+            sys.stderr.write(
+                "[native] libwormhole_native.so unavailable — parsing, "
+                "hashing and radix sort fall back to the Python/numpy "
+                f"reference paths:\n{_failure}\n")
         return _lib
+
+
+def status() -> str:
+    """Whether the C++ core serves this process: `loaded`, `disabled`
+    (WORMHOLE_NO_NATIVE) or `failed: <reason>` (the full compiler output
+    went to stderr). Apps print it at start-up and chip_smoke.py
+    requires `loaded`. Triggers the lazy build/load."""
+    if get_lib() is not None:
+        return "loaded"
+    if os.environ.get("WORMHOLE_NO_NATIVE"):
+        return "disabled"
+    return "failed: " + _failure.splitlines()[0]
 
 
 def available() -> bool:

@@ -44,8 +44,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.pallas import tpu as pltpu
 
-from wormhole_tpu.ops.pallas_compat import CompilerParams
-
 import os
 
 # Tile geometry. The per-block cost is dominated by materializing the
@@ -332,7 +330,7 @@ def coo_spmv(w, sidx, sseg, sval, tmap, first, num_rows: int, dtype=None):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_rows // LANES, LANES),
                                        jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
     )(tmap, first, w, sidx, sseg, sval)
@@ -395,7 +393,7 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_buckets // LANES, LANES),
                                        jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
     )(tmap, first, d2, sidx, sseg, sval)
@@ -610,7 +608,7 @@ def tile_gather(table2, uniq, tmap_u, dtype=None):
         partial(_tile_gather_kernel, dtype=dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((u_cap,), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
     )(tmap_u, table2, uniq)
@@ -699,7 +697,7 @@ def fm_push_contrib(V, a, b, sidx, tmap, first, dtype=None):
         partial(_fm_push_contrib_kernel, dim=dim, dtype=dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, dim), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_FM_VMEM_LIMIT),
         interpret=_use_interpret(),
     )(tmap, first, V, ab, sidx)
@@ -789,9 +787,10 @@ def mesh_coo_spmv(mesh, w, sidx, sseg, sval, tmap, first,
     """xw = X w on a (data x model) mesh. w is table-sharded over the
     model axis; returns xw sharded over the data axis. The psum over the
     model axis is the ZPull collective."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from wormhole_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, shard_map
+    from wormhole_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
     D = mesh.shape[DATA_AXIS]
 
@@ -815,9 +814,10 @@ def mesh_coo_spmv_t(mesh, d, sidx, sseg, sval, tmap, first,
     """g = X^T d on a (data x model) mesh. d is row-sharded over the data
     axis; returns g table-sharded over the model axis. The psum over the
     data axis is the ZPush reduce."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from wormhole_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, shard_map
+    from wormhole_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
     M = mesh.shape[MODEL_AXIS]
 

@@ -36,8 +36,6 @@ import jax.experimental.pallas as pl
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-from wormhole_tpu.ops.pallas_compat import CompilerParams
-
 from wormhole_tpu.ops.coo_kernels import (_VMEM_LIMIT, BLK_U, LANES,
                                           TILE, TILE_HI, _onehot,
                                           _onehot_t, _prec, _row_fetch,
@@ -216,7 +214,7 @@ def row_tile_gather(flat2, uniq_rows, tmap_u, dim: int, dtype=None):
         partial(_row_gather_kernel, dim=dim, dtype=dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((u_cap, dim), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
     )(tmap_u, flat2, uniq_rows)
@@ -319,7 +317,7 @@ def v_scatter_update(Vflat, nVflat, gV, vtouched, uniq_rows, tmap_u,
         out_shape=[jax.ShapeDtypeStruct((n_rows2, LANES), jnp.float32),
                    jax.ShapeDtypeStruct((n_rows2, LANES), jnp.float32)],
         input_output_aliases=aliases,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
     )(tmap_u, first_u, last_u, gV, vtouched, uniq_rows, V2, nV2)
@@ -395,7 +393,7 @@ def scatter_update(algo: str, state: dict, g, uniq, tmap_u, first_u,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         input_output_aliases=aliases,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
     )(tmap_u, first_u, last_u, qscale, g, uniq, *add_args, *tabs)

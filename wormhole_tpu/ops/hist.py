@@ -35,8 +35,6 @@ import jax.experimental.pallas as pl
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-from wormhole_tpu.ops.pallas_compat import CompilerParams
-
 from wormhole_tpu.ops.coo_kernels import _use_interpret
 
 import os
@@ -119,7 +117,7 @@ def level_hist(binned, g, h, rel, num_nodes: int, B: int):
         partial(_hist_kernel, F=F, B=B),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((4 * nodes_p, F * B), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 2**20),
         interpret=_use_interpret(),
     )(s, binned)

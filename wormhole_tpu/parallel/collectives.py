@@ -22,6 +22,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from wormhole_tpu.parallel.mesh import DATA_AXIS
@@ -65,8 +66,6 @@ class Communicator:
     def _sum_fn(self, ndim: int):
         fn = self._sum_fns.get(ndim)
         if fn is None:
-            from wormhole_tpu.parallel.mesh import shard_map
-
             spec = P(self.axis, *([None] * (ndim - 1)))
 
             @jax.jit

@@ -25,19 +25,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # 0.4.x keeps it in experimental, as check_rep
-    from functools import wraps
-
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    @wraps(_shard_map_04)
-    def shard_map(f, *, check_vma: Optional[bool] = None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _shard_map_04(f, **kw)
-
 
 def make_mesh(
     num_data: Optional[int] = None,
@@ -81,3 +68,25 @@ def replicated(mesh: Mesh) -> NamedSharding:
 def single_device_mesh() -> Mesh:
     """1x1 mesh on the first device — single-chip paths."""
     return make_mesh(1, 1, devices=jax.devices()[:1])
+
+
+def describe_placement(mesh: Mesh, learner: str, pallas: bool,
+                       why_not: str = "") -> str:
+    """The one start-up line a learner states at construction: the
+    backend and device kind it runs on, its mesh, the kernel path it
+    chose, and the reason whenever that is not the compiled Pallas path
+    — so a run that quietly landed on CPU, in interpret mode or on the
+    XLA formulation says so (the solver prints it beside its `[loader]`
+    line)."""
+    dev = mesh.devices.flat[0]
+    shape = "x".join(str(mesh.shape[a]) for a in (DATA_AXIS, MODEL_AXIS))
+    if pallas:
+        # off-TPU the kernels only run interpreted, and only on request
+        why = "" if dev.platform == "tpu" else "interpret mode, by request"
+    else:
+        why = why_not or f"backend is {dev.platform}, not tpu"
+    line = (f"[{learner}] backend={dev.platform} "
+            f"device_kind={dev.device_kind!r} devices={mesh.devices.size} "
+            f"mesh={shape} (data x model) "
+            f"path={'pallas' if pallas else 'xla'}")
+    return f"{line} ({why})" if why else line

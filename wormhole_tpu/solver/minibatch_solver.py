@@ -245,6 +245,14 @@ class MinibatchSolver:
         self._log(f"[loader] {num_loaders} loader thread(s) ({src}), "
                   f"adaptive={'on' if self.controller else 'off'}, "
                   f"pack_cache={cache_desc}")
+        from wormhole_tpu import native
+
+        # where and how this run executes, stated once: the learner's
+        # backend/mesh/kernel path and whether the C++ parsing core or
+        # the Python parsers feed it
+        placement = getattr(learner, "placement",
+                            "[learner] placement not stated")
+        self._log(f"{placement} native={native.status()}")
 
     @property
     def _ckpt_store(self):
