@@ -1,0 +1,418 @@
+"""The benchmark's harness, checked where there is no chip: its arithmetic,
+its generator against the program's parser, the kernel counts by hand, the
+refusal to measure without a TPU, and whole rehearsal runs (tiny sizes,
+Pallas kernels interpreted) — one sound, whose last line is pinned to the
+contract's keys and whose reference check agrees, one with the timed path
+broken underneath, which must come out not correct, and the control (the
+reference with bfloat16 tables), which must fail the same limits.
+
+A rehearsal names the platform it ran on (`cpu`); nothing here is a speed.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
+
+from benchmark import check, gen, window  # noqa: E402
+from benchmark.kernels import coo_push, fused_update, tile_gather  # noqa: E402
+
+sys.path.insert(0, os.path.join(REPO, "tests", "benchmark", "fixtures"))
+import coo_pull  # noqa: E402  (a kernel count no cell uses yet)
+
+KEYS = gen.KeyModel("criteo-terabyte")
+
+
+def _env(tmp, **extra):
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp / "cache"), **extra)
+    env.pop("XLA_FLAGS", None)  # one device, like the chip
+    return env
+
+
+# ------------------------------------------------------------- arithmetic
+def test_rate_runs_from_the_opening_step_to_the_last_step():
+    # (the last is the step that crosses the deadline: test below)
+    ends = [10.5, 11.0, 12.0, 14.9, 15.0]
+    assert window.rate(10.0, ends, [100] * 5) == pytest.approx(100.0)
+    with pytest.raises(ValueError):
+        window.rate(10.0, [], [])
+
+
+def test_tap_opens_after_warmup_and_counts_whole_steps_only(monkeypatch):
+    from benchmark import tap as tp
+
+    class Learner:
+        def train_batch(self, b):
+            return {"nex": 4.0}
+
+    clock = iter([0.0, 1.0,        # the warm-up pass's step
+                  9.9, 10.0,       # opens the window, not counted
+                  10.0, 12.5, 12.5, 14.9,
+                  14.9, 18.0])     # crosses the deadline: the last, counted
+    monkeypatch.setattr(tp.time, "perf_counter", lambda: next(clock))
+    reads = []
+    monkeypatch.setattr(tp, "stage_hists",
+                        lambda: reads.append(len(reads)) or {"h": (len(reads),
+                                                                  0.0)})
+    clog, warns = tp.CompileLog(), tp.WarningLog()
+    tap = tp.Tap(Learner(), clog, warns)
+    tap.begin_window(5.0, warmup_passes=1)
+    b = ("tcoo", None, None, None, 4)
+    tap.on_pass_start()
+    tap.train_batch(b)
+    assert tap.t_open is None and clog.phase == "warmup"
+    tap.on_pass_start()
+    tap.train_batch(b)
+    assert tap.t_open == 10.0 and tap.ends == [] and clog.phase == "window"
+    tap.train_batch(b)
+    tap.train_batch(b)
+    tap.train_batch(b)
+    assert clog.phase == "after" and tap.t_hist_close == 18.0
+    with pytest.raises(tp.WindowClosed):
+        tap.train_batch(b)         # the batch after the window ends the run
+    assert tap.ends == [12.5, 14.9, 18.0] and tap.rows == [4.0] * 3
+    assert tap.step_s == pytest.approx([2.5, 2.4, 3.1])
+    # the histograms are read on entry to the first counted step and on
+    # entry to the call after the last: the solver observes a step after it
+    # returns, so what they hold lies between t_open and t_hist_close and
+    # a share of that time cannot pass 100 %
+    assert tap.hist_open == {"h": (1, 0.0)}
+    assert tap.hist_close == {"h": (4, 0.0)}
+
+
+def test_gaps_include_the_opening_step_as_first_boundary():
+    assert window.gaps_ms(1.0, [1.5, 2.5]) == pytest.approx([500.0, 1000.0])
+
+
+@pytest.mark.parametrize("n,expect", [(1, 0), (20, 18), (100, 94),
+                                      (1000, 949)])
+def test_p95_is_nearest_rank(n, expect):
+    assert window.p95(list(range(n))) == expect
+
+
+def test_p95_of_nothing_raises():
+    with pytest.raises(ValueError):
+        window.p95([])
+
+
+# -------------------------------------------------------------- generator
+def test_generator_keys_are_the_criteo_formats_keys():
+    """The benchmark's own CityHash64 and field packing against the
+    program's parser, on the text the generator writes."""
+    from wormhole_tpu.data.parsers import parse_criteo, parse_text
+
+    rows = gen.Rows(KEYS, 3_000_000_019, gen.TRAIN_STREAM, 1, 512)  # > 2**31
+    text = rows.text().decode()
+    assert text.count("\n") == 512
+    for parse in (parse_criteo, lambda t: parse_text(t, "criteo")):
+        blk = parse(text)
+        assert blk.size == 512
+        assert np.array_equal(blk.index.reshape(512, gen.NNZ), rows.keys())
+        assert np.array_equal(blk.label, rows.label)
+
+
+def test_generator_same_seed_same_rows_other_seed_other_rows():
+    a = gen.Rows(KEYS, 5, 0, 0, 64)
+    b = gen.Rows(KEYS, 5, 0, 0, 64)
+    c = gen.Rows(KEYS, 6, 0, 0, 64)
+    assert a.text() == b.text() and a.text() != c.text()
+    assert 0.05 < gen.Rows(KEYS, 5, 0, 0, 4096).label.mean() < 0.6
+
+
+def test_hex_words_spell_percent_08x():
+    v = np.array([0, 0xdeadbeef, 0x0000000a, 0xffffffff], np.uint64)
+    got = [w.tobytes().decode() for w in gen.hex_words(v)]
+    assert got == ["%08x" % int(x) for x in v]
+
+
+def test_dataset_writes_crb_the_program_reads_back(tmp_path):
+    from wormhole_tpu.data.crb import read_crb
+
+    ds = gen.Dataset(str(tmp_path), KEYS, 9, "crb", 128, 2, 2, 1)
+    blocks = list(read_crb(str(tmp_path / "train-001.crb")))
+    keys = np.concatenate([b.index for b in blocks]).reshape(-1, gen.NNZ)
+    want = np.concatenate([ds.batch(1, j)[0] for j in range(2)])
+    assert np.array_equal(keys, want)
+    assert ds.by_label[ds.batch(1, 1)[1].tobytes()] == (1, 1)
+    assert ds.link_until(3 * ds.train_rows) == 2
+    assert len(list(tmp_path.glob("again-*.crb"))) == 4
+
+
+# ----------------------------------------------------------- kernel counts
+def test_kernel_counts_by_hand_for_one_shape():
+    b = {"rows": 65536, "nnz": 65536 * 39, "uniq": 1_000_000}
+    # gather: id + weight read, compact copy written
+    assert tile_gather.cost(b) == {"bytes": 12_000_000, "flops": 0.0}
+    # push: d once a row, (idx, seg, val) a nonzero, g a unique bucket
+    assert coo_push.cost(b) == {
+        "bytes": 65536 * 4 + 2555904 * 12 + 4_000_000,
+        "flops": 2.0 * 2555904}
+    assert coo_pull.cost(b)["bytes"] == 2555904 * 12 + 4_000_000 + 262144
+    # update: id + g read, z n w read and written
+    assert fused_update.cost(b) == {"bytes": 32_000_000,
+                                    "flops": 20_000_000.0}
+
+
+def test_roofline_reducer_takes_the_larger_bound_per_kernel():
+    from benchmark.reducers import kernel_roofline_share as rf
+
+    ctx = {"peaks": {"bytes_per_s": 819e9, "flops_per_s": 197e12},
+           "batch": {"rows": 65536, "nnz": 65536 * 39, "uniq": 1_000_000},
+           "kernels": [tile_gather, coo_push, fused_update],
+           "trace": {"ops": {"custom-call.1": [4, 0.040],
+                             "fusion.2": [4, 1.0]}},
+           "trace_steps": 4}
+    least, bound = rf.least_seconds(ctx)
+    assert bound == "bytes"
+    assert least == pytest.approx((12e6 + 34_933_056 + 32e6) / 819e9)
+    share = rf.read(ctx, pattern="custom-call")
+    assert share == pytest.approx(100 * least / 0.010)
+    assert share < 100
+    assert rf.read(dict(ctx, trace={"ops": {"fusion.2": [4, 1.0]}}),
+                   pattern="custom-call") is None
+
+
+# --------------------------------------------------------- compared numbers
+def _run(objv, z1, final, ids1, ids, nex=4.0):
+    return {"objv": objv, "nex": [nex] * len(objv), "ids1": ids1, "z1": z1,
+            "ids": ids, "final": final}
+
+
+def test_numbers_are_zero_for_equal_runs_and_catch_each_fault():
+    ids1, ids = np.arange(3), np.arange(5)
+    z1 = np.array([1.0, -2.0, 3.0], np.float32)
+    final = {"z": np.array([1, -2, 3, 4, 5], np.float32),
+             "n": np.array([1, 4, 9, 16, 25], np.float32),
+             "w": np.array([0, .1, -.2, .3, 0], np.float32)}
+    ref = _run([2.0, 1.5], z1, final, ids1, ids)
+    assert set(check.numbers(ref, ref).values()) == {0.0}
+    # a step that returns its state unchanged: the change's norm is gone
+    zero = {k: np.zeros_like(v) for k, v in final.items()}
+    n = check.numbers(_run([2.0, 1.5], z1 * 0, zero, ids1, ids), ref)
+    assert n["delta_norm_gap"] == pytest.approx(1.0)
+    assert n["grad_norm_gap"] == pytest.approx(1.0)
+    assert n["state_rel_l2"] == pytest.approx(1.0)
+    assert n["state_off_share"] == pytest.approx(1.0)
+    # a quarter of the batch left out of the loss
+    n = check.numbers(_run([1.5, 1.5], z1, final, ids1, ids), ref)
+    assert n["loss_gap"] == pytest.approx(0.25)
+    # a row the step did not count
+    n2 = check.numbers(_run([2.0, 1.5], z1, final, ids1, ids, nex=3.0), ref)
+    assert n2["loss_gap"] == float("inf")
+    ok, lines = check.verdict(n, {"loss_gap": 1e-3, "state_off_share": 1e-3})
+    assert not ok and "OVER" in lines[0] and "ok" in lines[1]
+    assert "printed, not compared" in lines[-1]
+
+
+def test_served_numbers_are_zero_for_equal_steps_and_catch_each_fault():
+    pre = {"z": np.array([1, -2, 3], np.float32),
+           "n": np.array([1, 4, 9], np.float32),
+           "w": np.array([0, .1, -.2], np.float32)}
+    post = {"z": np.array([2, -2.5, 3], np.float32),
+            "n": np.array([2, 4.25, 9], np.float32),
+            "w": np.array([-.05, .12, -.2], np.float32)}
+    ref = {"pre": pre, "post": post, "objv": 2.0, "nex": 4.0}
+    assert set(check.served_numbers(ref, ref).values()) == {0.0}
+    # a step that returns its state unchanged
+    n = check.served_numbers(dict(ref, post=pre), ref)
+    assert n["served_delta_gap"] == pytest.approx(1.0)
+    assert n["served_off_share"] == pytest.approx(2 / 3)
+    # a quarter of the batch left out of the loss; a row not counted
+    assert check.served_numbers(dict(ref, objv=1.5), ref)[
+        "served_loss_gap"] == pytest.approx(0.25)
+    assert check.served_numbers(dict(ref, nex=3.0), ref)[
+        "served_loss_gap"] == float("inf")
+    # tables kept in bfloat16: nearly every value is off by 2^-9 or so
+    low = {k: (v.view(np.uint32) & np.uint32(0xFFFF0000)).view(np.float32)
+           for k, v in {k: v * np.float32(1.003)
+                        for k, v in post.items()}.items()}
+    assert check.served_numbers(dict(ref, post=low), ref)[
+        "served_off_share"] > 0.6
+
+
+def test_reference_from_a_given_state_equals_its_own_continuation():
+    """`run_steps(..., start=)`: two steps from zero equal one step from
+    zero and one more from the state it left (on the second's buckets)."""
+    from benchmark.reference import linear_ftrl as ref
+
+    rows = [gen.Rows(KEYS, 31, 0, p, 64) for p in range(2)]
+    batches = [(r.keys(), r.label) for r in rows]
+    hyper = {"lr_eta": .1, "lr_beta": 1.0, "lambda_l1": .01, "lambda_l2": 0.0}
+    prec = {"tables": "f32", "pull_w": "bf16", "push_d": "bf16",
+            "push_g": "bf16"}
+    nb = 1 << 20
+    both = ref.run_steps(batches, nb, hyper, prec)
+    ids2 = np.unique(ref.bucket_ids(batches[1][0], nb))
+    pos = np.searchsorted(both["ids"], ids2)
+    start = {k: v[pos] for k, v in both["states"][0].items()}
+    one = ref.run_steps(batches[1:], nb, hyper, prec,
+                        start=dict(start, ids=ids2))
+    assert one["objv"][0] == pytest.approx(both["objv"][1], rel=1e-6)
+    for k in check.LEAVES:
+        np.testing.assert_allclose(one["states"][0][k],
+                                   both["states"][1][k][pos], rtol=1e-6)
+    with pytest.raises(ValueError):
+        ref.run_steps(batches[1:], nb, hyper, prec,
+                      start=dict(start, ids=ids2 + 1))
+
+
+# ------------------------------------------------------------ whole runs
+def test_run_py_refuses_to_measure_without_a_tpu(tmp_path):
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
+         "--workload", "criteo1tb.crb-stream", "--seed", "1", "--seconds",
+         "2", "--trace", "0"], capture_output=True, text=True, timeout=300,
+        env=_env(tmp_path), cwd=REPO)
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr and "Nothing was measured" in r.stderr
+    assert "{" not in r.stdout, r.stdout  # no result of any kind
+
+
+def test_run_py_fails_where_only_the_benchmark_is(tmp_path):
+    """In a directory that holds only BENCHMARK.json and the files under
+    `paths` there is no program: non-zero, and no result."""
+    import shutil
+
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(os.path.join(REPO, "benchmark"), tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    env = _env(tmp_path)
+    env.pop("PYTHONPATH")
+    r = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload",
+         "criteo1tb.crb-stream", "--seed", "1", "--seconds", "2",
+         "--trace", "0"], capture_output=True, text=True, timeout=300,
+        env=env, cwd=tmp_path)
+    assert r.returncode != 0
+    assert "cannot import the program" in r.stderr
+    assert "{" not in r.stdout
+
+
+@pytest.fixture(scope="module")
+def sound_run(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp("sound")
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "rehearse.py"),
+         "--workload", "criteo1tb.text-stream", "--seed", "2147483659",
+         "--seconds", "3"], capture_output=True, text=True, timeout=900,
+        env=_env(tmp, BENCH_RUN="3"), cwd=REPO)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    return r.stdout
+
+
+def test_last_line_is_the_contracts_object(sound_run):
+    out = json.loads(sound_run.splitlines()[-1])
+    assert set(out) == {"correct", "attempted", "failed", "metrics",
+                        "device"}
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] >= 2
+    # text-stream does not report batch_gap_p95_ms
+    assert set(out["metrics"]) == {"train_ex_per_s", "val_logloss",
+                                   "setup_s"}
+    for m in out["metrics"].values():
+        assert set(m) == {"value", "unit"} and m["value"] > 0
+    assert set(out["device"]) == {"platform", "kind", "count",
+                                  "memory_peak_bytes"}
+    # a rehearsal is named for what it is
+    assert out["device"]["platform"] == "cpu"
+
+
+def test_reference_agrees_with_the_learner_and_every_number_is_printed(
+        sound_run):
+    """Through run_minibatch_app / MinibatchSolver / loader threads /
+    train_batch at the rehearsal size, kernels interpreted."""
+    lines = [ln for ln in sound_run.splitlines() if "correct:" in ln]
+    for name in ("loss_gap", "grad_norm_gap", "delta_norm_gap",
+                 "state_off_share", "served_loss_gap", "served_delta_gap",
+                 "served_off_share"):
+        (ln,) = [x for x in lines if f" {name} = " in x]
+        assert "(limit" in ln and ln.rstrip().endswith("ok"), ln
+        # the norm of one step's change is a difference of two float32
+        # states many steps old: cancellation costs it some digits; and
+        # where z + g - sigma w nearly cancels, one bucket of the few
+        # thousand touched here can be off by more than 2^-12 of itself
+        assert float(ln.split(" = ")[1].split()[0]) < (
+            2e-3 if name in ("served_delta_gap", "state_off_share",
+                             "served_off_share") else 1e-5)
+    assert any("compilations inside the window = 0" in x for x in lines)
+    assert any("staged batch kinds ['tcoo']" in x for x in lines)
+    assert "native=loaded" in sound_run and "interpret mode" in sound_run
+
+
+_BROKEN = """
+import json
+import sys
+from benchmark import run
+from wormhole_tpu.apps import linear as app
+
+sound_from = int(sys.argv[1])     # the steps before this one stay sound
+make = app.make_learner
+
+def broken(cfg, env):
+    learner = make(cfg, env)
+    real, calls = learner.train_batch, [0]
+    def frozen(b):
+        import jax.numpy as jnp
+        calls[0] += 1
+        if calls[0] <= sound_from:
+            return real(b)
+        keep = {k: jnp.array(v) for k, v in learner.store.state.items()}
+        out = real(b)
+        learner.store.state = keep      # the step returns its state unchanged
+        return out
+    learner.train_batch = frozen
+    return learner
+
+app.make_learner = broken               # the program underneath, not run.py
+bench = run.load_json(run.ROOT, "BENCHMARK.json")
+out = run.run_cell(bench, "criteo1tb.replay", 11, 2.0, False, rehearsal=True)
+print(json.dumps(out))
+"""
+
+
+@pytest.mark.parametrize("sound_from,caught_by", [
+    (0, "delta_norm_gap"),            # broken from the first step on
+    (64, "served_delta_gap"),         # only once the pack cache serves
+])
+def test_a_broken_timed_path_comes_out_not_correct(tmp_path, sound_from,
+                                                   caught_by):
+    """The rest of a run, with the look for a chip skipped and a train
+    step underneath that returns its state unchanged: from the start, or
+    only after the fixed pass and the cache-filling pass (32 + 32 steps
+    here), where the first steps see nothing and the step followed after
+    the window, served from the pack cache, does."""
+    r = subprocess.run([sys.executable, "-c", _BROKEN, str(sound_from)],
+                       capture_output=True, text=True, timeout=900,
+                       env=_env(tmp_path), cwd=REPO)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    out = json.loads(r.stdout.splitlines()[-1])
+    assert out["correct"] is False
+    (ln,) = [x for x in r.stdout.splitlines() if f" {caught_by} = " in x]
+    assert ln.rstrip().endswith("OVER"), ln
+    if sound_from:
+        for name in ("loss_gap", "delta_norm_gap", "state_off_share"):
+            (ln,) = [x for x in r.stdout.splitlines() if f" {name} = " in x]
+            assert ln.rstrip().endswith("ok"), ln
+
+
+def test_the_control_fails_the_same_limits(tmp_path):
+    """The reference with bfloat16 tables in the program's place, at the
+    rehearsal size: every seed has to come out not correct."""
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "control.py"),
+         "--config", "linear-ftrl-criteo1tb", "--seeds", "21,22,23",
+         "--rehearsal", "1"], capture_output=True, text=True, timeout=600,
+        env=_env(tmp_path), cwd=REPO)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    assert r.stdout.count("correct=False") == 3
+    for name in ("state_off_share", "served_off_share"):
+        lines = [x for x in r.stdout.splitlines() if f" {name} = " in x]
+        assert len(lines) == 3 and all("OVER" in x for x in lines), name
