@@ -57,6 +57,30 @@ def synth_libsvm_text(n_rows=512, n_feat=1000, nnz_per_row=8, seed=0,
     return "\n".join(lines) + "\n"
 
 
+def profiled_spans(logdir, prefixes=("data.", "loader.", "solver.", "step.",
+                                    "test.")):
+    """The program's spans in the newest JAX profile under `logdir`, as
+    dicts(name, start, end, thread, args) sorted by start; `thread`
+    numbers the host line (one per OS thread) the span lies on."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    pbs = sorted(glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"),
+                           recursive=True))
+    assert pbs, f"no profile under {logdir}"
+    out, thread = [], 0
+    for plane in ProfileData.from_file(pbs[-1]).planes:
+        for line in plane.lines:
+            thread += 1
+            for e in line.events:
+                if e.name.startswith(prefixes):
+                    out.append(dict(name=e.name, start=e.start_ns,
+                                    end=e.start_ns + e.duration_ns,
+                                    thread=thread, args=dict(e.stats)))
+    return sorted(out, key=lambda s: (s["start"], -s["end"]))
+
+
 @pytest.fixture
 def synth_libsvm_file(tmp_path):
     p = tmp_path / "synth.libsvm"

@@ -344,8 +344,10 @@ def test_obs_smoke_linear_job(tmp_path, retrace):
     assert report["run_id"] == "smoke-run"
     assert set(report) >= {"summary", "counters", "gauges", "hists",
                            "nodes"}
-    # the solver's Perf mirror put step timings in the registry
-    assert any(k.startswith("perf.") for k in report["hists"])
+    # the pass loop's per-batch timings are the train.stage.* histograms
+    # alone: it feeds no `perf.*` mirror beside them
+    assert report["hists"]["train.stage.step_s"]["count"] == 4
+    assert not any(k.startswith("perf.") for k in report["hists"])
     # training-step stage attribution: the train thread's pipeline
     # stages (load + step + metrics) must explain the per-batch wall
     tstages = report["train_stages"]

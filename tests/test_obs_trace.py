@@ -16,6 +16,8 @@ from wormhole_tpu.obs import prom as obs_prom
 from wormhole_tpu.obs import trace as obs_trace
 from wormhole_tpu.runtime.net import recv_frame, send_frame
 
+from conftest import profiled_spans
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -195,6 +197,94 @@ def test_tracing_off_is_null_on_every_hook(retrace):
             f.close()
         a.close()
         b.close()
+
+
+def test_no_sink_means_the_shared_noop_and_no_clock_read(retrace,
+                                                        monkeypatch):
+    """With the JSONL tracer, the flight ring and the profiler all off,
+    span() is one object for every caller and reads no clock — `cpu`
+    asked for or not."""
+    retrace.delenv("WH_OBS_DIR", raising=False)
+    assert obs_trace.init_from_env() is None
+    assert not obs_trace._profiling()
+
+    def no_clock():
+        raise AssertionError("a clock was read with no sink on")
+
+    monkeypatch.setattr(obs_trace.time, "monotonic", no_clock)
+    monkeypatch.setattr(obs_trace.time, "thread_time", no_clock)
+    with obs_trace.span("test.off", cpu=True, part=1) as sp:
+        sp.set(rows=3)
+    assert sp is obs_trace._NULL_SPAN
+    assert obs_trace.span("test.off2") is sp
+
+
+def test_span_lands_in_a_running_profiler_session(tmp_path, retrace):
+    """The third sink: under a jax.profiler session a span is a
+    TraceAnnotation in the host plane, with the arguments given at entry,
+    the ones set inside the block and its thread CPU time — from the
+    main thread and from a worker thread, each on its own line."""
+    import jax
+
+    retrace.delenv("WH_OBS_DIR", raising=False)
+    assert obs_trace.init_from_env() is None
+
+    def burn(tag):
+        with obs_trace.span("test.burn", cat="t", cpu=True, part=tag) as sp:
+            n = sum(range(200_000))
+            sp.set(rows=7, kind="tcoo")
+        return n
+
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        assert obs_trace._profiling()
+        burn(1)
+        t = threading.Thread(target=burn, args=(2,))
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        with obs_trace.span("test.plain"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert obs_trace.span("test.after") is obs_trace._NULL_SPAN
+    spans = profiled_spans(tmp_path / "prof")
+    burns = {s["args"]["part"]: s for s in spans if s["name"] == "test.burn"}
+    assert set(burns) == {1, 2}
+    assert burns[1]["thread"] != burns[2]["thread"]
+    for s in burns.values():
+        assert s["args"]["rows"] == 7 and s["args"]["kind"] == "tcoo"
+        wall_us = (s["end"] - s["start"]) / 1e3
+        # the loop is pure CPU: most of the wall, and never more than it
+        assert 0 < s["args"]["cpu_us"] <= wall_us + 1000
+    plain = next(s for s in spans if s["name"] == "test.plain")
+    assert "cpu_us" not in plain["args"]
+
+
+def test_profiler_sink_composes_with_the_jsonl_tracer(tmp_path, retrace):
+    import jax
+
+    retrace.setenv("WH_OBS_DIR", str(tmp_path / "obs"))
+    tracer = obs_trace.init_from_env()
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        with obs_trace.span("test.both", cpu=True, part=4):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (both,) = [s for s in _spans(_trace_lines(tracer))
+               if s["name"] == "test.both"]
+    assert both["args"]["part"] == 4 and "cpu_us" in both["args"]
+    (prof,) = [s for s in profiled_spans(tmp_path / "prof")
+               if s["name"] == "test.both"]
+    assert prof["args"]["part"] == 4
+    assert prof["args"]["cpu_us"] == both["args"]["cpu_us"]
+
+
+def test_maybe_trace_is_off_without_its_variable(monkeypatch):
+    monkeypatch.delenv("WORMHOLE_PROFILE_DIR", raising=False)
+    with obs_trace.maybe_trace():
+        assert not obs_trace._profiling()
 
 
 # ------------------------------------------------------------- lifecycle

@@ -3,6 +3,7 @@ with fake workloads, SURVEY §4), full solver loop, checkpoint/resume,
 predict output."""
 
 import os
+import re
 import time
 
 import numpy as np
@@ -14,7 +15,7 @@ from wormhole_tpu.solver.minibatch_solver import MinibatchSolver
 from wormhole_tpu.solver.workload import WorkloadPool, WorkType
 from wormhole_tpu.utils import checkpoint as ckpt
 
-from conftest import synth_libsvm_text
+from conftest import profiled_spans, synth_libsvm_text
 
 
 # ------------------------------------------------------------- pool logic
@@ -242,9 +243,12 @@ def test_checkpoint_missing_raises(tmp_path):
 
 def test_perf_accounting_and_pass_summary(tmp_path, capsys):
     """The solver logs FinishMinibatch-style pass summaries (avg step
-    time + io/comm overhead share, reference minibatch_solver.h:246-275)
-    and classifies op timings difacto-Perf-style (async_sgd.h:108-127)."""
+    time + io/comm overhead share, reference minibatch_solver.h:246-275);
+    its per-batch accounting is the train.stage.* histograms alone — the
+    pass loop calls `Perf` no more — and `Perf` classifies the PS plane's
+    op timings difacto-style (async_sgd.h:108-127)."""
     from wormhole_tpu.models.linear import LinearConfig, LinearLearner
+    from wormhole_tpu.obs.metrics import REGISTRY
     from wormhole_tpu.solver.minibatch_solver import MinibatchSolver
     from wormhole_tpu.utils.perf import Perf
 
@@ -254,13 +258,19 @@ def test_perf_accounting_and_pass_summary(tmp_path, capsys):
     cfg = LinearConfig(train_data=str(p).replace(".libsvm", r"\.libsvm"),
                        minibatch=128, num_buckets=1 << 10, nnz_per_row=16,
                        max_data_pass=1)
+    stages = {k: REGISTRY.histogram(f"train.stage.{k}_s")
+              for k in ("load", "pack", "h2d", "step", "metrics")}
+    before = {k: (h.count, h.sum) for k, h in stages.items()}
     solver = MinibatchSolver(LinearLearner(cfg), cfg, verbose=True)
     solver.run()
     out = capsys.readouterr().out
     assert "io/comm overhead" in out and "ms/step" in out
-    assert solver.perf.count("train_step") > 0
-    assert solver.perf.count("wait") > 0
-    assert solver.perf.mean_ms("train_step") > 0
+    n = int(re.search(r"train pass 0: (\d+) minibatches", out).group(1))
+    assert n >= 5                                   # 600 rows / 128
+    for k, h in stages.items():
+        assert h.count - before[k][0] == n, k
+    assert stages["step"].sum > before["step"][1]
+    assert solver.perf.snapshot() == ({}, {})
 
     # Perf unit behavior: periodic row logging
     rows = []
@@ -274,12 +284,71 @@ def test_profile_trace_env(tmp_path, monkeypatch):
     """WORMHOLE_PROFILE_DIR wraps the run in a JAX profiler trace."""
     import os
 
-    from wormhole_tpu.utils.perf import maybe_trace
+    from wormhole_tpu.obs.trace import maybe_trace
 
     out = tmp_path / "trace"
     monkeypatch.setenv("WORMHOLE_PROFILE_DIR", str(out))
     import jax.numpy as jnp
-    with maybe_trace("t"):
+    with maybe_trace():
         float(jnp.sum(jnp.arange(8.0)))
     files = [os.path.join(r, f) for r, _, fs in os.walk(out) for f in fs]
     assert files, "no profiler output written"
+
+
+def test_training_spans_in_the_device_profile(tmp_path, monkeypatch):
+    """A solver run under WORMHOLE_PROFILE_DIR: the nine spans of the
+    training path lie in the profile's host plane, each on the thread
+    that does the work, and the spans of one batch share (part, i)."""
+    p = tmp_path / "d.libsvm"
+    p.write_text(synth_libsvm_text(n_rows=640, n_feat=100, nnz_per_row=8,
+                                   seed=5))
+    cfg = LinearConfig(train_data=str(p).replace(".libsvm", r"\.libsvm"),
+                       minibatch=64, num_buckets=1 << 10, nnz_per_row=16,
+                       max_data_pass=1, num_parts_per_file=2)
+    solver = MinibatchSolver(LinearLearner(cfg), cfg, num_loaders=2,
+                             verbose=False)
+    monkeypatch.setenv("WORMHOLE_PROFILE_DIR", str(tmp_path / "prof"))
+    prog = solver.run()["train"]
+    spans = profiled_spans(tmp_path / "prof")
+    by = {}
+    for s in spans:
+        by.setdefault(s["name"], []).append(s)
+    steps = by["solver.train_step"]
+    n = len(steps)
+    assert n >= 10 and prog.value("nex") == 640
+    for name in ("loader.pack", "loader.h2d", "step.dispatch", "step.fetch",
+                 "solver.merge"):
+        assert len(by[name]) == n, name
+    assert len(by["solver.queue_wait"]) >= n      # the end markers too
+    # read + parse work in chunks, not batches: both parts were read and
+    # every row came out of a parse span
+    assert {s["args"]["part"] for s in by["data.parse"]} == {0, 1}
+    assert sum(s["args"]["rows"] for s in by["data.parse"]) == 640
+    assert sum(s["args"]["bytes"] for s in by["data.read"]
+               if "bytes" in s["args"]) == len(p.read_text())
+    for s in by["data.read"] + by["data.parse"] + by["loader.pack"]:
+        assert 0 <= s["args"]["cpu_us"] <= (s["end"] - s["start"]) / 1e3 + 1e3
+
+    # threads: one train thread, loader threads, parser threads, disjoint
+    (train,) = {s["thread"] for s in by["solver.train_pass"]}
+    for name in ("solver.queue_wait", "solver.train_step", "step.dispatch",
+                 "step.fetch", "solver.merge"):
+        assert {s["thread"] for s in by[name]} == {train}, name
+    loaders = {s["thread"] for s in by["loader.pack"] + by["loader.h2d"]}
+    parsers_ = {s["thread"] for s in by["data.read"] + by["data.parse"]}
+    assert train not in loaders | parsers_ and not loaders & parsers_
+
+    # a step holds its dispatch, then its fetch, and nothing overlaps
+    for st, d, f in zip(steps, by["step.dispatch"], by["step.fetch"]):
+        assert st["start"] <= d["start"] <= d["end"] <= f["start"]
+        assert f["end"] <= st["end"]
+        assert d["args"]["kind"] in ("xla", "coo", "tcoo", "mcoo")
+    # (part, i) joins a step to the one pack and the one h2d of its batch
+    packs = {(s["args"]["part"], s["args"]["i"]): s
+             for s in by["loader.pack"]}
+    h2ds = {(s["args"]["part"], s["args"]["i"]) for s in by["loader.h2d"]}
+    keys = [(s["args"]["part"], s["args"]["i"]) for s in steps]
+    assert len(set(keys)) == n and set(keys) == set(packs) == h2ds
+    for key, st in zip(keys, steps):
+        assert packs[key]["end"] <= st["start"]
+        assert packs[key]["args"]["rows"] <= 64

@@ -8,10 +8,17 @@ BLK=4096, BLK_U=1024, FM_BLK=1024; 65,536 x 39 batches; bf16 and f32). A
 Mosaic rejection — a VMEM limit, a block shape, an op it no longer lowers —
 fails here, on the CPU, instead of on chip time.
 
+Each kernel also has to reach the compiled program under its own name
+(`pallas_call(name=...)` becomes the HLO instruction's name, which is
+what a device trace calls the operation): the per-kernel metrics of the
+benchmark match on `%tile_gather`, `%fused_update`, `%coo_push`.
+
 Kernel-only programs on purpose: a whole train step adds the AUC sort,
 which alone compiles for ~25-50 s (PERF.md §6, PR 21). There is one
 installation, so a topology that cannot be built is a failure, not a skip.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -52,13 +59,18 @@ def compiled_not_interpreted(monkeypatch):
                                                          1024)
 
 
-def aot(fn, sharding, *shapes):
-    """Compile fn for the described chip; shapes are (shape, dtype)."""
+def aot(kernel, fn, sharding, *shapes):
+    """Compile fn for the described chip; shapes are (shape, dtype).
+    `kernel` is the name its one Pallas kernel has to carry."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
             for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    # Mosaic really ran: an interpreted kernel leaves no custom call
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    # Mosaic really ran (an interpreted kernel leaves no custom call),
+    # and the instruction is named for the kernel, not for the jit
+    calls = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target="
+                       r'"tpu_custom_call"', text, re.M)
+    assert calls and all(re.fullmatch(re.escape(kernel) + r"(\.\d+)*", c)
+                         for c in calls), (kernel, calls)
 
 
 def coo_stream(capacity, num_buckets, tile=None, blk=None):
@@ -78,20 +90,22 @@ def slot_blocks(u_cap, n):
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_coo_pull_push_dense(v5e, dtype):
     stream = coo_stream(CAP, NB_DENSE)
-    aot(lambda w, *s: ck.coo_spmv(w, *s, ROWS, dtype=dtype), v5e,
-        ((NB_DENSE,), jnp.float32), *stream)
-    aot(lambda d, *s: ck.coo_spmv_t(d, *s, NB_DENSE, dtype=dtype), v5e,
-        ((ROWS,), jnp.float32), *stream)
+    aot("coo_pull", lambda w, *s: ck.coo_spmv(w, *s, ROWS, dtype=dtype),
+        v5e, ((NB_DENSE,), jnp.float32), *stream)
+    aot("coo_push",
+        lambda d, *s: ck.coo_spmv_t(d, *s, NB_DENSE, dtype=dtype),
+        v5e, ((ROWS,), jnp.float32), *stream)
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_compacted_linear_kernels(v5e, dtype):
     f32, i32 = jnp.float32, jnp.int32
     table2 = ((NB_BIG // ck.LANES, ck.LANES), f32)
-    aot(lambda t, u, tm: ck.tile_gather(t, u, tm, dtype=dtype), v5e,
-        table2, ((U_CAP,), i32), *slot_blocks(U_CAP, 1))
-    aot(lambda d, *s: ck.coo_spmv_t(d, *s, U_CAP, dtype=dtype), v5e,
-        ((ROWS,), f32), *coo_stream(CAP, U_CAP))
+    aot("tile_gather",
+        lambda t, u, tm: ck.tile_gather(t, u, tm, dtype=dtype),
+        v5e, table2, ((U_CAP,), i32), *slot_blocks(U_CAP, 1))
+    aot("coo_push", lambda d, *s: ck.coo_spmv_t(d, *s, U_CAP, dtype=dtype),
+        v5e, ((ROWS,), f32), *coo_stream(CAP, U_CAP))
 
     def update(z, n, w, g, uniq, tm, fi, la):
         return fu.scatter_update(
@@ -99,8 +113,8 @@ def test_compacted_linear_kernels(v5e, dtype):
             lr_eta=0.1, lr_beta=1.0, lambda_l1=1.0, lambda_l2=0.0,
             dtype=dtype)
 
-    aot(update, v5e, *[((NB_BIG,), f32)] * 3, ((U_CAP,), f32),
-        ((U_CAP,), i32), *slot_blocks(U_CAP, 3))
+    aot("fused_update", update, v5e, *[((NB_BIG,), f32)] * 3,
+        ((U_CAP,), f32), ((U_CAP,), i32), *slot_blocks(U_CAP, 3))
 
 
 @pytest.mark.parametrize("algo,tables", [("adagrad", 2), ("sgd", 1)])
@@ -114,8 +128,8 @@ def test_fused_update_other_handles(v5e, algo, tables):
                                  lr_beta=1.0, lambda_l1=1.0, lambda_l2=0.0,
                                  fixed_bytes=1, dtype=jnp.bfloat16)
 
-    aot(update, v5e, *[((NB_BIG,), f32)] * tables, ((U_CAP,), f32),
-        ((U_CAP,), i32), *slot_blocks(U_CAP, 3))
+    aot("fused_update", update, v5e, *[((NB_BIG,), f32)] * tables,
+        ((U_CAP,), f32), ((U_CAP,), i32), *slot_blocks(U_CAP, 3))
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
@@ -123,18 +137,21 @@ def test_fm_kernels(v5e, dtype):
     f32, i32 = jnp.float32, jnp.int32
     vflat = ((VB * DIM,), f32)
     v2 = ((VB * DIM // ck.LANES, ck.LANES), f32)
-    aot(lambda t, u, tm: fu.row_tile_gather(t, u, tm, DIM, dtype=dtype),
+    aot("row_gather",
+        lambda t, u, tm: fu.row_tile_gather(t, u, tm, DIM, dtype=dtype),
         v5e, v2, ((UV_CAP,), i32), *slot_blocks(UV_CAP, 1))
 
     idx, _, _, tmap, first = coo_stream(CAP, UV_CAP, ck.TILE_HI, ck.FM_BLK)
     p = idx[0][0]
     wire = dtype  # a/b arrive at the gather wire dtype (difacto._build_fm)
-    aot(lambda V, a, b, si, tm, fi: ck.fm_push_contrib(
+    aot("fm_push_contrib",
+        lambda V, a, b, si, tm, fi: ck.fm_push_contrib(
             V, a, b, si, tm, fi, dtype=dtype),
         v5e, ((UV_CAP, DIM), f32), ((p, DIM), wire), ((p,), wire), idx,
         tmap, first)
 
-    aot(lambda V, nV, g, tch, u, tm, fi, la: fu.v_scatter_update(
+    aot("v_update",
+        lambda V, nV, g, tch, u, tm, fi, la: fu.v_scatter_update(
             V, nV, g, tch, u, tm, fi, la, dim=DIM, V_lr_eta=0.01,
             V_lr_beta=1.0, lambda_V=0.01, dtype=dtype),
         v5e, vflat, vflat, ((UV_CAP, DIM), f32), ((UV_CAP,), f32),
@@ -147,12 +164,14 @@ def test_fm_kernels(v5e, dtype):
             la, lr_eta=0.1, lr_beta=1.0, lambda_l1=1.0, lambda_l2=0.0,
             dtype=dtype, add_table="cnt", add_values=wcnts)
 
-    aot(update, v5e, *[((NB_DENSE,), f32)] * 4, ((UW_CAP,), f32),
-        ((UW_CAP,), i32), *slot_blocks(UW_CAP, 3), ((UW_CAP,), f32))
+    aot("fused_update", update, v5e, *[((NB_DENSE,), f32)] * 4,
+        ((UW_CAP,), f32), ((UW_CAP,), i32), *slot_blocks(UW_CAP, 3),
+        ((UW_CAP,), f32))
 
 
 def test_gbdt_histogram(v5e):
     rows, F, B, nodes = 1 << 21, 28, 256, 32   # HIGGS shape, a deep level
-    aot(lambda b, g, h, rel: hist.level_hist(b, g, h, rel, nodes, B), v5e,
+    aot("level_hist",
+        lambda b, g, h, rel: hist.level_hist(b, g, h, rel, nodes, B), v5e,
         ((rows, F), jnp.uint8), ((rows,), jnp.float32),
         ((rows,), jnp.float32), ((rows,), jnp.int32))

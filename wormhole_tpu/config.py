@@ -568,7 +568,8 @@ declare_knob("WORMHOLE_NATIVE_LIB", str, None,
              "Explicit path to the native library (overrides discovery).",
              group="debug")
 declare_knob("WORMHOLE_PROFILE_DIR", str, None,
-             "Directory for utils/perf.py profile dumps.", group="debug")
+             "Directory for the JAX profiler trace (obs/trace.maybe_trace).",
+             group="debug")
 
 # tools (cross-tool knobs owned by the core registry)
 declare_knob("WH_CRITEO_DIR", str, "data",

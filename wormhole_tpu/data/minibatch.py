@@ -23,15 +23,40 @@ from wormhole_tpu.data import parsers
 def _iter_rowblocks(
     filename: str, part: int, num_parts: int, fmt: str
 ) -> Iterator[RowBlock]:
-    if fmt == "crb":
+    """Parsed blocks of one file part. Each pull on the source is a
+    `data.read` span (a text chunk off the file, or one crb record read,
+    inflated and decoded) and each `parse_text` a `data.parse` span, on
+    whichever thread iterates (the ThreadedParser's); none is open
+    across a `yield`. `bytes` counts what the step produced: characters
+    of text, or the decoded record's array bytes."""
+    # not at import: `import wormhole_tpu` reaches this module, and a
+    # process with telemetry off imports no obs (tests/test_obs.py)
+    from wormhole_tpu.obs import trace as _trace
+
+    text = fmt != "crb"
+    if text:
+        src = parsers.iter_file_chunks(filename, part, num_parts)
+    else:
         from wormhole_tpu.data import crb
 
-        yield from crb.read_crb(filename, part, num_parts)
-        return
-    for chunk in parsers.iter_file_chunks(filename, part, num_parts):
-        blk = parsers.parse_text(chunk, fmt)
-        if blk.size:
-            yield blk
+        src = crb.read_crb(filename, part, num_parts)
+    while True:
+        with _trace.span("data.read", cat="data", cpu=True,
+                         part=part) as sp:
+            got = next(src, None)
+            if got is None:
+                return
+            if text:
+                sp.set(bytes=len(got))
+            else:
+                sp.set(bytes=got.nbytes, rows=got.size)
+        if text:
+            with _trace.span("data.parse", cat="data", cpu=True,
+                             part=part, bytes=len(got)) as sp:
+                got = parsers.parse_text(got, fmt)
+                sp.set(rows=got.size)
+        if got.size:
+            yield got
 
 
 #: end-of-stream marker on the ThreadedParser queue

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from wormhole_tpu.data.rowblock import DeviceBatch, RowBlock, to_device_batch
+from wormhole_tpu.obs import trace as _trace
 from wormhole_tpu.ops import coo_kernels as ck
 from wormhole_tpu.ops import metrics as M
 from wormhole_tpu.ops.penalty import l1l2_solve
@@ -694,6 +695,20 @@ class LinearLearner:
         return {k: u for k in self.store.state}
 
     def train_batch(self, blk) -> dict:
+        # two spans, so that a device profile can tell a late dispatch
+        # from a late return out of the blocking fetch (PERF.md §5: on
+        # the chip it is the fetch the device idles under)
+        with _trace.span("step.dispatch", cat="step") as sp:
+            kind, prog = self._dispatch_train(blk)
+            sp.set(kind=kind)
+        with _trace.span("step.fetch", cat="step"):
+            # one host round trip per scalar of prog: blocks until the
+            # device has finished the step
+            return jax.tree_util.tree_map(float, prog)
+
+    def _dispatch_train(self, blk):
+        """Enqueue one train step; returns (batch kind, the step's
+        progress scalars still on the device)."""
         b = self._prepared(blk)
         if self.track_touched:
             self._note_touched(b)
@@ -706,7 +721,7 @@ class LinearLearner:
             if step is None:  # tcoo builds lazily
                 step = self._tcoo_steps[0]
             self.store.state, prog = step(self.store.state, *args)
-            return jax.tree_util.tree_map(float, prog)
+            return kind, prog
         if b[0] == "mcoo":
             _, mc, label, mask, _ = b
             self.store.state, prog = self._train_step_mcoo(
@@ -725,7 +740,7 @@ class LinearLearner:
             self.store.state, prog = self._train_step(
                 self.store.state,
                 *self._shard(db.seg, db.idx, db.val, db.label, db.row_mask))
-        return jax.tree_util.tree_map(float, prog)
+        return b[0], prog
 
     def eval_batch(self, blk) -> dict:
         b = self._prepared(blk)

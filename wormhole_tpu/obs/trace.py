@@ -48,13 +48,24 @@ out a fresh context for every ``WH_TRACE_SAMPLE``-th call (1 = every
 request, 0 = off), so a replayed run samples the same requests and the
 hot path for unsampled requests is one counter bump. With tracing off
 entirely, every hook is a single ``ACTIVE is None`` check.
+
+The device profile is the third sink. While a JAX profiler session runs
+— ``maybe_trace`` below (``WORMHOLE_PROFILE_DIR``) or anybody's
+``start_trace`` — every ``span()`` also enters a ``TraceAnnotation`` of
+its name, its arguments as the annotation's metadata, so the program's
+spans lie in the ``.xplane.pb`` on the clock of the device's own
+operations and an idle gap on the chip can be laid against what each
+host thread was doing. A process that never imported JAX is not made to,
+and with no session the cost is one ``is_enabled()`` call.
 """
 
 from __future__ import annotations
 
 import atexit
+import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from typing import Optional
@@ -163,6 +174,24 @@ class Tracer:
 ACTIVE: Optional[Tracer] = None
 
 
+#: jax.profiler.TraceAnnotation, once JAX has been imported by somebody
+_ANNOTATION = None
+
+
+def _profiling() -> bool:
+    """True while a JAX profiler session runs in this process. No
+    session can run before `jax.profiler` was imported, so a process
+    without JAX is answered from `sys.modules` and never imports it."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is None:
+            return False
+        ann = _ANNOTATION = prof.TraceAnnotation
+    return ann.is_enabled()
+
+
 class _NullSpan:
     __slots__ = ()
 
@@ -172,23 +201,42 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "cat", "args", "t0", "_ctx", "_saved")
+    __slots__ = ("tracer", "name", "cat", "args", "t0", "_ctx", "_saved",
+                 "_ann", "_cpu", "_cpu0")
 
     def __init__(self, tracer: Optional[Tracer], name: str, cat: str,
-                 args: dict):
+                 args: dict, profiled: bool = False, cpu: bool = False):
         # tracer may be None: the span then only feeds the flight
-        # recorder (no file, no trace context — those need a Tracer)
+        # recorder and/or the device profile (no file, no trace context
+        # — those need a Tracer)
         self.tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = _ANNOTATION(name, **args) if profiled else None
+        self._cpu = cpu
+
+    def set(self, **args) -> None:
+        """Arguments known only inside the block (rows parsed, the
+        batch's kind); they reach every sink like the ones given at
+        entry."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._cpu:
+            self._cpu0 = time.thread_time()
         cur = getattr(_TLS, "ctx", None)
         if cur is not None and self.tracer is not None:
             sid = self.tracer.next_sid()
@@ -203,8 +251,14 @@ class _Span:
 
     def __exit__(self, etype, exc, tb):
         dur = time.monotonic() - self.t0
+        if self._cpu:
+            # this thread's CPU time inside the block: what is left of
+            # the wall is waiting (the interpreter lock, I/O, a queue)
+            self.set(cpu_us=round(1e6 * (time.thread_time() - self._cpu0)))
         if etype is not None:
-            self.args = dict(self.args or {}, error=etype.__name__)
+            self.set(error=etype.__name__)
+        if self._ann is not None:
+            self._ann.__exit__(etype, exc, tb)
         if self._ctx is not None:
             _TLS.ctx = self._saved
         if self.tracer is not None:
@@ -236,15 +290,19 @@ class _Bind:
         return False
 
 
-def span(name: str, cat: str = "span", **args):
-    """Context manager timing a block into the trace. When both tracing
-    and the flight recorder are off this returns a shared no-op object —
-    no allocation, no clock read — so it is safe on hot paths. With only
-    the flight recorder on, the span lands in its in-memory ring."""
+def span(name: str, cat: str = "span", cpu: bool = False, **args):
+    """Context manager timing a block into every sink that is on: the
+    JSONL tracer, the flight recorder's in-memory ring, the running JAX
+    profiler session. With none on this returns a shared no-op object —
+    no allocation, no clock read — so it is safe on hot paths. `cpu`
+    asks for the thread's CPU time as well (argument `cpu_us`). Never
+    hold a span open across a `yield`: the consumer's time is not the
+    block's."""
     t = ACTIVE
-    if t is None and _flight.ACTIVE is None:
+    profiled = _profiling()
+    if t is None and _flight.ACTIVE is None and not profiled:
         return _NULL_SPAN
-    return _Span(t, name, cat, args)
+    return _Span(t, name, cat, args, profiled, cpu)
 
 
 def request_span(name: str, cat: str = "span", **args):
@@ -318,6 +376,22 @@ def bind_wire(header: dict):
     if not isinstance(tc, dict) or "t" not in tc:
         return _Bind(None)
     return _Bind((tc["t"], tc.get("s")))
+
+
+@contextlib.contextmanager
+def maybe_trace():
+    """Wrap a region in a JAX profiler trace when WORMHOLE_PROFILE_DIR is
+    set; no-op (and no jax import) otherwise. Every `span()` entered
+    while it runs lands in that trace."""
+    out = os.environ.get("WORMHOLE_PROFILE_DIR")
+    if not out:
+        yield
+        return
+    import jax
+
+    os.makedirs(out, exist_ok=True)
+    with jax.profiler.trace(out):
+        yield
 
 
 def node_id() -> str:

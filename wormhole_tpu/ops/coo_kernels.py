@@ -333,6 +333,7 @@ def coo_spmv(w, sidx, sseg, sval, tmap, first, num_rows: int, dtype=None):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
+        name="coo_pull",
     )(tmap, first, w, sidx, sseg, sval)
     return out.reshape(num_rows)
 
@@ -396,6 +397,7 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
+        name="coo_push",
     )(tmap, first, d2, sidx, sseg, sval)
     return out.reshape(num_buckets)
 
@@ -611,6 +613,7 @@ def tile_gather(table2, uniq, tmap_u, dtype=None):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
+        name="tile_gather",
     )(tmap_u, table2, uniq)
 
 
@@ -700,6 +703,7 @@ def fm_push_contrib(V, a, b, sidx, tmap, first, dtype=None):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_FM_VMEM_LIMIT),
         interpret=_use_interpret(),
+        name="fm_push_contrib",
     )(tmap, first, V, ab, sidx)
 
 

@@ -217,6 +217,7 @@ def row_tile_gather(flat2, uniq_rows, tmap_u, dim: int, dtype=None):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
+        name="row_gather",
     )(tmap_u, flat2, uniq_rows)
 
 
@@ -320,6 +321,7 @@ def v_scatter_update(Vflat, nVflat, gV, vtouched, uniq_rows, tmap_u,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
+        name="v_update",
     )(tmap_u, first_u, last_u, gV, vtouched, uniq_rows, V2, nV2)
     return Vn.reshape(Vflat.shape), nVn.reshape(nVflat.shape)
 
@@ -396,6 +398,7 @@ def scatter_update(algo: str, state: dict, g, uniq, tmap_u, first_u,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
+        name="fused_update",
     )(tmap_u, first_u, last_u, qscale, g, uniq, *add_args, *tabs)
     new_tabs, nw = outs[:-1], outs[-1]
     new_state = dict(state)

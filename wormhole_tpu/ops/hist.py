@@ -120,6 +120,7 @@ def level_hist(binned, g, h, rel, num_nodes: int, B: int):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 2**20),
         interpret=_use_interpret(),
+        name="level_hist",
     )(s, binned)
     G = (out[:nodes_p] + out[nodes_p:2 * nodes_p])[:num_nodes]
     H = (out[2 * nodes_p:3 * nodes_p] + out[3 * nodes_p:])[:num_nodes]

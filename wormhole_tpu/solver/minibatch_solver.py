@@ -33,7 +33,7 @@ from wormhole_tpu.obs.metrics import REGISTRY
 from wormhole_tpu.solver.progress import Progress
 from wormhole_tpu.solver.workload import WorkloadPool, WorkType
 from wormhole_tpu.utils import checkpoint as ckpt
-from wormhole_tpu.utils.perf import Perf, maybe_trace
+from wormhole_tpu.utils.perf import Perf
 
 
 def _env_flag(name: str, default: bool) -> bool:
@@ -178,6 +178,10 @@ _POOL = REGISTRY.gauge("loader.pool_size")
 # wall per batch splits into load (queue wait) + step (jitted call) +
 # metrics (merge/print); pack and h2d run in loader threads overlapped
 # with compute, and sync_s is observed by the PS client sync paths.
+# Each boundary is also a span (obs/names.py: loader.pack, loader.h2d,
+# solver.queue_wait, solver.*_step, solver.merge) that a running device
+# profile lays beside the chip's own operations; the spans of one batch
+# share (part, i), the part's id and the batch's index in it.
 _ST_LOAD = REGISTRY.histogram("train.stage.load_s")
 _ST_PACK = REGISTRY.histogram("train.stage.pack_s")
 _ST_H2D = REGISTRY.histogram("train.stage.h2d_s")
@@ -234,8 +238,9 @@ class MinibatchSolver:
         # leave those reading a half-merged model; None in single-process
         # runs (no PS plane) and the distributed runner wires it up
         self.sync_flush: Optional[Callable] = None
-        # per-op perf accounting (reference minibatch_solver.h:246-275 +
-        # difacto async_sgd.h:108-127 style)
+        # per-op accounting for the PS plane's sync paths (difacto
+        # async_sgd.h:108-127 style; apps/_runner.py hands it on). The
+        # pass loop itself keeps the train.stage.* histograms and spans
         self.perf = Perf(log=self._log)
         cache_desc = "off"
         if self.pack_cache is not None:
@@ -266,7 +271,7 @@ class MinibatchSolver:
             ckpt.load_model(self._ckpt_store, cfg.model_in,
                             cfg.load_iter if cfg.load_iter >= 0 else None)
         result = {}
-        with maybe_trace("minibatch_solver"):
+        with _trace.maybe_trace():
             result = self._run_passes(cfg)
         if _report.enabled() and not os.environ.get("WH_ROLE"):
             # single-process run: no scheduler to aggregate, so this
@@ -385,6 +390,8 @@ class MinibatchSolver:
                             seed=data_pass * 7919 + part_id,
                         )
 
+                    i = 0   # batches of this part delivered so far
+
                     def prep(blk):
                         # host-side batch prep (padding + pallas
                         # tile-sort) happens here in the loader thread,
@@ -392,7 +399,9 @@ class MinibatchSolver:
                         if prepare is None:
                             return blk
                         t0p = time.perf_counter()
-                        with self.perf.timer("prepare"):
+                        with _trace.span("loader.pack", cat="loader",
+                                         cpu=True, part=part_id, i=i,
+                                         rows=blk.size):
                             out = prepare(blk, train=train)
                         if train:
                             _ST_PACK.observe(time.perf_counter() - t0p)
@@ -410,12 +419,15 @@ class MinibatchSolver:
                             self.pack_cache, part_key, raw_iter, prep):
                         if stage is not None:
                             t0h = time.perf_counter()
-                            b = stage(b, train=train)
+                            with _trace.span("loader.h2d", cat="loader",
+                                             part=part_id, i=i):
+                                b = stage(b, train=train)
                             if train:
                                 _ST_H2D.observe(
                                     time.perf_counter() - t0h)
-                        if not _put(b):
+                        if not _put((b, part_id, i)):
                             return
+                        i += 1
                     pool.finish(part_id)
             except BaseException as e:
                 # CPython list.append is atomic; main thread reads only
@@ -458,27 +470,29 @@ class MinibatchSolver:
                     if depth >= max(1, self.max_queued // 2):
                         high += 1
                     t_w = time.perf_counter()
-                    item = q.get()
+                    with _trace.span("solver.queue_wait", cat="solver"):
+                        item = q.get()
                     dw = time.perf_counter() - t_w
-                    self.perf.add("wait", dw)
                     stall_s += dw
                     _STALL.set(stall_s)
                     if item is _END:
                         done_loaders += 1
                         continue
+                    b, part_id, i = item
                     t_s = time.perf_counter()
-                    with _trace.span(f"solver.{mode}_step", cat="solver"):
-                        out = step(item)
+                    with _trace.span(f"solver.{mode}_step", cat="solver",
+                                     part=part_id, i=i):
+                        out = step(b)
                     dt = time.perf_counter() - t_s
-                    self.perf.add(f"{mode}_step", dt)
                     t_step += dt
                     n_steps += 1
                     t_m = time.perf_counter()
-                    prog.merge(out)
-                    if self.verbose \
-                            and time.time() - last_print >= cfg.print_sec:
-                        self._log(prog.row(self.t0))
-                        last_print = time.time()
+                    with _trace.span("solver.merge", cat="solver"):
+                        prog.merge(out)
+                        if self.verbose and (time.time() - last_print
+                                             >= cfg.print_sec):
+                            self._log(prog.row(self.t0))
+                            last_print = time.time()
                     if train:
                         dm = time.perf_counter() - t_m
                         _ST_LOAD.observe(dw)

@@ -5,9 +5,9 @@ Parity targets (SURVEY §5 tracing/profiling):
   the average plus the share of time spent outside compute ("comm
   overhead") when a workload finishes (minibatch_solver.h:246-275);
 - difacto's server classifies ops (push-count / push-grad / pull) and
-  logs mean latencies every N ops (difacto async_sgd.h:108-127);
-- beyond parity: `maybe_trace` hooks the JAX profiler so a run can emit
-  an XProf trace by setting WORMHOLE_PROFILE_DIR.
+  logs mean latencies every N ops (difacto async_sgd.h:108-127).
+
+(The device profile, WORMHOLE_PROFILE_DIR, is `obs.trace.maybe_trace`.)
 
 Every Perf.add is mirrored into the process-wide metrics registry
 (wormhole_tpu/obs) as histogram `perf.<op>_s`, so Perf timings ride the
@@ -20,7 +20,6 @@ solver and tests already use.
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
 import time
 from typing import Callable, Optional
@@ -103,18 +102,3 @@ class Perf:
     def row(self) -> str:
         with self._lock:
             return self._row_locked()
-
-
-@contextlib.contextmanager
-def maybe_trace(label: str = "run"):
-    """Wrap a region in a JAX profiler trace when WORMHOLE_PROFILE_DIR is
-    set; no-op (and no jax import) otherwise."""
-    out = os.environ.get("WORMHOLE_PROFILE_DIR")
-    if not out:
-        yield
-        return
-    import jax
-
-    os.makedirs(out, exist_ok=True)
-    with jax.profiler.trace(out):
-        yield
