@@ -361,6 +361,117 @@ def test_filesys_uri_scheme_roundtrip():
     assert c0 and c1
 
 
+# ---------------------------------------------------- InputSplit chunks
+def _line_end(data: bytes, at: int) -> int:
+    """The first line boundary at-or-after byte offset `at` (> 0)."""
+    nl = data.find(b"\n", at - 1)
+    return nl + 1 if nl >= 0 else len(data)
+
+
+def _split_chunks(data: bytes, part: int, num_parts: int,
+                  chunk_bytes: int) -> list[str]:
+    """The InputSplit rule on the file's bytes: a part starts at the
+    first line beginning at-or-after its range's start; a chunk closes
+    at the first line end at-or-past `chunk_bytes` or the range's end."""
+    size = len(data)
+    begin, end = size * part // num_parts, size * (part + 1) // num_parts
+    pos = _line_end(data, begin) if begin else 0
+    out = []
+    while pos < end:
+        stop = _line_end(data, pos + min(chunk_bytes, end - pos))
+        out.append(data[pos:stop].decode("utf-8", errors="replace"))
+        pos = stop
+    return out
+
+
+_LINES = b"".join(b"%d %d:1 %d:0.5\n" % (i % 2, i, i * i)
+                  for i in range(400))
+_SPLIT_FILES = {
+    "final_newline": _LINES,
+    "no_final_newline": _LINES[:-1],
+    "long_line": _LINES[:2000] + b"1 " + b"7:1 " * 3000 + b"\n"
+                 + _LINES[2000:],
+    "crlf": _LINES.replace(b"\n", b"\r\n"),
+    "fewer_lines_than_parts": b"1 1:1\n0 2:1\n1 3:1",
+    "empty": b"",
+}
+
+
+@pytest.mark.parametrize("scheme", ["local", "memtest"])
+@pytest.mark.parametrize("chunk_bytes", [16, 64, 4096, 1 << 24])
+@pytest.mark.parametrize("num_parts", [1, 2, 3, 7])
+@pytest.mark.parametrize("kind", sorted(_SPLIT_FILES))
+def test_iter_file_chunks_equals_split_rule(tmp_path, kind, num_parts,
+                                            chunk_bytes, scheme):
+    from wormhole_tpu.data import filesys as fsys
+
+    data = _SPLIT_FILES[kind]
+    if scheme == "local":
+        path = str(tmp_path / "d.txt")
+        with open(path, "wb") as f:
+            f.write(data)
+    else:
+        mem = _MemFS()
+        fsys.register_filesystem("memtest", mem)
+        path = "memtest://bucket/d.txt"
+        mem.files["bucket/d.txt"] = data
+    got = [list(iter_file_chunks(path, k, num_parts, chunk_bytes))
+           for k in range(num_parts)]
+    assert got == [_split_chunks(data, k, num_parts, chunk_bytes)
+                   for k in range(num_parts)]
+    # the parts partition the file, in order
+    assert "".join(c for part in got for c in part) == data.decode()
+
+
+def test_iter_file_chunks_calls_do_not_grow_with_lines():
+    """A chunk costs one block read and at most one readline, whatever
+    the number of lines in it: each per-line `readline` or `tell` on a
+    buffered file drops the interpreter lock (PERF.md §6, PR 25)."""
+    import collections
+    import io
+
+    from wormhole_tpu.data import filesys as fsys
+
+    calls = collections.Counter()
+
+    class _Counting(io.BytesIO):
+        def read(self, *a):
+            calls["read"] += 1
+            return super().read(*a)
+
+        def readline(self, *a):
+            calls["readline"] += 1
+            return super().readline(*a)
+
+        def tell(self):
+            calls["tell"] += 1
+            return super().tell()
+
+    class _CountingFS(_MemFS):
+        def open(self, path, mode="rb"):
+            return _Counting(self.files[path])
+
+    mem = _CountingFS()
+    fsys.register_filesystem("counttest", mem)
+    per_chunk = {}
+    for lines in (2000, 8000):
+        data = b"".join(b"1 %d:1 %d:1\n" % (i, i + 1) for i in range(lines))
+        mem.files["b/d.txt"] = data
+        num_parts, chunk_bytes = 3, 4096
+        calls.clear()
+        chunks = sum(
+            len(list(iter_file_chunks("counttest://b/d.txt", part,
+                                      num_parts, chunk_bytes)))
+            for part in range(num_parts))
+        assert chunks > len(data) // (chunk_bytes + 64)
+        assert (calls["readline"] + calls["tell"]
+                <= 2 * chunks + 2 * num_parts)
+        assert calls["read"] <= chunks + num_parts
+        per_chunk[lines] = (calls["readline"] + calls["tell"]) / chunks
+    # four times the lines, the same calls a chunk
+    assert per_chunk[8000] <= per_chunk[2000] + 0.5
+
+
 def test_filesys_crb_over_remote_scheme(tmp_path):
     from wormhole_tpu.data import filesys as fsys
     from wormhole_tpu.data.crb import read_crb, write_crb

@@ -1,8 +1,9 @@
 """Text parsers: libsvm, Criteo CTR, adfea -> RowBlock.
 
-Python reference implementations. A native C++ parsing fast path (planned
-under wormhole_tpu/native) will be cross-checked against these; until it
-lands, these are the production parsers.
+Python reference implementations. The native C++ core
+(wormhole_tpu/native/src/parsers.cc) is the production path: `parse_text`
+hands it each chunk, tests/test_native.py holds it bit-identical to
+these, and these serve when its library is missing.
 
 Format parity with the reference:
 - libsvm "label idx:val ..."                 (dmlc-core LibSVMParser)
@@ -157,7 +158,17 @@ def iter_file_chunks(
     a part starts at the first line beginning at-or-after its byte range
     start and ends at the first line boundary at-or-after its range end.
     `path` may be any URI data/filesys.py supports (Stream::Create
-    parity)."""
+    parity).
+
+    A chunk is one block `read` of up to `chunk_bytes` plus at most one
+    `readline` that finishes the block's last line, so it closes at the
+    first line end at-or-past `chunk_bytes` (or the part's end); the
+    position advances by the bytes taken, with no `tell`. Keep it free
+    of per-line calls: every `readline` refill and every `tell` on a
+    buffered file is a syscall that drops the interpreter lock, and with
+    the loader pool's other threads wanting it a chunk's ~45,000 lines
+    cost seconds where the block read costs milliseconds (PERF.md §6,
+    PR 25; tests/test_data.py holds the call count)."""
     from wormhole_tpu.data import filesys as fsys
 
     size = fsys.getsize(path)
@@ -169,17 +180,11 @@ def iter_file_chunks(
             # consume the partial line belonging to the previous part
             f.readline()
         pos = f.tell()
-        buf: list[bytes] = []
-        buffered = 0
         while pos < end:
-            line = f.readline()
-            if not line:
+            chunk = f.read(min(chunk_bytes, end - pos))
+            if not chunk:
                 break
-            pos = f.tell()
-            buf.append(line)
-            buffered += len(line)
-            if buffered >= chunk_bytes:
-                yield b"".join(buf).decode("utf-8", errors="replace")
-                buf, buffered = [], 0
-        if buf:
-            yield b"".join(buf).decode("utf-8", errors="replace")
+            if not chunk.endswith(b"\n"):
+                chunk += f.readline()
+            pos += len(chunk)
+            yield chunk.decode("utf-8", errors="replace")
