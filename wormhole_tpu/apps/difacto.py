@@ -8,27 +8,15 @@ from __future__ import annotations
 
 import sys
 
-import jax
-
 from wormhole_tpu.apps._runner import app_main, parse_cli, run_minibatch_app
 from wormhole_tpu.models.difacto import (
     DifactoConfig, DifactoLearner, make_early_stop_hook,
 )
-from wormhole_tpu.parallel.mesh import make_mesh
+from wormhole_tpu.parallel.mesh import local_mesh
 
 
 def make_learner(cfg: DifactoConfig, env):
-    # local device mesh. model_shards > 1 splits the state tables over
-    # the mesh "model" axis (the hot plane's HBM residency); cross-
-    # PROCESS sharding stays the ps server group's job (ps_server.py)
-    shards = max(int(cfg.model_shards), 1)
-    ndev = len(jax.devices())
-    if shards > ndev:
-        print(f"[difacto] model_shards={shards} > {ndev} devices; "
-              f"clamping to {ndev}", flush=True)
-        shards = ndev
-    mesh = make_mesh(num_model=shards)
-    return DifactoLearner(cfg, mesh)
+    return DifactoLearner(cfg, local_mesh("difacto", cfg.model_shards))
 
 
 def serve_scorer(cfg: DifactoConfig):

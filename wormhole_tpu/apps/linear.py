@@ -8,25 +8,13 @@ from __future__ import annotations
 
 import sys
 
-import jax
-
 from wormhole_tpu.apps._runner import app_main
 from wormhole_tpu.models.linear import LinearConfig, LinearLearner
-from wormhole_tpu.parallel.mesh import make_mesh
+from wormhole_tpu.parallel.mesh import local_mesh
 
 
 def make_learner(cfg: LinearConfig, env):
-    # local device mesh. model_shards > 1 splits the state tables over
-    # the mesh "model" axis (the hot plane's HBM residency); cross-
-    # PROCESS sharding stays the ps server group's job (ps_server.py)
-    shards = max(int(cfg.model_shards), 1)
-    ndev = len(jax.devices())
-    if shards > ndev:
-        print(f"[linear] model_shards={shards} > {ndev} devices; "
-              f"clamping to {ndev}", flush=True)
-        shards = ndev
-    mesh = make_mesh(num_model=shards)
-    return LinearLearner(cfg, mesh)
+    return LinearLearner(cfg, local_mesh("linear", cfg.model_shards))
 
 
 def serve_scorer(cfg: LinearConfig):

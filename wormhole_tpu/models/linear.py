@@ -31,6 +31,7 @@ import numpy as np
 
 from wormhole_tpu.data.rowblock import DeviceBatch, RowBlock, to_device_batch
 from wormhole_tpu.obs import trace as _trace
+from wormhole_tpu.obs.metrics import REGISTRY
 from wormhole_tpu.ops import coo_kernels as ck
 from wormhole_tpu.ops import metrics as M
 from wormhole_tpu.ops.penalty import l1l2_solve
@@ -38,6 +39,13 @@ from wormhole_tpu.ops.spmv import spmv, spmv_t
 from wormhole_tpu.parallel.kvstore import KVStore, TableSpec, quantize_push
 from wormhole_tpu.parallel.mesh import (batch_sharding, describe_placement,
                                         make_mesh)
+
+# the mesh pack, a batch at a time: nonzeros a full shard dropped, and
+# the fullest cell beside the sum of all cells (hot shard = max * cells
+# / sum)
+_MESH_DROPPED = REGISTRY.counter("linear.mesh.dropped_nnz")
+_MESH_NNZ_MAX = REGISTRY.counter("linear.mesh.shard_nnz_max")
+_MESH_NNZ_SUM = REGISTRY.counter("linear.mesh.shard_nnz_sum")
 
 
 @dataclasses.dataclass
@@ -371,7 +379,10 @@ class LinearLearner:
                 touched = 1.0
             else:
                 touched = (raw_g != 0).astype(jnp.float32)
-            new_state = _update(cfg.algo, state, g, touched, cfg)
+            # the dense per-shard update: every bucket of the shard, the
+            # touched and the untouched alike
+            with jax.named_scope("mesh_update"):
+                new_state = _update(cfg.algo, state, g, touched, cfg)
             new_w = (jnp.sum(new_state["w"] != 0)
                      - jnp.sum(w != 0)).astype(jnp.float32)
             return new_state, _progress(obj, xw, label, mask, new_w)
@@ -573,9 +584,12 @@ class LinearLearner:
             mc = ck.pack_mesh_coo(db.idx, db.seg, db.val,
                                   self.cfg.num_buckets, self.cfg.minibatch,
                                   D, M, self._shard_cap)
+            _MESH_NNZ_MAX.inc(int(mc.cell_nnz.max()))
+            _MESH_NNZ_SUM.inc(int(mc.cell_nnz.sum()))
             if mc.dropped_nnz:
                 import logging
 
+                _MESH_DROPPED.inc(mc.dropped_nnz)
                 logging.getLogger(__name__).warning(
                     "mesh shard overflow: dropped %d nonzeros — raise "
                     "nnz_per_row or mesh_capacity slack", mc.dropped_nnz)
@@ -644,6 +658,9 @@ class LinearLearner:
         if kind == "mcoo":
             _, mc, label, mask, _ = b
             args = tuple(self._mcoo_args(mc, label, mask))
+            # what the batch moves to the chips, a [1, M, P] slice a
+            # shard: on the solver's loader.h2d span round this call
+            _trace.annotate(bytes=sum(a.nbytes for a in args))
         elif kind == "tcoo":
             _, tc, label, mask, _ = b
             args = tuple(self._tcoo_args(tc, label, mask, train=train))

@@ -77,7 +77,8 @@ from wormhole_tpu.obs import flight as _flight
 SAMPLE_N: int = 0
 
 _INIT_LOCK = threading.Lock()
-_TLS = threading.local()  # .ctx = (trace_id, span_id) while bound
+# .ctx = (trace_id, span_id) while bound; .span = innermost open span
+_TLS = threading.local()
 
 
 class Tracer:
@@ -210,7 +211,7 @@ _NULL_SPAN = _NullSpan()
 
 class _Span:
     __slots__ = ("tracer", "name", "cat", "args", "t0", "_ctx", "_saved",
-                 "_ann", "_cpu", "_cpu0")
+                 "_ann", "_cpu", "_cpu0", "_outer")
 
     def __init__(self, tracer: Optional[Tracer], name: str, cat: str,
                  args: dict, profiled: bool = False, cpu: bool = False):
@@ -246,11 +247,14 @@ class _Span:
         else:
             self._ctx = None
             self._saved = None
+        self._outer = getattr(_TLS, "span", None)
+        _TLS.span = self
         self.t0 = time.monotonic()
         return self
 
     def __exit__(self, etype, exc, tb):
         dur = time.monotonic() - self.t0
+        _TLS.span = self._outer
         if self._cpu:
             # this thread's CPU time inside the block: what is left of
             # the wall is waiting (the interpreter lock, I/O, a queue)
@@ -303,6 +307,15 @@ def span(name: str, cat: str = "span", cpu: bool = False, **args):
     if t is None and _flight.ACTIVE is None and not profiled:
         return _NULL_SPAN
     return _Span(t, name, cat, args, profiled, cpu)
+
+
+def annotate(**args) -> None:
+    """Arguments for the innermost span open on this thread, from code
+    that did not open it (a learner's `stage_batch` under the solver's
+    `loader.h2d`). Nothing where no span is open."""
+    sp = getattr(_TLS, "span", None)
+    if sp is not None:
+        sp.set(**args)
 
 
 def request_span(name: str, cat: str = "span", **args):

@@ -729,6 +729,7 @@ class MeshCOO:
     tmap: np.ndarray   # [D, M, P/BLK]
     first: np.ndarray  # [D, M, P/BLK]
     dropped_nnz: int   # nonzeros beyond a shard's capacity (overflow)
+    cell_nnz: np.ndarray  # [D, M] live nonzeros a cell got, before the cut
 
 
 def mesh_capacity(capacity: int, D: int, M: int, slack: float = 2.0) -> int:
@@ -764,6 +765,7 @@ def pack_mesh_coo(idx, seg, val, num_buckets: int, num_rows: int,
     sval = np.zeros((D, M, P), np.float32)
     tmap = np.zeros((D, M, nblk), np.int32)
     first = np.zeros((D, M, nblk), np.int32)
+    cell_nnz = np.zeros((D, M), np.int64)
     dropped = 0
     for d in range(D):
         for m in range(M):
@@ -771,6 +773,7 @@ def pack_mesh_coo(idx, seg, val, num_buckets: int, num_rows: int,
             ci = idx[sel] - m * nb_m
             cs = seg[sel] - d * rows_d
             cv = val[sel]
+            cell_nnz[d, m] = len(ci)
             if len(ci) > capacity_per_shard:
                 dropped += len(ci) - capacity_per_shard
                 ci = ci[:capacity_per_shard]
@@ -783,7 +786,7 @@ def pack_mesh_coo(idx, seg, val, num_buckets: int, num_rows: int,
             sval[d, m] = p.val
             tmap[d, m] = p.tmap
             first[d, m] = p.first
-    return MeshCOO(sidx, sseg, sval, tmap, first, dropped)
+    return MeshCOO(sidx, sseg, sval, tmap, first, dropped, cell_nnz)
 
 
 def mesh_coo_spmv(mesh, w, sidx, sseg, sval, tmap, first,

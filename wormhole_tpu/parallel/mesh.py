@@ -22,6 +22,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from wormhole_tpu.obs.metrics import REGISTRY
+
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
@@ -48,6 +50,26 @@ def make_mesh(
     devs = devs[:need]
     arr = np.array(devs).reshape(num_data, num_model)
     return Mesh(arr, (DATA_AXIS, MODEL_AXIS))
+
+
+def local_mesh(learner: str, model_shards: int) -> Mesh:
+    """The in-process mesh of an app's conf: `model_shards` splits the
+    state tables over the mesh "model" axis (the hot plane's HBM
+    residency), the remaining devices take the data axis; cross-PROCESS
+    sharding stays the ps server group's job (ps_server.py). A conf
+    that asks for more shards than there are devices is clamped, with a
+    printed line and the counter `<learner>.mesh.clamped_shards` raised
+    by the shards that went missing: a conf written for four chips
+    trains one shard on a one-chip machine, and says so."""
+    shards = max(int(model_shards), 1)
+    ndev = len(jax.devices())
+    if shards > ndev:
+        print(f"[{learner}] model_shards={shards} > {ndev} devices; "
+              f"clamping to {ndev}", flush=True)
+        REGISTRY.counter(f"{learner}.mesh.clamped_shards").inc(
+            shards - ndev)
+        shards = ndev
+    return make_mesh(num_model=shards)
 
 
 def table_sharding(mesh: Mesh, ndim: int = 1) -> NamedSharding:
