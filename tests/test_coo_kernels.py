@@ -8,6 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from wormhole_tpu.ops import coo_kernels as ck
+from wormhole_tpu.ops import fused_update as fu
 from wormhole_tpu.ops.coo_kernels import (
     BLK, TILE, pack_sorted_coo, packed_size, coo_spmv, coo_spmv_t,
 )
@@ -92,3 +94,193 @@ def test_pack_concentrated_single_tile():
                                rtol=1e-5, atol=1e-4)
     # untouched tiles are exactly zero
     assert not np.asarray(got[TILE:]).any()
+
+
+# ------------------------------------------------- live-extent bodies
+# The kernels build their one-hot operands only over a block's live
+# prefix (ops/coo_kernels._live_chunks). The reference is the same
+# kernel with every extent forced to a whole block: the full-width body,
+# which is what the kernels ran before they looked at the extent.
+
+CH, BU = ck.CHUNK, ck.BLK_U
+# live slots of the one tile the case is about: empty, one slot, exactly
+# one chunk, one over a chunk, half full + 1 (the full-width body's
+# first), full, and a run that spills into a second block
+U_FILLS = [0, 1, CH, CH + 1, BU // 2, BU // 2 + 1, BU, BU + 5]
+C_FILLS = [0, 1, CH, CH + 1, BLK // 2, BLK // 2 + 1, BLK, BLK + 7]
+
+
+@pytest.fixture
+def full_width(monkeypatch):
+    """Call it, and from then on the wrappers hand every block a
+    whole-block extent: the kernels take their full-width body."""
+    def whole(live, blk):
+        return jnp.full((live.shape[0] // blk,), blk, jnp.int32)
+
+    def force():
+        monkeypatch.setattr(ck, "block_extents", whole)
+        monkeypatch.setattr(fu, "block_extents", whole)
+    return force
+
+
+def _tile_slots(fill, nb_tiles=3, u_cap=8 * BU):
+    """fill keys in tile 0, none in tile 1, 3 in tile 2; u_cap leaves
+    trailing spare blocks."""
+    rng = np.random.default_rng(fill)
+    keys = np.concatenate([
+        np.sort(rng.choice(TILE, size=fill, replace=False)),
+        2 * TILE + np.array([5, 77, 4000])]).astype(np.int64)
+    return ck.assign_tile_slots(keys, TILE, u_cap, nb_tiles * TILE), keys
+
+
+def _assert_live_prefix(stream_is_live, blk):
+    """In every block the live slots come first: no hole before one."""
+    live = np.asarray(stream_is_live).reshape(-1, blk)
+    n = live.sum(1)
+    assert (live == (np.arange(blk)[None, :] < n[:, None])).all()
+    return n
+
+
+@pytest.mark.parametrize("fill", U_FILLS)
+def test_assign_tile_slots_live_slots_are_a_prefix(fill):
+    nb = 3 * TILE
+    ts, keys = _tile_slots(fill)
+    n = _assert_live_prefix(ts.uniq != nb, BU)
+    assert n.sum() == len(keys) == ts.num_uniq
+    used = -(-fill // BU) + 1 if fill else 1
+    assert not n[used:].any()                  # trailing spare blocks
+    # the extents the wrappers derive are those prefix lengths, and the
+    # host's sampled count is the chunks below them
+    ext = np.asarray(ck.block_extents(jnp.asarray(ts.uniq) != nb, BU))
+    np.testing.assert_array_equal(ext, n)
+    chunks, run = ck.host_chunk_counts(ts.uniq, nb, BU)
+    assert chunks == len(n) * (BU // CH)
+    assert run == int(np.sum(ck.chunks_run(ext, BU)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("fill", U_FILLS)
+def test_tile_gather_equals_full_width(fill, dtype, full_width):
+    nb = 3 * TILE
+    ts, keys = _tile_slots(fill)
+    w = np.random.default_rng(1).normal(size=nb).astype(np.float32)
+    args = (jnp.asarray(w).reshape(-1, ck.LANES), jnp.asarray(ts.uniq),
+            jnp.asarray(ts.tmap_u))
+    got = np.asarray(ck.tile_gather(*args, dtype=dtype))
+    full_width()
+    want = np.asarray(ck.tile_gather(*args, dtype=dtype))
+    np.testing.assert_array_equal(got, want)   # bit-equal
+    live = ts.uniq != nb
+    assert not got[~live].any()
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got[live], w[ts.uniq[live]], rtol=1e-6)
+
+
+def _update_case(algo, fill, with_add):
+    nb = 3 * TILE
+    ts, keys = _tile_slots(fill)
+    rng = np.random.default_rng(100 + fill)
+    names = {"ftrl": ("z", "n", "w"), "adagrad": ("n", "w"),
+             "sgd": ("w",)}[algo]
+    state = {k: (np.abs(rng.normal(size=nb)) if k == "n"
+                 else rng.normal(size=nb)).astype(np.float32)
+             for k in names}
+    live = ts.uniq != nb
+    g = np.where(live, rng.normal(size=len(live)), 0).astype(np.float32)
+    kw = dict(lr_eta=0.3, lr_beta=1.0, lambda_l1=0.2, lambda_l2=0.01,
+              dtype=jnp.float32)
+    adds = None
+    if with_add:   # counts over 256: the additive scatter stays f32
+        state["cnt"] = rng.integers(0, 9, size=nb).astype(np.float32)
+        adds = np.where(live, rng.integers(1, 400, size=len(live)),
+                        0).astype(np.float32)
+        kw.update(add_table="cnt", add_values=jnp.asarray(adds))
+
+    def run():
+        st, nw = fu.scatter_update(
+            algo, {k: jnp.asarray(v) for k, v in state.items()},
+            jnp.asarray(g), jnp.asarray(ts.uniq), jnp.asarray(ts.tmap_u),
+            jnp.asarray(ts.first_u), jnp.asarray(ts.last_u), **kw)
+        return {k: np.asarray(v) for k, v in st.items()}, float(nw)
+    return run, state, ts, adds
+
+
+@pytest.mark.parametrize("algo,with_add", [
+    ("ftrl", False), ("adagrad", False), ("sgd", False), ("ftrl", True)])
+@pytest.mark.parametrize("fill", U_FILLS)
+def test_fused_update_equals_full_width(fill, algo, with_add, full_width):
+    run, before, ts, adds = _update_case(algo, fill, with_add)
+    got, nw = run()
+    full_width()
+    want, nw_want = run()
+    assert nw == nw_want
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k])   # bit-equal
+    # the untouched tile came through as it was, and touched keys moved
+    for k in got:
+        np.testing.assert_array_equal(got[k][TILE:2 * TILE],
+                                      before[k][TILE:2 * TILE])
+    live = ts.uniq != 3 * TILE
+    moved = got["w"][ts.uniq[live]] != before["w"][ts.uniq[live]]
+    assert moved.any()
+    if with_add:
+        want_cnt = before["cnt"].copy()
+        want_cnt[ts.uniq[live]] += adds[live]
+        np.testing.assert_array_equal(got["cnt"], want_cnt)
+
+
+def _coo_case(fill, num_rows=256):
+    """fill nonzeros in tile 0 (a tenth of them explicit zeros, as input
+    padding triples are), none in tile 1, 3 in tile 2."""
+    nb = 3 * TILE
+    rng = np.random.default_rng(200 + fill)
+    idx = np.concatenate([rng.integers(0, TILE, size=fill),
+                          2 * TILE + np.array([5, 77, 4000])]).astype(
+                              np.int32)
+    seg = rng.integers(1, num_rows, size=len(idx)).astype(np.int32)
+    val = rng.normal(size=len(idx)).astype(np.float32)
+    val[:fill][rng.random(fill) < 0.1] = 0.0
+    return idx, seg, val, nb
+
+
+@pytest.mark.parametrize("fill", C_FILLS)
+def test_pack_sorted_coo_runs_are_a_prefix(fill):
+    idx, seg, val, nb = _coo_case(fill)
+    p = pack_sorted_coo(idx, seg, val, nb, capacity=2 * BLK)
+    # every input triple, zero-valued ones included, has seg >= 1 and
+    # the pack's own padding seg == 0: no padding before an input triple
+    n = _assert_live_prefix(p.seg != 0, BLK)
+    assert n.sum() == len(idx)
+    assert not p.val[p.seg == 0].any()
+    # so past a block's extent (one past its last val != 0) all is zero,
+    # and the extent is at most the run's length in the block
+    ext = np.asarray(ck.block_extents(jnp.asarray(p.val) != 0, BLK))
+    assert (ext <= n).all()
+    chunks, run = ck.host_chunk_counts(p.val, 0, BLK)
+    assert chunks == p.num_blocks * (BLK // CH)
+    assert run <= int(np.sum(ck.chunks_run(n, BLK)))
+
+
+@pytest.mark.parametrize("fill", C_FILLS)
+def test_pull_and_push_equal_full_width(fill, full_width, dtype=jnp.float32):
+    num_rows = 256
+    idx, seg, val, nb = _coo_case(fill, num_rows)
+    p = pack_sorted_coo(idx, seg, val, nb, capacity=2 * BLK)
+    stream = [jnp.asarray(x) for x in (p.idx, p.seg, p.val, p.tmap,
+                                       p.first)]
+    rng = np.random.default_rng(9)
+    w = jnp.asarray(rng.normal(size=nb).astype(np.float32))
+    d = jnp.asarray(rng.normal(size=num_rows).astype(np.float32))
+    xw = np.asarray(coo_spmv(w, *stream, num_rows, dtype=dtype))
+    g = np.asarray(coo_spmv_t(d, *stream, nb, dtype=dtype))
+    full_width()
+    xw_want = np.asarray(coo_spmv(w, *stream, num_rows, dtype=dtype))
+    g_want = np.asarray(coo_spmv_t(d, *stream, nb, dtype=dtype))
+    # the same products summed in another order
+    for got, want in ((xw, xw_want), (g, g_want)):
+        scale = max(float(np.max(np.abs(want))), 1e-30)
+        assert np.max(np.abs(got - want)) <= 1e-6 * scale
+    assert not g[TILE:2 * TILE].any()          # the empty tile is zeroed
+    want = spmv(jnp.asarray(seg), jnp.asarray(idx), jnp.asarray(val), w,
+                num_rows)
+    np.testing.assert_allclose(xw, np.asarray(want), rtol=1e-5, atol=1e-4)

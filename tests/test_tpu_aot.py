@@ -11,7 +11,14 @@ fails here, on the CPU, instead of on chip time.
 Each kernel also has to reach the compiled program under its own name
 (`pallas_call(name=...)` becomes the HLO instruction's name, which is
 what a device trace calls the operation): the per-kernel metrics of the
-benchmark match on `%tile_gather`, `%fused_update`, `%coo_push`.
+benchmark match on `%tile_gather`, `%fused_update`, `%coo_push`,
+`%coo_pull`.
+
+The benchmark's own geometries are here too (PERF.md §4): the compacted
+kernels at 2^29 buckets with the 12,582,912-slot compact domain its
+batches get, and the `mcoo` pair on a 1 x 4 mesh at 2^28 buckets a shard.
+Their bodies run over chunks of a block under `pl.when` (ops/coo_kernels
+`_live_chunks`), which only Mosaic can accept or refuse.
 
 Kernel-only programs on purpose: a whole train step adds the AUC sort,
 which alone compiles for ~25-50 s (PERF.md §6, PR 21). There is one
@@ -36,16 +43,24 @@ CAP = ROWS * NNZ
 NB_DENSE = 1 << 22          # headline table: dense coo kernels
 NB_BIG = 1 << 26            # Criteo-1TB table: compacted kernels
 U_CAP = 1572864             # its auto compact_cap (24 tiles)
+NB_1TB = 1 << 29            # the benchmark's one-chip table
+U_CAP_1TB = 12582912        # its compact domain: 12,288 update blocks
+NB_MESH = 1 << 30           # the four-chip table, 2^28 buckets a shard
 DIM, VB = 8, 1 << 20        # DiFacto bench shape
 UW_CAP, UV_CAP = 6 * ck.TILE, 256 * ck.BLK_U
 DTYPES = [jnp.bfloat16, jnp.float32]
 
 
 @pytest.fixture(scope="module")
-def v5e():
+def topo():
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     assert topo.devices[0].device_kind == "TPU v5 lite", topo.devices
+    return topo
+
+
+@pytest.fixture(scope="module")
+def v5e(topo):
     return NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
 
 
@@ -60,10 +75,12 @@ def compiled_not_interpreted(monkeypatch):
 
 
 def aot(kernel, fn, sharding, *shapes):
-    """Compile fn for the described chip; shapes are (shape, dtype).
+    """Compile fn for the described chip; shapes are (shape, dtype), or
+    (shape, dtype, sharding) where an argument has one of its own.
     `kernel` is the name its one Pallas kernel has to carry."""
-    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
-            for s, d in shapes]
+    args = [jax.ShapeDtypeStruct(s[0], s[1], sharding=(s[2:] or
+                                                       (sharding,))[0])
+            for s in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     # Mosaic really ran (an interpreted kernel leaves no custom call),
     # and the instruction is named for the kernel, not for the jit
@@ -115,6 +132,50 @@ def test_compacted_linear_kernels(v5e, dtype):
 
     aot("fused_update", update, v5e, *[((NB_BIG,), f32)] * 3,
         ((U_CAP,), f32), ((U_CAP,), i32), *slot_blocks(U_CAP, 3))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_compacted_linear_kernels_at_the_benchmarks_table(v5e, dtype):
+    f32, i32 = jnp.float32, jnp.int32
+    aot("tile_gather",
+        lambda t, u, tm: ck.tile_gather(t, u, tm, dtype=dtype),
+        v5e, ((NB_1TB // ck.LANES, ck.LANES), f32), ((U_CAP_1TB,), i32),
+        *slot_blocks(U_CAP_1TB, 1))
+    aot("coo_push",
+        lambda d, *s: ck.coo_spmv_t(d, *s, U_CAP_1TB, dtype=dtype),
+        v5e, ((ROWS,), f32), *coo_stream(CAP, U_CAP_1TB))
+
+    def update(z, n, w, g, uniq, tm, fi, la):
+        return fu.scatter_update(
+            "ftrl", {"z": z, "n": n, "w": w}, g, uniq, tm, fi, la,
+            lr_eta=0.1, lr_beta=1.0, lambda_l1=4.0, lambda_l2=0.0,
+            dtype=dtype)
+
+    aot("fused_update", update, v5e, *[((NB_1TB,), f32)] * 3,
+        ((U_CAP_1TB,), f32), ((U_CAP_1TB,), i32),
+        *slot_blocks(U_CAP_1TB, 3))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_mesh_coo_pull_push_at_a_quarter_of_2p30(topo, dtype):
+    from wormhole_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4),
+                (DATA_AXIS, MODEL_AXIS))
+    M = 4
+    cell = NamedSharding(mesh, P(DATA_AXIS, MODEL_AXIS, None))
+    table = NamedSharding(mesh, P(MODEL_AXIS))
+    rows = NamedSharding(mesh, P(DATA_AXIS))
+    stream = [((1, M) + s, d, cell) for s, d in coo_stream(
+        ck.mesh_capacity(CAP, 1, M), NB_MESH // M)]
+    assert stream[0][0] == (1, 4, 18055168)   # PERF.md §4
+    aot("coo_pull",
+        lambda w, *s: ck.mesh_coo_spmv(mesh, w, *s, ROWS, dtype=dtype),
+        None, ((NB_MESH,), jnp.float32, table), *stream)
+    aot("coo_push",
+        lambda d, *s: ck.mesh_coo_spmv_t(mesh, d, *s, NB_MESH,
+                                         dtype=dtype),
+        None, ((ROWS,), jnp.float32, rows), *stream)
 
 
 @pytest.mark.parametrize("algo,tables", [("adagrad", 2), ("sgd", 1)])

@@ -46,6 +46,17 @@ from wormhole_tpu.parallel.mesh import (batch_sharding, describe_placement,
 _MESH_DROPPED = REGISTRY.counter("linear.mesh.dropped_nnz")
 _MESH_NNZ_MAX = REGISTRY.counter("linear.mesh.shard_nnz_max")
 _MESH_NNZ_SUM = REGISTRY.counter("linear.mesh.shard_nnz_sum")
+_CHUNKS = REGISTRY.counter("linear.blocks.chunks")
+_CHUNKS_RUN = REGISTRY.counter("linear.blocks.chunks_run")
+
+
+def _count_chunks(stream, dead, blk: int, kernels: int):
+    """Count the chunks of a packed stream's grid blocks and those the
+    `kernels` kernels that walk it will execute
+    (ops/coo_kernels._live_chunks)."""
+    n, run = ck.host_chunk_counts(stream, dead, blk)
+    _CHUNKS.inc(n * kernels)
+    _CHUNKS_RUN.inc(run * kernels)
 
 
 @dataclasses.dataclass
@@ -484,9 +495,11 @@ class LinearLearner:
         from the same key distribution; overflow falls back to
         drop-and-warn), rounded to whole tiles. Engaged only when the
         compact domain is well under the table size — otherwise the dense
-        path's per-tile padding is already cheaper than the extra
-        tile_gather / scatter_update streaming (constant measured on
-        v5e)."""
+        path's one padding block a tile and its O(num_buckets) update
+        sweep are already cheaper than the extra tile_gather /
+        scatter_update streaming (the factor 32 was measured on v5e
+        with full-width blocks and not swept again since a block costs
+        what it holds, ops/coo_kernels._live_chunks)."""
         cfg = self.cfg
         if cfg.compact_cap > 0:
             return -(-cfg.compact_cap // ck.TILE) * ck.TILE
@@ -593,6 +606,8 @@ class LinearLearner:
                 logging.getLogger(__name__).warning(
                     "mesh shard overflow: dropped %d nonzeros — raise "
                     "nnz_per_row or mesh_capacity slack", mc.dropped_nnz)
+            if train:  # pull and push walk the same COO blocks
+                _count_chunks(mc.sval, 0, ck.BLK, 2)
             return ("mcoo", mc, db.label, db.row_mask, blk.size)
         if self.ensure_compact(db.idx):
             tc = ck.pack_tile_coo(db.idx, db.seg, db.val,
@@ -607,6 +622,9 @@ class LinearLearner:
                     "compaction overflow: dropped %d unique keys "
                     "(%d nonzeros) — raise compact_cap (currently %d)",
                     tc.dropped_uniq, tc.dropped_nnz, self._compact_cap)
+            if train:  # tile_gather and the fused update; the push
+                _count_chunks(tc.uniq, self.cfg.num_buckets, ck.BLK_U, 2)
+                _count_chunks(tc.coo.val, 0, ck.BLK, 1)
             return ("tcoo", tc, db.label, db.row_mask, blk.size)
         p = ck.pack_sorted_coo(db.idx, db.seg, db.val, self.cfg.num_buckets,
                                capacity=self.cfg.row_capacity)

@@ -46,10 +46,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 import os
 
-# Tile geometry. The per-block cost is dominated by materializing the
-# (BLK, TILE_HI) one-hot gather/scatter operands on the VPU, so smaller
-# tiles are cheaper per block as long as the MXU matmuls stay large
-# enough; the env overrides exist for hardware tuning sweeps.
+# Tile geometry. A grid block's cost is the one-hot gather/scatter
+# operands it builds on the VPU and feeds the MXU: one row of TILE_HI
+# (or LANES) columns for each slot it works on, whether or not the slot
+# holds anything. At Criteo-1TB table sizes most blocks are a few percent
+# full, so the kernels build those operands only over a block's live
+# prefix (CHUNK and _live_chunks below), and a block costs what it
+# holds. The env overrides exist for hardware tuning sweeps.
 TILE_HI = int(os.environ.get("WORMHOLE_TILE_HI", 512))  # sublanes per tile
 LANES = 128
 TILE = TILE_HI * LANES  # buckets per table tile
@@ -66,8 +69,70 @@ _FM_VMEM_LIMIT = int(os.environ.get("WORMHOLE_FM_VMEM", 64 * 2**20))
 _VMEM_LIMIT = int(os.environ.get("WORMHOLE_VMEM", 96 * 2**20))
 
 
+# Slots of a block that the one-hot bodies take at a time when the block
+# is at most half full. The live slots of a block are always a prefix of
+# it (assign_tile_slots: a tile's keys sit at base + rank;
+# pack_sorted_coo: a tile's run is written at d0:d0+n and padded after
+# it), so one number a block, the extent of that prefix, bounds its work.
+CHUNK = 128
+
+
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def block_extents(live, blk: int):
+    """live: (P,) bool, P a multiple of blk. Returns (P // blk,) int32,
+    for each block of blk slots one past its last live slot (0 = the block
+    holds nothing). Derived on the device inside the kernel wrappers and
+    handed to the kernel as a scalar-prefetch operand: one fused reduce
+    over the stream, so the packed layouts carry no count."""
+    live2 = live.reshape(-1, blk)
+    pos = jax.lax.broadcasted_iota(jnp.int32, live2.shape, 1) + 1
+    return jnp.max(jnp.where(live2, pos, 0), axis=1)
+
+
+def chunks_run(ext, blk: int):
+    """How many of a block's blk // CHUNK chunks the kernels execute at
+    extent ext (array or scalar, numpy or jax): the chunks below the
+    extent, or all of them once the block is more than half full and
+    takes the full-width body (_live_chunks)."""
+    return (ext > blk // 2) * (blk // CHUNK) + (ext <= blk // 2) * (
+        -(-ext // CHUNK))
+
+
+def host_chunk_counts(stream, dead, blk: int) -> tuple[int, int]:
+    """(chunks, chunks the kernels execute) of a packed host stream whose
+    slots equal to `dead` hold nothing: `uniq` with its sentinel, or a COO
+    stream's `val` with 0. Live slots are a prefix of their block, so a
+    chunk runs iff its first slot is live: only those slots are looked
+    at, some thousands a batch where the stream has millions."""
+    heads = np.asarray(stream).reshape(-1, blk)[:, ::CHUNK] != dead
+    # a prefix of n live heads is an extent in ((n - 1) * CHUNK, n * CHUNK]
+    return heads.size, int(chunks_run(heads.sum(1) * CHUNK, blk).sum())
+
+
+def _live_chunks(ext, blk: int, body):
+    """Run body(slice) over the live prefix of a grid block of blk slots:
+    a block more than half full takes the whole block in one body, as the
+    kernels always did; any other takes CHUNK-slot bodies below its
+    extent, none at extent 0. Slices are static (the chunks are unrolled
+    under pl.when), so each body lowers exactly like the full-width one.
+    What lies past the extent adds 0.0 to every product in these
+    kernels; where a kernel writes rather than accumulates (tile_gather)
+    it zeroes its output first."""
+    half = blk // 2
+
+    @pl.when(ext > half)
+    def _():
+        body(slice(None))
+
+    @pl.when(ext <= half)
+    def _():
+        for lo in range(0, half, CHUNK):
+            @pl.when(lo < ext)
+            def _(lo=lo):
+                body(pl.ds(lo, CHUNK))
 
 
 @dataclasses.dataclass
@@ -275,8 +340,8 @@ def _onehot_t(ids, width: int, dtype):
 
 
 # --------------------------------------------------------------------- pull
-def _pull_kernel(tmap_ref, first_ref, w_ref, idx_ref, seg_ref, val_ref,
-                 out_ref, *, num_rows: int, dtype):
+def _pull_kernel(tmap_ref, first_ref, ext_ref, w_ref, idx_ref, seg_ref,
+                 val_ref, out_ref, *, num_rows: int, dtype):
     blk = pl.program_id(0)
 
     @pl.when(blk == 0)
@@ -284,23 +349,28 @@ def _pull_kernel(tmap_ref, first_ref, w_ref, idx_ref, seg_ref, val_ref,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     base = tmap_ref[blk] * TILE
-    local = idx_ref[:] - base
-    hi = local >> 7
-    lo = local & (LANES - 1)
-    w2 = w_ref[:].reshape(TILE_HI, LANES)
-    c_lo = _onehot(lo, LANES, dtype)
-    p = _lane_pick(_row_fetch(w2, hi, dtype), c_lo) * val_ref[:]
 
-    rhi = seg_ref[:] >> 7
-    rlo = seg_ref[:] & (LANES - 1)
-    e_rt = _onehot_t(rhi, num_rows // LANES, dtype)
-    c_r = _onehot(rlo, LANES, dtype)
-    out_ref[:] += jax.lax.dot_general(
-        e_rt, (p[:, None] * c_r).astype(dtype),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=_prec(dtype),
-    )
+    def body(sl):
+        local = idx_ref[sl] - base
+        hi = local >> 7
+        lo = local & (LANES - 1)
+        w2 = w_ref[:].reshape(TILE_HI, LANES)
+        c_lo = _onehot(lo, LANES, dtype)
+        p = _lane_pick(_row_fetch(w2, hi, dtype), c_lo) * val_ref[sl]
+
+        rhi = seg_ref[sl] >> 7
+        rlo = seg_ref[sl] & (LANES - 1)
+        e_rt = _onehot_t(rhi, num_rows // LANES, dtype)
+        c_r = _onehot(rlo, LANES, dtype)
+        out_ref[:] += jax.lax.dot_general(
+            e_rt, (p[:, None] * c_r).astype(dtype),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=_prec(dtype),
+        )
+
+    # a block past whose extent every val is 0 adds nothing there
+    _live_chunks(ext_ref[blk], BLK, body)
 
 
 def coo_spmv(w, sidx, sseg, sval, tmap, first, num_rows: int, dtype=None):
@@ -313,11 +383,12 @@ def coo_spmv(w, sidx, sseg, sval, tmap, first, num_rows: int, dtype=None):
         dtype = jnp.bfloat16 if not _use_interpret() else jnp.float32
     assert num_rows % LANES == 0
     nblk = tmap.shape[0]
+    ext = block_extents(sval != 0, BLK)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(nblk,),
         in_specs=[
-            pl.BlockSpec((TILE,), lambda b, tmap, first: (tmap[b],)),
+            pl.BlockSpec((TILE,), lambda b, tmap, *_: (tmap[b],)),
             pl.BlockSpec((BLK,), lambda b, *_: (b,)),
             pl.BlockSpec((BLK,), lambda b, *_: (b,)),
             pl.BlockSpec((BLK,), lambda b, *_: (b,)),
@@ -334,36 +405,41 @@ def coo_spmv(w, sidx, sseg, sval, tmap, first, num_rows: int, dtype=None):
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
         name="coo_pull",
-    )(tmap, first, w, sidx, sseg, sval)
+    )(tmap, first, ext, w, sidx, sseg, sval)
     return out.reshape(num_rows)
 
 
 # --------------------------------------------------------------------- push
-def _push_kernel(tmap_ref, first_ref, d_ref, idx_ref, seg_ref, val_ref,
-                 out_ref, *, dtype):
+def _push_kernel(tmap_ref, first_ref, ext_ref, d_ref, idx_ref, seg_ref,
+                 val_ref, out_ref, *, dtype):
     blk = pl.program_id(0)
 
     @pl.when(first_ref[blk] == 1)
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    rhi = seg_ref[:] >> 7
-    rlo = seg_ref[:] & (LANES - 1)
-    c_r = _onehot(rlo, LANES, dtype)
-    c = _lane_pick(_row_fetch(d_ref[:], rhi, dtype), c_r) * val_ref[:]
-
     base = tmap_ref[blk] * TILE
-    local = idx_ref[:] - base
-    hi = local >> 7
-    lo = local & (LANES - 1)
-    e_hit = _onehot_t(hi, TILE_HI, dtype)
-    c_lo = _onehot(lo, LANES, dtype)
-    out_ref[:] += jax.lax.dot_general(
-        e_hit, (c[:, None] * c_lo).astype(dtype),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=_prec(dtype),
-    )
+
+    def body(sl):
+        rhi = seg_ref[sl] >> 7
+        rlo = seg_ref[sl] & (LANES - 1)
+        c_r = _onehot(rlo, LANES, dtype)
+        c = _lane_pick(_row_fetch(d_ref[:], rhi, dtype), c_r) * val_ref[sl]
+
+        local = idx_ref[sl] - base
+        hi = local >> 7
+        lo = local & (LANES - 1)
+        e_hit = _onehot_t(hi, TILE_HI, dtype)
+        c_lo = _onehot(lo, LANES, dtype)
+        out_ref[:] += jax.lax.dot_general(
+            e_hit, (c[:, None] * c_lo).astype(dtype),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=_prec(dtype),
+        )
+
+    # an empty block only zeroes its tile (above) when it opens one
+    _live_chunks(ext_ref[blk], BLK, body)
 
 
 def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
@@ -377,8 +453,9 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
     assert num_buckets % TILE == 0
     nblk = tmap.shape[0]
     d2 = d.reshape(num_rows // LANES, LANES)
+    ext = block_extents(sval != 0, BLK)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((num_rows // LANES, LANES), lambda b, *_: (0, 0)),
@@ -387,7 +464,7 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
             pl.BlockSpec((BLK,), lambda b, *_: (b,)),
         ],
         out_specs=pl.BlockSpec(
-            (TILE_HI, LANES), lambda b, tmap, first: (tmap[b], 0)),
+            (TILE_HI, LANES), lambda b, tmap, *_: (tmap[b], 0)),
     )
     out = pl.pallas_call(
         partial(_push_kernel, dtype=dtype),
@@ -398,7 +475,7 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
         name="coo_push",
-    )(tmap, first, d2, sidx, sseg, sval)
+    )(tmap, first, ext, d2, sidx, sseg, sval)
     return out.reshape(num_buckets)
 
 
@@ -425,6 +502,18 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
 #   place (ops/fused_update.py, aliased in/out) — the TPU analog of the
 #   reference server handle updating the entry at its storage on push
 #   (async_sgd.h:160-180), with untouched tiles never streamed at all.
+#
+# What a step then costs (measured on v5e, PERF.md §5): at 2^26 buckets
+# a batch touches 1,024 tiles of ~240 keys each; at 2^29 it touches
+# every one of the 8,192 tiles with ~30 keys each, so each update block
+# of BLK_U slots is 3 % full and the 1.5x headroom of the compact
+# capacity adds half as many empty ones. Streaming the tiles is still
+# the right formulation there (a random access is ~20-50 ns a key and
+# table; the update touches z, n, w twice), but only because a block's
+# one-hot work follows its live extent (_live_chunks): with every block
+# built at full width the pull took 2.84 us a block where the tile's
+# DMA is 0.32 us; bounded by the extent it takes ~0.55 us and the fused
+# update runs near the bytes it moves.
 
 # slots per update block; 1024 is the minimum 1D block Mosaic accepts
 # against XLA's s32[...]{0:T(1024)} layout for large 1D operands
@@ -576,15 +665,28 @@ def pack_tile_coo(idx, seg, val, num_buckets: int, u_cap: int,
                    rm_slot, rm_val)
 
 
-def _tile_gather_kernel(tmap_ref, w_ref, uniq_ref, out_ref, *, dtype):
-    base = tmap_ref[pl.program_id(0)] * TILE
-    local = uniq_ref[:] - base
-    hi = local >> 7
-    lo = local & (LANES - 1)
-    # sentinel slots (uniq == num_buckets) produce hi outside [0, TILE_HI):
-    # their one-hot row is all zeros, so they fetch 0.0 — no clamp needed
-    c_lo = _onehot(lo, LANES, dtype)
-    out_ref[:] = _lane_pick(_row_fetch(w_ref[:], hi, dtype), c_lo)
+def _tile_gather_kernel(tmap_ref, ext_ref, w_ref, uniq_ref, out_ref, *,
+                        dtype):
+    b = pl.program_id(0)
+    base = tmap_ref[b] * TILE
+    ext = ext_ref[b]
+
+    def body(sl):
+        local = uniq_ref[sl] - base
+        hi = local >> 7
+        lo = local & (LANES - 1)
+        # sentinel slots (uniq == num_buckets) produce hi outside
+        # [0, TILE_HI): their one-hot row is all zeros, so they fetch
+        # 0.0 — no clamp needed
+        c_lo = _onehot(lo, LANES, dtype)
+        out_ref[sl] = _lane_pick(_row_fetch(w_ref[:], hi, dtype), c_lo)
+
+    # past the extent every slot is a sentinel hole and reads 0.0
+    @pl.when(ext <= BLK_U // 2)
+    def _():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    _live_chunks(ext, BLK_U, body)
 
 
 def tile_gather(table2, uniq, tmap_u, dtype=None):
@@ -597,11 +699,12 @@ def tile_gather(table2, uniq, tmap_u, dtype=None):
         dtype = jnp.bfloat16 if not _use_interpret() else jnp.float32
     nb = tmap_u.shape[0]
     u_cap = nb * BLK_U
+    ext = block_extents(uniq != table2.shape[0] * LANES, BLK_U)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((TILE_HI, LANES), lambda b, tmap: (tmap[b], 0)),
+            pl.BlockSpec((TILE_HI, LANES), lambda b, tmap, *_: (tmap[b], 0)),
             pl.BlockSpec((BLK_U,), lambda b, *_: (b,)),
         ],
         out_specs=pl.BlockSpec((BLK_U,), lambda b, *_: (b,)),
@@ -614,7 +717,7 @@ def tile_gather(table2, uniq, tmap_u, dtype=None):
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
         name="tile_gather",
-    )(tmap_u, table2, uniq)
+    )(tmap_u, ext, table2, uniq)
 
 
 # ------------------------------------------------------------ FM / SpMM

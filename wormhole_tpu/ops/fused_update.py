@@ -11,6 +11,15 @@ through aliased in/out buffers — so a training step performs NO XLA
 element gathers or scatters of optimizer state at all, and untouched
 tiles are never streamed.
 
+Cost: the scatter's one-hot operands are built only over a block's live
+slots (a prefix of the block, whose extent scatter_update derives on the
+device; coo_kernels._live_chunks), so a touched tile costs its three
+tiles in and out plus the handle math, whatever the block's capacity:
+at 2^29 buckets, ~30 keys in each of 8,192 tiles, ~2.6 us a tile for
+1.5 MB of traffic (measured on v5e, PERF.md §5). A block that both
+opens and closes its tile's run skips the copy-through: the apply
+overwrites every output tile anyway.
+
 Semantics match models/linear._update exactly:
 - FTRL: w is a pure function of (z, n); entries with zero gradient
   round-trip unchanged, so updating the whole tile is a no-op exactly
@@ -37,9 +46,10 @@ import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
 from wormhole_tpu.ops.coo_kernels import (_VMEM_LIMIT, BLK_U, LANES,
-                                          TILE, TILE_HI, _onehot,
-                                          _onehot_t, _prec, _row_fetch,
-                                          _use_interpret)
+                                          TILE, TILE_HI, _live_chunks,
+                                          _onehot, _onehot_t, _prec,
+                                          _row_fetch, _use_interpret,
+                                          block_extents)
 from wormhole_tpu.ops.penalty import l1l2_solve
 
 
@@ -80,9 +90,9 @@ def _apply(algo: str, z, n, w, g, touched, *, lr_eta, lr_beta,
     raise ValueError(f"unknown algo {algo!r}")
 
 
-def _kernel(tmap_ref, first_ref, last_ref, qscale_ref, g_ref, uniq_ref,
-            *refs, algo: str, dtype, fixed_bytes: int, hyper: dict,
-            n_state: int, with_add: bool):
+def _kernel(tmap_ref, first_ref, last_ref, ext_ref, qscale_ref, g_ref,
+            uniq_ref, *refs, algo: str, dtype, fixed_bytes: int,
+            hyper: dict, n_state: int, with_add: bool):
     # refs = [add values (if with_add)] + state-in tiles (n_state, plus
     # the additive table last if with_add), then the matching out tiles,
     # then nw_out, then the g_acc scratch (+ add_acc scratch)
@@ -105,39 +115,49 @@ def _kernel(tmap_ref, first_ref, last_ref, qscale_ref, g_ref, uniq_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
         if with_add:
             add_acc[:] = jnp.zeros_like(add_acc)
-        # copy-through so a partially-visited tile flushes its original
-        # values, never uninitialized VMEM
+
+    # copy-through so a tile whose run has further blocks flushes its
+    # original values, never uninitialized VMEM; a block that also ends
+    # its tile's run overwrites all of them below
+    @pl.when((first_ref[b] == 1) & (last_ref[b] == 0))
+    def _():
         for i_ref, o_ref in zip(in_refs, out_refs):
             o_ref[:] = i_ref[:]
 
     base = tmap_ref[b] * TILE
-    local = uniq_ref[:] - base
-    hi = local >> 7
-    lo = local & (LANES - 1)
-    # sentinel slots (uniq == num_buckets) fall outside [0, TILE_HI) and
-    # contribute all-zero one-hot rows — they scatter nothing
-    e_t = _onehot_t(hi, TILE_HI, dtype)
-    c_lo = _onehot(lo, LANES, dtype)
-    acc_ref[:] += jax.lax.dot_general(
-        e_t, (g_ref[:][:, None] * c_lo).astype(dtype),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=_prec(dtype),
-    )
-    if with_add:
-        # a second additive table (difacto's cnt) rides the same
-        # one-hots: scattering it here replaces an XLA element scatter
-        # into the full bucket table (~4 ms at the Criteo shape).
-        # Occurrence counts above 256 would round in bf16, so this
-        # matmul stays f32 regardless of the kernel dtype (counts are
-        # integers — exact in f32 up to 2^24).
-        add_acc[:] += jax.lax.dot_general(
-            e_t.astype(jnp.float32), add_ref[:][:, None] * c_lo.astype(
-                jnp.float32),
+
+    def scatter(sl):
+        local = uniq_ref[sl] - base
+        hi = local >> 7
+        lo = local & (LANES - 1)
+        # sentinel slots (uniq == num_buckets) fall outside [0, TILE_HI)
+        # and contribute all-zero one-hot rows — they scatter nothing
+        e_t = _onehot_t(hi, TILE_HI, dtype)
+        c_lo = _onehot(lo, LANES, dtype)
+        acc_ref[:] += jax.lax.dot_general(
+            e_t, (g_ref[sl][:, None] * c_lo).astype(dtype),
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
+            precision=_prec(dtype),
         )
+        if with_add:
+            # a second additive table (difacto's cnt) rides the same
+            # one-hots: scattering it here replaces an XLA element
+            # scatter into the full bucket table (~4 ms at the Criteo
+            # shape). Occurrence counts above 256 would round in bf16,
+            # so this matmul stays f32 regardless of the kernel dtype
+            # (counts are integers — exact in f32 up to 2^24).
+            add_acc[:] += jax.lax.dot_general(
+                e_t.astype(jnp.float32),
+                add_ref[sl][:, None] * c_lo.astype(jnp.float32),
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            )
+
+    # only the block's live slots scatter; the first / last bookkeeping
+    # above and the apply below run whatever the block holds
+    _live_chunks(ext_ref[b], BLK_U, scatter)
 
     @pl.when(last_ref[b] == 1)
     def _():
@@ -360,14 +380,15 @@ def scatter_update(algo: str, state: dict, g, uniq, tmap_u, first_u,
     hyper = dict(lr_eta=lr_eta, lr_beta=lr_beta, lambda_l1=lambda_l1,
                  lambda_l2=lambda_l2)
 
-    def tile_map(b, tmap, first, last, qs):
+    def tile_map(b, tmap, *_):
         return (tmap[b], 0)
 
+    ext = block_extents(uniq != num_buckets, BLK_U)
     add_specs = ([pl.BlockSpec((BLK_U,), lambda b, *_: (b,))]
                  if with_add else [])
     add_args = [add_values] if with_add else []
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((BLK_U,), lambda b, *_: (b,)),   # g
@@ -385,9 +406,9 @@ def scatter_update(algo: str, state: dict, g, uniq, tmap_u, first_u,
                                        jnp.float32) for _ in tabs] + [
         jax.ShapeDtypeStruct((8, LANES), jnp.float32)]
     # alias each state table input onto its output: flat input index =
-    # 4 scalar-prefetch args + 2 (g, uniq) + optional add values +
+    # 5 scalar-prefetch args + 2 (g, uniq) + optional add values +
     # table position
-    base_in = 4 + 2 + (1 if with_add else 0)
+    base_in = 5 + 2 + (1 if with_add else 0)
     aliases = {base_in + i: i for i in range(len(tabs))}
     outs = pl.pallas_call(
         partial(_kernel, algo=algo, dtype=dtype, fixed_bytes=fixed_bytes,
@@ -399,7 +420,7 @@ def scatter_update(algo: str, state: dict, g, uniq, tmap_u, first_u,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
         name="fused_update",
-    )(tmap_u, first_u, last_u, qscale, g, uniq, *add_args, *tabs)
+    )(tmap_u, first_u, last_u, ext, qscale, g, uniq, *add_args, *tabs)
     new_tabs, nw = outs[:-1], outs[-1]
     new_state = dict(state)
     for k, t in zip(order, new_tabs):
