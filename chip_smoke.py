@@ -137,15 +137,48 @@ def device_info() -> dict:
 
 
 # --------------------------------------------------------------------- data
+# Criteo-like per-field value cardinalities: 13 integer features (small
+# ranges after the log transform) + 26 categorical with a mix of tiny
+# (geo/flag-like) and huge (id-like) vocabularies.
+FIELD_CARDS = [50] * 13 + [
+    10, 100, 1000, 10_000, 100_000, 1_000_000, 10_000_000,
+    25, 250, 2500, 25_000, 250_000, 2_500_000,
+    40, 400, 4000, 40_000, 400_000, 4_000_000,
+    60, 600, 6000, 60_000, 600_000,
+    80, 800,
+]
+assert len(FIELD_CARDS) == 39
+
+
+def criteo_field_draws(rng, n):
+    """(n, 39) per-field value draws: Zipf-ish within each field's
+    vocabulary (CTR datasets are power-law within each field)."""
+    draws = np.empty((n, len(FIELD_CARDS)), dtype=np.uint64)
+    for f, card in enumerate(FIELD_CARDS):
+        draws[:, f] = rng.zipf(1.2, size=n).astype(np.uint64) % card
+    return draws
+
+
+def mix_field_values(draws):
+    """64-bit key per (field, value): per-field salt then a splitmix-style
+    mix, matching the criteo parser's field-salted hashing
+    (criteo_parser.h:69-82)."""
+    with np.errstate(over="ignore"):  # 64-bit mixing wraps by design
+        x = draws + (np.arange(draws.shape[1], dtype=np.uint64)
+                     * np.uint64(0x9E3779B97F4A7C15))
+        x ^= x >> np.uint64(30)
+        x *= np.uint64(0xBF58476D1CE4E5B9)
+        x ^= x >> np.uint64(27)
+    return x
+
+
 def write_criteo_files(out_dir: str, prefix: str, parts: int,
                        rows_per_part: int, seed: int) -> str:
     """Criteo-format text (label, 13 ints, 26 hex categoricals, tabs) with
-    bench.py's field cardinalities and Zipf draws; returns the file
+    FIELD_CARDS' cardinalities and Zipf draws; returns the file
     pattern. Categorical tokens carry the field-salted mix of the value,
     integer tokens the bare value (like the real data, where the same
     integer in two fields is the same token)."""
-    from bench import criteo_field_draws, mix_field_values
-
     fmt = "%d\t" + "%d\t" * 13 + "\t".join(["%08x"] * 26) + "\n"
     for p in range(parts):
         rng = np.random.default_rng([seed, p])

@@ -1,7 +1,8 @@
 """Pallas COO kernels vs the XLA segment-op reference implementations.
 
 Runs in interpret mode on the CPU test mesh; the same code compiles to
-Mosaic on TPU (bench.py exercises that path).
+Mosaic on TPU (tests/test_tpu_aot.py compiles them for v5e; chip_smoke.py
+and the benchmark run them there).
 """
 
 import jax.numpy as jnp

@@ -221,7 +221,7 @@ def test_compacted_matches_xla(synth_file, algo):
 
     p_x, l_x = run("xla", 0)
     p_r, l_r = run("pallas", ck.TILE)
-    assert l_r._compact_cap == ck.TILE and l_r._tcoo_steps is not None
+    assert l_r._compact_cap == ck.TILE and "tcoo" in l_r._kinds
     assert abs(p_x["logloss"] - p_r["logloss"]) < 1e-3
     assert abs(p_x["auc"] - p_r["auc"]) < 1e-3
     w_x = l_x.store.to_numpy()["w"]
@@ -268,3 +268,129 @@ def test_compacted_predict_and_eval(synth_file):
     acc = ((margins > 0) == (blk.label > 0.5)).mean()
     ev = lrn.eval_batch(blk)
     np.testing.assert_allclose(acc, ev["acc"] / ev["nex"], atol=1e-6)
+
+
+# ------------------------------------------- one record a kind, one path
+# kind -> (config, mesh shape, pack_cache_token written out). The token's
+# tail is the block geometry (TILE, BLK, BLK_U, LANES): a data format,
+# constant beside the kernels
+_KINDS = {
+    "xla": (dict(kernel="xla"), (1, 1),
+            ("linear", 1, False, False, 0, 4096, 256, 8, 262144, 1, 1,
+             65536, 4096, 1024, 128)),
+    "coo": (dict(kernel="pallas", compact_cap=0), (1, 1),
+            ("linear", 1, True, False, 0, 4096, 256, 8, 262144, 1, 1,
+             65536, 4096, 1024, 128)),
+    "tcoo": (dict(kernel="pallas", compact_cap=1), (1, 1),
+             ("linear", 1, True, False, 65536, 4096, 256, 8, 262144, 1, 1,
+              65536, 4096, 1024, 128)),
+    "mcoo": (dict(kernel="pallas", model_shards=2), (2, 2),
+             ("linear", 1, True, True, 0, 4096, 256, 8, 262144, 2, 2,
+              65536, 4096, 1024, 128)),
+}
+
+
+def _kind_learner(kind, **kw):
+    conf, mesh, _ = _KINDS[kind]
+    cfg = LinearConfig(minibatch=256, num_buckets=1 << 18, nnz_per_row=8,
+                       algo="ftrl", lr_eta=0.5, lambda_l1=0.1,
+                       kernel_dtype="f32", **conf, **kw)
+    return LinearLearner(cfg, make_mesh(*mesh))
+
+
+def _kind_blocks(n=3, rows=200, nnz=8, nb=1 << 18):
+    from wormhole_tpu.data.rowblock import RowBlock
+
+    rng = np.random.default_rng(28)
+    return [RowBlock(
+        label=(rng.random(rows) < 0.4).astype(np.float32),
+        offset=np.arange(0, rows * nnz + 1, nnz, dtype=np.int64),
+        index=rng.integers(0, nb, rows * nnz).astype(np.uint64),
+        value=rng.random(rows * nnz).astype(np.float32))
+        for _ in range(n)]
+
+
+@pytest.mark.parametrize("kind", list(_KINDS))
+def test_every_form_of_a_batch_takes_the_one_path(kind):
+    """A RowBlock, prepare_batch's tuple and stage_batch's tuple reach the
+    kind's step the same way: progress and tables are the same to the
+    bit, and so are predict's margins for the two forms it takes."""
+    forms = {
+        "rowblock": lambda lrn, blk, train: blk,
+        "prepared": lambda lrn, blk, train: lrn.prepare_batch(blk, train),
+        "staged": lambda lrn, blk, train: lrn.stage_batch(
+            lrn.prepare_batch(blk, train), train),
+    }
+    b0, b1, b2 = _kind_blocks()
+    got = {}
+    for name, form in forms.items():
+        lrn = _kind_learner(kind)
+        progs = [lrn.train_batch(form(lrn, b0, True)),
+                 lrn.train_batch(form(lrn, b1, True)),
+                 lrn.eval_batch(form(lrn, b2, False))]
+        if name != "staged":    # a staged batch was never a predict input
+            progs.append(lrn.predict_batch(form(lrn, b2, False)))
+        got[name] = (progs, lrn.store.to_numpy())
+    assert got["rowblock"][0][0]["nex"] == 200.0
+    assert got["rowblock"][0][0]["new_w"] > 0
+    ref_progs, ref_tables = got["rowblock"]
+    for name in ("prepared", "staged"):
+        progs, tables = got[name]
+        assert progs[:3] == ref_progs[:3], name
+        for k, v in ref_tables.items():
+            np.testing.assert_array_equal(tables[k], v, err_msg=name)
+    np.testing.assert_array_equal(got["prepared"][0][3], ref_progs[3])
+    assert ref_progs[3].shape == (200,) and np.any(ref_progs[3] != 0)
+
+
+@pytest.mark.parametrize("kind", list(_KINDS))
+def test_a_batch_staged_for_the_other_step_is_refused(kind):
+    lrn = _kind_learner(kind)
+    blk = _kind_blocks(1)[0]
+    with pytest.raises(AssertionError, match="staged for eval"):
+        lrn.train_batch(lrn.stage_batch(blk, train=False))
+    with pytest.raises(AssertionError, match="staged for train"):
+        lrn.eval_batch(lrn.stage_batch(blk, train=True))
+
+
+@pytest.mark.parametrize("kind", list(_KINDS))
+def test_batch_layouts_and_pack_cache_token_are_pinned(kind):
+    """The pack cache's entries, benchmark/check.py and chip_smoke.py read
+    these tuples by position, and the token keys the cache's entries."""
+    from wormhole_tpu.data.rowblock import DeviceBatch
+
+    lrn = _kind_learner(kind)
+    lrn.track_touched = True
+    blk = _kind_blocks(1)[0]
+    assert lrn._PACK_VERSION == 1
+    if kind == "tcoo":      # undecided until the first batch sizes it
+        assert lrn.pack_cache_token() is None
+    b = lrn.prepare_batch(blk)
+    assert lrn.pack_cache_token() == _KINDS[kind][2]
+    assert b[0] == kind and b[-1] == 200
+    if kind == "xla":
+        assert len(b) == 3 and isinstance(b[1], DeviceBatch)
+        label, mask = b[1].label, b[1].row_mask
+    else:
+        assert len(b) == 5
+        label, mask = b[2], b[3]
+    assert label.shape == mask.shape == (256,)
+    np.testing.assert_array_equal(label[:200], blk.label)
+    assert mask.sum() == 200
+    for train in (True, False):
+        st = lrn.stage_batch(b, train)
+        assert len(st) == 6 and st[:2] == ("staged", kind)
+        assert st[3] == 200 and st[5] is train
+        assert lrn.stage_batch(st, train) is st
+        args = st[2]
+        assert isinstance(args, tuple)
+        np.testing.assert_array_equal(np.asarray(args[-2]), label)
+        np.testing.assert_array_equal(np.asarray(args[-1]), mask)
+        ids = st[4]
+        if not train or kind == "mcoo":   # mcoo: left to the delta scan
+            assert ids is None
+        else:
+            want = np.unique(blk.index.astype(np.int64) % (1 << 18))
+            if kind == "tcoo":      # the padding's bucket rides along
+                want = np.union1d(want, [0])
+            np.testing.assert_array_equal(ids, want)

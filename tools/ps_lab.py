@@ -10,9 +10,7 @@ row assembly), pull_apply (client-side scatter of pulled rows), wire
 the composed loops: sync mode ms/sync, async mode ms/sync as the train
 loop sees it (with simulated compute between syncs) plus the measured
 overlap fraction, and the key-cache wire saving (bytes/sync, first sync
-vs steady state). Extends tools/ps_sync_micro.py, which only had the
-3-way gather/push/pull split; this is where PERF.md "PS plane" numbers
-come from.
+vs steady state). This is where PERF.md "PS plane" numbers come from.
 
 The hot-plane stage table (hot_* rows) times the device-resident path
 the same sync rides when WH_PS_PLANE=hot: sharded row gather (ZPull),

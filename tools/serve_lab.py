@@ -13,8 +13,8 @@ versions mid-load, and shard kill/respawn recovery when --chaos kills
 a shard mid-load (the run asserts ZERO failed requests: the router
 must absorb the death through redial + seq-replayed fetches).
 
-This is where PERF.md serving numbers and the bench.py --group serve
-row come from; the final line is machine-readable:
+This is where PERF.md serving numbers come from; the final line is
+machine-readable:
 
     [serve-lab] {"qps": ..., "p50_ms": ..., "p99_ms": ..., ...}
 
@@ -256,7 +256,7 @@ def run(num_shards: int = 2, num_buckets: int = 1 << 20,
                 * 1e3)
     # stage decomposition over THIS run's observations: count/sum are
     # delta'd against the run-start snapshot so a previous run in the
-    # same process (bench.py runs fetch then score back to back)
+    # same process (a caller that runs fetch then score back to back)
     # cannot leak stages it exercised — or its means — into this run's
     # table. Quantiles still read the full reservoirs, which are
     # recent-sample-biased toward this run (and the single warmup

@@ -1,6 +1,6 @@
 """wormlint: AST static analysis for wormhole-tpu's bug classes.
 
-Eight checkers over ``wormhole_tpu/``, ``tools/`` and ``bench.py``:
+Eight checkers over ``wormhole_tpu/`` and ``tools/``:
 lock-discipline, env-knobs, metric-names, jit-purity, thread-lifecycle,
 retry-policy, rpc-discipline, frame-header.
 See docs/static_analysis.md and ``python -m tools.wormlint --help``.

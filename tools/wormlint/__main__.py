@@ -17,7 +17,7 @@ import sys
 from . import run_checks
 from .core import (FileSource, _iter_py, load_baseline, match_baseline)
 
-_DEFAULT_ROOTS = ("wormhole_tpu", "tools", "bench.py")
+_DEFAULT_ROOTS = ("wormhole_tpu", "tools")
 
 
 def _repo_root() -> str:

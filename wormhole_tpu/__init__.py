@@ -24,8 +24,8 @@ import os as _os
 
 
 def _place_compile_cache() -> None:
-    """Persistent XLA compile cache for every entry point (apps, bench.py,
-    chip_smoke.py, launcher children): a cold linear step costs ~1 min of
+    """Persistent XLA compile cache for every entry point (apps,
+    benchmark/run.py, chip_smoke.py, launcher children): a cold linear step costs ~1 min of
     compile, mostly the AUC sort (PERF.md §6, PR 21). The path is part of
     the cache key, so it is fixed — JAX_COMPILATION_CACHE_DIR when the
     environment places it (JAX reads that itself; nothing is set here),

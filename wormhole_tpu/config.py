@@ -350,9 +350,6 @@ declare_knob("WH_NUM_LOADERS", int, None,
 declare_knob("WH_ADAPTIVE_LOADERS", bool, True,
              "Stall-driven loader pool resizing between passes (defaults on "
              "unless WH_NUM_LOADERS pins the size).", group="data")
-declare_knob("WH_DEVICE_FEED", bool, True,
-             "Loader-side device staging (double-buffered feed).",
-             group="data")
 
 # PS sync plane
 declare_knob("WH_ASYNC_SYNC", bool, False,
@@ -540,22 +537,12 @@ declare_knob("WH_ELASTIC_PLAN", str, "",
              "(seconds from job start): deterministic churn for drills; "
              "empty = gauge-driven controller decisions.", group="elastic")
 
-# kernel tuning (WORMHOLE_* block-size overrides for Pallas kernels)
-declare_knob("WORMHOLE_TILE_HI", int, 512,
-             "Sublanes per tile in the COO kernels.", group="kernel")
-declare_knob("WORMHOLE_BLK", int, 4096,
-             "Nonzeros per grid block in the COO kernels.", group="kernel")
-declare_knob("WORMHOLE_FM_BLK", int, 1024,
-             "FM kernel block size.", group="kernel")
+# kernel limits of the hardware (block geometry is a data format, and a
+# constant beside the kernels: ops/coo_kernels.py, ops/hist.py)
 declare_knob("WORMHOLE_FM_VMEM", int, 64 * 2**20,
              "FM kernel VMEM budget in bytes.", group="kernel")
 declare_knob("WORMHOLE_VMEM", int, 96 * 2**20,
              "COO kernel VMEM budget in bytes.", group="kernel")
-declare_knob("WORMHOLE_BLK_U", int, 1024,
-             "Update-kernel block size.", group="kernel")
-declare_knob("WORMHOLE_HIST_FGROUP", int, 7,
-             "Features per group in the GBDT histogram kernel.",
-             group="kernel")
 
 # debug / native escape hatches
 declare_knob("WORMHOLE_STACKDUMP", bool, False,

@@ -21,13 +21,16 @@ TPU design (vs the reference's worker/server processes):
 from __future__ import annotations
 
 import dataclasses
+import logging
 import threading
 from functools import partial
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from wormhole_tpu.data.rowblock import DeviceBatch, RowBlock, to_device_batch
 from wormhole_tpu.obs import trace as _trace
@@ -39,6 +42,8 @@ from wormhole_tpu.ops.spmv import spmv, spmv_t
 from wormhole_tpu.parallel.kvstore import KVStore, TableSpec, quantize_push
 from wormhole_tpu.parallel.mesh import (batch_sharding, describe_placement,
                                         make_mesh)
+
+_log = logging.getLogger(__name__)
 
 # the mesh pack, a batch at a time: nonzeros a full shard dropped, and
 # the fullest cell beside the sum of all cells (hot shard = max * cells
@@ -57,6 +62,51 @@ def _count_chunks(stream, dead, blk: int, kernels: int):
     n, run = ck.host_chunk_counts(stream, dead, blk)
     _CHUNKS.inc(n * kernels)
     _CHUNKS_RUN.inc(run * kernels)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Kind:
+    """One kind of batch. `LinearLearner._choose_kind` picks it; every
+    other method looks the record up by the name the batch's tuple
+    carries."""
+
+    pack: Callable     # (db, train) -> packed: host side, loader thread
+    args: Callable     # _device_args: what a step takes after the state
+    train: Callable    # (state, *args) -> (state, progress); donates state
+    eval: Callable     # (state, *args) -> progress
+    predict: Callable  # (state, *args less label and mask) -> margins
+    #: packed -> the unique buckets it touches (the sparse PS push set;
+    #: reference ZPush of the minibatch's keys, async_sgd.h:270-287), or
+    #: None = unknown, which forces a full delta scan
+    touched: Callable
+
+
+def _device_args(arrays, put, put_rows=None):
+    """A kind's `args`: the host arrays `arrays(packed, train)` names go
+    to the device through `put`, label and mask after them through
+    `put_rows` (`put` where the rows go the same way); predict passes
+    neither."""
+    put_rows = put_rows or put
+
+    def args(packed, label=None, mask=None, train=False):
+        out = [put(x) for x in arrays(packed, train)]
+        if label is not None:
+            out += [put_rows(label), put_rows(mask)]
+        return tuple(out)
+    return args
+
+
+def _split(b):
+    """(kind, packed, label, mask, size) of a prepared batch: the short
+    ("xla", db, size) form carries its label and mask inside db."""
+    if len(b) == 3:
+        kind, db, size = b
+        return kind, db, db.label, db.row_mask, size
+    return b
+
+
+def _nonzero_ids(packed) -> np.ndarray:
+    return np.unique(packed.idx[packed.val != 0]).astype(np.int64)
 
 
 @dataclasses.dataclass
@@ -283,145 +333,55 @@ class LinearLearner:
         else:
             self._coo_dtype = None
 
-        @partial(jax.jit, donate_argnums=0)
-        def train_step(state, seg, idx, val, label, mask):
-            w = state["w"]
-            xw = spmv(seg, idx, val, w, label.shape[0])
-            obj, d = _loss_dual(cfg.loss, label, xw)
-            d = d * mask
-            g = spmv_t(seg, idx, val, d, cfg.num_buckets)
-            # touched is derived from the unquantized gradient so that
-            # values the transfer filter rounds to zero still count as
-            # pushed (the reference server receives and shrinks them too)
-            raw_g = g
-            g = quantize_push(g, cfg.fixed_bytes)
-            g = self.store.constrain("w", g)
-            # The touched mask marks buckets that received a push this step.
-            # For FTRL it is unnecessary: g == 0 leaves z and n unchanged and
-            # w is a pure function of (z, n), so untouched buckets are exact
-            # no-ops without masking — this saves a second full scatter
-            # (~25% of step time on TPU). adagrad/sgd apply repeated L1
-            # shrinkage through l1l2_solve, so they still need the mask;
-            # g != 0 reproduces the reference's per-key Push granularity
-            # (async_sgd.h:160-175) except for exact zero-cancellation
-            # gradients, which the reference would push and shrink on.
-            if cfg.algo == "ftrl":
-                touched = 1.0
-            else:
-                touched = (raw_g != 0).astype(jnp.float32)
-            new_state = _update(cfg.algo, state, g, touched, cfg)
-            new_w = (jnp.sum(new_state["w"] != 0)
-                     - jnp.sum(w != 0)).astype(jnp.float32)
-            prog = _progress(obj, xw, label, mask, new_w)
-            return new_state, prog
-
-        @jax.jit
-        def eval_step(state, seg, idx, val, label, mask):
-            xw = spmv(seg, idx, val, state["w"], label.shape[0])
-            obj, _ = _loss_dual(cfg.loss, label, xw)
-            return _progress(obj, xw, label, mask)
-
-        @jax.jit
-        def predict_step(state, seg, idx, val):
-            return spmv(seg, idx, val, state["w"], cfg.minibatch)
-
-        self._train_step = train_step
-        self._eval_step = eval_step
-        self._predict_step = predict_step
-
-        @partial(jax.jit, donate_argnums=0)
-        def train_step_coo(state, sidx, sseg, sval, tmap, first, label, mask):
+        mesh, dt = self.mesh, self._coo_dtype
+        rows = partial(jax.device_put, device=self._bsh1)
+        cells = partial(jax.device_put, device=NamedSharding(
+            mesh, P("data", "model", None)))
+        self._kinds: dict[str, _Kind] = {
+            "xla": _Kind(
+                lambda db, train: db,
+                _device_args(lambda db, train: (db.seg, db.idx, db.val),
+                             rows),
+                *self._dense_steps(
+                    lambda w, seg, idx, val, n: spmv(seg, idx, val, w, n),
+                    lambda d, seg, idx, val, n: self.store.constrain(
+                        "w", spmv_t(seg, idx, val, d, n))),
+                _nonzero_ids),
             # NOTE r5: a row-major xw (XLA row gather from a widened w
-            # table) was tried here and measured ~50 ns/row — the dense
-            # table (num_buckets x 8 B, 32 MB at the headline shape) is
-            # too large for the fast-gather regime, unlike the compact
-            # paths (PERF.md "Row-gather regimes"). The radix-image
-            # kernel stays.
-            xw = ck.coo_spmv(state["w"], sidx, sseg, sval, tmap, first,
-                             cfg.minibatch, dtype=self._coo_dtype)
-            obj, d = _loss_dual(cfg.loss, label, xw)
-            d = d * mask
-            g = ck.coo_spmv_t(d, sidx, sseg, sval, tmap, first,
-                              cfg.num_buckets, dtype=self._coo_dtype)
-            raw_g = g
-            g = quantize_push(g, cfg.fixed_bytes)
-            if cfg.algo == "ftrl":
-                touched = 1.0
-            else:
-                touched = (raw_g != 0).astype(jnp.float32)
-            new_w = -jnp.sum(state["w"] != 0).astype(jnp.float32)
-            new_state = _update(cfg.algo, state, g, touched, cfg)
-            new_w = new_w + jnp.sum(new_state["w"] != 0)
-            return new_state, _progress(obj, xw, label, mask, new_w)
+            # table) was tried for coo and measured ~50 ns/row — the
+            # dense table (num_buckets x 8 B, 32 MB at the headline
+            # shape) is too large for the fast-gather regime, unlike the
+            # compact paths (PERF.md "Row-gather regimes"). The
+            # radix-image kernel stays.
+            "coo": _Kind(
+                self._pack_coo,
+                _device_args(
+                    lambda p, train: (p.idx, p.seg, p.val, p.tmap, p.first),
+                    jnp.asarray),
+                *self._dense_steps(partial(ck.coo_spmv, dtype=dt),
+                                   partial(ck.coo_spmv_t, dtype=dt)),
+                _nonzero_ids),
+            # tiles shard_map'ed over the model axis, rows over the data
+            # axis; psum plays ZPull/ZPush (async_sgd.h:277-287). The
+            # packed batch holds shard-local layouts, so its touched set
+            # is left to the full delta scan
+            "mcoo": _Kind(
+                self._pack_mcoo,
+                _device_args(
+                    lambda mc, train: (mc.sidx, mc.sseg, mc.sval, mc.tmap,
+                                       mc.first),
+                    cells, rows),
+                *self._dense_steps(
+                    partial(ck.mesh_coo_spmv, mesh, dtype=dt),
+                    partial(ck.mesh_coo_spmv_t, mesh, dtype=dt)),
+                lambda mc: None),
+        }
 
-        @jax.jit
-        def eval_step_coo(state, sidx, sseg, sval, tmap, first, label, mask):
-            xw = ck.coo_spmv(state["w"], sidx, sseg, sval, tmap, first,
-                             cfg.minibatch, dtype=self._coo_dtype)
-            obj, _ = _loss_dual(cfg.loss, label, xw)
-            return _progress(obj, xw, label, mask)
-
-        @jax.jit
-        def predict_step_coo(state, sidx, sseg, sval, tmap, first):
-            return ck.coo_spmv(state["w"], sidx, sseg, sval, tmap, first,
-                               cfg.minibatch, dtype=self._coo_dtype)
-
-        self._train_step_coo = train_step_coo
-        self._eval_step_coo = eval_step_coo
-        self._predict_step_coo = predict_step_coo
-
-        # mesh variants: tiles shard_map'ed over the model axis, rows over
-        # the data axis; psum plays ZPull/ZPush (async_sgd.h:277-287)
-        mesh = self.mesh
-
-        @partial(jax.jit, donate_argnums=0)
-        def train_step_mcoo(state, sidx, sseg, sval, tmap, first,
-                            label, mask):
-            w = state["w"]
-            xw = ck.mesh_coo_spmv(mesh, w, sidx, sseg, sval, tmap, first,
-                                  cfg.minibatch, dtype=self._coo_dtype)
-            obj, d = _loss_dual(cfg.loss, label, xw)
-            d = d * mask
-            g = ck.mesh_coo_spmv_t(mesh, d, sidx, sseg, sval, tmap, first,
-                                   cfg.num_buckets, dtype=self._coo_dtype)
-            raw_g = g
-            g = quantize_push(g, cfg.fixed_bytes)
-            if cfg.algo == "ftrl":
-                touched = 1.0
-            else:
-                touched = (raw_g != 0).astype(jnp.float32)
-            # the dense per-shard update: every bucket of the shard, the
-            # touched and the untouched alike
-            with jax.named_scope("mesh_update"):
-                new_state = _update(cfg.algo, state, g, touched, cfg)
-            new_w = (jnp.sum(new_state["w"] != 0)
-                     - jnp.sum(w != 0)).astype(jnp.float32)
-            return new_state, _progress(obj, xw, label, mask, new_w)
-
-        @jax.jit
-        def eval_step_mcoo(state, sidx, sseg, sval, tmap, first,
-                           label, mask):
-            xw = ck.mesh_coo_spmv(mesh, state["w"], sidx, sseg, sval,
-                                  tmap, first, cfg.minibatch,
-                                  dtype=self._coo_dtype)
-            obj, _ = _loss_dual(cfg.loss, label, xw)
-            return _progress(obj, xw, label, mask)
-
-        @jax.jit
-        def predict_step_mcoo(state, sidx, sseg, sval, tmap, first):
-            return ck.mesh_coo_spmv(mesh, state["w"], sidx, sseg, sval,
-                                    tmap, first, cfg.minibatch,
-                                    dtype=self._coo_dtype)
-
-        self._train_step_mcoo = train_step_mcoo
-        self._eval_step_mcoo = eval_step_mcoo
-        self._predict_step_mcoo = predict_step_mcoo
-
-        # compacted steps are built lazily once the unique-key capacity
-        # is known (auto mode sizes it from the first batch); the lock
-        # serializes the decide+build against concurrent loader threads
+        # the compacted kind ("tcoo") joins the table lazily, once the
+        # unique-key capacity is known (auto mode sizes it from the first
+        # batch); the lock serializes the decide+build against concurrent
+        # loader threads
         self._compact_cap: Optional[int] = None
-        self._tcoo_steps = None
         self._compact_lock = threading.Lock()
         if self._mesh_coo or not self.use_pallas or cfg.compact_cap == 0:
             self._compact_cap = 0
@@ -433,13 +393,14 @@ class LinearLearner:
 
     # -- global-mesh SPMD protocol (apps/_runner._global_train) ------------
     def global_step_protocol(self):
+        xla = self._kinds["xla"]
+
         def train_fn(args, rng):
-            self.store.state, prog = self._train_step(
-                self.store.state, *args)
+            self.store.state, prog = xla.train(self.store.state, *args)
             return prog
 
         def eval_fn(args):
-            return self._eval_step(self.store.state, *args)
+            return xla.eval(self.store.state, *args)
 
         return train_fn, eval_fn
 
@@ -448,13 +409,11 @@ class LinearLearner:
         (margins pinned to the batch sharding — so each rank reads back
         exactly its contributed rows — and the GLOBAL live-row count
         that drives the lockstep drain decision)."""
-        from wormhole_tpu.parallel.mesh import batch_sharding
-
-        bsh = batch_sharding(self.mesh, 1)
+        predict, bsh = self._kinds["xla"].predict, self._bsh1
 
         @jax.jit
         def pred(state, seg, idx, val, mask):
-            xw = self._predict_step(state, seg, idx, val)
+            xw = predict(state, seg, idx, val)
             return jax.lax.with_sharding_constraint(xw, bsh), jnp.sum(mask)
 
         def pred_fn(args):
@@ -474,18 +433,75 @@ class LinearLearner:
                       "lr_beta": cfg.lr_beta, "lambda_l1": cfg.lambda_l1,
                       "lambda_l2": cfg.lambda_l2}}
 
+    # -- the jitted steps ----------------------------------------------------
+    def _read_steps(self, pull):
+        """(eval, predict) of a kind whose `pull(w, *batch, rows)` is
+        xw = X w over its own batch arrays."""
+        cfg = self.cfg
+
+        @jax.jit
+        def eval_step(state, *args):
+            *batch, label, mask = args
+            xw = pull(state["w"], *batch, label.shape[0])
+            obj, _ = _loss_dual(cfg.loss, label, xw)
+            return _progress(obj, xw, label, mask)
+
+        @jax.jit
+        def predict_step(state, *batch):
+            return pull(state["w"], *batch, cfg.minibatch)
+
+        return eval_step, predict_step
+
+    def _dense_steps(self, pull, push):
+        """(train, eval, predict) of a kind that updates every bucket:
+        `push(d, *batch, num_buckets)` is g = X^T d in table layout."""
+        cfg = self.cfg
+
+        @partial(jax.jit, donate_argnums=0)
+        def train_step(state, *args):
+            *batch, label, mask = args
+            w = state["w"]
+            xw = pull(w, *batch, label.shape[0])
+            obj, d = _loss_dual(cfg.loss, label, xw)
+            d = d * mask
+            g = push(d, *batch, cfg.num_buckets)
+            # touched is derived from the unquantized gradient so that
+            # values the transfer filter rounds to zero still count as
+            # pushed (the reference server receives and shrinks them too)
+            raw_g = g
+            g = quantize_push(g, cfg.fixed_bytes)
+            # The touched mask marks buckets that received a push this step.
+            # For FTRL it is unnecessary: g == 0 leaves z and n unchanged and
+            # w is a pure function of (z, n), so untouched buckets are exact
+            # no-ops without masking — this saves a second full scatter
+            # (~25% of step time on TPU). adagrad/sgd apply repeated L1
+            # shrinkage through l1l2_solve, so they still need the mask;
+            # g != 0 reproduces the reference's per-key Push granularity
+            # (async_sgd.h:160-175) except for exact zero-cancellation
+            # gradients, which the reference would push and shrink on.
+            if cfg.algo == "ftrl":
+                touched = 1.0
+            else:
+                touched = (raw_g != 0).astype(jnp.float32)
+            new_state = _update(cfg.algo, state, g, touched, cfg)
+            new_w = (jnp.sum(new_state["w"] != 0)
+                     - jnp.sum(w != 0)).astype(jnp.float32)
+            return new_state, _progress(obj, xw, label, mask, new_w)
+
+        return (train_step, *self._read_steps(pull))
+
     # -- unique-key compaction ---------------------------------------------
     def ensure_compact(self, idx) -> int:
         """Decide (once, from the first batch) whether the unique-key
-        compacted path engages and build its jitted steps. Returns the
+        compacted path engages and build its kind record. Returns the
         compact capacity (0 = dense path)."""
         with self._compact_lock:
             if self._compact_cap is None:
                 cap = self._decide_compact_cap(idx)
                 if cap:
                     self._build_tcoo(cap)
-                # publish the cap only after the steps exist, so a racing
-                # reader can never see cap set but steps still None
+                # publish the cap only after the record exists, so a
+                # racing reader can never see cap set but no "tcoo" kind
                 self._compact_cap = cap
         return self._compact_cap
 
@@ -511,27 +527,27 @@ class LinearLearner:
         return 0
 
     def _build_tcoo(self, U: int):
-        cfg = self.cfg
+        cfg, dt = self.cfg, self._coo_dtype
         from wormhole_tpu.ops.fused_update import scatter_update
 
-        def rm_xw_c(wc, rm_slot, rm_val):
+        def pull_c(w, uniq, tmap_u, rm_slot, rm_val, rows):
+            w2 = w.reshape(-1, ck.LANES)
+            wc = ck.tile_gather(w2, uniq, tmap_u, dtype=dt)
             # same row-major pull as the dense path, over the compact wc
             wz = jnp.concatenate([wc, jnp.zeros((1,), wc.dtype)])
             w2c = jnp.stack([wz, wz], axis=1)
             got = jnp.take(w2c, rm_slot, axis=0)[:, 0]
-            return (rm_val * got).reshape(cfg.minibatch, -1).sum(1)
+            return (rm_val * got).reshape(rows, -1).sum(1)
 
         @partial(jax.jit, donate_argnums=0)
         def train_step_tcoo(state, uniq, tmap_u, first_u, last_u,
                             sidx, sseg, sval, tmap, first,
                             rm_slot, rm_val, label, mask):
-            w2 = state["w"].reshape(-1, ck.LANES)
-            wc = ck.tile_gather(w2, uniq, tmap_u, dtype=self._coo_dtype)
-            xw = rm_xw_c(wc, rm_slot, rm_val)
+            xw = pull_c(state["w"], uniq, tmap_u, rm_slot, rm_val,
+                        cfg.minibatch)
             obj, d = _loss_dual(cfg.loss, label, xw)
             d = d * mask
-            g = ck.coo_spmv_t(d, sidx, sseg, sval, tmap, first, U,
-                              dtype=self._coo_dtype)
+            g = ck.coo_spmv_t(d, sidx, sseg, sval, tmap, first, U, dtype=dt)
             # the scatter, quantization filter, touched masking, and the
             # per-key handle update all happen inside the fused kernel,
             # in place on the touched tiles
@@ -539,96 +555,101 @@ class LinearLearner:
                 cfg.algo, state, g, uniq, tmap_u, first_u, last_u,
                 lr_eta=cfg.lr_eta, lr_beta=cfg.lr_beta,
                 lambda_l1=cfg.lambda_l1, lambda_l2=cfg.lambda_l2,
-                fixed_bytes=cfg.fixed_bytes, dtype=self._coo_dtype)
+                fixed_bytes=cfg.fixed_bytes, dtype=dt)
             return new_state, _progress(obj, xw, label, mask, new_w)
 
-        # eval/predict read only the gathered compact w and the row-major
-        # (rm_slot, rm_val) pull — the COO stream and update-block bounds
-        # are train-only, so shipping them host→device every eval batch
-        # was pure waste (ADVICE #3)
-        @jax.jit
-        def eval_step_tcoo(state, uniq, tmap_u, rm_slot, rm_val,
-                           label, mask):
-            w2 = state["w"].reshape(-1, ck.LANES)
-            wc = ck.tile_gather(w2, uniq, tmap_u, dtype=self._coo_dtype)
-            xw = rm_xw_c(wc, rm_slot, rm_val)
-            obj, _ = _loss_dual(cfg.loss, label, xw)
-            return _progress(obj, xw, label, mask)
+        def arrays(tc, train):
+            # eval/predict read only the gathered compact w and the
+            # row-major (rm_slot, rm_val) pull: the COO stream and the
+            # update-block bounds feed the train step's gradient
+            # transpose and fused scatter alone, and go only with it
+            p = tc.coo
+            mid = (tc.first_u, tc.last_u, p.idx, p.seg, p.val, p.tmap,
+                   p.first) if train else ()
+            return (tc.uniq, tc.tmap_u, *mid, tc.rm_slot, tc.rm_val)
 
-        @jax.jit
-        def predict_step_tcoo(state, uniq, tmap_u, rm_slot, rm_val):
-            w2 = state["w"].reshape(-1, ck.LANES)
-            wc = ck.tile_gather(w2, uniq, tmap_u, dtype=self._coo_dtype)
-            return rm_xw_c(wc, rm_slot, rm_val)
-
-        self._tcoo_steps = (train_step_tcoo, eval_step_tcoo,
-                            predict_step_tcoo)
+        self._kinds["tcoo"] = _Kind(
+            self._pack_tcoo, _device_args(arrays, jnp.asarray),
+            train_step_tcoo, *self._read_steps(pull_c),
+            lambda tc: tc.uniq[tc.uniq < cfg.num_buckets].astype(np.int64))
 
     # -- device batch plumbing ---------------------------------------------
-    def _shard(self, *arrays):
-        return tuple(jax.device_put(x, self._bsh1) for x in arrays)
-
     def make_device_batch(self, blk: RowBlock) -> DeviceBatch:
         db = to_device_batch(
             blk, self.cfg.minibatch, self.cfg.row_capacity, self.cfg.num_buckets
         )
         if db.dropped_rows:
             self._dropped_rows += db.dropped_rows
-            import logging
-
-            logging.getLogger(__name__).warning(
+            _log.warning(
                 "minibatch overflow: dropped %d rows (total %d) — raise "
                 "nnz_per_row or minibatch capacity",
                 db.dropped_rows, self._dropped_rows,
             )
         return db
 
+    def _choose_kind(self, db: DeviceBatch) -> str:
+        """The one place that decides what kind of batch this learner
+        makes: everything downstream looks `self._kinds` up by the name
+        the batch's tuple carries."""
+        if not self.use_pallas:
+            return "xla"
+        if self._mesh_coo:
+            return "mcoo"
+        if self.ensure_compact(db.idx):
+            return "tcoo"
+        return "coo"
+
     def prepare_batch(self, blk: RowBlock, train: bool = True):
         """Host-side batch prep (runs in loader threads): pad to the fixed
         device shape, and for the pallas path additionally tile-sort the
         COO triples (the Localizer role). Returns an opaque prepared batch
-        accepted by train/eval/predict_batch."""
+        accepted by train/eval/predict_batch: (kind, packed, label, mask,
+        size), or ("xla", db, size) where the padded batch is the packed
+        one."""
         db = self.make_device_batch(blk)
-        if not self.use_pallas:
-            return ("xla", db, blk.size)
-        if self._mesh_coo:
-            D = self.mesh.shape.get("data", 1)
-            M = self.mesh.shape.get("model", 1)
-            mc = ck.pack_mesh_coo(db.idx, db.seg, db.val,
-                                  self.cfg.num_buckets, self.cfg.minibatch,
-                                  D, M, self._shard_cap)
-            _MESH_NNZ_MAX.inc(int(mc.cell_nnz.max()))
-            _MESH_NNZ_SUM.inc(int(mc.cell_nnz.sum()))
-            if mc.dropped_nnz:
-                import logging
+        kind = self._choose_kind(db)
+        packed = self._kinds[kind].pack(db, train)
+        if packed is db:
+            return (kind, db, blk.size)
+        return (kind, packed, db.label, db.row_mask, blk.size)
 
-                _MESH_DROPPED.inc(mc.dropped_nnz)
-                logging.getLogger(__name__).warning(
-                    "mesh shard overflow: dropped %d nonzeros — raise "
-                    "nnz_per_row or mesh_capacity slack", mc.dropped_nnz)
-            if train:  # pull and push walk the same COO blocks
-                _count_chunks(mc.sval, 0, ck.BLK, 2)
-            return ("mcoo", mc, db.label, db.row_mask, blk.size)
-        if self.ensure_compact(db.idx):
-            tc = ck.pack_tile_coo(db.idx, db.seg, db.val,
-                                  self.cfg.num_buckets, self._compact_cap,
-                                  capacity=self.cfg.row_capacity,
-                                  rm_rows=self.cfg.minibatch,
-                                  rm_width=self.cfg.nnz_per_row)
-            if tc.dropped_nnz:
-                import logging
+    def _pack_mcoo(self, db: DeviceBatch, train: bool):
+        D = self.mesh.shape.get("data", 1)
+        M = self.mesh.shape.get("model", 1)
+        mc = ck.pack_mesh_coo(db.idx, db.seg, db.val,
+                              self.cfg.num_buckets, self.cfg.minibatch,
+                              D, M, self._shard_cap)
+        _MESH_NNZ_MAX.inc(int(mc.cell_nnz.max()))
+        _MESH_NNZ_SUM.inc(int(mc.cell_nnz.sum()))
+        if mc.dropped_nnz:
+            _MESH_DROPPED.inc(mc.dropped_nnz)
+            _log.warning(
+                "mesh shard overflow: dropped %d nonzeros — raise "
+                "nnz_per_row or mesh_capacity slack", mc.dropped_nnz)
+        if train:  # pull and push walk the same COO blocks
+            _count_chunks(mc.sval, 0, ck.BLK, 2)
+        return mc
 
-                logging.getLogger(__name__).warning(
-                    "compaction overflow: dropped %d unique keys "
-                    "(%d nonzeros) — raise compact_cap (currently %d)",
-                    tc.dropped_uniq, tc.dropped_nnz, self._compact_cap)
-            if train:  # tile_gather and the fused update; the push
-                _count_chunks(tc.uniq, self.cfg.num_buckets, ck.BLK_U, 2)
-                _count_chunks(tc.coo.val, 0, ck.BLK, 1)
-            return ("tcoo", tc, db.label, db.row_mask, blk.size)
-        p = ck.pack_sorted_coo(db.idx, db.seg, db.val, self.cfg.num_buckets,
-                               capacity=self.cfg.row_capacity)
-        return ("coo", p, db.label, db.row_mask, blk.size)
+    def _pack_tcoo(self, db: DeviceBatch, train: bool):
+        tc = ck.pack_tile_coo(db.idx, db.seg, db.val,
+                              self.cfg.num_buckets, self._compact_cap,
+                              capacity=self.cfg.row_capacity,
+                              rm_rows=self.cfg.minibatch,
+                              rm_width=self.cfg.nnz_per_row)
+        if tc.dropped_nnz:
+            _log.warning(
+                "compaction overflow: dropped %d unique keys "
+                "(%d nonzeros) — raise compact_cap (currently %d)",
+                tc.dropped_uniq, tc.dropped_nnz, self._compact_cap)
+        if train:  # tile_gather and the fused update; the push
+            _count_chunks(tc.uniq, self.cfg.num_buckets, ck.BLK_U, 2)
+            _count_chunks(tc.coo.val, 0, ck.BLK, 1)
+        return tc
+
+    def _pack_coo(self, db: DeviceBatch, train: bool):
+        return ck.pack_sorted_coo(db.idx, db.seg, db.val,
+                                  self.cfg.num_buckets,
+                                  capacity=self.cfg.row_capacity)
 
     def _prepared(self, x):
         if isinstance(x, RowBlock):
@@ -659,63 +680,31 @@ class LinearLearner:
 
     # -- double-buffered device feed -----------------------------------------
     def stage_batch(self, b, train: bool = True):
-        """Move a prepared batch's arrays to the device from the loader
-        thread, so the host->device transfer of batch N+1 overlaps the
-        main thread's step on batch N. Returns a staged tuple that
-        train_batch/eval_batch consume without further transfers. The
-        `train` flag must match the consuming step (tcoo ships the COO
-        stream + update-block bounds only for training)."""
+        """Move a batch's arrays to the device (a RowBlock is prepared
+        first; a staged batch comes back as it is). The solver calls this
+        from the loader thread, so the host->device transfer of batch N+1
+        overlaps the main thread's step on batch N; train_batch /
+        eval_batch call it on whatever they are given, so every batch
+        reaches its step this one way. Returns ("staged", kind, args,
+        size, ids, train). The `train` flag must match the consuming step
+        (tcoo ships the COO stream + update-block bounds only for
+        training)."""
         b = self._prepared(b)
         if b[0] == "staged":
             return b
-        kind, size = b[0], b[-1]
-        # touched-id extraction needs the host arrays; grab it now
-        # because after staging only device arrays remain
-        ids = self._touched_ids(b) if (train and self.track_touched) \
-            else None
-        if kind == "mcoo":
-            _, mc, label, mask, _ = b
-            args = tuple(self._mcoo_args(mc, label, mask))
+        kind, packed, label, mask, size = _split(b)
+        k = self._kinds[kind]
+        # the touched ids need the host arrays; grab them now because
+        # after staging only device arrays remain
+        ids = k.touched(packed) if (train and self.track_touched) else None
+        args = k.args(packed, label, mask, train)
+        if self._mesh_coo:
             # what the batch moves to the chips, a [1, M, P] slice a
             # shard: on the solver's loader.h2d span round this call
             _trace.annotate(bytes=sum(a.nbytes for a in args))
-        elif kind == "tcoo":
-            _, tc, label, mask, _ = b
-            args = tuple(self._tcoo_args(tc, label, mask, train=train))
-        elif kind == "coo":
-            _, p, label, mask, _ = b
-            args = tuple(self._coo_args(p, label, mask))
-        else:
-            db = b[1]
-            args = self._shard(db.seg, db.idx, db.val, db.label,
-                               db.row_mask)
         return ("staged", kind, args, size, ids, train)
 
     # -- sparse PS wire hints ------------------------------------------------
-    def _touched_ids(self, b) -> Optional[np.ndarray]:
-        """Unique buckets a prepared batch touches, from its host arrays
-        (the sparse PS push set; reference ZPush of the minibatch's keys,
-        async_sgd.h:270-287). None = unknown (forces a full delta scan)."""
-        kind = b[0]
-        if kind == "staged":
-            return b[4]
-        if kind == "xla":
-            db = b[1]
-            ids = np.unique(db.idx[db.val != 0])
-        elif kind == "coo":
-            p = b[1]
-            ids = np.unique(p.idx[p.val != 0])
-        elif kind == "tcoo":
-            u = b[1].uniq
-            ids = u[u < self.cfg.num_buckets]
-        else:  # mcoo holds shard-local layouts; fall back to the scan
-            return None
-        return ids.astype(np.int64)
-
-    def _note_touched(self, b) -> None:
-        with self._touched_lock:
-            self._touched.append(self._touched_ids(b))
-
     def collect_touched(self):
         """Sorted-unique global rows touched since the last call, per
         table, or None if any batch lacked a hint (SyncedStore then
@@ -734,136 +723,33 @@ class LinearLearner:
         # from a late return out of the blocking fetch (PERF.md §5: on
         # the chip it is the fetch the device idles under)
         with _trace.span("step.dispatch", cat="step") as sp:
-            kind, prog = self._dispatch_train(blk)
+            _, kind, args, _, ids, st_train = self.stage_batch(blk, True)
+            assert st_train, "batch was staged for eval, not train"
+            if self.track_touched:
+                with self._touched_lock:
+                    self._touched.append(ids)
+            self.store.state, prog = self._kinds[kind].train(
+                self.store.state, *args)
             sp.set(kind=kind)
         with _trace.span("step.fetch", cat="step"):
             # one host round trip per scalar of prog: blocks until the
             # device has finished the step
             return jax.tree_util.tree_map(float, prog)
 
-    def _dispatch_train(self, blk):
-        """Enqueue one train step; returns (batch kind, the step's
-        progress scalars still on the device)."""
-        b = self._prepared(blk)
-        if self.track_touched:
-            self._note_touched(b)
-        if b[0] == "staged":
-            _, kind, args, _, _, st_train = b
-            assert st_train, "batch was staged for eval, not train"
-            step = {"mcoo": self._train_step_mcoo,
-                    "coo": self._train_step_coo,
-                    "xla": self._train_step}.get(kind)
-            if step is None:  # tcoo builds lazily
-                step = self._tcoo_steps[0]
-            self.store.state, prog = step(self.store.state, *args)
-            return kind, prog
-        if b[0] == "mcoo":
-            _, mc, label, mask, _ = b
-            self.store.state, prog = self._train_step_mcoo(
-                self.store.state, *self._mcoo_args(mc, label, mask))
-        elif b[0] == "tcoo":
-            _, tc, label, mask, _ = b
-            self.store.state, prog = self._tcoo_steps[0](
-                self.store.state,
-                *self._tcoo_args(tc, label, mask, train=True))
-        elif b[0] == "coo":
-            _, p, label, mask, _ = b
-            self.store.state, prog = self._train_step_coo(
-                self.store.state, *self._coo_args(p, label, mask))
-        else:
-            db = b[1]
-            self.store.state, prog = self._train_step(
-                self.store.state,
-                *self._shard(db.seg, db.idx, db.val, db.label, db.row_mask))
-        return b[0], prog
-
     def eval_batch(self, blk) -> dict:
-        b = self._prepared(blk)
-        if b[0] == "staged":
-            _, kind, args, _, _, st_train = b
-            assert not st_train, "batch was staged for train, not eval"
-            step = {"mcoo": self._eval_step_mcoo,
-                    "coo": self._eval_step_coo,
-                    "xla": self._eval_step}.get(kind)
-            if step is None:
-                step = self._tcoo_steps[1]
-            prog = step(self.store.state, *args)
-            return jax.tree_util.tree_map(float, prog)
-        if b[0] == "mcoo":
-            _, mc, label, mask, _ = b
-            prog = self._eval_step_mcoo(
-                self.store.state, *self._mcoo_args(mc, label, mask))
-        elif b[0] == "tcoo":
-            _, tc, label, mask, _ = b
-            prog = self._tcoo_steps[1](
-                self.store.state, *self._tcoo_args(tc, label, mask))
-        elif b[0] == "coo":
-            _, p, label, mask, _ = b
-            prog = self._eval_step_coo(
-                self.store.state, *self._coo_args(p, label, mask))
-        else:
-            db = b[1]
-            prog = self._eval_step(
-                self.store.state,
-                *self._shard(db.seg, db.idx, db.val, db.label, db.row_mask))
+        _, kind, args, _, _, st_train = self.stage_batch(blk, False)
+        assert not st_train, "batch was staged for train, not eval"
+        prog = self._kinds[kind].eval(self.store.state, *args)
         return jax.tree_util.tree_map(float, prog)
 
     def predict_batch(self, blk) -> np.ndarray:
-        b = self._prepared(blk)
-        if b[0] == "mcoo":
-            _, mc, _, _, size = b
-            xw = self._predict_step_mcoo(
-                self.store.state, *self._mcoo_args(mc))
-        elif b[0] == "tcoo":
-            _, tc, _, _, size = b
-            xw = self._tcoo_steps[2](
-                self.store.state, *self._tcoo_args(tc))
-        elif b[0] == "coo":
-            _, p, _, _, size = b
-            xw = self._predict_step_coo(
-                self.store.state, *self._coo_args(p))
-        else:
-            db, size = b[1], b[2]
-            xw = self._predict_step(
-                self.store.state, *self._shard(db.seg, db.idx, db.val))
+        kind, packed, _, _, size = _split(self._prepared(blk))
+        k = self._kinds[kind]
+        xw = k.predict(self.store.state, *k.args(packed))
         out = np.asarray(xw)[:size]
         if self.cfg.prob_predict:
             out = 1.0 / (1.0 + np.exp(-out))
         return out
-
-    def _tcoo_args(self, tc, label=None, mask=None, train=False):
-        # the COO stream + update-block bounds feed only the train step's
-        # gradient transpose and fused scatter; eval/predict take the
-        # short form (see eval_step_tcoo)
-        args = [jnp.asarray(tc.uniq), jnp.asarray(tc.tmap_u)]
-        if train:
-            p = tc.coo
-            args += [jnp.asarray(tc.first_u), jnp.asarray(tc.last_u),
-                     jnp.asarray(p.idx), jnp.asarray(p.seg),
-                     jnp.asarray(p.val), jnp.asarray(p.tmap),
-                     jnp.asarray(p.first)]
-        args += [jnp.asarray(tc.rm_slot), jnp.asarray(tc.rm_val)]
-        if label is not None:
-            args += [jnp.asarray(label), jnp.asarray(mask)]
-        return args
-
-    def _coo_args(self, p, label=None, mask=None):
-        args = [jnp.asarray(p.idx), jnp.asarray(p.seg), jnp.asarray(p.val),
-                jnp.asarray(p.tmap), jnp.asarray(p.first)]
-        if label is not None:
-            args += [jnp.asarray(label), jnp.asarray(mask)]
-        return args
-
-    def _mcoo_args(self, mc, label=None, mask=None):
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        sh = NamedSharding(self.mesh, P("data", "model", None))
-        args = [jax.device_put(x, sh) for x in
-                (mc.sidx, mc.sseg, mc.sval, mc.tmap, mc.first)]
-        if label is not None:
-            args += [jax.device_put(label, self._bsh1),
-                     jax.device_put(mask, self._bsh1)]
-        return args
 
     def nnz(self) -> int:
         return self.store.nnz("w")

@@ -52,16 +52,17 @@ import os
 # holds anything. At Criteo-1TB table sizes most blocks are a few percent
 # full, so the kernels build those operands only over a block's live
 # prefix (CHUNK and _live_chunks below), and a block costs what it
-# holds. The env overrides exist for hardware tuning sweeps.
-TILE_HI = int(os.environ.get("WORMHOLE_TILE_HI", 512))  # sublanes per tile
+# holds. The geometry decides the layout of every packed batch and
+# pack-cache entry (models/linear.pack_cache_token), so it is constant.
+TILE_HI = 512  # sublanes per tile
 LANES = 128
 TILE = TILE_HI * LANES  # buckets per table tile
-BLK = int(os.environ.get("WORMHOLE_BLK", 4096))  # nnz per grid block
+BLK = 4096  # nnz per grid block
 # The FM kernels keep dim-many per-nnz temporaries alive per block.
 # Swept on v5e: 1024 beats 2048/4096 (their per-block operands blow the
 # VMEM working set and stall the pipeline; the kernels are VPU-
 # throughput-bound, ~1 ns/nnz/channel, not per-block-overhead-bound).
-FM_BLK = int(os.environ.get("WORMHOLE_FM_BLK", 1024))
+FM_BLK = 1024
 _FM_VMEM_LIMIT = int(os.environ.get("WORMHOLE_FM_VMEM", 64 * 2**20))
 # Scoped-VMEM ceiling for the scalar COO / compaction kernels: the
 # compiler's 16 MB default rejects fatter grid blocks (BLK/BLK_U sweeps)
@@ -517,7 +518,7 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
 
 # slots per update block; 1024 is the minimum 1D block Mosaic accepts
 # against XLA's s32[...]{0:T(1024)} layout for large 1D operands
-BLK_U = int(os.environ.get("WORMHOLE_BLK_U", 1024))
+BLK_U = 1024
 assert TILE % BLK_U == 0, "BLK_U must divide TILE (block map alignment)"
 
 

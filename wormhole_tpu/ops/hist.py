@@ -37,14 +37,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from wormhole_tpu.ops.coo_kernels import _use_interpret
 
-import os
-
 HBLK = 4096   # rows per grid block
-# features per in-kernel matmul group (env-overridable for sweeps): the
-# standalone-kernel lab favored one full-width group, but inside the
-# fused round the production vmem budget favors 7 (tools/gbdt_hist_lab
-# + whole-round A/B, r5)
-FGROUP = int(os.environ.get("WORMHOLE_HIST_FGROUP", 7))
+# features per in-kernel matmul group: the standalone-kernel lab favored
+# one full-width group, but inside the fused round the production vmem
+# budget favors 7 (tools/gbdt_hist_lab + whole-round A/B, r5)
+FGROUP = 7
 
 
 def _hist_kernel(s_ref, binned_ref, out_ref, *, F: int, B: int):

@@ -15,7 +15,7 @@ plane exposes a hook that consults this module:
 Faults are armed by the `WH_FAULT_SPEC` env var, parsed once at import.
 Every hook site guards with `if faults.ACTIVE is not None:` — a single
 module-level None check — so an unfaulted process pays nothing on the
-hot path (the zero-overhead contract `tools/ps_sync_micro.py` checks).
+hot path.
 
 Spec grammar (comma-separated specs; all counters are deterministic):
 

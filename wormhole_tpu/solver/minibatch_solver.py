@@ -228,9 +228,6 @@ class MinibatchSolver:
             if _env_flag("WH_ADAPTIVE_LOADERS", default=not pinned)
             else None)
         self.pack_cache = _pc.from_env()
-        # loader-side device staging (double-buffer): batch N+1's arrays
-        # go to the device while the main thread steps batch N
-        self.device_feed = _env_flag("WH_DEVICE_FEED", True)
         # early-stop hook: (pass progress, data_pass, type) -> bool
         self.stop_hook: Optional[Callable] = None
         # PS barrier hook (SyncedStore.flush): called before eval,
@@ -367,8 +364,9 @@ class MinibatchSolver:
         train = wtype == WorkType.TRAIN
         token = self._pass_cache_token(train)
         prepare = getattr(self.learner, "prepare_batch", None)
-        stage = (getattr(self.learner, "stage_batch", None)
-                 if self.device_feed else None)
+        # loader-side device staging (double-buffer): batch N+1's arrays
+        # go to the device while the main thread steps batch N
+        stage = getattr(self.learner, "stage_batch", None)
 
         def loader(node_id: int):
             _pyprof.tag_thread("loader")
