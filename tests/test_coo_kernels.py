@@ -285,3 +285,221 @@ def test_pull_and_push_equal_full_width(fill, full_width, dtype=jnp.float32):
     want = spmv(jnp.asarray(seg), jnp.asarray(idx), jnp.asarray(val), w,
                 num_rows)
     np.testing.assert_allclose(xw, np.asarray(want), rtol=1e-5, atol=1e-4)
+
+
+# ------------------------------------------- the native tcoo pack (PR 29)
+# pack_tile_coo has two bodies: one call of the native core
+# (native/src/pack.cc: one radix sort, every array written in its order)
+# and the numpy body (localize, assign_tile_slots, build_rm,
+# pack_sorted_coo). The second is the oracle: every field bit-equal.
+
+def _rows_batch(rng, live_per_row, num_buckets, pad_to=None):
+    """CSR-ordered triples with `live_per_row[r]` entries in row r, then
+    padding triples as to_device_batch makes them (val 0, idx 0, seg the
+    last row) up to `pad_to`."""
+    rows = len(live_per_row)
+    seg = np.repeat(np.arange(rows, dtype=np.int32), live_per_row)
+    n = len(seg)
+    idx = rng.integers(0, num_buckets, n).astype(np.int32)
+    val = rng.normal(size=n).astype(np.float32)
+    if pad_to is not None:
+        pad = pad_to - n
+        seg = np.concatenate([seg, np.full(pad, rows - 1, np.int32)])
+        idx = np.concatenate([idx, np.zeros(pad, np.int32)])
+        val = np.concatenate([val, np.zeros(pad, np.float32)])
+    return idx, seg, val
+
+
+def _keys_batch(keys, rng, repeat=2):
+    """Each key `repeat` times, shuffled, one entry a row."""
+    idx = rng.permutation(np.repeat(np.asarray(keys, np.int32), repeat))
+    n = len(idx)
+    return idx, np.arange(n, dtype=np.int32), rng.normal(
+        size=n).astype(np.float32)
+
+
+def _tile_pack_case(name):
+    """kwargs of pack_tile_coo for a named case."""
+    rng = np.random.default_rng(29)
+    nb = 128 * TILE
+    U = ck.BLK_U
+    roomy = 4 * TILE               # 256 update blocks: nothing is cut
+    if name == "fixed_width":      # exactly rm_width a row: build_rm's
+        idx, seg, val = _rows_batch(rng, [8] * 64, nb)    # fast path
+        return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=roomy,
+                    capacity=512, rm_rows=64, rm_width=8)
+    if name == "ragged_padded":
+        live = rng.integers(0, 9, 64)
+        idx, seg, val = _rows_batch(rng, live, nb, pad_to=512)
+        val[rng.random(512) < 0.1] = 0.0          # explicit zeros too
+        return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=roomy,
+                    capacity=512, rm_rows=64, rm_width=8)
+    if name == "row_over_width":   # rows 3 and 9 hold 7 and 5 live
+        live = [2] * 16            # entries against a width of 4
+        live[3], live[9] = 7, 5
+        idx, seg, val = _rows_batch(rng, live, nb, pad_to=64)
+        return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=roomy,
+                    capacity=64, rm_rows=16, rm_width=4)
+    if name in ("u_cap_truncates_boundary_tile", "u_cap_cuts_at_a_tile"):
+        # u_cap = TILE is 64 update blocks. 63 tiles of 3 keys take 63;
+        # tile 63's 1,500 keys want 2 and get 1 (1,024 kept), tile 64
+        # is cut whole. Or 64 tiles fill it exactly and tile 64 goes.
+        trunc = name == "u_cap_truncates_boundary_tile"
+        keys = [t * TILE + 11 * k for t in range(63 if trunc else 64)
+                for k in range(3)]
+        if trunc:
+            keys += list(63 * TILE + 5 * np.arange(1500))
+        keys += [64 * TILE + k for k in range(5)]
+        idx, seg, val = _keys_batch(keys, rng)
+        val[rng.random(len(val)) < 0.2] = 0.0     # not counted as dropped
+        return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=TILE,
+                    capacity=len(idx), rm_rows=len(idx), rm_width=2)
+    if name == "cut_leaves_exact_rows":
+        # the kept entries alone are exactly rm_width a row in row order
+        # (the fast path), with cut entries lying between them
+        keys = np.repeat(np.arange(64) * TILE + 5, 2)
+        seg = np.repeat(np.arange(64, dtype=np.int32), 2)
+        at = np.sort(rng.integers(0, 129, 9))
+        idx = np.insert(keys, at, 70 * TILE + np.arange(9)).astype(np.int32)
+        seg = np.insert(seg, at, 7).astype(np.int32)
+        return dict(idx=idx, seg=seg, val=np.ones(len(idx), np.float32),
+                    num_buckets=nb, u_cap=TILE, capacity=len(idx),
+                    rm_rows=64, rm_width=2)
+    if name in ("empty", "empty_no_rm"):
+        z = np.zeros(0, np.int32)
+        rm = dict(rm_rows=4, rm_width=2) if name == "empty" else {}
+        return dict(idx=z, seg=z, val=np.zeros(0, np.float32),
+                    num_buckets=nb, u_cap=TILE, capacity=None, **rm)
+    if name == "tile_edges_and_block_runs":
+        # a tile's first and last bucket (the table's too), a run of
+        # exactly BLK_U keys in one tile and of BLK_U + 1 in another
+        keys = [0, TILE - 1, TILE, 5 * TILE - 1, 5 * TILE, nb - TILE,
+                nb - 1]
+        keys += list(9 * TILE + 3 * np.arange(U))
+        keys += list(20 * TILE + TILE - 1 - 7 * np.arange(U + 1))
+        idx, seg, val = _keys_batch(keys, rng)
+        return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=roomy,
+                    capacity=2 * len(idx), rm_rows=len(idx), rm_width=1)
+    if name == "duplicate_keys_across_rows":
+        # five keys, 600 entries: equal keys keep their input order
+        live = [6] * 100
+        idx, seg, val = _rows_batch(rng, live, nb)
+        idx = rng.choice(np.array([3, TILE + 1, TILE + 2, 9 * TILE, nb - 1],
+                                  np.int32), 600)
+        return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=roomy,
+                    capacity=600, rm_rows=100, rm_width=6)
+    if name == "one_key":          # every digit constant: no sort pass
+        idx, seg, val = _rows_batch(rng, [4] * 32, nb)
+        return dict(idx=np.full_like(idx, 3 * TILE + 17), seg=seg, val=val,
+                    num_buckets=nb, u_cap=roomy, capacity=128, rm_rows=32,
+                    rm_width=4)
+    if name == "no_row_major":
+        idx, seg, val = _rows_batch(rng, rng.integers(0, 9, 64), nb)
+        return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=roomy,
+                    capacity=512)
+    if name == "ids_up_to_2p31":   # 31 bits of key: three 11-bit digits
+        top = 2**31 - TILE
+        idx, seg, val = _rows_batch(rng, [8] * 64, 64 * TILE)
+        idx[::2] += top - 64 * TILE               # the table's two ends
+        idx[:3] = top - 1, 0, top - 1
+        return dict(idx=idx, seg=seg, val=val, num_buckets=top, u_cap=roomy,
+                    capacity=512, rm_rows=64, rm_width=8)
+    if name == "criteo_shape":
+        # skewed keys over 64 tiles, a compact domain of 16 tiles, spare
+        # capacity: what a batch of the benchmark looks like, smaller
+        rows, width = 4096, 39
+        raw = rng.zipf(1.2, rows * width).astype(np.uint64)
+        idx = ((raw * np.uint64(0x9E3779B97F4A7C15)) % np.uint64(64 * TILE)
+               ).astype(np.int32)
+        seg = np.repeat(np.arange(rows, dtype=np.int32), width)
+        return dict(idx=idx, seg=seg, val=np.ones(rows * width, np.float32),
+                    num_buckets=64 * TILE, u_cap=16 * TILE,
+                    capacity=rows * width + 3 * BLK, rm_rows=rows,
+                    rm_width=width)
+    raise KeyError(name)
+
+
+_TILE_PACK_CASES = [
+    "fixed_width", "ragged_padded", "row_over_width",
+    "u_cap_truncates_boundary_tile", "u_cap_cuts_at_a_tile",
+    "cut_leaves_exact_rows", "empty", "empty_no_rm",
+    "tile_edges_and_block_runs", "duplicate_keys_across_rows", "one_key",
+    "no_row_major", "ids_up_to_2p31", "criteo_shape"]
+
+
+def _numpy_body(monkeypatch, **kw):
+    """pack_tile_coo with the native pass out of reach."""
+    from wormhole_tpu import native
+
+    with monkeypatch.context() as m:
+        m.setattr(native, "pack_tile_coo", lambda *a, **k: None)
+        return ck.pack_tile_coo(**kw)
+
+
+def _assert_same_bits(got, want, path="TileCOO"):
+    import dataclasses
+
+    if dataclasses.is_dataclass(want):
+        assert type(got) is type(want), path
+        for f in dataclasses.fields(want):
+            _assert_same_bits(getattr(got, f.name), getattr(want, f.name),
+                              f"{path}.{f.name}")
+    elif isinstance(want, np.ndarray):
+        assert got.dtype == want.dtype and got.shape == want.shape, (
+            path, got.dtype, want.dtype, got.shape, want.shape)
+        assert got.tobytes() == want.tobytes(), (
+            path, np.flatnonzero(got != want)[:8])
+    else:
+        assert type(got) is type(want) and got == want, (path, got, want)
+
+
+@pytest.mark.parametrize("case", _TILE_PACK_CASES)
+def test_native_tile_pack_is_bit_equal_to_the_numpy_body(case, monkeypatch):
+    from wormhole_tpu import native
+
+    if not native.available():
+        pytest.skip("native library unavailable (no toolchain?)")
+    kw = _tile_pack_case(case)
+    got = ck.pack_tile_coo(**kw)
+    want = _numpy_body(monkeypatch, **kw)
+    assert got.packed_native and not want.packed_native
+    _assert_same_bits(got, want)
+    # the cases are what their names say
+    if case.startswith(("u_cap", "cut_")):
+        assert want.dropped_uniq > 0 and want.dropped_nnz > 0
+        # only a truncated tile's keys reach the domain's last slot
+        assert (want.uniq[-1] != kw["num_buckets"]) == (
+            case == "u_cap_truncates_boundary_tile")
+    else:
+        assert want.dropped_uniq == 0
+    if case == "row_over_width":   # both sides lost the same 3 + 1
+        live = np.count_nonzero(kw["val"])
+        assert np.count_nonzero(want.rm_val) == live - 4
+        assert np.count_nonzero(want.coo.val) == live - 4
+    if case in ("fixed_width", "cut_leaves_exact_rows"):
+        assert (want.rm_slot != kw["u_cap"]).all()     # the fast path
+
+
+@pytest.mark.parametrize("case", ["int64_ids", "rows_not_grouped",
+                                  "over_capacity"])
+def test_tile_pack_outside_the_native_domain_runs_the_numpy_body(case):
+    """What the native pass cannot take, it hands to the numpy body,
+    which decides what such a batch means: the same answer or the same
+    refusal as before."""
+    kw = _tile_pack_case("fixed_width")
+    if case == "int64_ids":
+        tc = ck.pack_tile_coo(**{**kw, "idx": kw["idx"].astype(np.int64)})
+        assert not tc.packed_native
+        _assert_same_bits(tc, ck.pack_tile_coo(**kw))
+    elif case == "rows_not_grouped":
+        kw = _tile_pack_case("ragged_padded")
+        live = np.flatnonzero(kw["val"])
+        kw["seg"][live[0]] = 63
+        with pytest.raises(ValueError, match="row-grouped"):
+            ck.pack_tile_coo(**kw)
+    else:
+        # two blocks of entries in one compact tile, room for one
+        idx, seg, val = _rows_batch(np.random.default_rng(1), [8] * 1024,
+                                    TILE)
+        with pytest.raises(AssertionError):
+            ck.pack_tile_coo(idx, seg, val, TILE, TILE, capacity=0)

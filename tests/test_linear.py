@@ -203,6 +203,49 @@ def test_pack_tile_coo_roundtrip():
     assert (tc2.coo.val != 0).sum() + tc2.dropped_nnz == (val != 0).sum()
 
 
+def test_pack_counters_say_which_body_packed(synth_file, monkeypatch):
+    """`linear.pack.batches` counts every tcoo batch `_pack_tcoo` packs,
+    train or eval, and `linear.pack.native` those of them the native
+    pass packed: their ratio is the benchmark's `pack_native_share`, 1.0
+    where the library serves and 0.0 where only the numpy body runs. The
+    start-up line says the same once."""
+    from wormhole_tpu import native
+    from wormhole_tpu.obs.metrics import REGISTRY
+    from wormhole_tpu.ops import coo_kernels as ck
+
+    if not native.available():
+        pytest.skip("native library unavailable (no toolchain?)")
+
+    def counters():
+        c = REGISTRY.snapshot()["counters"]
+        return c["linear.pack.batches"], c["linear.pack.native"]
+
+    cfg = LinearConfig(minibatch=128, num_buckets=8 * ck.TILE,
+                       nnz_per_row=16, algo="ftrl", kernel="pallas",
+                       compact_cap=ck.TILE, kernel_dtype="f32")
+    lrn = LinearLearner(cfg, make_mesh(1, 1))
+    assert lrn.placement.endswith("tcoo_pack=native")
+    blk = next(iter(MinibatchIter(synth_file, fmt="libsvm",
+                                  minibatch_size=128)))
+    b0, n0 = counters()
+    assert lrn.prepare_batch(blk, train=True)[0] == "tcoo"
+    lrn.prepare_batch(blk, train=False)
+    assert counters() == (b0 + 2, n0 + 2)
+    monkeypatch.setattr(native, "pack_tile_coo", lambda *a, **k: None)
+    assert lrn.prepare_batch(blk, train=True)[0] == "tcoo"
+    assert counters() == (b0 + 3, n0 + 2)
+    # a process without the library says so before its first batch
+    monkeypatch.setattr(native, "available", lambda: False)
+    assert LinearLearner(cfg, make_mesh(1, 1)).placement.endswith(
+        "tcoo_pack=numpy")
+    # the xla path packs no tcoo batch and names no pack
+    xla = LinearLearner(LinearConfig(minibatch=128, num_buckets=1 << 10,
+                                     nnz_per_row=16), make_mesh(1, 1))
+    assert "tcoo_pack" not in xla.placement
+    xla.prepare_batch(blk)
+    assert counters() == (b0 + 3, n0 + 2)
+
+
 @pytest.mark.parametrize("algo", ["ftrl", "adagrad", "sgd"])
 def test_compacted_matches_xla(synth_file, algo):
     """The tile-compacted (Localizer + fused in-place update) path must

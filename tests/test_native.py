@@ -244,16 +244,65 @@ def test_native_concurrent_stress():
         for _ in range(2000)) + "\n"
     keys = rng.integers(0, 1 << 30, size=200000).astype(np.uint64)
     vals = rng.standard_normal(200000).astype(np.float32)
+    ids = (keys % np.uint64(1 << 22)).astype(np.int32)
+    rows = np.sort(rng.integers(0, 5000, 200000)).astype(np.int32)
 
     def work(i):
         blk = native.parse_text(lines, "libsvm")
         order = native.radix_argsort(keys)
         got = native.gather(vals, order)
         h = native.cityhash64(b"stress-%d" % i)
-        return blk.size, int(order[0]), float(got[0]), h
+        # each thread keeps its own work space between its packs
+        tc = native.pack_tile_coo(ids, rows, vals, 1 << 22, 1 << 19,
+                                  200000, 5000, 64, 1 << 16, 4096, 1024)
+        return (blk.size, int(order[0]), float(got[0]), h,
+                tc["uniq"].tobytes() + tc["val"].tobytes())
 
     with ThreadPoolExecutor(max_workers=8) as ex:
         results = list(ex.map(work, range(32)))
     sizes = {r[0] for r in results}
     firsts = {r[1] for r in results}
     assert sizes == {2000} and len(firsts) == 1
+    assert len({r[4] for r in results}) == 1
+
+
+def test_tile_pack_releases_the_interpreter_lock():
+    """The native tcoo pack is one ctypes call, and ctypes.CDLL gives
+    the interpreter lock up for a call's whole duration: a thread that
+    needs the lock for every step it takes keeps stepping while a pack
+    runs. (Held, it would stand still from the call's entry to its
+    return.) That is what lets four loaders pack beside the train
+    thread."""
+    import threading
+
+    from wormhole_tpu.ops import coo_kernels as ck
+
+    if native.get_lib() is None:
+        pytest.skip("native lib unavailable")
+    rng = np.random.default_rng(12)
+    n = 1 << 21
+    idx = rng.integers(0, 64 * ck.TILE, n).astype(np.int32)
+    seg = np.sort(rng.integers(0, 1 << 16, n)).astype(np.int32)
+    val = np.ones(n, np.float32)
+    steps, stop = [0], threading.Event()
+
+    def step():
+        while not stop.is_set():
+            steps[0] += 1
+
+    t = threading.Thread(target=step, daemon=True)
+    t.start()
+    try:
+        while steps[0] == 0:
+            pass
+        before = steps[0]
+        tc = ck.pack_tile_coo(idx, seg, val, 64 * ck.TILE, 64 * ck.TILE,
+                              capacity=n)
+        during = steps[0] - before
+    finally:
+        stop.set()
+        t.join()
+    assert tc.packed_native and tc.dropped_nnz == 0
+    # tens of ms of pack: ~10^5 steps with the lock free, one or two
+    # (the hand-over at entry and return) with it held
+    assert during > 1000, during

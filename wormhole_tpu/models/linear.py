@@ -32,6 +32,7 @@ import numpy as np
 
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from wormhole_tpu import native
 from wormhole_tpu.data.rowblock import DeviceBatch, RowBlock, to_device_batch
 from wormhole_tpu.obs import trace as _trace
 from wormhole_tpu.obs.metrics import REGISTRY
@@ -53,6 +54,9 @@ _MESH_NNZ_MAX = REGISTRY.counter("linear.mesh.shard_nnz_max")
 _MESH_NNZ_SUM = REGISTRY.counter("linear.mesh.shard_nnz_sum")
 _CHUNKS = REGISTRY.counter("linear.blocks.chunks")
 _CHUNKS_RUN = REGISTRY.counter("linear.blocks.chunks_run")
+# tcoo batches packed, and those the native pass packed
+_PACK_BATCHES = REGISTRY.counter("linear.pack.batches")
+_PACK_NATIVE = REGISTRY.counter("linear.pack.native")
 
 
 def _count_chunks(stream, dead, blk: int, kernels: int):
@@ -316,6 +320,12 @@ class LinearLearner:
                                             self.use_pallas, why_not)
         # mesh layout (shard_map + psum collectives) whenever any axis > 1
         self._mesh_coo = self.use_pallas and (D > 1 or M > 1)
+        if self.use_pallas and not self._mesh_coo:
+            # which body of ck.pack_tile_coo this process has for the
+            # compacted (tcoo) batches: counted a batch as
+            # linear.pack.native over linear.pack.batches
+            self.placement += (" tcoo_pack="
+                               + ("native" if native.available() else "numpy"))
         self._shard_cap = ck.mesh_capacity(cfg.row_capacity, D, M)
         if self.use_pallas:
             assert cfg.num_buckets % (M * ck.TILE) == 0, (
@@ -636,6 +646,8 @@ class LinearLearner:
                               capacity=self.cfg.row_capacity,
                               rm_rows=self.cfg.minibatch,
                               rm_width=self.cfg.nnz_per_row)
+        _PACK_BATCHES.inc()
+        _PACK_NATIVE.inc(int(tc.packed_native))
         if tc.dropped_nnz:
             _log.warning(
                 "compaction overflow: dropped %d unique keys "

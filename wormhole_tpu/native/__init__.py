@@ -2,10 +2,13 @@
 
 The reference's data path is native C++ (learn/base/*_parser.h over
 dmlc-core's parser machinery); this package is its equivalent: a small
-C++ shared library (`src/parsers.cc`) built with plain g++ and bound via
-ctypes (no pybind11 in the image). The Python parsers in
-wormhole_tpu/data/parsers.py stay the reference implementation and the
-fallback — `tests/test_native.py` cross-checks the two bit-for-bit.
+C++ shared library (`src/parsers.cc`; the radix sort and gather of
+`src/sort.cc`; the tcoo pack of `src/pack.cc`) built with plain g++ and
+bound via ctypes (no pybind11 in the image). The Python parsers in
+wormhole_tpu/data/parsers.py and the numpy body of
+ops/coo_kernels.pack_tile_coo stay the reference implementations and
+the fallback — `tests/test_native.py` and `tests/test_coo_kernels.py`
+cross-check each pair bit-for-bit.
 
 The library is built lazily on first use (`make -C wormhole_tpu/native`).
 A build that fails is reported once on stderr with the compiler's output
@@ -93,6 +96,10 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.wh_rb_free.argtypes = [ctypes.c_void_p]
     lib.wh_cityhash64.restype = ctypes.c_uint64
     lib.wh_cityhash64.argtypes = [ctypes.c_char_p, ctypes.c_int64]
+    lib.wh_pack_tile_coo.restype = ctypes.c_int32
+    lib.wh_pack_tile_coo.argtypes = ([ctypes.c_void_p] * 3
+                                     + [ctypes.c_int64] * 9
+                                     + [ctypes.c_void_p] * 12)
     return lib
 
 
@@ -131,8 +138,8 @@ def get_lib() -> Optional[ctypes.CDLL]:
 
             sys.stderr.write(
                 "[native] libwormhole_native.so unavailable — parsing, "
-                "hashing and radix sort fall back to the Python/numpy "
-                f"reference paths:\n{_failure}\n")
+                "hashing, radix sort and the tcoo pack fall back to the "
+                f"Python/numpy reference paths:\n{_failure}\n")
         return _lib
 
 
@@ -254,4 +261,64 @@ def gather(src, order):
     fn(src.ctypes.data_as(ctypes.c_void_p),
        order.ctypes.data_as(ctypes.c_void_p), ctypes.c_int64(n),
        out.ctypes.data_as(ctypes.c_void_p))
+    return out
+
+
+def pack_tile_coo(idx, seg, val, num_buckets: int, u_cap: int,
+                  capacity, rm_rows, rm_width, tile: int, blk: int,
+                  blk_u: int):
+    """The whole tcoo pack in one call that holds no interpreter lock
+    (src/pack.cc): one radix sort of the ids, then every array of
+    ops/coo_kernels.TileCOO written in that order, bit-equal to the numpy
+    body of ops/coo_kernels.pack_tile_coo. Returns a dict of those
+    arrays and counts (`over`: nonzeros dropped from rows over
+    rm_width), or None when the library is missing or the batch is
+    outside the pass's domain (ids not int32 in [0, num_buckets), sizes
+    of 2^31 or more, rows the numpy body would refuse): the caller then
+    runs the numpy body."""
+    lib = get_lib()
+    if (lib is None or not isinstance(idx, np.ndarray)
+            or idx.dtype != np.int32 or idx.ndim != 1):
+        return None
+    n = idx.shape[0]
+    rm = rm_rows is not None
+    n_rm = rm_rows * rm_width if rm else 0
+    # capacity None: that of the entries kept, at most n
+    P = ((n if capacity is None else capacity) // blk + u_cap // tile) * blk
+    if (not 0 < num_buckets < 2 ** 31 or not 0 < u_cap < 2 ** 31
+            or max(n, P, n_rm) >= 2 ** 31 or min(P, n_rm) < 0):
+        return None
+    idx = np.ascontiguousarray(idx)
+    seg = np.ascontiguousarray(seg, np.int32)
+    val = np.ascontiguousarray(val, np.float32)
+    if seg.shape != idx.shape or val.shape != idx.shape:
+        return None
+    i32 = np.int32
+    out = dict(  # in the order wh_pack_tile_coo takes them
+        uniq=np.empty(u_cap, i32), tmap_u=np.empty(u_cap // blk_u, i32),
+        first_u=np.empty(u_cap // blk_u, i32),
+        last_u=np.empty(u_cap // blk_u, i32),
+        idx=np.empty(P, i32), seg=np.empty(P, i32),
+        val=np.empty(P, np.float32), tmap=np.empty(P // blk, i32),
+        first=np.empty(P // blk, i32),
+        rm_slot=np.empty(n_rm, i32) if rm else None,
+        rm_val=np.empty(n_rm, np.float32) if rm else None)
+    counts = np.zeros(5, np.int64)
+
+    def ptr(a):
+        return None if a is None else a.ctypes.data_as(ctypes.c_void_p)
+
+    rc = lib.wh_pack_tile_coo(
+        ptr(idx), ptr(seg), ptr(val), n, num_buckets, u_cap,
+        -1 if capacity is None else capacity,
+        rm_rows if rm else -1, rm_width if rm else 0, tile, blk, blk_u,
+        *(ptr(a) for a in out.values()), ptr(counts))
+    if rc != 0:
+        return None
+    if counts[4] != P:  # capacity None and entries cut: a shorter stream
+        for k, per in (("idx", 1), ("seg", 1), ("val", 1), ("tmap", blk),
+                       ("first", blk)):
+            out[k] = out[k][:counts[4] // per]
+    out.update(zip(("num_uniq", "dropped_uniq", "dropped_nnz", "over"),
+                   counts.tolist()))
     return out

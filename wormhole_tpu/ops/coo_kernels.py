@@ -539,6 +539,10 @@ class TileCOO:
     # optional row-major companion layout over the compact slot domain
     rm_slot: np.ndarray | None = None
     rm_val: np.ndarray | None = None
+    #: which body of pack_tile_coo made this batch. Not a field: it is
+    #: not part of the batch (compared, stored in the pack cache), only
+    #: the packer's word to its caller's counter
+    packed_native = False
 
 
 @dataclasses.dataclass
@@ -636,10 +640,37 @@ def pack_tile_coo(idx, seg, val, num_buckets: int, u_cap: int,
     localizer.h:98-221) into tile-run-aligned compact slots and pack the
     COO triples over that domain (host-side, loader threads). With
     rm_rows/rm_width, also emit the row-major companion layout (see
-    build_rm) over the compact slot domain, with u_cap as sentinel."""
+    build_rm) over the compact slot domain, with u_cap as sentinel.
+
+    Where the native core is loaded and the batch is what
+    to_device_batch makes (int32 ids in [0, num_buckets)), the whole pack
+    is one call of it that holds no interpreter lock: one radix sort,
+    whose order serves the slots too (slot order is key order), and every
+    array written in that order (native/src/pack.cc). The numpy body
+    below is the same function for every other input, bit for bit."""
     assert u_cap % TILE == 0, f"u_cap must be a multiple of {TILE}"
     assert num_buckets < 2**31, "sentinel id must fit int32"
+    from wormhole_tpu import native
     from wormhole_tpu.ops.localizer import localize
+
+    got = native.pack_tile_coo(
+        idx, seg, val, num_buckets, u_cap, capacity, rm_rows, rm_width,
+        TILE, BLK, BLK_U)
+    if got is not None:
+        if got["over"]:
+            import logging
+
+            logging.getLogger(__name__).warning(
+                "row-major pack: dropped %d nonzeros from rows with more "
+                "than %d live entries", got["over"], rm_width)
+        tc = TileCOO(
+            got["uniq"], SortedCOO(*(got[k] for k in (
+                "idx", "seg", "val", "tmap", "first"))),
+            got["tmap_u"], got["first_u"], got["last_u"], got["num_uniq"],
+            got["dropped_uniq"], got["dropped_nnz"], got["rm_slot"],
+            got["rm_val"])
+        tc.packed_native = True
+        return tc
 
     idx = np.asarray(idx, np.int64)
     seg = np.asarray(seg, np.int32)
