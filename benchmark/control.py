@@ -6,12 +6,13 @@
 For each seed: the first steps' batches as run.py would generate them, the
 float32 reference of the configuration, and the same reference with the
 configuration's `control_precision` put in the program's place (the tables
-z, n, w stored in bfloat16 between steps: the nearest precision below the
-float32 the configuration states, and the step that would tempt a later
-PR, since it halves the update's memory traffic) — over the first steps
-from zeroed tables, and over one more step from the state they leave (the
-served step). Prints the compared numbers of the control beside the
-limits; every seed has to come out NOT correct. Run on the chip at the
+the reference declares stored in bfloat16 between steps: the nearest
+precision below the float32 the configuration states, and the step that
+would tempt a later PR, since it halves the update's memory traffic) —
+over the first steps from the start (zeroed tables; a leaf that does not
+start at zero as the reference's `draw_start` draws it from the seed),
+and over one more step from the state they leave (the served step).
+Prints the compared numbers of the control beside the limits; every seed has to come out NOT correct. Run on the chip at the
 configuration's real sizes for the readings in PERF.md; tests/benchmark
 runs it at the rehearsal size. The benchmark's own runs never run it.
 """
@@ -44,23 +45,36 @@ def control_numbers(config: dict, seed: int, rehearsal: bool = False) -> dict:
     for p in range(steps + 1):
         r = gen.Rows(model, seed, gen.TRAIN_STREAM, p, rows)
         batches.append((r.keys(), r.label))
-    nb, hyper = int(conf["num_buckets"]), config["hyper"]
-    ref = reference.run_steps(batches[:steps], nb, hyper, precision)
-    low = reference.run_steps(batches[:steps], nb, hyper, lower)
-    nums = check.numbers(check.reference_as_run(low, rows),
-                         check.reference_as_run(ref, rows))
+    sizes, hyper = check.space_sizes(reference, conf), config["hyper"]
+    decl = reference.TABLES
+    seeded = [k for k, d in decl.items() if not d["zero_start"]]
+
+    def drawn(ids):
+        """Leaves that do not start at zero, as the reference draws them
+        (the program's own start is nothing the control may take)."""
+        return reference.draw_start(ids, sizes, hyper, seed) if seeded else {}
+
+    ids = check.union_ids(reference, sizes, [k for k, _ in batches[:steps]])
+    start = {"ids": ids, "tables": drawn(ids)} if seeded else None
+    ref = reference.run_steps(batches[:steps], sizes, hyper, precision,
+                              start=start)
+    low = reference.run_steps(batches[:steps], sizes, hyper, lower,
+                              start=start)
+    nums = check.numbers(check.reference_as_run(low, rows, start),
+                         check.reference_as_run(ref, rows, start))
     # the served step: one more batch from the float32 state the first
     # steps left, by the reference and by the control in its place
-    ids = np.unique(reference.bucket_ids(batches[steps][0], nb))
-    pos = np.searchsorted(ref["ids"], ids)
-    hit = (pos < len(ref["ids"])) & (
-        ref["ids"][np.minimum(pos, len(ref["ids"]) - 1)] == ids)
-    pre = {k: np.where(hit, v[np.minimum(pos, len(v) - 1)], 0.0)
-           .astype(np.float32) for k, v in ref["states"][-1].items()}
-    start = dict(pre, ids=ids)
+    ids = check.union_ids(reference, sizes, [batches[steps][0]])
+    fresh, pre = drawn(ids), {}
+    for k, v in ref["states"][-1].items():
+        known, mine = ref["ids"][decl[k]["space"]], ids[decl[k]["space"]]
+        pos = np.minimum(np.searchsorted(known, mine), len(known) - 1)
+        hit = (known[pos] == mine).reshape((-1,) + (1,) * (v.ndim - 1))
+        pre[k] = np.where(hit, v[pos], fresh.get(k, 0.0)).astype(np.float32)
+    start = {"ids": ids, "tables": pre}
     one = [batches[steps]]
-    r1 = reference.run_steps(one, nb, hyper, precision, start=start)
-    l1 = reference.run_steps(one, nb, hyper, lower, start=start)
+    r1 = reference.run_steps(one, sizes, hyper, precision, start=start)
+    l1 = reference.run_steps(one, sizes, hyper, lower, start=start)
 
     def as_run(r):
         return {"pre": pre, "post": r["states"][0], "objv": r["objv"][0],

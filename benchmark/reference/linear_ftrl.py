@@ -39,6 +39,13 @@ rounded to `kernel_dtype` exactly where the program's kernels round them
 
 `tables` other than float32 is the control of the benchmark's check (the
 tables stored in a lower precision between steps), never a configuration.
+
+What `correct` compares is declared here and named nowhere in the harness
+(PR 30): `SPACES`, the id spaces the tables are indexed by, each with the
+conf key that holds its size; `TABLES`, the leaves compared, each with its
+id space and whether it starts at zero; `GRADIENT`, the leaf that after
+one step from the start is the first gradient as the optimizer got it;
+`space_ids`, from a batch's keys to the ids of each space.
 """
 
 from __future__ import annotations
@@ -66,10 +73,23 @@ def _rounded(x, name: str):
                                     mantissa_bits=fmt[1])
 
 
+SPACES = {"bucket": "num_buckets"}
+TABLES = {"z": {"space": "bucket", "zero_start": True},
+          "n": {"space": "bucket", "zero_start": True},
+          "w": {"space": "bucket", "zero_start": True}}
+# from zero tables z after one step IS the first gradient: sigma * w = 0
+GRADIENT = "z"
+
+
 def bucket_ids(keys: np.ndarray, num_buckets: int) -> np.ndarray:
     """The hash kernel: a raw 64-bit key's bucket is key mod num_buckets
     (upstream localizer.h:107-115 under FLAGS_max_key)."""
     return (keys % np.uint64(num_buckets)).astype(np.int64)
+
+
+def space_ids(keys: np.ndarray, sizes: dict) -> dict:
+    """The ids a batch's keys touch, by id space, in the keys' shape."""
+    return {"bucket": bucket_ids(keys, sizes["bucket"])}
 
 
 def _step(z, n, w, lidx, seg, val, label, *, rows, hyper, prec):
@@ -93,24 +113,30 @@ def _step(z, n, w, lidx, seg, val, label, *, rows, hyper, prec):
     return _rounded(z, t), _rounded(n, t), _rounded(w, t), obj
 
 
-def run_steps(batches, num_buckets: int, hyper: dict, precision: dict,
+def run_steps(batches, sizes: dict, hyper: dict, precision: dict,
               start: dict | None = None):
     """Train over `batches`, in order, from zeroed tables or from `start`
-    (`ids`: sorted bucket ids that hold every bucket the batches touch,
-    and z, n, w on them). Each batch is (keys (rows, nnz) uint64, label
-    (rows,)); features are binary. Returns per step the summed loss, the
-    touched ids of each batch, and after each step the tables on all
-    touched buckets: `ids` (sorted bucket ids), `states[k]` = dict z, n,
-    w after step k+1 (numpy, len(ids))."""
+    (`ids`: by id space the sorted ids that hold every id the batches
+    touch; `tables`: z, n, w on them, a leaf left out starting at zero).
+    `sizes` holds each id space's size. Each batch is (keys (rows, nnz)
+    uint64, label (rows,)); features are binary. Returns per step the
+    summed loss, the touched ids of each batch by id space, and after
+    each step the tables on all touched ids: `ids` (by id space, sorted),
+    `states[k]` = dict z, n, w after step k+1 (numpy, len(ids)), and
+    `gradient`: the `GRADIENT` leaf and its id space. With one id space,
+    `sizes` may be its size alone."""
+    num_buckets = sizes["bucket"] if isinstance(sizes, dict) else int(sizes)
     idx = [bucket_ids(k, num_buckets) for k, _ in batches]
     ids = np.unique(np.concatenate([i.reshape(-1) for i in idx]))
     if start is None:
         z = n = w = jnp.zeros(len(ids), jnp.float32)
     else:
-        if not np.array_equal(ids, start["ids"]):
+        if not np.array_equal(ids, start["ids"]["bucket"]):
             raise ValueError("start holds other buckets than the batches "
                              "touch")
-        z, n, w = (jnp.asarray(start[k], jnp.float32) for k in "znw")
+        zero = np.zeros(len(ids), np.float32)
+        z, n, w = (jnp.asarray(start["tables"].get(k, zero), jnp.float32)
+                   for k in "znw")
     objs, states, touched = [], [], []
     step = jax.jit(functools.partial(_step, hyper=dict(hyper),
                                      prec=dict(precision)),
@@ -126,5 +152,7 @@ def run_steps(batches, num_buckets: int, hyper: dict, precision: dict,
             objs.append(float(obj))
             states.append({"z": np.asarray(z), "n": np.asarray(n),
                            "w": np.asarray(w)})
-            touched.append(np.unique(gi))
-    return {"ids": ids, "objv": objs, "states": states, "touched": touched}
+            touched.append({"bucket": np.unique(gi)})
+    return {"ids": {"bucket": ids}, "objv": objs, "states": states,
+            "touched": touched,
+            "gradient": (GRADIENT, TABLES[GRADIENT]["space"])}

@@ -17,7 +17,9 @@ then followed for the served-step check, and the run ends.
 
 The last line of standard output is one JSON object: `correct`,
 `attempted`, `failed`, `metrics`, `device` (and `breakdown` with
-`--trace 1`). Everything else is on earlier lines. Without a TPU, with
+`--trace 1`), then `compared`: every number `correct` compared, as
+`[value, limit]` by name; the same, in words, are the last lines of
+standard error. Everything else is on earlier lines. Without a TPU, with
 interpreted kernels, without the native parser or with another number of
 chips than the cell asks for, nothing is measured and the exit code is
 not 0. The cell, its configuration, its traffic mix and every per-layer
@@ -177,15 +179,16 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
         with tp.CompileLog() as clog:
             ds = make_data(work, traffic, conf, config, seed)
             reference = load_module("reference", config["reference"])
-            nb = int(conf["num_buckets"])
-            first = check.FirstSteps(ds, nb, config["correct"]["steps"],
-                                     reference.bucket_ids)
-            served = check.ServedStep(ds, nb, reference.bucket_ids)
+            sizes = check.space_sizes(reference, conf)
+            first = check.FirstSteps(ds, sizes, config["correct"]["steps"],
+                                     reference)
+            served = check.ServedStep(ds, sizes, reference)
             plan = trace_plan(work, traffic, seconds) if trace else None
             tap = drive(config, traffic, conf, ds, seconds, plan,
                         tp.Tap(None, clog, warns, first, served))
             out = result(tap, seconds, warns)
-            ok, lines = correct(config, conf, first, reference, tap, clog)
+            ok, lines, compared = correct(config, first, reference, tap,
+                                          clog)
             for line in lines:
                 say("correct: " + line)
             out["correct"] = bool(ok)
@@ -198,11 +201,17 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
                 say("a traced run: the end-to-end numbers on this line "
                     "are not results: " + json.dumps(e2e))
                 per_layer(out, bench, workload, config, conf, tap, first,
-                          clog, plan, keep_trace)
+                          reference, clog, plan, keep_trace)
     finally:
         logging.getLogger("wormhole_tpu").removeHandler(warns)
         shutil.rmtree(work, ignore_errors=True)
     out["device"] = {**device, **out["device"]}
+    # each number compared beside its limit: the result line's last key
+    # and the last lines of standard error (what a record of a run that
+    # came out not correct keeps)
+    out["compared"] = compared
+    for line in lines:
+        print(f"{TAG} correct: {line}", file=sys.stderr, flush=True)
     return out
 
 
@@ -301,8 +310,24 @@ def end_to_end(tap) -> dict:
     }
 
 
-def per_layer(out, bench, workload, config, conf, tap, first, clog, plan,
-              keep_trace) -> None:
+def batch_shapes(conf, config, reference, first) -> dict:
+    """What a kernel's `cost(batch)` may read: the batch's shapes, the
+    configuration's `hyper` values (a row's width among them) and, by id
+    space, the mean count of distinct rows a followed first step touched
+    (`distinct`); `uniq` is that of the first space the reference
+    declares, the learner's main table."""
+    touched = (first.reference or {}).get("touched", [])
+    distinct = {s: sum(len(t[s]) for t in touched) / max(len(touched), 1)
+                for s in reference.SPACES}
+    rows = int(conf["minibatch"])
+    return {"rows": rows, "nnz": rows * int(conf["nnz_per_row"]),
+            "uniq": distinct[next(iter(reference.SPACES))],
+            "num_buckets": int(conf["num_buckets"]),
+            "hyper": dict(config["hyper"]), "distinct": distinct}
+
+
+def per_layer(out, bench, workload, config, conf, tap, first, reference,
+              clog, plan, keep_trace) -> None:
     """The traced run's metrics, `device.busy_s`/`window_s` and the
     breakdown, each layer metric through its own file and reducer."""
     import jax
@@ -318,8 +343,6 @@ def per_layer(out, bench, workload, config, conf, tap, first, clog, plan,
     if kind not in peaks:
         raise SystemExit(f"run.py: no peaks for device kind {kind!r} in "
                          "benchmark/peaks.json")
-    uniq = [len(t) for t in (first.reference or {}).get("touched", [])]
-    rows = int(conf["minibatch"])
     ctx = {
         "hist": tp.hist_delta(tap.hist_open, tap.hist_close),
         "host_window_s": tap.t_hist_close - tap.t_open,
@@ -329,9 +352,7 @@ def per_layer(out, bench, workload, config, conf, tap, first, clog, plan,
         "trace": summary,
         "trace_steps": tap.trace_steps,
         "peaks": peaks[kind],
-        "batch": {"rows": rows, "nnz": rows * int(conf["nnz_per_row"]),
-                  "uniq": sum(uniq) / max(len(uniq), 1),
-                  "num_buckets": int(conf["num_buckets"])},
+        "batch": batch_shapes(conf, config, reference, first),
         "kernels": [load_module("kernels", k) for k in config["kernels"]],
     }
     say(f"trace: {summary['window_s']:.3f}s traced, device busy "
@@ -348,11 +369,13 @@ def per_layer(out, bench, workload, config, conf, tap, first, clog, plan,
     out["breakdown"] = xplane.breakdown(summary)
 
 
-def correct(config, conf, first, reference, tap, clog):
+def correct(config, first, reference, tap, clog):
     """(i) the reference check, (ii) no compilation inside the window,
     (iii) held-out logloss under ln 2 and the fixed pass's train logloss
-    falling, (iv) the staged batch kind the configuration names."""
-    lines, ok = [], True
+    falling, (iv) the staged batch kind the configuration names. Returns
+    whether all hold, a line for each, and every number compared beside
+    its limit (`[value, limit]` by name)."""
+    lines, ok, compared = [], True, {}
     spec = config["correct"]
     if first.problem or not first.done:
         ok = False
@@ -362,17 +385,21 @@ def correct(config, conf, first, reference, tap, clog):
     else:
         t0 = time.perf_counter()
         batches = [first.ds.batch(*o) for o in first.order]
-        ref = reference.run_steps(batches, int(conf["num_buckets"]),
-                                  config["hyper"], config["precision"])
+        start = first.start()
+        ref = reference.run_steps(batches, first.sizes, config["hyper"],
+                                  config["precision"], start=start)
         first.reference = ref
         nums = check.numbers(first.as_run(), check.reference_as_run(
-            ref, first.ds.minibatch))
+            ref, first.ds.minibatch, start))
         good, ls = check.verdict(nums, spec["limits"])
         ok &= good
         lines += ls
+        compared.update({k: [nums[k], v] for k, v in spec["limits"].items()})
+        touched = ", ".join(
+            f"{len(v)} touched {s}s of {first.sizes[s]}"
+            for s, v in ref["ids"].items())
         lines.append(f"reference: {len(batches)} steps on batches "
-                     f"{first.order}, {len(ref['ids'])} touched buckets of "
-                     f"{conf['num_buckets']}, "
+                     f"{first.order}, {touched}, "
                      f"{time.perf_counter() - t0:.1f}s (not in setup_s)")
     seen = tap.served.seen
     if seen is None:
@@ -382,22 +409,27 @@ def correct(config, conf, first, reference, tap, clog):
     else:
         t0 = time.perf_counter()
         ref = reference.run_steps(
-            [first.ds.batch(*seen["batch"])], int(conf["num_buckets"]),
+            [first.ds.batch(*seen["batch"])], first.sizes,
             config["hyper"], config["precision"],
-            start=dict(seen["pre"], ids=seen["ids"]))
-        good, ls = check.verdict(check.served_numbers(seen, {
+            start={"ids": seen["ids"], "tables": seen["pre"]})
+        nums = check.served_numbers(seen, {
             "pre": seen["pre"], "post": ref["states"][0],
-            "objv": ref["objv"][0], "nex": float(first.ds.minibatch)}),
-            spec["served_limits"])
+            "objv": ref["objv"][0], "nex": float(first.ds.minibatch)})
+        good, ls = check.verdict(nums, spec["served_limits"])
         ok &= good
         lines += ls
+        compared.update({k: [nums[k], v]
+                         for k, v in spec["served_limits"].items()})
+        read_back = ", ".join(f"{len(v)} {s}s"
+                              for s, v in seen["ids"].items())
         lines.append(f"served step: batch {seen['batch']} as the window's "
                      f"feed delivered it (pass {tap.pass_no} of the window "
-                     f"run), {len(seen['ids'])} buckets read back before "
-                     f"and after, {time.perf_counter() - t0:.1f}s")
+                     f"run), {read_back} read back before and after, "
+                     f"{time.perf_counter() - t0:.1f}s")
     in_window = clog.compiles("window")
     lines.append(f"compilations inside the window = {in_window}  (limit 0)")
     ok &= in_window == 0
+    compared["window_compiles"] = [in_window, 0]
     val = tap.fixed_val[-1]["logloss"] / tap.fixed_val[-1]["nex"]
     losses = tap.fixed_train[0]["losses"]
     falling = (sum(losses[-2:]) < sum(losses[:2])) and all(
@@ -407,10 +439,13 @@ def correct(config, conf, first, reference, tap, clog):
                  f"logloss {losses[0]:.4f} -> {losses[-1]:.4f} "
                  f"{'falling' if falling else 'NOT falling'}")
     ok &= math.isfinite(val) and val < spec["val_logloss_max"] and falling
+    compared["val_logloss"] = [val, spec["val_logloss_max"]]
     lines.append(f"staged batch kinds {sorted(tap.kinds)}  (expected "
                  f"{config['expect_kind']!r})")
     ok &= tap.kinds == {config["expect_kind"]}
-    return ok, lines
+    # a number that is not finite has no JSON: it is named
+    return ok, lines, {k: [v if math.isfinite(v) else repr(v), lim]
+                       for k, (v, lim) in compared.items()}
 
 
 def main(argv=None) -> int:

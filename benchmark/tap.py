@@ -173,6 +173,8 @@ class Tap:
             if self._want_hist_close and self.hist_close is None:
                 self.hist_close = self._entry_hist
             faulthandler.dump_traceback_later(STALL_DUMP_S, file=sys.stderr)
+        elif self.phase == "fixed" and self.first is not None:
+            self.first.before_step(self._learner)
         try:
             if self.trace_t0 is not None and self.trace_t1 is None:
                 import jax
@@ -205,13 +207,13 @@ class Tap:
             self._mode = mode
             passes.append(dict(losses=[], nex=0.0, logloss=0.0))
         p = passes[-1]
-        self.kinds.add(batch_kind(b))
+        self.kinds.add(batch_kind(self._learner, b))
         p["nex"] += out["nex"]
         p["logloss"] += out["logloss"]
         p["losses"].append(out["logloss"] / max(out["nex"], 1.0))
 
     def _note_window(self, b, out, t0, t1):
-        self.kinds.add(batch_kind(b))
+        self.kinds.add(batch_kind(self._learner, b))
         if self.t_open is None:
             # the first step completed after warm-up opens the window
             self.t_open = t1

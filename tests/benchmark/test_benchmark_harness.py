@@ -53,6 +53,9 @@ def test_tap_opens_after_warmup_and_counts_whole_steps_only(monkeypatch):
         def train_batch(self, b):
             return {"nex": 4.0}
 
+        def batch_kind(self, b):       # the learner tells a batch's kind
+            return b[0]
+
     clock = iter([0.0, 1.0,        # the warm-up pass's step
                   9.9, 10.0,       # opens the window, not counted
                   10.0, 12.5, 12.5, 14.9,
@@ -80,6 +83,7 @@ def test_tap_opens_after_warmup_and_counts_whole_steps_only(monkeypatch):
         tap.train_batch(b)         # the batch after the window ends the run
     assert tap.ends == [12.5, 14.9, 18.0] and tap.rows == [4.0] * 3
     assert tap.step_s == pytest.approx([2.5, 2.4, 3.1])
+    assert tap.kinds == {"tcoo"}
     # the histograms are read on entry to the first counted step and on
     # entry to the call after the last: the solver observes a step after it
     # returns, so what they hold lies between t_open and t_hist_close and
@@ -182,8 +186,9 @@ def test_roofline_reducer_takes_the_larger_bound_per_kernel():
 
 # --------------------------------------------------------- compared numbers
 def _run(objv, z1, final, ids1, ids, nex=4.0):
-    return {"objv": objv, "nex": [nex] * len(objv), "ids1": ids1, "z1": z1,
-            "ids": ids, "final": final}
+    return {"objv": objv, "nex": [nex] * len(objv),
+            "ids1": {"bucket": ids1}, "grad1": z1, "ids": {"bucket": ids},
+            "final": final, "start": {}}
 
 
 def test_numbers_are_zero_for_equal_runs_and_catch_each_fault():
@@ -248,20 +253,25 @@ def test_reference_from_a_given_state_equals_its_own_continuation():
     hyper = {"lr_eta": .1, "lr_beta": 1.0, "lambda_l1": .01, "lambda_l2": 0.0}
     prec = {"tables": "f32", "pull_w": "bf16", "push_d": "bf16",
             "push_g": "bf16"}
-    nb = 1 << 20
-    both = ref.run_steps(batches, nb, hyper, prec)
-    ids2 = np.unique(ref.bucket_ids(batches[1][0], nb))
-    pos = np.searchsorted(both["ids"], ids2)
+    sizes = check.space_sizes(ref, {"num_buckets": 1 << 20})
+    assert sizes == {"bucket": 1 << 20}
+    both = ref.run_steps(batches, sizes, hyper, prec)
+    ids2 = check.union_ids(ref, sizes, [batches[1][0]])
+    assert np.array_equal(ids2["bucket"], np.unique(
+        ref.bucket_ids(batches[1][0], 1 << 20)))
+    pos = np.searchsorted(both["ids"]["bucket"], ids2["bucket"])
     start = {k: v[pos] for k, v in both["states"][0].items()}
-    one = ref.run_steps(batches[1:], nb, hyper, prec,
-                        start=dict(start, ids=ids2))
+    one = ref.run_steps(batches[1:], sizes, hyper, prec,
+                        start={"ids": ids2, "tables": start})
     assert one["objv"][0] == pytest.approx(both["objv"][1], rel=1e-6)
-    for k in check.LEAVES:
+    assert list(ref.TABLES) == ["z", "n", "w"] and ref.GRADIENT == "z"
+    for k in ref.TABLES:
         np.testing.assert_allclose(one["states"][0][k],
                                    both["states"][1][k][pos], rtol=1e-6)
     with pytest.raises(ValueError):
-        ref.run_steps(batches[1:], nb, hyper, prec,
-                      start=dict(start, ids=ids2 + 1))
+        ref.run_steps(batches[1:], sizes, hyper, prec,
+                      start={"ids": {"bucket": ids2["bucket"] + 1},
+                             "tables": start})
 
 
 # ------------------------------------------------------------ whole runs
@@ -310,8 +320,16 @@ def sound_run(tmp_path_factory):
 
 def test_last_line_is_the_contracts_object(sound_run):
     out = json.loads(sound_run.splitlines()[-1])
-    assert set(out) == {"correct", "attempted", "failed", "metrics",
-                        "device"}
+    # the contract's keys, then the numbers compared, each beside its limit
+    assert list(out) == ["correct", "attempted", "failed", "metrics",
+                         "device", "compared"]
+    assert set(out["compared"]) == {
+        "loss_gap", "grad_norm_gap", "delta_norm_gap", "state_off_share",
+        "served_loss_gap", "served_delta_gap", "served_off_share",
+        "window_compiles", "val_logloss"}
+    for value, limit in out["compared"].values():
+        assert value <= limit
+    assert out["compared"]["delta_norm_gap"][1] == 0.01
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] >= 2
     # text-stream does not report batch_gap_p95_ms
