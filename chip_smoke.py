@@ -321,7 +321,7 @@ class Tap:
         p = self.passes[-1]
         p["t1"] = time.perf_counter()
         p["mode"] = mode
-        p["kinds"].add(b[1] if b[0] == "staged" else b[0])
+        p["kinds"].add(self._learner.batch_kind(b))
         p["steps"] += 1
         p["nex"] += out["nex"]
         p["logloss"] += out["logloss"]

@@ -208,9 +208,11 @@ def test_fm_compact_matches_xla_exactly(fm_file):
 
 
 def test_fm_compact_admission_and_convergence(fm_file):
-    """With a real threshold, the compact path's host-mirror admission
-    tracks the device count table and the model still learns the
-    interaction structure."""
+    """With a real threshold the compact step decides admission on the
+    device: the count table holds every key's occurrences, nothing of it
+    lives on the host, the step's counters say how many nonzeros were
+    admitted, and the model still learns the interaction structure."""
+    from wormhole_tpu.obs.metrics import REGISTRY
     from wormhole_tpu.ops import coo_kernels as ck
 
     cfg = DifactoConfig(minibatch=256, num_buckets=2 * ck.TILE,
@@ -218,12 +220,24 @@ def test_fm_compact_admission_and_convergence(fm_file):
                         dim=4, threshold=3, lr_eta=0.3, V_lr_eta=0.1,
                         kernel="pallas", kernel_dtype="f32")
     lrn = DifactoLearner(cfg, make_mesh(1, 1))
+    c0 = REGISTRY.snapshot()["counters"]
     tot = _train_file(lrn, fm_file, passes=4)
     auc = tot["auc"] / tot["nex"]
     assert auc > 0.78, auc  # == the XLA path's AUC on this config
-    # mirror == device count table
-    np.testing.assert_allclose(lrn._cnt_host,
-                               np.asarray(lrn.store.state["cnt"]))
+    # the device's count table == the data's occurrence counts
+    want = np.zeros(cfg.num_buckets, np.float32)
+    for blk in MinibatchIter(fm_file, minibatch_size=256):
+        np.add.at(want, (blk.index % cfg.num_buckets).astype(np.int64), 4.0)
+    np.testing.assert_array_equal(np.asarray(lrn.store.state["cnt"]), want)
+    assert not hasattr(lrn, "_cnt_host")
+    c1 = REGISTRY.snapshot()["counters"]
+    live = c1["difacto.step.live_nnz"] - c0.get("difacto.step.live_nnz", 0)
+    adm = (c1["difacto.step.admitted_nnz"]
+           - c0.get("difacto.step.admitted_nnz", 0))
+    assert live == 4 * 2 * 3000
+    # 80 keys, each seen ~37 times a pass: all but their first two
+    # occurrences are admitted
+    assert live - 2 * 80 <= adm + 80 and adm < live
     # eval/predict run the compact forward too
     blk = next(iter(MinibatchIter(fm_file, minibatch_size=128)))
     margins = lrn.predict_batch(blk)
@@ -291,21 +305,29 @@ def test_fm_pack_row_overflow_drops_from_both_layouts():
     idx = np.array(idxs, np.int64)
     val = np.array(vals, np.float32)
     db = types.SimpleNamespace(seg=seg, idx=idx, val=val)
-    pk = lrn._pack_fm(db, train=True)
-    (_, _, wcoo, ts_v, _, vcoo, rm_slot, rm_wval, rm_vval, _) = pk
-    rm_w2 = rm_wval.reshape(cfg.minibatch, W)
-    rm_v2 = rm_vval.reshape(cfg.minibatch, W)
-    # row 0 keeps exactly W of its 7 interactions in every channel...
+    pk = dict(zip(lrn._FM_TRAIN, lrn._pack_fm(db, train=True)))
+    # the batch's 8 rows are padded to the kernels' 128; the layout is
+    # position-major (all rows' first nonzero, then all rows' second...)
+    rm_w2 = pk["rm_wval"].reshape(W, 128).T
+    assert not rm_w2[8:].any()
+    # row 0 keeps exactly W of its 7 interactions in the forward...
     assert np.count_nonzero(rm_w2[0]) == W
-    assert np.count_nonzero(rm_v2[0]) == W
-    # ...and the slot COOs keep the SAME multiset of values per row
-    live = vcoo.val != 0
-    coo_row0 = np.sort(vcoo.val[live & (vcoo.seg == 0)])
-    np.testing.assert_array_equal(coo_row0, np.sort(rm_v2[0]))
-    livew = wcoo.val != 0
-    wcoo_row0 = np.sort(wcoo.val[livew & (wcoo.seg == 0)])
+    # ...and both sorted streams keep the SAME multiset of values per row
+    live = pk["vval"] != 0
+    coo_row0 = np.sort(pk["vval"][live & (pk["vseg"] == 0)])
+    np.testing.assert_array_equal(coo_row0, np.sort(rm_w2[0]))
+    livew = pk["wval"] != 0
+    wcoo_row0 = np.sort(pk["wval"][livew & (pk["wseg"] == 0)])
     np.testing.assert_array_equal(wcoo_row0, np.sort(rm_w2[0]))
+    # the V stream is sorted by key rank: a nonzero's rank leads to its
+    # key's w slot, and that bucket's V row lies in the line the key's
+    # compact row belongs to (32 rows of 4 floats a line)
+    rank = pk["vidx"][live]
+    assert (np.diff(rank) >= 0).all()
+    bucket = pk["uniq_w"][pk["key_slot"][rank]]
+    assert np.array_equal(bucket % cfg.vb // 32,
+                          pk["vlines"][pk["key_vslot"][rank] // 32])
     # untouched rows are intact in both layouts
     for r in range(1, 8):
-        assert np.count_nonzero(rm_v2[r]) == 2
-        assert np.count_nonzero(vcoo.val[live & (vcoo.seg == r)]) == 2
+        assert np.count_nonzero(rm_w2[r]) == 2
+        assert np.count_nonzero(pk["vval"][live & (pk["vseg"] == r)]) == 2

@@ -51,7 +51,8 @@ def test_fm_forward_row_major_matches_reference():
 def test_fm_push_contrib_matches_reference():
     """fm_push_contrib (the row-major path's tile scatter with
     precomputed a = c*xv[seg], b = c*val) must equal the dense per-nnz
-    dV accumulation; padding entries (val = 0) must vanish."""
+    dV accumulation; padding entries (val = 0) must vanish; the touch
+    column is summed by row beside it."""
     rng = np.random.default_rng(6)
     num_rows, vrows, dim, nnz = 256, 4 * ck.TILE_HI, 8, 3000
     idx, seg, val, p = _pack_v(rng, nnz, num_rows, vrows, 8192)
@@ -61,18 +62,26 @@ def test_fm_push_contrib_matches_reference():
     xv_ref = np.zeros((num_rows, dim), np.float32)
     for j in range(nnz):
         xv_ref[seg[j]] += val[j] * V[idx[j]]
-    # kernel operands from the packed (sorted+padded) layout: padding
-    # entries carry val == 0, so their a/b are zero
-    c = d[p.seg] * p.val
-    a = c[:, None] * xv_ref[p.seg]
-    b = c * p.val
-    gV = np.asarray(ck.fm_push_contrib(
-        jnp.asarray(V), jnp.asarray(a.astype(np.float32)),
-        jnp.asarray(b.astype(np.float32)), jnp.asarray(p.idx),
-        jnp.asarray(p.tmap), jnp.asarray(p.first), dtype=jnp.float32))
+    # kernel operands from the packed (sorted+padded) layout: each
+    # nonzero's batch row and value; padding entries carry val == 0 and
+    # every other live nonzero stands for one that is not admitted
+    vv = np.where(np.arange(len(p.val)) % 2 == 0, p.val, 0.0)
+    gV, touched = ck.fm_push_contrib(
+        jnp.asarray(V), jnp.asarray(xv_ref), jnp.asarray(d),
+        jnp.asarray(p.seg),
+        jnp.asarray(vv.astype(np.float32)), jnp.asarray(p.idx),
+        jnp.asarray(p.tmap), jnp.asarray(p.first), dtype=jnp.float32)
+    gV = np.asarray(gV)
+    np.testing.assert_array_equal(
+        np.asarray(touched),
+        np.bincount(p.idx, weights=vv != 0, minlength=vrows))
+    # the reference below: over the admitted nonzeros only
+    adm = np.zeros(nnz, bool)
+    order = np.argsort(idx, kind="stable")
+    adm[order] = (vv != 0)[p.val != 0]
 
     gV_ref = np.zeros((vrows, dim), np.float32)
-    for j in range(nnz):
+    for j in np.flatnonzero(adm):
         gV_ref[idx[j]] += d[seg[j]] * val[j] * (
             xv_ref[seg[j]] - val[j] * V[idx[j]])
     np.testing.assert_allclose(gV, gV_ref, rtol=1e-3, atol=1e-3)

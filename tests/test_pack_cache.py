@@ -79,9 +79,9 @@ def test_difacto_pack_disk_roundtrip_bit_identical(tmp_path):
     cfg = DifactoConfig(minibatch=64, num_buckets=1 << 9, nnz_per_row=8,
                         dim=4, threshold=1)
     fm = DifactoLearner(cfg, make_mesh(1, 1))
-    # eval pack only: the train pack mutates the count mirror, which is
-    # exactly why the learner declines to cache it
-    assert fm.pack_cache_token(train=True) is not None or fm._use_fm_pallas
+    # the XLA path packs with no state at all: both packs have a key (the
+    # compact path's, once its capacities are known: test_difacto_compact)
+    assert fm.pack_cache_token(train=True) is not None and not fm._use_fm_pallas
     blk = _rowblock()
     fresh = fm.prepare_batch(blk, train=False)
     cache = pc.PackCache(mem_bytes=1 << 20, disk_dir=str(tmp_path))

@@ -46,7 +46,9 @@ U_CAP = 1572864             # its auto compact_cap (24 tiles)
 NB_1TB = 1 << 29            # the benchmark's one-chip table
 U_CAP_1TB = 12582912        # its compact domain: 12,288 update blocks
 NB_MESH = 1 << 30           # the four-chip table, 2^28 buckets a shard
-DIM, VB = 8, 1 << 20        # DiFacto bench shape
+VB = 1 << 20                 # DiFacto smoke shape: V rows
+# the benchmark's FM cell: dim 50 at stride 64, 100,000 rows padded to 128s
+FM_1TB = (50, 64, 100096)
 UW_CAP, UV_CAP = 6 * ck.TILE, 256 * ck.BLK_U
 DTYPES = [jnp.bfloat16, jnp.float32]
 
@@ -194,29 +196,36 @@ def test_fused_update_other_handles(v5e, algo, tables):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_fm_kernels(v5e, dtype):
+@pytest.mark.parametrize("dim,stride,rows", [(8, 8, ROWS), FM_1TB])
+def test_fm_kernels(v5e, dtype, dim, stride, rows):
+    """The vector-row side of the FM step: the one Pallas kernel it has
+    (`fm_push_contrib`, whose row width is the table's stride) at the
+    smoke's dim 8 and at the benchmark's dim 50 / stride 64 / 100,096
+    rows, and the by-line gather and update round it, which are XLA's:
+    they have to compile beside it with the tables donated."""
     f32, i32 = jnp.float32, jnp.int32
-    vflat = ((VB * DIM,), f32)
-    v2 = ((VB * DIM // ck.LANES, ck.LANES), f32)
-    aot("row_gather",
-        lambda t, u, tm: fu.row_tile_gather(t, u, tm, DIM, dtype=dtype),
-        v5e, v2, ((UV_CAP,), i32), *slot_blocks(UV_CAP, 1))
-
-    idx, _, _, tmap, first = coo_stream(CAP, UV_CAP, ck.TILE_HI, ck.FM_BLK)
+    rpl = ck.LANES // stride
+    ul_cap, cap = UV_CAP // rpl // 4, rows * NNZ
+    uvr_cap = ul_cap * rpl
+    idx, _, _, tmap, first = coo_stream(cap, uvr_cap, ck.TILE_HI, ck.FM_BLK)
     p = idx[0][0]
-    wire = dtype  # a/b arrive at the gather wire dtype (difacto._build_fm)
-    aot("fm_push_contrib",
-        lambda V, a, b, si, tm, fi: ck.fm_push_contrib(
-            V, a, b, si, tm, fi, dtype=dtype),
-        v5e, ((UV_CAP, DIM), f32), ((p, DIM), wire), ((p,), wire), idx,
-        tmap, first)
+    wire = dtype  # xv, d are looked up at the wire dtype (_build_fm)
 
-    aot("v_update",
-        lambda V, nV, g, tch, u, tm, fi, la: fu.v_scatter_update(
-            V, nV, g, tch, u, tm, fi, la, dim=DIM, V_lr_eta=0.01,
-            V_lr_beta=1.0, lambda_V=0.01, dtype=dtype),
-        v5e, vflat, vflat, ((UV_CAP, DIM), f32), ((UV_CAP,), f32),
-        ((UV_CAP,), i32), *slot_blocks(UV_CAP, 3))
+    def push(V2, nV2, vlines, xv, d, seg, vv, si, tm, fi):
+        Vl = fu.row_gather(V2, vlines)
+        gV, touched = ck.fm_push_contrib(
+            Vl.reshape(uvr_cap, stride), xv, d, seg, vv, si, tm, fi,
+            dtype=dtype, wire=wire)
+        return fu.v_update(
+            V2, nV2, Vl, gV.reshape(ul_cap, ck.LANES),
+            jnp.broadcast_to(touched[:, None], (uvr_cap, stride)
+                             ).reshape(ul_cap, ck.LANES),
+            vlines, V_lr_eta=0.01, V_lr_beta=1.0, lambda_V=0.01)
+
+    v2 = ((VB * stride // ck.LANES, ck.LANES), f32)
+    aot("fm_push_contrib", push, v5e, v2, v2, ((ul_cap,), i32),
+        ((rows, stride), f32), ((rows,), f32), ((p,), i32), ((p,), f32),
+        idx, tmap, first)
 
     # difacto's w update: FTRL with cnt riding as the additive table
     def update(z, n, w, cnt, g, uniq, tm, fi, la, wcnts):
@@ -236,3 +245,62 @@ def test_gbdt_histogram(v5e):
         lambda b, g, h, rel: hist.level_hist(b, g, h, rel, nodes, B), v5e,
         ((rows, F), jnp.uint8), ((rows,), jnp.float32),
         ((rows,), jnp.float32), ((rows,), jnp.int32))
+
+
+def test_fm_step_carries_the_names_its_layer_metrics_match(v5e):
+    """The benchmark finds the vector-row step's device operations by
+    text: the Pallas kernels by the name their call carries, XLA's line
+    gathers and scatters by the jit argument they read (`vstate['V']`,
+    `vstate['nV']` lower to `%vstate__V__`, `%vstate__nV__`). The real
+    train step, built by the learner and compiled for the chip at dim 50
+    (tables large enough that XLA gathers from HBM as it does at the
+    benchmark's size), has to match every such pattern: a renamed
+    argument or kernel would otherwise make a metric read nothing,
+    silently."""
+    import json
+    import os
+    import types
+
+    from jax._src.lib import xla_client as xc
+
+    from wormhole_tpu.models import difacto as df
+    from wormhole_tpu.parallel.mesh import make_mesh
+
+    rows, nnz = 256, 8
+    cfg = df.DifactoConfig(minibatch=rows, nnz_per_row=nnz,
+                           num_buckets=16 * ck.TILE, v_buckets=VB, dim=50,
+                           threshold=2, kernel="pallas", kernel_dtype="bf16")
+    fm = df.DifactoLearner(cfg, make_mesh(1, 1))
+    rng = np.random.default_rng(0)
+    db = types.SimpleNamespace(
+        seg=np.repeat(np.arange(rows, dtype=np.int32), nnz),
+        idx=rng.integers(0, cfg.num_buckets, rows * nnz).astype(np.int32),
+        val=np.ones(rows * nnz, np.float32))
+    pack = fm._pack_fm(db, True)
+
+    def shaped(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e)
+
+    mask = jax.ShapeDtypeStruct((fm._rows,), jnp.float32, sharding=v5e)
+    step = fm._fm_steps[0].lower(
+        jax.tree_util.tree_map(shaped, fm.store.state),
+        jax.tree_util.tree_map(shaped, fm.vstore.state),
+        *map(shaped, pack), mask, mask,
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e)).compile()
+    # operands with their shapes, as the profiler names an operation
+    opts = xc._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    lines = [ln.strip() for ln in step.runtime_executable().hlo_modules()[0]
+             .to_string(opts).splitlines()]
+    metrics = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "layer_metrics")
+    # how many operations of one step each metric has to find
+    for name, want in (("row_gather_ms", 2), ("v_update_ms", 2),
+                       ("fm_push_contrib_ms", 1), ("tile_gather_ms", 2),
+                       ("fused_update_ms", 1), ("coo_push_ms", 1),
+                       ("fm_kernel_ms_per_step", 5),
+                       ("kernel_ms_per_step", 5)):
+        with open(os.path.join(metrics, name + ".json")) as fh:
+            rx = re.compile(json.load(fh)["params"]["pattern"])
+        hit = [ln for ln in lines if rx.search(ln)]
+        assert len(hit) == want, (name, hit)

@@ -29,9 +29,10 @@ TPU design (SURVEY §7.5 two-table plan):
 from __future__ import annotations
 
 import dataclasses
+import logging
 import threading
+from collections.abc import Mapping
 from functools import partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -39,14 +40,27 @@ import numpy as np
 
 from wormhole_tpu.data.rowblock import DeviceBatch, RowBlock, to_device_batch
 from wormhole_tpu.models import linear as linmod
+from wormhole_tpu.obs import trace as _trace
+from wormhole_tpu.obs.metrics import REGISTRY
 from wormhole_tpu.ops import coo_kernels as ck
-from wormhole_tpu.ops import metrics as M
+from wormhole_tpu.ops.fused_update import (row_gather, scatter_update,
+                                           v_update)
 from wormhole_tpu.ops.localizer import localize
-from wormhole_tpu.ops.penalty import l1l2_solve
 from wormhole_tpu.ops.spmv import row_squares, spmm, spmv, spmv_t
 from wormhole_tpu.parallel.kvstore import KVStore, TableSpec, quantize_push
 from wormhole_tpu.parallel.mesh import (batch_sharding, describe_placement,
                                         make_mesh)
+
+_log = logging.getLogger(__name__)
+
+# the compact path's own record (docs/observability.md): what the pack
+# made and dropped on the host, what the step admitted on the device
+_PACK_BATCHES = REGISTRY.counter("difacto.pack.batches")
+_PACK_NNZ = REGISTRY.counter("difacto.pack.nnz")
+_PACK_DROPPED = REGISTRY.counter("difacto.pack.dropped_nnz")
+_V_ROWS = REGISTRY.counter("difacto.v.rows")
+_STEP_LIVE = REGISTRY.counter("difacto.step.live_nnz")
+_STEP_ADMITTED = REGISTRY.counter("difacto.step.admitted_nnz")
 
 
 @dataclasses.dataclass
@@ -90,7 +104,17 @@ def _fm_forward(cfg: DifactoConfig, w, V, cnt, seg, idx, vidx, val,
     return margin, xw, xv, vval
 
 
-def _tables_for(cfg: DifactoConfig) -> dict[str, TableSpec]:
+def row_stride(dim: int) -> int:
+    """Floats from one embedding row to the next where the table is held
+    lane-packed (parallel/kvstore.TableSpec.stride): the smallest power
+    of two that holds `dim`, so that 128 // stride whole rows make a lane
+    line and none straddles two (50 -> 64, 5 -> 8, 8 -> 8). 0 where a
+    row is wider than a line."""
+    stride = 1 << max(dim - 1, 0).bit_length()
+    return stride if stride <= ck.LANES else 0
+
+
+def _tables_for(cfg: DifactoConfig, stride: int = 0) -> dict[str, TableSpec]:
     def v_init(key, shape, dtype):
         return cfg.V_init_scale * jax.random.normal(key, shape, dtype)
 
@@ -101,8 +125,8 @@ def _tables_for(cfg: DifactoConfig) -> dict[str, TableSpec]:
         # wire (huge-dynamic-range nonnegative deltas: see TableSpec)
         "n": TableSpec(wire_cap="bf16"),
         "cnt": TableSpec(dtype=jnp.float32, wire_cap="bf16"),
-        "V": TableSpec(tail=(cfg.dim,), init=v_init),
-        "nV": TableSpec(tail=(cfg.dim,), wire_cap="bf16"),
+        "V": TableSpec(tail=(cfg.dim,), init=v_init, stride=stride),
+        "nV": TableSpec(tail=(cfg.dim,), wire_cap="bf16", stride=stride),
     }
 
 
@@ -113,9 +137,6 @@ class _CombinedStore:
     def __init__(self, *stores):
         self.stores = stores
         self.mesh = stores[0].mesh
-
-    on_load = None  # callback fired after from_numpy (count-mirror sync)
-    on_sparse_pull = None  # callback fired with {table: (idx, rows)}
 
     def to_numpy(self):
         out = {}
@@ -130,8 +151,6 @@ class _CombinedStore:
         for s in self.stores:
             own = {k: v for k, v in arrays.items() if k in s.state}
             s.from_numpy(own)
-        if self.on_load is not None:
-            self.on_load()
 
     def _sub(self, name):
         for s in self.stores:
@@ -169,18 +188,37 @@ class _CombinedStore:
 
     @property
     def state(self):
-        """Merged read view over both table groups (do not assign into
-        it; use the sub-stores)."""
+        """Merged read view over both table groups as they are stored (do
+        not assign into it; use the sub-stores)."""
         out = {}
         for s in self.stores:
             out.update(s.state)
         return out
 
     def nnz(self, name="w"):
-        for s in self.stores:
-            if name in s.state:
-                return s.nnz(name)
-        raise KeyError(name)
+        return self._sub(name).nnz(name)
+
+    def rows_view(self, name):
+        return self._sub(name).rows_view(name)
+
+
+class _Tables(Mapping):
+    """Every table by name, each as an array of rows (num_buckets,
+    *tail): what `DifactoLearner.tables()` hands out. A lane-packed
+    table is unpacked when it is asked for and not kept, so that two of
+    them never lie unpacked side by side."""
+
+    def __init__(self, store: _CombinedStore):
+        self._store = store
+
+    def __getitem__(self, name):
+        return self._store.rows_view(name)
+
+    def __iter__(self):
+        return iter(self._store.state)
+
+    def __len__(self):
+        return len(self._store.state)
 
 
 class DifactoLearner:
@@ -194,43 +232,50 @@ class DifactoLearner:
             f"algo={cfg.algo!r} is not supported here")
         self.cfg = cfg
         self.mesh = mesh if mesh is not None else make_mesh(num_model=1)
+        # compact Pallas FM path (see the block comment above _pack_fm);
+        # l1_shrk needs w != 0 beside the count in the admission test,
+        # sharded meshes use the XLA collectives path
+        D = self.mesh.shape.get("data", 1)
+        M_ = self.mesh.shape.get("model", 1)
+        want = cfg.kernel == "pallas" or (
+            cfg.kernel == "auto" and jax.default_backend() == "tpu")
+        stride = row_stride(cfg.dim)
+        # the w tables are walked by whole (TILE_HI, 128) tiles and the V
+        # tables gathered by whole lane lines; ids and line numbers are
+        # int32 on the device. Neither the row width nor the batch's row
+        # count is among the blockers: a row is padded to its stride in
+        # the table (zero lanes that stay zero), the batch to the
+        # kernels' 128-row multiple under row_mask
+        blockers = [reason for bad, reason in (
+            (cfg.l1_shrk, "l1_shrk needs device-resident w"),
+            (D != 1 or M_ != 1, f"mesh {D}x{M_} has more than one device"),
+            (stride == 0, f"dim {cfg.dim} is wider than a {ck.LANES}-lane "
+                          "line"),
+            (cfg.num_buckets % ck.TILE != 0
+             or (cfg.vb * stride) % ck.LANES != 0,
+             f"tables are not whole {ck.TILE}-entry tiles and lane lines"),
+            (cfg.num_buckets >= 2**31, "bucket ids overflow int32"),
+        ) if bad]
+        self._use_fm_pallas = want and not blockers
+        #: floats from one V row to the next as the tables are stored:
+        #: lane-packed on the compact path, else 0 (rows of dim)
+        self._stride = stride if self._use_fm_pallas else 0
+        #: rows of a compact batch: the minibatch padded to the kernels'
+        #: lane multiple; rows past cfg.minibatch are masked and empty
+        self._rows = -(-cfg.minibatch // ck.LANES) * ck.LANES
+        specs = _tables_for(cfg, self._stride)
         self.store = KVStore(self.mesh, cfg.num_buckets,
-                             {k: v for k, v in _tables_for(cfg).items()
+                             {k: v for k, v in specs.items()
                               if v.tail == ()}, seed=seed)
         # V tables may use a smaller bucket space; keep them in a second
         # KVStore so each table's bucket axis shards over the model axis
         self.vstore = KVStore(self.mesh, cfg.vb,
-                              {k: v for k, v in _tables_for(cfg).items()
+                              {k: v for k, v in specs.items()
                                if v.tail != ()}, seed=seed + 1)
         self._bsh1 = batch_sharding(self.mesh, 1)
         self._dropped_rows = 0
         self._step_count = 0
         self.ckpt_store = _CombinedStore(self.store, self.vstore)
-        # compact Pallas FM path (see the block comment above _pack_fm);
-        # l1_shrk needs device-resident w, sharded meshes use the XLA
-        # collectives path
-        D = self.mesh.shape.get("data", 1)
-        M_ = self.mesh.shape.get("model", 1)
-        want = cfg.kernel == "pallas" or (
-            cfg.kernel == "auto" and jax.default_backend() == "tpu")
-        # the fused in-place updates need tables that tile cleanly: dim a
-        # power of two dividing 128, V and w tables whole numbers of
-        # (TILE_HI, 128) flat tiles, lane-aligned rows; the row-gather
-        # kernels compute flat int32 offsets uniq * dim, so the flat V
-        # table must fit int32 (ADVICE r2; pack_tile_coo asserts the
-        # same for w)
-        blockers = [reason for bad, reason in (
-            (cfg.l1_shrk, "l1_shrk needs device-resident w"),
-            (D != 1 or M_ != 1, f"mesh {D}x{M_} has more than one device"),
-            (cfg.minibatch % 128 != 0, "minibatch % 128 != 0"),
-            (cfg.dim & (cfg.dim - 1) != 0 or 128 % cfg.dim != 0,
-             f"dim {cfg.dim} is not a power of two dividing 128"),
-            ((cfg.vb * cfg.dim) % ck.TILE != 0
-             or cfg.num_buckets % ck.TILE != 0,
-             f"tables are not whole {ck.TILE}-entry tiles"),
-            (cfg.vb * cfg.dim >= 2**31, "flat V table overflows int32"),
-        ) if bad]
-        self._use_fm_pallas = want and not blockers
         #: start-up statement of where and how this learner runs
         self.placement = describe_placement(
             self.mesh, "difacto", self._use_fm_pallas,
@@ -239,13 +284,6 @@ class DifactoLearner:
         self._fm_caps = None
         self._fm_steps = None
         self._fm_lock = threading.Lock()
-        self._cnt_host = np.zeros(cfg.num_buckets, np.float32)
-        # pack-version counter for the epoch cache: bumped whenever the
-        # count mirror resyncs, since admission (hence the packed vval)
-        # is a function of the mirror's contents
-        self._pack_epoch = 0
-        self.ckpt_store.on_load = self.refresh_count_mirror
-        self.ckpt_store.on_sparse_pull = self._on_sparse_pull
         # sparse PS wire hints: unique w-space / V-space rows touched by
         # trained batches since the last collect_touched() drain
         self.track_touched = False
@@ -345,28 +383,44 @@ class DifactoLearner:
                       "lambda_l2": cfg.lambda_l2}}
 
     # -- compact Pallas FM path ---------------------------------------------
-    # The XLA segment-op step spends ~85ms/step at Criteo shape: per-nnz
-    # [nnz, dim] gathers + two segment-sums for the V terms, a 4M-wide
-    # count scatter, and dense table updates. The compact path localizes
-    # both key spaces on the host (the Localizer role), runs the scalar
-    # COO kernels on the compact w domain and the FM/SpMM kernels
-    # (fm_pull/fm_push) on the compact V domain, and updates/scatters
-    # only touched entries. Admission (cnt >= threshold) is computed on a
-    # HOST count mirror during packing — counts are pure data statistics
-    # the host can track exactly, and the mirror resyncs from the store
-    # after loads and PS pulls. l1_shrk needs device-resident w, so it
-    # stays on the XLA path.
+    # The XLA segment-op step makes dense temporaries of both tables'
+    # shapes and per-nnz [nnz, dim] gathers: ~85 ms a step at dim 8, and
+    # at dim 50 over 2^24 rows it does not fit a chip. The compact path
+    # localizes the batch on the host (the Localizer role) and leaves on
+    # the device only work that scales with the batch:
+    #   w side   the batch's distinct buckets in tile-run-aligned compact
+    #            slots (ck.assign_tile_slots): w and cnt pulled by
+    #            ck.tile_gather, the gradient by ck.coo_spmv_t, FTRL and
+    #            the count push by the fused in-place update
+    #   V side   the batch's distinct lane lines of the lane-packed V
+    #            table (128 // stride rows a line), sorted: a compact row
+    #            domain of ul_cap * rpl rows, sized by the batch's keys
+    #            and not by the table's tiles. Lines are fetched and
+    #            written back by row gather / scatter (ops/fused_update);
+    #            the gradient is summed by key (ck.fm_push_contrib over
+    #            the batch's distinct keys), masked, and added up by row
+    #   forward  a row gather a nonzero from a table over the batch's
+    #            distinct keys, U[key] = [V row of the key, if admitted |
+    #            w], laid out by position in the row (ck.build_rm,
+    #            position-major): xw and xv / x2 together
+    # Admission is decided in the step, as in the XLA step and upstream
+    # (the weight pull waits for the count push of the same minibatch,
+    # async_sgd.h:374-381): cnt is read at the compact w slots, the
+    # batch's own counts added, `>= threshold` tested there, and the V
+    # side of the forward and of the push masked by it. The pack decides
+    # nothing of it, so a train pack is a pure function of the batch and
+    # the capacities, and the pack cache replays it. l1_shrk needs w
+    # beside the count, so it stays on the XLA path.
 
-    def refresh_count_mirror(self) -> None:
-        self._cnt_host = np.asarray(self.store.state["cnt"]).copy()
-        self._pack_epoch += 1
-
-    def on_pass_start(self) -> None:
-        """Solver hook: resync the count mirror from the device table so
-        any drift (e.g. batches packed but never consumed after an
-        aborted pass) is bounded to one pass."""
-        with self._fm_lock:
-            self.refresh_count_mirror()
+    #: what _pack_fm returns, in the order the steps take it: an eval
+    #: pack, and the train pack with the two sorted COO streams between
+    #: its head and its tail
+    _FM_EVAL = ("uniq_w", "wtmap_u", "vlines", "key_slot", "key_vslot",
+                "rm_key", "rm_wval")
+    _FM_TRAIN = _FM_EVAL[:2] + (
+        "wfirst_u", "wlast_u", "wcnts", "widx", "wseg", "wval", "wtmap",
+        "wfirst", "vidx", "vseg", "vval", "vtmap", "vfirst",
+    ) + _FM_EVAL[2:]
 
     def _fm_dtype_of(self):
         cfg = self.cfg
@@ -376,19 +430,39 @@ class DifactoLearner:
             return jnp.float32
         return None  # kernel default (bf16 on TPU, f32 in interpret)
 
-    @property
-    def _v_rows_per_tile(self) -> int:
-        return ck.TILE // self.cfg.dim
-
-    def _pack_fm(self, db: DeviceBatch, train: bool):
-        """Host pack (loader threads, serialized by _fm_lock so the count
-        mirror sees batches in order): localize w keys and V row ids into
-        tile-run-aligned compact slots (coo_kernels.assign_tile_slots),
-        apply admission to the V values, and lay both out for the
-        kernels. The tile alignment is what lets the training step update
-        both tables in place (ops/fused_update.py) with no XLA element
-        gathers or scatters."""
+    def _size_fm(self, uniq, live_counts, nnz_live: int) -> tuple:
+        """The permanent capacities, from the first batch packed: compact
+        w slots (whole tiles' runs), distinct keys, distinct V lines.
+        That batch may be a short tail part: its counts are scaled up to
+        a full minibatch's worth (capped at 4x), then by 1.5, then
+        rounded up to a coarse step (a sixteenth of their power of two),
+        so that jobs whose first batches differ by a percent compile the
+        same step and share its entry in the compile cache."""
         cfg = self.cfg
+        fill = cfg.row_capacity / max(nnz_live, 1)
+        scale = 1.5 * min(max(fill, 1.0), 4.0)
+
+        def coarse(n: float, unit: int) -> int:
+            step = max(unit, (1 << int(n).bit_length()) // 16 // unit * unit)
+            return -(-int(n + 1) // step) * step
+
+        blocks_w = ck.tile_blocks_needed(uniq, ck.TILE)
+        uw = -(-int(scale * blocks_w) * ck.BLK_U // ck.TILE) * ck.TILE
+        # the keys are tiled by TILE_HI in the V push
+        uk = coarse(scale * len(uniq), ck.BLK_U)
+        rpl = ck.LANES // self._stride
+        lines = np.unique(uniq[live_counts > 0] % cfg.vb // rpl)
+        ul = coarse(scale * len(lines), ck.TILE_HI)
+        return uw, uk, ul
+
+    def _pack_fm(self, db: DeviceBatch, train: bool) -> tuple:
+        """Host pack (loader threads, concurrently): localize the w keys
+        into tile-run-aligned compact slots and a dense key rank, the V
+        rows into the batch's sorted distinct lane lines, and lay both
+        out for the kernels. Returns host arrays in the order the step
+        takes them, a pure function of the batch and the capacities."""
+        cfg = self.cfg
+        rpl = ck.LANES // self._stride
         idx64 = db.idx.astype(np.int64)
         live = db.val != 0
         loc = localize(idx64.astype(np.uint64))
@@ -398,132 +472,111 @@ class DifactoLearner:
             inv[live], minlength=len(uniq)).astype(np.float32)
         with self._fm_lock:
             if self._fm_caps is None:
-                # the first batch to pack may be a short tail part: scale
-                # its unique counts up to a full minibatch's worth (capped
-                # at 4x) so the permanent capacities are not sized from a
-                # fragment
-                fill = cfg.row_capacity / max(int(live.sum()), 1)
-                scale = 1.5 * min(max(fill, 1.0), 4.0)
-                blocks_w = ck.tile_blocks_needed(uniq, ck.TILE)
-                uw = (-(-int(scale * blocks_w) * ck.BLK_U // ck.TILE)
-                      * ck.TILE)
-                vuniq0 = (np.unique(idx64[live] % cfg.vb)
-                          if live.any() else np.zeros(1, np.int64))
-                blocks_v = ck.tile_blocks_needed(vuniq0,
-                                                 self._v_rows_per_tile)
-                uv = int(scale * blocks_v + 1) * ck.BLK_U
-                self._fm_caps = (uw, uv)
-                self._build_fm(uw, uv)
-        uw_cap, uv_cap = self._fm_caps
+                self._fm_caps = self._size_fm(uniq, live_counts,
+                                              int(live.sum()))
+                self._build_fm(*self._fm_caps)
+        uw_cap, uk_cap, ul_cap = self._fm_caps
+        uvr_cap = ul_cap * rpl
 
+        # a key is kept if it has a w slot and a rank among the keys
         ts_w = ck.assign_tile_slots(uniq, ck.TILE, uw_cap, cfg.num_buckets)
-        slot_nz = ts_w.slot_of_uniq[inv]
-        keep = slot_nz < uw_cap
+        kept_key = ts_w.slot_of_uniq < uw_cap
+        kept_key[uk_cap:] = False
+        keep = kept_key[inv]
         dropped = int(np.count_nonzero(~keep & live))
-        idx64, seg, val, slot_nz = (idx64[keep], db.seg[keep],
-                                    db.val[keep], slot_nz[keep])
-        live = val != 0
-        kept_r = ts_w.slot_of_uniq < uw_cap
+        seg, val, key_nz = db.seg[keep], db.val[keep], inv[keep]
+        slot_nz = ts_w.slot_of_uniq[key_nz]
         wcnts = np.zeros(uw_cap, np.float32)
-        wcnts[ts_w.slot_of_uniq[kept_r]] = live_counts[kept_r]
+        wcnts[ts_w.slot_of_uniq[kept_key]] = live_counts[kept_key]
 
-        # admission per key from the mirror; training includes this
-        # batch's own counts (the reference makes the weight pull depend
-        # on the count push of the same minibatch, async_sgd.h:374-381).
-        # Only this mirror read-modify-write needs the lock — packing
-        # itself runs concurrently across loader threads.
-        with self._fm_lock:
-            cnt_key = self._cnt_host[uniq]
-            if train:
-                cnt_key = cnt_key + live_counts
-                self._cnt_host[uniq[kept_r]] += live_counts[kept_r]
-        adm_nz = (cnt_key >= cfg.threshold)[inv][keep] & live
+        # V domain: the distinct lines of the live kept keys' rows; a
+        # key's compact row is its line's rank * rpl + its place in the
+        # line, past ul_cap the sentinel row uvr_cap, which reads zero
+        vrow_key = uniq % cfg.vb
+        live_key = kept_key & (live_counts > 0)
+        lines = np.unique(vrow_key[live_key] // rpl)
+        rank_key = np.searchsorted(lines, vrow_key // rpl)
+        v_key = live_key & (rank_key < ul_cap)
+        dropped += int(live_counts[live_key & ~v_key].sum())
+        vslot_key = np.where(v_key, rank_key * rpl + vrow_key % rpl,
+                             uvr_cap)
+        # padding lines lie past the table's end, distinct and rising:
+        # the gather reads zero there and the scatter drops them
+        vlines = (cfg.vb // rpl + np.arange(ul_cap)).astype(np.int32)
+        vlines[:min(len(lines), ul_cap)] = lines[:ul_cap]
+        nk = min(len(uniq), uk_cap)
+        key_slot = np.full(uk_cap, uw_cap, np.int32)
+        key_slot[:nk] = np.minimum(ts_w.slot_of_uniq[:nk], uw_cap)
+        key_vslot = np.full(uk_cap, uvr_cap, np.int32)
+        key_vslot[:nk] = vslot_key[:nk]
 
-        # V domain: localize (bucket % vb) row ids of the kept nonzeros
-        vidx = (idx64 % cfg.vb).astype(np.uint64)
-        loc_v = localize(vidx)
-        ts_v = ck.assign_tile_slots(loc_v.uniq_keys, self._v_rows_per_tile,
-                                    uv_cap, cfg.vb)
-        vslot_nz = ts_v.slot_of_uniq[loc_v.local_index]
-        vval = np.where(adm_nz, val, 0.0).astype(np.float32)
-        keepv = vslot_nz < uv_cap
-        dropped += int(np.count_nonzero(~keepv & (vval != 0)))
-        segv, vvalv, vslotv = seg[keepv], vval[keepv], vslot_nz[keepv]
-        # row-major padded view (minibatch x nnz_per_row) of the live
-        # nonzeros, laid out over the W-SLOT domain (ck.build_rm): the
-        # forward's xw AND xv/x2 sums become ONE XLA row gather from the
-        # unified compact table U = [V-row | w] (indexed by w slot; see
-        # _build_fm) + a dense reshape-reduce — no radix-image kernel on
-        # the whole forward path. Slot `uw_cap` is the appended zero
-        # row. Three channels ride the layout: the w slot, the w value
-        # (all live nonzeros), and the ADMITTED value (V side — zero
-        # where the count threshold or uv_cap overflow masks the
-        # embedding, matching the reference's unallocated entries).
+        # row-major padded view (rows x nnz_per_row) of the live
+        # nonzeros over the KEY domain (ck.build_rm): the forward's xw
+        # and xv / x2 sums are row gathers from U (see _build_fm). Key
+        # uk_cap is the appended zero row.
         W = cfg.nnz_per_row
-        mb = cfg.minibatch
-        rm_slot, (rm_wval, rm_vval), over = ck.build_rm(
-            seg, slot_nz, val, mb, W, uw_cap,
-            extra=(np.where(keepv, vval, 0.0),))
+        rm_key, (rm_wval,), over = ck.build_rm(
+            seg, key_nz, val, cfg.minibatch, W, uk_cap)
         rm_dropped = 0
         if len(over):
-            # overflow beyond nnz_per_row: since the forward's xw rides
-            # the SAME row-major layout, a row's nonzeros past
-            # nnz_per_row are dropped from EVERY layout (rm forward —
-            # including the linear xw term — wcoo backward, vcoo
-            # backward) so pull and push agree about which nonzeros
-            # exist
+            # a row's nonzeros past nnz_per_row are dropped from EVERY
+            # layout (the rm forward, the w push, the V push) so that
+            # pull and push agree about which nonzeros exist
             rm_dropped = int(np.count_nonzero(val[over]))
             val = val.copy()
             val[over] = 0.0
-            mask_src = np.ones(len(seg), bool)
-            mask_src[over] = False
-            vvalv[~mask_src[keepv]] = 0.0
-        # per-w-slot V row for the unified table: slot's key -> its V
-        # bucket's compact slot (uv_cap sentinel -> zero V row, covering
-        # alignment holes AND uv_cap-overflowed keys)
-        vslot_w = np.full(uw_cap, uv_cap, np.int32)
-        w_slots_valid = np.flatnonzero(ts_w.uniq < cfg.num_buckets)
-        vkeys = (ts_w.uniq[w_slots_valid].astype(np.int64)
-                 % cfg.vb).astype(np.uint64)
-        li = np.searchsorted(loc_v.uniq_keys, vkeys)
-        li = np.clip(li, 0, max(len(loc_v.uniq_keys) - 1, 0))
-        ok = loc_v.uniq_keys[li] == vkeys
-        vs = np.minimum(ts_v.slot_of_uniq[li], uv_cap).astype(np.int32)
-        vslot_w[w_slots_valid] = np.where(ok, vs, uv_cap)
-        if dropped or rm_dropped:
-            # two distinct causes with distinct remedies, counted
-            # separately so an undersized nnz_per_row is diagnosable
-            # (ADVICE #4): slot-cap overflow (the compact W/V tables
-            # sized off the first batch ran out of slots — raise
-            # compact caps / first-batch key diversity) vs row-cap
-            # overflow (a row carried more than nnz_per_row nonzeros —
-            # raise nnz_per_row; note the rm layout caps the xw forward
-            # too, not just the V embeddings)
-            import logging
+        pad = (self._rows - cfg.minibatch) * W
+        rm_key = np.concatenate([rm_key, np.full(pad, uk_cap, np.int32)])
+        rm_wval = np.concatenate([rm_wval, np.zeros(pad, np.float32)])
+        # position-major: all rows' first nonzero, then all rows' second
+        # ... so that the step sums a row's nonzeros one position at a
+        # time into a [rows, S] accumulator and never holds [nnz, S]
+        rm_key, rm_wval = (np.ascontiguousarray(
+            a.reshape(self._rows, W).T).reshape(-1)
+            for a in (rm_key, rm_wval))
 
-            logging.getLogger(__name__).warning(
+        _PACK_BATCHES.inc()
+        _PACK_NNZ.inc(int(live.sum()))
+        _V_ROWS.inc(int(np.count_nonzero(v_key)))
+        if dropped or rm_dropped:
+            # two causes with two remedies: the capacities sized off the
+            # first batch ran out (its key diversity was too low), or a
+            # row carried more than nnz_per_row nonzeros (the rm forward
+            # caps xw too, not just the embeddings)
+            _PACK_DROPPED.inc(dropped + rm_dropped)
+            _log.warning(
                 "fm compaction overflow: dropped %d nonzeros to the "
-                "slot caps (caps %s — raise key diversity of the first "
-                "batch) and %d to the nnz_per_row row cap (%d — raise "
-                "nnz_per_row; the row-major forward caps xw too)",
-                dropped, self._fm_caps, rm_dropped, W)
+                "capacities %s (w slots, keys, V lines) and %d to the "
+                "nnz_per_row row cap (%d)", dropped, self._fm_caps,
+                rm_dropped, W)
+        head = (ts_w.uniq, ts_w.tmap_u)
+        tail = (vlines, key_slot, key_vslot, rm_key, rm_wval)
         if not train:
             # eval/predict never scatter: the sorted COO streams (and
             # their radix sorts) are a train-only cost
-            return (ts_w, wcnts, None, ts_v, None, None,
-                    rm_slot, rm_wval, rm_vval, vslot_w)
+            return head + tail
         wcoo = ck.pack_sorted_coo(slot_nz, seg, val, uw_cap,
                                   capacity=cfg.row_capacity)
-        vtouched = np.zeros(uv_cap, np.float32)
-        vtouched[np.unique(vslotv[vvalv != 0])] = 1.0
-        vcoo = ck.pack_sorted_coo(vslotv, segv, vvalv, uv_cap,
-                                  capacity=cfg.row_capacity,
-                                  tile=ck.TILE_HI, blk=ck.FM_BLK)
-        return (ts_w, wcnts, wcoo, ts_v, vtouched, vcoo,
-                rm_slot, rm_wval, rm_vval, vslot_w)
+        # tile_gather of w and of cnt, the fused update; the push
+        linmod._count_chunks(ts_w.uniq, cfg.num_buckets, ck.BLK_U, 3)
+        linmod._count_chunks(wcoo.val, 0, ck.BLK, 1)
+        # the V stream: the same nonzeros sorted by their key's rank,
+        # in the push kernel's geometry. The push sums by key, so that
+        # the step can mask a key's sum by its admission before it adds
+        # the keys of a row up.
+        at = np.flatnonzero(val != 0)
+        vcoo = ck.pack_sorted_coo(
+            key_nz[at], seg[at], val[at], uk_cap,
+            capacity=cfg.row_capacity, tile=ck.TILE_HI, blk=ck.FM_BLK)
+        return (head + (ts_w.first_u, ts_w.last_u, wcnts, wcoo.idx,
+                        wcoo.seg, wcoo.val, wcoo.tmap, wcoo.first)
+                + (vcoo.idx, vcoo.seg, vcoo.val, vcoo.tmap, vcoo.first)
+                + tail)
 
-    def _build_fm(self, uw_cap: int, uv_cap: int) -> None:
+    def _build_fm(self, uw_cap: int, uk_cap: int, ul_cap: int) -> None:
         cfg = self.cfg
+        S, rows = self._stride, self._rows
+        uvr_cap = ul_cap * (ck.LANES // S)
         dt = self._fm_dtype_of()
         # wire dtype for the XLA gather operands (U, xvd): dt resolves
         # to None in bf16 mode (the kernels pick bf16 internally), but
@@ -533,54 +586,64 @@ class DifactoLearner:
         # documented throughput opt-in; f32 mode stays exact).
         wire = dt if dt is not None else (
             jnp.float32 if ck._use_interpret() else jnp.bfloat16)
-        from wormhole_tpu.ops.fused_update import (row_tile_gather,
-                                                   scatter_update,
-                                                   v_scatter_update)
 
-        def gather_compact(state, vstate, uniq_w, wtm, uniq_v, vtm):
+        def pull(state, vstate, uniq_w, wtm, vlines):
+            # counts are whole numbers far over bf16's 256: their gather
+            # is f32 whatever the kernel dtype (exact up to 2^24)
             wc = ck.tile_gather(state["w"].reshape(-1, ck.LANES),
                                 uniq_w, wtm, dtype=dt)
-            Vc = row_tile_gather(vstate["V"].reshape(-1, ck.LANES),
-                                 uniq_v, vtm, cfg.dim, dtype=dt)
-            return wc, Vc
+            cc = ck.tile_gather(state["cnt"].reshape(-1, ck.LANES),
+                                uniq_w, wtm, dtype=jnp.float32)
+            return wc, cc, row_gather(vstate["V"], vlines)
 
-        def forward_rm(wc, Vc, rm_slot, rm_wval, rm_vval, vslot_w):
-            # row-major forward over the UNIFIED compact table
-            # U[s] = [V-row of slot s's key | w[s]]: ONE XLA row gather
-            # + a dense reshape-reduce yields xw AND xv/x2 together —
-            # no radix-image kernel anywhere on the forward path (the
-            # former coo_spmv xw was ~7.5 ms of the step, r4 PERF.md).
-            # U's V side is a u_cap-sized row gather (cheap: compact
-            # rows, not nnz), its w side is the tile-gathered compact
-            # w. Rows move at the kernel dtype (half the bytes in bf16
+        def forward_rm(wc, cnt_c, Vl, key_slot, key_vslot, rm_key,
+                       rm_wval):
+            # row-major forward over the table of the batch's distinct
+            # keys, U[k] = [V row of key k, zero unless admitted | w[k]]:
+            # one XLA row gather a nonzero position yields xw AND xv/x2
+            # together. Admission masks a key's row of U, so two keys
+            # that share a V row are admitted each on its own count.
+            # Rows move at the kernel dtype (half the bytes in bf16
             # mode); products and sums accumulate in f32.
-            Vcz = jnp.concatenate(
-                [Vc.astype(wire), jnp.zeros((1, cfg.dim), wire)], axis=0)
-            U = jnp.concatenate(
-                [jnp.take(Vcz, vslot_w, axis=0),
-                 wc.astype(wire)[:, None]], axis=1)   # [uw_cap, dim+1]
-            Uz = jnp.concatenate(
-                [U, jnp.zeros((1, cfg.dim + 1), wire)], axis=0)
-            U_nnz = jnp.take(Uz, rm_slot, axis=0)     # [mb*W, dim+1]
-            xw = (rm_wval * U_nnz[:, cfg.dim].astype(jnp.float32)
-                  ).reshape(cfg.minibatch, -1).sum(1)
-            p = rm_vval[:, None] * U_nnz[:, :cfg.dim].astype(jnp.float32)
-            xv = p.reshape(cfg.minibatch, -1, cfg.dim).sum(1)
-            x2 = (p * p).reshape(cfg.minibatch, -1, cfg.dim).sum(1)
+            admit = (cnt_c >= cfg.threshold).astype(jnp.float32)
+            zero1 = jnp.zeros((1,), jnp.float32)
+            w_key = jnp.take(jnp.concatenate([wc, zero1]), key_slot)
+            adm_key = jnp.take(jnp.concatenate([admit, zero1]), key_slot)
+            # each key's V row in f32: the push scales it by the key's
+            # sum of b, the forward takes it at the wire dtype
+            Vk = jnp.take(jnp.concatenate(
+                [Vl.reshape(uvr_cap, S), jnp.zeros((1, S), jnp.float32)],
+                axis=0), key_vslot, axis=0)
+            Uz = jnp.concatenate([
+                jnp.concatenate([Vk.astype(wire)
+                                 * adm_key.astype(wire)[:, None],
+                                 w_key.astype(wire)[:, None]], axis=1),
+                jnp.zeros((1, S + 1), wire)], axis=0)   # [uk_cap+1, S+1]
+
+            def position(acc, kv):
+                # every row's nonzero at one position of the layout
+                xw, xv, x2 = acc
+                u = jnp.take(Uz, kv[0], axis=0).astype(jnp.float32)
+                p = kv[1][:, None] * u[:, :S]
+                return (xw + kv[1] * u[:, S], xv + p, x2 + p * p), None
+
+            zero = jnp.zeros((rows, S), jnp.float32)
+            (xw, xv, x2), _ = jax.lax.scan(
+                position, (jnp.zeros((rows,), jnp.float32), zero, zero),
+                (rm_key.reshape(-1, rows), rm_wval.reshape(-1, rows)))
             margin = xw + 0.5 * jnp.sum(xv * xv - x2, axis=-1)
-            return xw, xv, margin
+            return xw, xv, margin, adm_key, Vk
 
         @partial(jax.jit, donate_argnums=(0, 1))
         def train_fm(state, vstate, uniq_w, wtm, wfi, wla, wcnts,
                      widx, wseg, wval, wtmap, wfirst,
-                     uniq_v, vtm, vfi, vla, vtouched,
                      vidx, vseg, vval, vtmap, vfirst,
-                     rm_slot, rm_wval, rm_vval, vslot_w,
+                     vlines, key_slot, key_vslot, rm_key, rm_wval,
                      label, mask, rngkey):
-            wc, Vc = gather_compact(state, vstate, uniq_w, wtm,
-                                    uniq_v, vtm)
-            xw, xv, margin = forward_rm(wc, Vc, rm_slot, rm_wval,
-                                        rm_vval, vslot_w)
+            wc, cc, Vl = pull(state, vstate, uniq_w, wtm, vlines)
+            # admission sees this batch's own counts (module docstring)
+            xw, xv, margin, adm_key, Vk = forward_rm(
+                wc, cc + wcnts, Vl, key_slot, key_vslot, rm_key, rm_wval)
             obj, d = linmod._loss_dual(cfg.loss, label, margin)
             d = d * mask
 
@@ -589,7 +652,7 @@ class DifactoLearner:
             gw = ck.coo_spmv_t(d, widx, wseg, wval, wtmap, wfirst,
                                uw_cap, dtype=dt)
             # cnt rides the fused update's touched-tile walk as an
-            # additive table (an XLA element scatter into the 4M-bucket
+            # additive table (an XLA element scatter into the bucket
             # table costs ~4 ms at the Criteo shape; sentinel slots
             # carry all-zero one-hot rows and scatter nothing)
             new_state, new_w = scatter_update(
@@ -599,23 +662,22 @@ class DifactoLearner:
                 fixed_bytes=cfg.fixed_bytes, dtype=dt,
                 add_table="cnt", add_values=wcnts)
 
-            # V: AdaGrad at the row's storage, same treatment; the grad
-            # filters apply on the compact gradient beforehand.
-            # dV_j += sum_i c*(xv_i - val*V_j), c = d_i*val: the xv and
-            # d factors ride ONE row gather from the [mb, dim+1] row
-            # layout (padding entries carry val = 0 and vanish); the
-            # kernel only re-derives tile V rows and scatters.
-            xvd = jnp.concatenate([xv, d[:, None]], axis=1).astype(wire)
-            G = jnp.take(xvd, vseg, axis=0)
-            c = G[:, cfg.dim].astype(jnp.float32) * vval
-            # kernel operands at the wire dtype: the contrib matmul
-            # runs at the kernel dtype anyway, so f32 a/b would only
-            # double the HBM traffic into the scatter kernel
-            a = (c[:, None] * G[:, :cfg.dim].astype(jnp.float32)
-                 ).astype(wire)
-            b = (c * vval).astype(wire)
-            gV = ck.fm_push_contrib(Vc, a, b, vidx, vtmap, vfirst,
-                                    dtype=dt)
+            # V: dV_r = sum over the admitted nonzeros of row r of
+            # c*(xv_i - val*V_r), c = d_i*val. The push sums by KEY (the
+            # xv and d factors ride one row gather from the [rows, S+1]
+            # row layout, at the wire dtype; the kernel forms the
+            # products and scatters them): a key's sum is then masked by
+            # the key's admission, one multiply a key where a lookup a
+            # nonzero cost 42 ms a step (PERF.md), and the keys of a row
+            # are added up (a row has one key but for collisions).
+            gK, nnzK = ck.fm_push_contrib(Vk, xv, d, vseg, vval, vidx,
+                                          vtmap, vfirst, dtype=dt,
+                                          wire=wire)
+            by_row = jnp.zeros((uvr_cap + 1, S + 1), jnp.float32).at[
+                key_vslot].add(jnp.concatenate(
+                    [gK, (nnzK > 0)[:, None]], axis=1)
+                    * adm_key[:, None])[:uvr_cap]
+            gV, touched = by_row[:, :S], by_row[:, S]
             if cfg.grad_normalization:
                 gV = gV / jnp.maximum(jnp.sum(mask), 1.0)
             if cfg.grad_clipping > 0:
@@ -625,10 +687,16 @@ class DifactoLearner:
                                             gV.shape)
                 gV = gV * keep
             gV = quantize_push(gV, cfg.fixed_bytes)
-            Vn, nVn = v_scatter_update(
-                vstate["V"], vstate["nV"], gV, vtouched, uniq_v,
-                vtm, vfi, vla, dim=cfg.dim, V_lr_eta=cfg.V_lr_eta,
-                V_lr_beta=cfg.V_lr_beta, lambda_V=cfg.lambda_V, dtype=dt)
+            # AdaGrad at the rows' storage, by line: a touched row is
+            # updated over its whole window (its spare lanes hold zero
+            # gradient and zero V, and stay zero)
+            Vn, nVn = v_update(
+                vstate["V"], vstate["nV"], Vl,
+                gV.reshape(ul_cap, ck.LANES),
+                jnp.broadcast_to(touched[:, None], (uvr_cap, S)
+                                 ).reshape(ul_cap, ck.LANES),
+                vlines, V_lr_eta=cfg.V_lr_eta, V_lr_beta=cfg.V_lr_beta,
+                lambda_V=cfg.lambda_V)
             new_vstate = dict(vstate)
             new_vstate["V"] = Vn
             new_vstate["nV"] = nVn
@@ -636,61 +704,40 @@ class DifactoLearner:
             prog = linmod._progress(obj, margin, label, mask, new_w)
             obj_w, _ = linmod._loss_dual(cfg.loss, label, xw)
             prog["objv_w"] = jnp.sum(obj_w * mask)
-            return new_state, new_vstate, prog
+            # for the step's two counters: fetched with the progress
+            nnz = {"live": jnp.sum(nnzK),
+                   "admitted": jnp.sum(nnzK * adm_key)}
+            return new_state, new_vstate, prog, nnz
 
         @jax.jit
-        def fwd_fm(state, vstate, uniq_w, wtm, uniq_v, vtm,
-                   rm_slot, rm_wval, rm_vval, vslot_w, label, mask):
+        def fwd_fm(state, vstate, uniq_w, wtm, vlines, key_slot,
+                   key_vslot, rm_key, rm_wval, label, mask):
             # eval/predict never scatter: only the compact gathers and
             # the rm channels ride along (the COO streams are a train-
             # only cost — _pack_fm skips packing them when train=False)
-            wc, Vc = gather_compact(state, vstate, uniq_w, wtm,
-                                    uniq_v, vtm)
-            margin = forward_rm(wc, Vc, rm_slot, rm_wval, rm_vval,
-                                vslot_w)[2]
+            wc, cc, Vl = pull(state, vstate, uniq_w, wtm, vlines)
+            margin = forward_rm(wc, cc, Vl, key_slot, key_vslot, rm_key,
+                                rm_wval)[2]
             obj, _ = linmod._loss_dual(cfg.loss, label, margin)
             return margin, linmod._progress(obj, margin, label, mask)
 
         self._fm_steps = (train_fm, fwd_fm)
 
     def prepare_batch(self, blk: RowBlock, train: bool = True):
-        """Host-side batch prep for the solver's loader threads."""
+        """Host-side batch prep for the solver's loader threads: pad to
+        the fixed device shape and, on the compact path, pack. Returns
+        ("xla", db, size) or ("fm", packed host arrays, label, mask,
+        size, train); stage_batch moves either to the device."""
         cfg = self.cfg
-        db = to_device_batch(blk, cfg.minibatch, cfg.row_capacity,
-                             cfg.num_buckets)
+        fm = self._use_fm_pallas
+        db = to_device_batch(blk, self._rows if fm else cfg.minibatch,
+                             cfg.row_capacity, cfg.num_buckets)
         if db.dropped_rows:
             self._dropped_rows += db.dropped_rows
-        if not self._use_fm_pallas:
+        if not fm:
             return ("xla", db, blk.size)
-        pk = self._pack_fm(db, train)
-        args = tuple(jax.device_put(a) for a in
-                     self._fm_args(pk, db.label, db.row_mask, train))
-        ids = None
-        if train and self.track_touched:
-            # host-side touched rows for the sparse PS wire, extracted
-            # before the pack moves to device (sentinel slots filtered)
-            ts_w, ts_v = pk[0], pk[3]
-            ids = (ts_w.uniq[ts_w.uniq < cfg.num_buckets].astype(np.int64),
-                   ts_v.uniq[ts_v.uniq < cfg.vb].astype(np.int64))
-        return ("fm", args, blk.size, train, ids)
-
-    def _fm_args(self, pk, label, mask, train: bool):
-        (ts_w, wcnts, wcoo, ts_v, vtouched, vcoo,
-         rm_slot, rm_wval, rm_vval, vslot_w) = pk
-        j = jnp.asarray
-        rm_parts = [j(rm_slot), j(rm_wval), j(rm_vval), j(vslot_w)]
-        if train:
-            wparts = [j(wcoo.idx), j(wcoo.seg), j(wcoo.val),
-                      j(wcoo.tmap), j(wcoo.first)]
-            vparts = [j(vcoo.idx), j(vcoo.seg), j(vcoo.val),
-                      j(vcoo.tmap), j(vcoo.first)] + rm_parts
-            return ([j(ts_w.uniq), j(ts_w.tmap_u), j(ts_w.first_u),
-                     j(ts_w.last_u), j(wcnts)] + wparts
-                    + [j(ts_v.uniq), j(ts_v.tmap_u), j(ts_v.first_u),
-                       j(ts_v.last_u), j(vtouched)] + vparts
-                    + [j(label), j(mask)])
-        return ([j(ts_w.uniq), j(ts_w.tmap_u), j(ts_v.uniq),
-                 j(ts_v.tmap_u)] + rm_parts + [j(label), j(mask)])
+        return ("fm", self._pack_fm(db, train), db.label, db.row_mask,
+                blk.size, train)
 
     # -- global-mesh SPMD protocol (apps/_runner._global_train) ------------
     def global_step_protocol(self):
@@ -743,41 +790,51 @@ class DifactoLearner:
 
     # -- epoch pack cache ----------------------------------------------------
     #: bump when prepare_batch's output layout changes for identical input
-    _PACK_VERSION = 1
+    _PACK_VERSION = 2
 
     def pack_cache_token(self, train: bool = True):
-        """See LinearLearner.pack_cache_token. The compact FM train pack
-        is NOT bit-identically replayable: admission depends on the
-        evolving count mirror AND packing mutates it (_pack_fm), so a
-        replayed pack would both be stale and skip the count push —
-        decline with None. Eval packs are pure given a mirror snapshot,
-        keyed by the pack-epoch counter that advances on every mirror
-        resync. The XLA fallback path packs with no host state at all
-        and caches for both."""
+        """See LinearLearner.pack_cache_token. Both paths pack with no
+        state but the compact path's capacities, which the first batch
+        packed fixes: until then the pack is not yet a function of the
+        key, and the first cold part goes uncached. Admission is the
+        step's, so a train pack replays like an eval pack."""
         cfg = self.cfg
         base = ("difacto", self._PACK_VERSION, self._use_fm_pallas,
                 cfg.minibatch, cfg.nnz_per_row, cfg.num_buckets, cfg.vb,
-                cfg.dim, cfg.threshold, cfg.l1_shrk)
+                cfg.dim)
         if not self._use_fm_pallas:
             return base
-        if train:
-            return None
         if self._fm_caps is None:
-            return None  # slot caps not yet sized from a first batch
-        return base + (self._fm_caps, self._pack_epoch,
-                       ck.TILE, ck.BLK_U, ck.TILE_HI, ck.FM_BLK,
-                       ck.LANES)
+            return None
+        return base + (self._fm_caps, self._stride, ck.TILE, ck.BLK,
+                       ck.BLK_U, ck.TILE_HI, ck.FM_BLK, ck.LANES)
 
     # -- double-buffered device feed -----------------------------------------
     def stage_batch(self, b, train: bool = True):
-        """Loader-side device placement. The compact FM pack already
-        device_puts its args in prepare_batch; only the XLA fallback
-        still carries host arrays, so stage those here."""
+        """Loader-side device placement of a prepared batch (a RowBlock
+        is prepared first; a staged batch comes back as it is). Returns
+        ("fm_staged" | "xla_staged", device args, size, train, ids)."""
         b = self._prepared(b, train)
-        if b[0] != "xla":
+        if b[0] in ("fm_staged", "xla_staged"):
             return b
-        db, size = b[1], b[2]
         ids = None
+        if b[0] == "fm":
+            _, pk, label, mask, size, train = b
+            with self._fm_lock:
+                if self._fm_caps is None:
+                    # a pack this learner did not make (the pack cache's
+                    # disk tier): the capacities are the lengths of its
+                    # uniq_w, key_slot and vlines (_FM_EVAL's order)
+                    self._fm_caps = (len(pk[0]), len(pk[-4]), len(pk[-5]))
+                    self._build_fm(*self._fm_caps)
+            if train and self.track_touched:
+                # touched rows for the sparse PS wire, from the host
+                # arrays while they are at hand (sentinel slots filtered)
+                ids_w = pk[0][pk[0] < self.cfg.num_buckets].astype(np.int64)
+                ids = (ids_w, np.unique(ids_w % self.cfg.vb))
+            args = tuple(jax.device_put(a) for a in (*pk, label, mask))
+            return ("fm_staged", args, size, train, ids)
+        db, size = b[1], b[2]
         if train and self.track_touched:
             ids_w = np.unique(db.idx[db.val != 0]).astype(np.int64)
             ids = (ids_w, ids_w % self.cfg.vb)
@@ -794,30 +851,54 @@ class DifactoLearner:
         return (put(db.seg), put(db.idx), put(vidx), put(db.val),
                 put(db.label), put(db.row_mask))
 
+    # -- what a harness asks of the learner (benchmark/check.py) -------------
+    def tables(self) -> Mapping:
+        """Every table by name, each readable by row: (num_buckets,) or
+        (v_buckets, dim), however it is stored."""
+        return _Tables(self.ckpt_store)
+
+    @staticmethod
+    def batch_kind(b) -> str:
+        """What step a batch takes: "fm" (the compact path, prepared or
+        staged), "xla" or, once staged, "xla_staged"."""
+        return "fm" if b[0] == "fm_staged" else b[0]
+
+    def batch_label(self, b) -> np.ndarray:
+        """A prepared or staged batch's labels on the host: the
+        minibatch's rows, without the rows the compact path pads on."""
+        label = b[1].label if b[0] == "xla" else (
+            b[2] if b[0] == "fm" else b[1][-2])
+        return np.asarray(label)[:self.cfg.minibatch]
+
     def train_batch(self, blk) -> dict:
-        b = self._prepared(blk, train=True)
-        self._rng, sub = jax.random.split(self._rng)
-        if b[0] == "fm":
-            args = b[1]
-            self.store.state, self.vstore.state, prog = self._fm_steps[0](
-                self.store.state, self.vstore.state, *args, sub)
+        # two spans, as LinearLearner.train_batch has them: a device
+        # profile then tells a late dispatch from a late return out of
+        # the blocking fetch
+        with _trace.span("step.dispatch", cat="step") as sp:
+            kind, args, _, st_train, ids = self.stage_batch(blk, True)
+            assert st_train, "batch was staged for eval, not train"
+            self._rng, sub = jax.random.split(self._rng)
+            nnz = None
+            if kind == "fm_staged":
+                (self.store.state, self.vstore.state, prog,
+                 nnz) = self._fm_steps[0](
+                    self.store.state, self.vstore.state, *args, sub)
+            else:
+                self.store.state, self.vstore.state, prog = \
+                    self._train_step(self.store.state, self.vstore.state,
+                                     *args, sub)
             if self.track_touched:
-                self._note_touched(b[4])
-        elif b[0] == "xla_staged":
-            self.store.state, self.vstore.state, prog = self._train_step(
-                self.store.state, self.vstore.state, *b[1], sub)
-            if self.track_touched:
-                self._note_touched(b[4])
-        else:
-            db = b[1]
-            self.store.state, self.vstore.state, prog = self._train_step(
-                self.store.state, self.vstore.state,
-                *self._xla_args(db), sub)
-            if self.track_touched:
-                ids_w = np.unique(db.idx[db.val != 0]).astype(np.int64)
-                self._note_touched((ids_w, ids_w % self.cfg.vb))
-        self._step_count += 1
-        return jax.tree_util.tree_map(float, prog)
+                self._note_touched(ids)
+            self._step_count += 1
+            sp.set(kind=kind)
+        with _trace.span("step.fetch", cat="step"):
+            # one host round trip per scalar: blocks until the device
+            # has finished the step
+            out = jax.tree_util.tree_map(float, prog)
+            if nnz is not None:
+                _STEP_LIVE.inc(int(nnz["live"]))
+                _STEP_ADMITTED.inc(int(nnz["admitted"]))
+            return out
 
     # -- sparse PS wire hints ------------------------------------------------
     def _note_touched(self, ids) -> None:
@@ -846,30 +927,11 @@ class DifactoLearner:
         out.update({k: uv for k in self.vstore.state})
         return out
 
-    def _on_sparse_pull(self, updates) -> None:
-        """Keep the host count mirror coherent with sparse PS pulls (the
-        dense path refreshes it via on_load/from_numpy)."""
-        got = updates.get("cnt")
-        if got is None:
-            return
-        idx, rows = got
-        with self._fm_lock:
-            self._cnt_host[idx] = rows
-
     def _fwd_any(self, blk):
-        b = self._prepared(blk, train=False)
-        if b[0] == "fm":
-            args, size = b[1], b[2]
-            margin, prog = self._fm_steps[1](
-                self.store.state, self.vstore.state, *args)
-        elif b[0] == "xla_staged":
-            size = b[2]
-            margin, prog = self._fwd(self.store.state, self.vstore.state,
-                                     *b[1])
-        else:
-            size = b[2]
-            margin, prog = self._fwd(self.store.state, self.vstore.state,
-                                     *self._xla_args(b[1]))
+        kind, args, size, st_train, _ = self.stage_batch(blk, False)
+        assert not st_train, "batch was staged for train, not eval"
+        fwd = self._fm_steps[1] if kind == "fm_staged" else self._fwd
+        margin, prog = fwd(self.store.state, self.vstore.state, *args)
         return margin, prog, size
 
     def eval_batch(self, blk) -> dict:

@@ -159,6 +159,11 @@ class LinearConfig:
     # of successive minibatches. 4 keeps a ~17 ms device step fed when a
     # 64k-row pack costs ~100 ms of host work.
     max_concurrency: int = 4
+    # staged minibatches that may wait in the solver's queue beside the
+    # one each loader thread holds. A staged batch lies in device memory:
+    # a job whose batches are large (a compact FM batch of 100,000 x 39
+    # is 0.19 GB) lowers this to keep them out of the tables' way
+    max_queued: int = 8
     # multi-process dispatch: online (greedy, straggler-reassigning) or
     # batch (stable n/num_workers assignment per pass); local_data asks
     # each worker to match train_data against ITS filesystem and report,
@@ -715,6 +720,23 @@ class LinearLearner:
             # shard: on the solver's loader.h2d span round this call
             _trace.annotate(bytes=sum(a.nbytes for a in args))
         return ("staged", kind, args, size, ids, train)
+
+    # -- what a harness asks of the learner (benchmark/check.py) -------------
+    def tables(self) -> dict:
+        """Every table by name, each readable by row."""
+        return self.store.state
+
+    @staticmethod
+    def batch_kind(b) -> str:
+        """A prepared or staged batch's kind: a key of `_kinds`."""
+        return b[1] if b[0] == "staged" else b[0]
+
+    @staticmethod
+    def batch_label(b) -> np.ndarray:
+        """A prepared or staged batch's labels, on the host."""
+        if b[0] == "staged":
+            return np.asarray(b[2][-2])
+        return np.asarray(b[1].label if b[0] == "xla" else b[-3])
 
     # -- sparse PS wire hints ------------------------------------------------
     def collect_touched(self):

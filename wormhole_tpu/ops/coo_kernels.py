@@ -763,33 +763,42 @@ def tile_gather(table2, uniq, tmap_u, dtype=None):
 # shape on v5e.
 
 
-def _fm_push_contrib_kernel(tmap_ref, first_ref, V_ref, ab_ref,
+def _fm_push_contrib_kernel(tmap_ref, first_ref, V_ref, g_ref, vv_ref,
                             idx_ref, out_ref, acc_ref, *, dim: int,
                             dtype):
-    # The row-major FM path's scatter: per-nnz contributions arrive
-    # PRECOMPUTED (a = c*xv[seg], b = c*val with c = d[seg]*val — both
-    # built by cheap XLA row gathers from the [rows, dim] xv, since the
-    # forward keeps xv in row layout). The per-nnz V-row term needs NO
-    # in-kernel fetch at all: with e the (BLK, TILE_HI) one-hot of the
-    # slot ids,
+    # The row-major FM path's scatter. Each nonzero arrives with the row
+    # it belongs to already looked up (g = [xv | d | 0] of its row: one
+    # cheap XLA row gather, since the forward keeps xv in row layout)
+    # and its admitted value vv; the kernel forms its contributions
+    #   c = d * vv,  a = c * xv,  b = c * vv,  t = (vv != 0)
+    # on the VPU, so that none of them is ever written to HBM. The
+    # per-nnz V-row term needs NO in-kernel fetch at all: with e the
+    # (BLK, TILE_HI) one-hot of the slot ids,
     #   eᵀ @ (b ⊙ (e @ V_tile)) = (eᵀ @ diag(b) @ e) @ V_tile
     #                            = diag(eᵀ b) @ V_tile
     # because eᵀ diag(b) e is diagonal (each nnz hits one slot). So the
-    # kernel scatters [a | b] with ONE eᵀ matmul and applies the b-sums
-    # as a per-row scale of the tile it already streams:
-    #   dV_tile += eᵀ @ [a|b][:, :dim] - (eᵀ @ [a|b][:, dim]) ⊙ V_tile
-    # — halving the one-hot build (the former fetch-side e) and dropping
-    # the (BLK, TILE_HI) x (TILE_HI, dim) vrows matmul entirely.
+    # kernel scatters [a | b | t] with ONE eᵀ matmul and applies the
+    # b-sums as a per-row scale of the tile it already streams:
+    #   dV_tile += eᵀ @ a - (eᵀ @ b) ⊙ V_tile
+    # and the t-sums, a row's count of admitted nonzeros, ride out in
+    # the column beside it.
     blk = pl.program_id(0)
 
     @pl.when(first_ref[blk] == 1)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
+    g = g_ref[:].astype(jnp.float32)                # (BLK, dim + 2)
+    vv = vv_ref[:][:, None]                         # (BLK, 1)
+    c = g[:, dim:dim + 1] * vv
+    lane = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
+    abt = jnp.where(lane < dim, c * g,
+                    jnp.where(lane == dim, c * vv,
+                              (vv != 0).astype(jnp.float32)))
     local = idx_ref[:] - tmap_ref[blk] * TILE_HI
     e_t = _onehot_t(local, TILE_HI, dtype)
     acc_ref[:] += jax.lax.dot_general(
-        e_t, ab_ref[:].astype(dtype),
+        e_t, abt.astype(dtype),
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
         precision=_prec(dtype),
@@ -804,42 +813,53 @@ def _fm_push_contrib_kernel(tmap_ref, first_ref, V_ref, ab_ref,
     @pl.when(is_last == 1)
     def _():
         acc = acc_ref[:]
-        out_ref[:] = acc[:, :dim] - acc[:, dim:dim + 1] * V_ref[:]
+        out_ref[:, :dim] = acc[:, :dim] - acc[:, dim:dim + 1] * V_ref[:]
+        out_ref[:, dim:] = acc[:, dim + 1:]
 
 
-def fm_push_contrib(V, a, b, sidx, tmap, first, dtype=None):
-    """FM embedding gradient from precomputed per-nnz contributions
-    (row-major FM path): dV[j] += sum_nnz (a_nnz - b_nnz * V[j]) over the
-    slot-sorted COO. a: [P, dim] = c*xv[seg]; b: [P] = c*val (c =
-    d[seg]*val; padding entries carry val = 0, so they vanish)."""
+def fm_push_contrib(V, xv, d, seg, vv, sidx, tmap, first, dtype=None,
+                    wire=jnp.float32):
+    """FM embedding gradient over the slot-sorted COO (row-major FM
+    path): dV[j] = sum over the nonzeros of slot j of c * (xv[seg] - vv *
+    V[j]), c = d[seg] * vv. xv: [rows, dim] and d: [rows] by batch row,
+    looked up a nonzero at a time at the dtype `wire`; seg, vv, sidx:
+    [P] the nonzero's batch row, admitted value (0 where it is padding
+    or not admitted) and slot. Returns ([slots, dim] dV, [slots] each
+    slot's count of nonzeros with vv != 0)."""
     if dtype is None:
         dtype = jnp.bfloat16 if not _use_interpret() else jnp.float32
     rows, dim = V.shape
     assert rows % TILE_HI == 0
     nblk = tmap.shape[0]
     blk = sidx.shape[0] // nblk
-    ab = jnp.concatenate([a, b[:, None]], axis=1)    # [P, dim+1]
+    # the nonzero's row of [xv | d | 0]: the kernel overwrites the two
+    # last columns with b and t, so the operand has its final shape
+    g = jnp.take(jnp.concatenate(
+        [xv, d[:, None], jnp.zeros_like(d)[:, None]], axis=1).astype(wire),
+        seg, axis=0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((TILE_HI, dim), lambda b_, tmap, first: (tmap[b_], 0)),
-            pl.BlockSpec((blk, dim + 1), lambda b_, *_: (b_, 0)),
+            pl.BlockSpec((blk, dim + 2), lambda b_, *_: (b_, 0)),
+            pl.BlockSpec((blk,), lambda b_, *_: (b_,)),
             pl.BlockSpec((blk,), lambda b_, *_: (b_,)),
         ],
-        out_specs=pl.BlockSpec((TILE_HI, dim),
+        out_specs=pl.BlockSpec((TILE_HI, dim + 1),
                                lambda b_, tmap, first: (tmap[b_], 0)),
-        scratch_shapes=[pltpu.VMEM((TILE_HI, dim + 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((TILE_HI, dim + 2), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         partial(_fm_push_contrib_kernel, dim=dim, dtype=dtype),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, dim), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((rows, dim + 1), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_FM_VMEM_LIMIT),
         interpret=_use_interpret(),
         name="fm_push_contrib",
-    )(tmap, first, V, ab, sidx)
+    )(tmap, first, V, g, vv, sidx)
+    return out[:, :dim], out[:, dim]
 
 
 # ---------------------------------------------------------- mesh sharding

@@ -48,8 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 from wormhole_tpu.ops.coo_kernels import (_VMEM_LIMIT, BLK_U, LANES,
                                           TILE, TILE_HI, _live_chunks,
                                           _onehot, _onehot_t, _prec,
-                                          _row_fetch, _use_interpret,
-                                          block_extents)
+                                          _use_interpret, block_extents)
 from wormhole_tpu.ops.penalty import l1l2_solve
 
 
@@ -184,166 +183,47 @@ def _kernel(tmap_ref, first_ref, last_ref, ext_ref, qscale_ref, g_ref,
         nw_ref[:] += delta
 
 
-# ---------------------------------------------- embedding-row variants
-# The difacto V table is [rows, dim] (dim 1..128, a power-of-two lane
-# divisor). Viewed flat, a row occupies dim consecutive lanes and never
-# straddles a (TILE_HI, 128) tile, so the same touched-tile streaming
-# works with a per-row dim-wide lane window instead of a single lane.
+# ------------------------------------------------ vector rows, by lane line
+# The difacto V table holds rows of dim floats, lane-packed at a stride
+# that divides 128 (parallel/kvstore.TableSpec.stride): 128 // stride rows
+# to a (128,) lane line, a row never straddling two. A batch touches a
+# few hundred thousand rows spread evenly over millions, so a walk over
+# touched 64k-entry tiles would stream the whole table (V and nV in and
+# out: 4 x the table's bytes a step). The rows are fetched and written
+# back by line instead: one XLA row gather / scatter of whole lines at
+# the batch's sorted distinct lines, 512 B each, which costs by the
+# batch and not by the table (PERF.md section 4 has both reckonings).
 
 
-def _row_window(off, dim: int, dtype):
-    """(BLK_U, 128) mask of each row's dim-wide lane window at offset
-    off (off is a multiple of dim for real rows)."""
-    shift = dim.bit_length() - 1
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (off.shape[0], LANES), 1)
-    return ((lanes >> shift) == (off[:, None] >> shift)).astype(dtype)
+def row_gather(lines2, vlines):
+    """The (len(vlines), 128) lane lines of a lane-packed table at the
+    batch's distinct lines: sorted, distinct, padded past the table's
+    end, where a line reads zero."""
+    with jax.named_scope("row_gather"):
+        return jnp.take(lines2, vlines, axis=0, mode="fill", fill_value=0,
+                        unique_indices=True, indices_are_sorted=True)
 
 
-def _row_gather_kernel(tmap_ref, V_ref, uniq_ref, out_ref, *, dim, dtype):
-    b = pl.program_id(0)
-    lf = uniq_ref[:] * dim - tmap_ref[b] * TILE    # flat offset in tile
-    hi = lf >> 7
-    off = lf & (LANES - 1)
-    # sentinel rows produce hi outside [0, TILE_HI): all-zero one-hot
-    groups = _row_fetch(V_ref[:], hi, dtype)       # (BLK_U, 128)
-    cols = [jnp.sum(groups * _onehot(off + j, LANES, dtype),
-                    axis=1, keepdims=True) for j in range(dim)]
-    out_ref[:] = jnp.concatenate(cols, axis=1)
-
-
-def row_tile_gather(flat2, uniq_rows, tmap_u, dim: int, dtype=None):
-    """Gather [row, dim] entries at tile-aligned compact row slots from a
-    flat row-major table viewed (rows*dim//128, 128). Returns
-    (u_cap, dim) f32 (zeros at sentinel holes)."""
-    if dtype is None:
-        dtype = jnp.bfloat16 if not _use_interpret() else jnp.float32
-    assert LANES % dim == 0 and dim & (dim - 1) == 0, \
-        "dim must be a power of two dividing 128"
-    nb = tmap_u.shape[0]
-    u_cap = nb * BLK_U
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((TILE_HI, LANES), lambda b, tmap: (tmap[b], 0)),
-            pl.BlockSpec((BLK_U,), lambda b, *_: (b,)),
-        ],
-        out_specs=pl.BlockSpec((BLK_U, dim), lambda b, *_: (b, 0)),
-    )
-    return pl.pallas_call(
-        partial(_row_gather_kernel, dim=dim, dtype=dtype),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((u_cap, dim), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=_use_interpret(),
-        name="row_gather",
-    )(tmap_u, flat2, uniq_rows)
-
-
-def _v_update_kernel(tmap_ref, first_ref, last_ref, gV_ref, tch_ref,
-                     uniq_ref, V_ref, nV_ref, V_out, nV_out, gacc, tacc,
-                     *, dim, dtype, V_lr_eta, V_lr_beta, lambda_V):
-    b = pl.program_id(0)
-
-    @pl.when(first_ref[b] == 1)
-    def _():
-        gacc[:] = jnp.zeros_like(gacc)
-        tacc[:] = jnp.zeros_like(tacc)
-        V_out[:] = V_ref[:]
-        nV_out[:] = nV_ref[:]
-
-    lf = uniq_ref[:] * dim - tmap_ref[b] * TILE
-    hi = lf >> 7
-    off = lf & (LANES - 1)
-    e_t = _onehot_t(hi, TILE_HI, dtype)
-    # rhs: each compact row's dim gradient values at its lane window;
-    # touched flags broadcast across the whole window (the reference
-    # updates the entire [w,V] entry when a row is pushed). The lane
-    # offset takes only LANES/dim distinct values (off = dim * residue),
-    # and a row's target lane for channel j is exactly column
-    # residue*dim + j — so concatenating the residue-masked gradients
-    # IS the scatter image: no per-channel one-hot builds at all (the
-    # former dim-iteration loop was this kernel's VPU wall).
-    nres = LANES // dim
-    res = off // dim
-    masks = [(res == r).astype(jnp.float32)[:, None] for r in range(nres)]
-    rhs = jnp.concatenate([gV_ref[:] * m for m in masks], axis=1)
-    win = _row_window(off, dim, jnp.float32)
-    gacc[:] += jax.lax.dot_general(
-        e_t, rhs.astype(dtype),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=_prec(dtype),
-    )
-    tacc[:] += jax.lax.dot_general(
-        e_t, (tch_ref[:][:, None] * win).astype(dtype),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=_prec(dtype),
-    )
-
-    @pl.when(last_ref[b] == 1)
-    def _():
-        g = gacc[:]
-        tch = (tacc[:] > 0).astype(jnp.float32)
-        nV, V = nV_ref[:], V_ref[:]
-        nV2 = nV + tch * g * g
-        etaV = (V_lr_beta + jnp.sqrt(nV2)) / V_lr_eta
-        V2 = jnp.where(tch > 0, V - (g + lambda_V * V) / etaV, V)
-        V_out[:] = V2
-        nV_out[:] = nV2
-
-
-def v_scatter_update(Vflat, nVflat, gV, vtouched, uniq_rows, tmap_u,
-                     first_u, last_u, *, dim, V_lr_eta, V_lr_beta,
-                     lambda_V, dtype=None):
-    """AdaGrad update of the embedding table at the touched tiles, in
-    place (difacto AdaGradHandle V branch, async_sgd.h:289-296): the
-    compact [u_cap, dim] gradient is scattered into each touched tile of
-    the flat table and the tile rewritten through aliased buffers.
-    Returns (Vflat', nVflat')."""
-    if dtype is None:
-        dtype = jnp.bfloat16 if not _use_interpret() else jnp.float32
-    assert LANES % dim == 0 and dim & (dim - 1) == 0, \
-        "dim must be a power of two dividing 128"
-    nb = tmap_u.shape[0]
-    V2 = Vflat.reshape(-1, LANES)
-    nV2 = nVflat.reshape(-1, LANES)
-    n_rows2 = V2.shape[0]
-
-    def tile_map(b, tmap, first, last):
-        return (tmap[b], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((BLK_U, dim), lambda b, *_: (b, 0)),   # gV
-            pl.BlockSpec((BLK_U,), lambda b, *_: (b,)),         # touched
-            pl.BlockSpec((BLK_U,), lambda b, *_: (b,)),         # uniq rows
-            pl.BlockSpec((TILE_HI, LANES), tile_map),           # V
-            pl.BlockSpec((TILE_HI, LANES), tile_map),           # nV
-        ],
-        out_specs=[pl.BlockSpec((TILE_HI, LANES), tile_map),
-                   pl.BlockSpec((TILE_HI, LANES), tile_map)],
-        scratch_shapes=[pltpu.VMEM((TILE_HI, LANES), jnp.float32),
-                        pltpu.VMEM((TILE_HI, LANES), jnp.float32)],
-    )
-    aliases = {3 + 3: 0, 3 + 4: 1}  # V, nV in -> out
-    Vn, nVn = pl.pallas_call(
-        partial(_v_update_kernel, dim=dim, dtype=dtype,
-                V_lr_eta=V_lr_eta, V_lr_beta=V_lr_beta, lambda_V=lambda_V),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n_rows2, LANES), jnp.float32),
-                   jax.ShapeDtypeStruct((n_rows2, LANES), jnp.float32)],
-        input_output_aliases=aliases,
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=_use_interpret(),
-        name="v_update",
-    )(tmap_u, first_u, last_u, gV, vtouched, uniq_rows, V2, nV2)
-    return Vn.reshape(Vflat.shape), nVn.reshape(nVflat.shape)
+def v_update(V2, nV2, Vl, gl, tl, vlines, *, V_lr_eta, V_lr_beta,
+             lambda_V):
+    """AdaGrad with L2 on the embedding rows the batch touched (difacto
+    AdaGradHandle V branch, async_sgd.h:289-296), at their storage:
+    Vl are the batch's lines of V as row_gather read them, gl the
+    gradient and tl the touched flag (> 0 over a touched row's window)
+    in the same lines; nV's lines are read here, and both tables written
+    back by line (in place when the caller donates them). A lane past a
+    row's width holds zero in Vl, gl and nV, and stays zero. Returns
+    (V2', nV2')."""
+    with jax.named_scope("v_update"):
+        nVl = row_gather(nV2, vlines)
+        tch = tl > 0
+        nVn = jnp.where(tch, nVl + gl * gl, nVl)
+        eta = (V_lr_beta + jnp.sqrt(nVn)) / V_lr_eta
+        Vn = jnp.where(tch, Vl - (gl + lambda_V * Vl) / eta, Vl)
+        put = dict(mode="drop", unique_indices=True,
+                   indices_are_sorted=True)
+        return (V2.at[vlines].set(Vn, **put),
+                nV2.at[vlines].set(nVn, **put))
 
 
 def scatter_update(algo: str, state: dict, g, uniq, tmap_u, first_u,

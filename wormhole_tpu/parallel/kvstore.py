@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -41,6 +42,8 @@ _SCATTER_S = _obs.REGISTRY.histogram("kv.scatter_s")
 _GATHER_ROWS = _obs.REGISTRY.counter("kv.gather_rows")
 _SCATTER_ROWS = _obs.REGISTRY.counter("kv.scatter_rows")
 _JIT_MISSES = _obs.REGISTRY.counter("kv.jit_cache_misses")
+
+LANES = 128  # a lane line: the minor dimension of a lane-packed table
 
 
 @dataclasses.dataclass
@@ -64,6 +67,28 @@ class TableSpec:
     dtype: object = jnp.float32
     init: Optional[Callable] = None  # (key, shape, dtype) -> array; 0 if None
     wire_cap: str = ""  # "" (no floor) or "bf16"
+    # Lane-packed vector rows. 0: the array is (num_buckets, *tail). A
+    # stride s (a divisor of 128, >= tail[0]) stores the table as
+    # (num_buckets * s // 128, 128): row r lies at flat offset r * s,
+    # 128 // s rows to a lane line, its lanes [tail[0], s) zero. On the
+    # chip a (rows, 50) f32 array pads its minor dimension to 128 lanes
+    # (2.56x the bytes); the packed form is what the compact FM step
+    # gathers and scatters by line (ops/fused_update.py). Every
+    # host-facing method below still speaks rows of `tail`.
+    stride: int = 0
+
+
+def pack_rows(rows: np.ndarray, stride: int) -> np.ndarray:
+    """(n, dim) rows -> (n * stride // 128, 128) lane lines, the lanes
+    [dim, stride) of every row zero."""
+    return np.pad(rows, ((0, 0), (0, stride - rows.shape[1]))
+                  ).reshape(-1, LANES)
+
+
+def unpack_rows(lines, stride: int, dim: int):
+    """The inverse view: (n, dim) rows of a lane-packed table."""
+    return lines.reshape(-1, stride)[:, :dim]
+
 
 
 class KVStore:
@@ -86,10 +111,21 @@ class KVStore:
         key = jax.random.PRNGKey(seed)
         self.state: dict[str, jax.Array] = {}
         for name, spec in self.specs.items():
-            shape = (self.num_buckets, *spec.tail)
+            shape = self.stored_shape(name)
             sh = table_sharding(mesh, ndim=len(shape))
             key, sub = jax.random.split(key)
-            if spec.init is None:
+            if spec.stride and spec.init is not None:
+                # drawn in the stored shape (a (rows, dim) temporary would
+                # pad to 128 lanes on the chip), the stride's spare lanes
+                # zeroed: they stay zero under every update
+                init, live = spec.init, (
+                    np.arange(LANES) % spec.stride < spec.tail[0])
+                arr = jax.jit(
+                    lambda sub=sub, init=init, live=live: jnp.where(
+                        live, init(sub, shape, spec.dtype), 0),
+                    out_shardings=sh,
+                )()
+            elif spec.init is None:
                 arr = jax.jit(
                     lambda: jnp.zeros(shape, spec.dtype), out_shardings=sh
                 )()
@@ -106,15 +142,33 @@ class KVStore:
         # set countable: kv.jit_cache_misses stays flat once every
         # padded size in the touched-row distribution has been seen, so
         # the lab can show steady-state compilation is zero.
-        self._gather_fns: dict[int, Callable] = {}
+        self._gather_fns: dict[tuple, Callable] = {}
         self._multi_gather_fns: dict[tuple, Callable] = {}
         self._scatter_fns: dict[tuple, Callable] = {}
 
+    def stored_shape(self, name: str) -> tuple:
+        """The shape `state[name]` has: (num_buckets, *tail), or the
+        lane-packed lines of a table with a stride (TableSpec.stride)."""
+        spec = self.specs[name]
+        if not spec.stride:
+            return (self.num_buckets, *spec.tail)
+        assert len(spec.tail) == 1 and LANES % spec.stride == 0 \
+            and spec.tail[0] <= spec.stride, (name, spec.tail, spec.stride)
+        assert self.num_buckets * spec.stride % LANES == 0
+        return (self.num_buckets * spec.stride // LANES, LANES)
+
+    def rows_view(self, name: str):
+        """`state[name]` as (num_buckets, *tail) on the device: the array
+        itself, or a lane-packed table unpacked into a new one (lane-
+        padded on the chip: for reading rows back, not for a step)."""
+        spec = self.specs[name]
+        if not spec.stride:
+            return self.state[name]
+        return _unpack_jit(self.state[name], spec.stride, spec.tail[0])
+
     # -- helpers used inside learner-jitted steps ---------------------------
     def sharding(self, name: str):
-        return table_sharding(
-            self.mesh, ndim=1 + len(self.specs[name].tail)
-        )
+        return table_sharding(self.mesh, ndim=len(self.stored_shape(name)))
 
     def constrain(self, name: str, arr):
         """Pin an intermediate (e.g. a dense gradient in table layout) to
@@ -143,16 +197,16 @@ class KVStore:
         """Fetch rows `idx` of a table to host — a device gather plus an
         O(touched) transfer, never a full-table copy (the ZPush side of
         the sparse PS wire reads current values this way)."""
+        spec = self.specs[name]
         if idx.size == 0:
-            tail = self.state[name].shape[1:]
-            return np.empty((0, *tail), np.float32)
+            return np.empty((0, *spec.tail), np.float32)
         t0 = time.perf_counter()
         pad, n = self._pad_pow2(np.asarray(idx), 0)
-        m = pad.shape[0]
-        fn = self._gather_fns.get(m)
+        key = (pad.shape[0], spec.stride)
+        fn = self._gather_fns.get(key)
         if fn is None:
-            fn = jax.jit(lambda a, i: a[i])
-            self._gather_fns[m] = fn
+            fn = jax.jit(partial(_take_rows, spec=spec))
+            self._gather_fns[key] = fn
             _JIT_MISSES.inc()
         out = fn(self.state[name], jnp.asarray(pad))
         out = np.asarray(out[:n], dtype=np.float32)
@@ -167,7 +221,7 @@ class KVStore:
         jitted dispatch for the whole group instead of per-table
         round-trips — the sync-snapshot path's gather cost halves."""
         if idx.size == 0:
-            return {k: np.empty((0, *self.state[k].shape[1:]), np.float32)
+            return {k: np.empty((0, *self.specs[k].tail), np.float32)
                     for k in names}
         t0 = time.perf_counter()
         pad, n = self._pad_pow2(np.asarray(idx), 0)
@@ -175,7 +229,9 @@ class KVStore:
         key = (names_key, pad.shape[0])
         fn = self._multi_gather_fns.get(key)
         if fn is None:
-            fn = jax.jit(lambda st, i: {k: st[k][i] for k in names_key})
+            specs = self.specs
+            fn = jax.jit(lambda st, i: {
+                k: _take_rows(st[k], i, specs[k]) for k in names_key})
             self._multi_gather_fns[key] = fn
             _JIT_MISSES.inc()
         outs = fn({k: self.state[k] for k in names}, jnp.asarray(pad))
@@ -193,18 +249,19 @@ class KVStore:
         if idx.size == 0:
             return
         t0 = time.perf_counter()
-        pad, n = self._pad_pow2(np.asarray(idx), self.state[name].shape[0])
+        spec = self.specs[name]
+        pad, n = self._pad_pow2(np.asarray(idx), self.num_buckets)
         key = (name, pad.shape[0])
         fn = self._scatter_fns.get(key)
         if fn is None:
             sh = self.sharding(name)
             fn = jax.jit(
                 lambda a, i, v: jax.lax.with_sharding_constraint(
-                    a.at[i].set(v, mode="drop"), sh),
+                    _set_rows(a, i, v, spec.stride), sh),
                 donate_argnums=0)
             self._scatter_fns[key] = fn
             _JIT_MISSES.inc()
-        tail = self.state[name].shape[1:]
+        tail = spec.tail
         v = np.zeros((pad.shape[0], *tail), np.float32)
         v[:n] = vals
         self.state[name] = fn(self.state[name], jnp.asarray(pad),
@@ -231,16 +288,69 @@ class KVStore:
         return int(jnp.sum(self.state[name] != 0))
 
     def to_numpy(self) -> dict[str, np.ndarray]:
-        return {k: np.asarray(v) for k, v in self.state.items()}
+        """Every table on the host, as (num_buckets, *tail)."""
+        out = {}
+        for k, v in self.state.items():
+            spec = self.specs[k]
+            out[k] = (unpack_rows(np.asarray(v), spec.stride, spec.tail[0])
+                      if spec.stride else np.asarray(v))
+        return out
 
     def from_numpy(self, arrays: dict[str, np.ndarray]) -> None:
         for k, v in arrays.items():
             assert k in self.state, f"unknown table {k}"
-            assert tuple(v.shape) == tuple(self.state[k].shape), (
-                f"table {k}: loaded shape {v.shape} != {self.state[k].shape}"
+            spec = self.specs[k]
+            want = (self.num_buckets, *spec.tail)
+            assert tuple(v.shape) == want, (
+                f"table {k}: loaded shape {v.shape} != {want}"
             )
+            if spec.stride:
+                v = pack_rows(np.asarray(v), spec.stride)
             sh = self.sharding(k)
             self.state[k] = jax.device_put(jnp.asarray(v), sh)
+
+
+def _take_rows(a, i, spec: TableSpec):
+    """Rows i of a table as (len(i), *tail): of a lane-packed one the
+    rows' lines, then each row's window of its line."""
+    if not spec.stride:
+        return a[i]
+    rpl = LANES // spec.stride
+    lines = a[i // rpl].reshape(i.shape[0], rpl, spec.stride)
+    return lines[jnp.arange(i.shape[0]), i % rpl, :spec.tail[0]]
+
+
+def _set_rows(a, i, v, stride: int):
+    """a with rows i overwritten by v (out-of-range rows dropped)."""
+    if not stride:
+        return a.at[i].set(v, mode="drop")
+    rpl = LANES // stride
+    lane = (i % rpl * stride)[:, None] + jnp.arange(v.shape[1])
+    return a.at[(i // rpl)[:, None], lane].set(v, mode="drop")
+
+
+# lane lines unpacked at a time by rows_view: the (rows, dim) result is
+# lane-padded on the chip, and so would a whole (rows, stride) view of
+# the table on the way to it be
+_UNPACK_LINES = 1 << 16
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _unpack_jit(lines, stride: int, dim: int):
+    n = lines.shape[0]
+    if n <= _UNPACK_LINES or n % _UNPACK_LINES:
+        return unpack_rows(lines, stride, dim)
+    rows = _UNPACK_LINES * (LANES // stride)
+
+    def chunk(i, out):
+        part = jax.lax.dynamic_slice_in_dim(lines, i * _UNPACK_LINES,
+                                            _UNPACK_LINES)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, unpack_rows(part, stride, dim), i * rows, 0)
+
+    return jax.lax.fori_loop(
+        0, n // _UNPACK_LINES, chunk,
+        jnp.zeros((n * (LANES // stride), dim), lines.dtype))
 
 
 def quantize_push(grad, nbytes: int = 0):

@@ -199,7 +199,7 @@ class MinibatchSolver:
     where a straggling host's parts move to another host."""
 
     def __init__(self, learner, cfg, num_loaders: int | None = None,
-                 max_queued: int = 8, verbose: bool = True):
+                 max_queued: int | None = None, verbose: bool = True):
         src = "arg"
         pinned = num_loaders is not None
         if num_loaders is None:
@@ -217,7 +217,10 @@ class MinibatchSolver:
         self.learner = learner
         self.cfg = cfg
         self.num_loaders = num_loaders
-        self.max_queued = max_queued
+        # a queued batch is a staged one and holds device memory: the
+        # configuration says how many may wait (cfg.max_queued)
+        self.max_queued = (max_queued if max_queued is not None
+                           else getattr(cfg, "max_queued", 8))
         self.verbose = verbose
         self.t0 = time.time()
         # adaptive sizing defaults on, but a pinned count (explicit arg or
