@@ -121,8 +121,7 @@ def test_bf16_kernels_round_only_the_table_values():
     seg = np.repeat(np.arange(rows, dtype=np.int32), nnz)
     val = np.ones(rows * nnz, np.float32)
 
-    tc = ck.pack_tile_coo(idx, seg, val, nb, ck.TILE, rm_rows=rows,
-                          rm_width=nnz)
+    tc = ck.pack_tile_coo(idx, seg, val, nb, ck.TILE)
     wc = np.asarray(ck.tile_gather(
         jnp.asarray(w).reshape(-1, ck.LANES), jnp.asarray(tc.uniq),
         jnp.asarray(tc.tmap_u), dtype=jnp.bfloat16))
@@ -137,3 +136,13 @@ def test_bf16_kernels_round_only_the_table_values():
     ref = np.zeros(rows, np.float32)
     np.add.at(ref, seg, wr[idx])
     np.testing.assert_allclose(xw, ref, atol=1e-5)
+    # the compact step's pull (PR 32): the same kernel over the compact
+    # domain, whose values the fetch has rounded already; with binary
+    # features the product the kernel rounds before the row sum is that
+    # value, so nothing rounds twice
+    c = tc.coo
+    xwc = np.asarray(ck.coo_spmv(
+        jnp.asarray(wc), *(jnp.asarray(a) for a in
+                           (c.idx, c.seg, c.val, c.tmap, c.first)),
+        rows, dtype=jnp.bfloat16))
+    np.testing.assert_allclose(xwc, ref, atol=1e-5)

@@ -198,8 +198,9 @@ def test_chunks_run_counter_equals_the_devices_extents(kind, tmp_path):
 
     if kind == "tcoo":
         tc = b[1]
+        # tile_gather and the fused update; pull and push (one stream)
         want = [on_device(jnp.asarray(tc.uniq) != nb, ck.BLK_U, 2),
-                on_device(jnp.asarray(tc.coo.val) != 0, ck.BLK, 1)]
+                on_device(jnp.asarray(tc.coo.val) != 0, ck.BLK, 2)]
     else:
         want = [on_device(jnp.asarray(b[1].sval) != 0, ck.BLK, 2)]
     assert (chunks, run) == tuple(map(sum, zip(*want)))

@@ -319,16 +319,16 @@ def test_compacted_predict_and_eval(synth_file):
 # constant beside the kernels
 _KINDS = {
     "xla": (dict(kernel="xla"), (1, 1),
-            ("linear", 1, False, False, 0, 4096, 256, 8, 262144, 1, 1,
+            ("linear", 2, False, False, 0, 4096, 256, 8, 262144, 1, 1,
              65536, 4096, 1024, 128)),
     "coo": (dict(kernel="pallas", compact_cap=0), (1, 1),
-            ("linear", 1, True, False, 0, 4096, 256, 8, 262144, 1, 1,
+            ("linear", 2, True, False, 0, 4096, 256, 8, 262144, 1, 1,
              65536, 4096, 1024, 128)),
     "tcoo": (dict(kernel="pallas", compact_cap=1), (1, 1),
-             ("linear", 1, True, False, 65536, 4096, 256, 8, 262144, 1, 1,
+             ("linear", 2, True, False, 65536, 4096, 256, 8, 262144, 1, 1,
               65536, 4096, 1024, 128)),
     "mcoo": (dict(kernel="pallas", model_shards=2), (2, 2),
-             ("linear", 1, True, True, 0, 4096, 256, 8, 262144, 2, 2,
+             ("linear", 2, True, True, 0, 4096, 256, 8, 262144, 2, 2,
               65536, 4096, 1024, 128)),
 }
 
@@ -405,7 +405,7 @@ def test_batch_layouts_and_pack_cache_token_are_pinned(kind):
     lrn = _kind_learner(kind)
     lrn.track_touched = True
     blk = _kind_blocks(1)[0]
-    assert lrn._PACK_VERSION == 1
+    assert lrn._PACK_VERSION == 2
     if kind == "tcoo":      # undecided until the first batch sizes it
         assert lrn.pack_cache_token() is None
     b = lrn.prepare_batch(blk)
@@ -437,3 +437,144 @@ def test_batch_layouts_and_pack_cache_token_are_pinned(kind):
             if kind == "tcoo":      # the padding's bucket rides along
                 want = np.union1d(want, [0])
             np.testing.assert_array_equal(ids, want)
+
+
+# ------------------------- the compact step pulls over its own COO stream
+# case -> (live rows of a 256-row batch, entries a row, buckets,
+# compact_cap). `nnz_per_row` is 8, so "short" rows leave padding inside
+# the capacity, "long" rows go over 8 entries a row while the batch stays
+# within it (one stream holds them all: a row-major companion of width 8
+# would have cut them), and "overflow" touches every one of 128 table
+# tiles with room for 64 update blocks, so the pack drops the keys of the
+# upper tiles from the one stream pull and push both walk.
+_PULL_CASES = {
+    "full_rows": (256, (8, 8), 8, 1),
+    "short_rows": (256, (1, 7), 8, 1),
+    "long_rows": (128, (1, 15), 8, 1),
+    "padding_rows": (200, (8, 8), 8, 1),
+    "overflow": (256, (8, 8), 128, 1),
+}
+
+
+def _pull_blocks(case, n=4):
+    from wormhole_tpu.data.rowblock import RowBlock
+    from wormhole_tpu.ops import coo_kernels as ck
+
+    rows, (lo, hi), tiles, _ = _PULL_CASES[case]
+    rng = np.random.default_rng(32)
+    out = []
+    for _ in range(n):
+        per_row = rng.integers(lo, hi + 1, rows)
+        offset = np.concatenate([[0], np.cumsum(per_row)]).astype(np.int64)
+        nnz = int(offset[-1])
+        # a third of the entries on 40 hot keys, so that rows share keys
+        # and the weights of the later steps are not all zero
+        index = np.where(rng.random(nnz) < 0.33,
+                         rng.integers(0, 40, nnz) * 1009,
+                         rng.integers(0, tiles * ck.TILE, nnz))
+        out.append(RowBlock(
+            label=(rng.random(rows) < 0.4).astype(np.float32),
+            offset=offset, index=index.astype(np.uint64),
+            value=(0.25 + rng.random(nnz)).astype(np.float32)))
+    return out
+
+
+@pytest.fixture(scope="module", params=list(_PULL_CASES))
+def pull_pair(request):
+    """(case, tcoo learner, xla learner, their progress over three train
+    steps, the fourth batch as each is to read it), trained once a case
+    and dropped before the next. The xla learner gets the batches less
+    what the compact pack dropped."""
+    from wormhole_tpu.data.rowblock import RowBlock
+    from wormhole_tpu.ops import coo_kernels as ck
+
+    case = request.param
+    _, _, tiles, cap = _PULL_CASES[case]
+    nb = tiles * ck.TILE
+
+    def learner(**kw):
+        return LinearLearner(LinearConfig(
+            minibatch=256, num_buckets=nb, nnz_per_row=8, algo="ftrl",
+            lr_eta=0.5, lambda_l1=0.05, kernel_dtype="f32", **kw),
+            make_mesh(1, 1))
+
+    tc_l, x_l = learner(kernel="pallas", compact_cap=cap), learner(
+        kernel="xla")
+
+    def kept_only(blk, packed):
+        kept = packed.uniq[packed.uniq < nb]
+        live = np.isin(blk.index.astype(np.int64) % nb, kept)
+        per_row = np.add.reduceat(live.astype(np.int64), blk.offset[:-1])
+        return RowBlock(
+            label=blk.label, index=blk.index[live], value=blk.value[live],
+            offset=np.concatenate([[0], np.cumsum(per_row)]).astype(np.int64))
+
+    progs, fourth = [], None
+    for i, blk in enumerate(_pull_blocks(case)):
+        b = tc_l.prepare_batch(blk, train=i < 3)
+        assert b[0] == "tcoo"
+        dropped = b[1].dropped_nnz
+        assert (dropped > 0) == (case == "overflow"), (case, dropped)
+        xblk = kept_only(blk, b[1]) if dropped else blk
+        if i < 3:
+            progs.append((tc_l.train_batch(b), x_l.train_batch(xblk)))
+        else:
+            fourth = (blk, xblk)
+    return case, tc_l, x_l, progs, fourth
+
+
+def _same_progress(got, want):
+    assert got["nex"] == want["nex"] > 0
+    for k in ("objv", "logloss", "pclk", "acc", "auc", "clk"):
+        np.testing.assert_allclose(got[k], want[k], rtol=2e-5, atol=1e-5,
+                                   err_msg=k)
+
+
+@pytest.mark.parametrize("step", ["train", "eval", "predict"])
+def test_compact_step_pulls_what_the_xla_kind_pulls(pull_pair, step):
+    """The compact (`tcoo`) steps compute xw = X w with `tile_gather` and
+    `coo_pull` over the batch's own COO stream, the one the push walks:
+    margins, progress and the tables after three train steps equal the
+    XLA kind's on the same batches (kernel_dtype f32: equal to summation
+    order), whatever the rows hold, and where the compact domain
+    overflows both agree on which nonzeros exist."""
+    case, tc_l, x_l, progs, (blk, xblk) = pull_pair
+    live = _PULL_CASES[case][0]
+    if step == "train":
+        for got, want in progs:
+            assert got["nex"] == live
+            _same_progress(got, want)
+        # the later steps pulled trained weights, not zeros
+        assert progs[0][0]["pclk"] != progs[2][0]["pclk"]
+        got_t, want_t = tc_l.store.to_numpy(), x_l.store.to_numpy()
+        assert np.count_nonzero(want_t["w"]) > 100
+        for k in ("z", "n", "w"):
+            np.testing.assert_allclose(got_t[k], want_t[k], rtol=2e-5,
+                                       atol=1e-6, err_msg=k)
+    elif step == "eval":
+        _same_progress(tc_l.eval_batch(blk), x_l.eval_batch(xblk))
+    else:
+        got, want = tc_l.predict_batch(blk), x_l.predict_batch(xblk)
+        assert got.shape == want.shape == (live,)
+        assert np.count_nonzero(want) > live // 2
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=1e-6)
+
+
+def test_compact_batches_ship_one_stream_and_no_row_major_companion():
+    """Nothing of the linear learner reads a row-major companion any
+    more: the pack makes none, and every step's arguments are the compact
+    slots and the COO stream (train adds the update-block bounds)."""
+    lrn = _kind_learner("tcoo")
+    b = lrn.prepare_batch(_kind_blocks(1)[0])
+    tc = b[1]
+    assert tc.rm_slot is None and tc.rm_val is None
+    p = tc.coo
+    stream = (p.idx, p.seg, p.val, p.tmap, p.first)
+    for train, head in ((True, (tc.uniq, tc.tmap_u, tc.first_u, tc.last_u)),
+                        (False, (tc.uniq, tc.tmap_u))):
+        args = lrn.stage_batch(b, train)[2]
+        assert len(args) == len(head) + 5 + 2      # + label, mask
+        for got, want in zip(args, head + stream):
+            np.testing.assert_array_equal(np.asarray(got), want)
+    # predict takes the same arrays less label and mask
+    assert len(lrn._kinds["tcoo"].args(tc)) == 7

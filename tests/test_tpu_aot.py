@@ -106,6 +106,30 @@ def slot_blocks(u_cap, n):
     return [((u_cap // ck.BLK_U,), jnp.int32)] * n
 
 
+def hlo_lines(compiled):
+    """The compiled program's instructions, operands with their shapes,
+    as the profiler names an operation."""
+    from jax._src.lib import xla_client as xc
+
+    opts = xc._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    return [ln.strip() for ln in compiled.runtime_executable()
+            .hlo_modules()[0].to_string(opts).splitlines()]
+
+
+def metric_pattern(name):
+    """The pattern by which the benchmark's layer metric `name` finds
+    its device operations."""
+    import json
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "layer_metrics",
+        name + ".json")
+    with open(path) as fh:
+        return re.compile(json.load(fh)["params"]["pattern"])
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_coo_pull_push_dense(v5e, dtype):
     stream = coo_stream(CAP, NB_DENSE)
@@ -146,6 +170,10 @@ def test_compacted_linear_kernels_at_the_benchmarks_table(v5e, dtype):
     aot("coo_push",
         lambda d, *s: ck.coo_spmv_t(d, *s, U_CAP_1TB, dtype=dtype),
         v5e, ((ROWS,), f32), *coo_stream(CAP, U_CAP_1TB))
+    # the compact step's pull since PR 32: a (TILE,) block of the
+    # 12.6 M-slot compact domain a grid step
+    aot("coo_pull", lambda wc, *s: ck.coo_spmv(wc, *s, ROWS, dtype=dtype),
+        v5e, ((U_CAP_1TB,), f32), *coo_stream(CAP, U_CAP_1TB))
 
     def update(z, n, w, g, uniq, tm, fi, la):
         return fu.scatter_update(
@@ -257,11 +285,7 @@ def test_fm_step_carries_the_names_its_layer_metrics_match(v5e):
     benchmark's size), has to match every such pattern: a renamed
     argument or kernel would otherwise make a metric read nothing,
     silently."""
-    import json
-    import os
     import types
-
-    from jax._src.lib import xla_client as xc
 
     from wormhole_tpu.models import difacto as df
     from wormhole_tpu.parallel.mesh import make_mesh
@@ -287,20 +311,93 @@ def test_fm_step_carries_the_names_its_layer_metrics_match(v5e):
         jax.tree_util.tree_map(shaped, fm.vstore.state),
         *map(shaped, pack), mask, mask,
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e)).compile()
-    # operands with their shapes, as the profiler names an operation
-    opts = xc._xla.HloPrintOptions()
-    opts.print_operand_shape = True
-    lines = [ln.strip() for ln in step.runtime_executable().hlo_modules()[0]
-             .to_string(opts).splitlines()]
-    metrics = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "layer_metrics")
+    lines = hlo_lines(step)
     # how many operations of one step each metric has to find
     for name, want in (("row_gather_ms", 2), ("v_update_ms", 2),
                        ("fm_push_contrib_ms", 1), ("tile_gather_ms", 2),
                        ("fused_update_ms", 1), ("coo_push_ms", 1),
                        ("fm_kernel_ms_per_step", 5),
                        ("kernel_ms_per_step", 5)):
-        with open(os.path.join(metrics, name + ".json")) as fh:
-            rx = re.compile(json.load(fh)["params"]["pattern"])
-        hit = [ln for ln in lines if rx.search(ln)]
+        hit = [ln for ln in lines if metric_pattern(name).search(ln)]
         assert len(hit) == want, (name, hit)
+
+
+def test_tcoo_step_pulls_and_pushes_over_one_stream(v5e):
+    """The linear learner's own compact train step, lowered for the chip
+    at `criteo1tb.replay`'s sizes (65,536 x 39, 2^29 buckets, the
+    12,582,912-slot compact domain, bf16): one call of each of its four
+    kernels, under the names the benchmark's metrics match; the pull and
+    the push read one computation of their stream's block extents; and
+    no XLA gather reads the compact domain or anything as large (the
+    `jnp.take` over a (U + 1, 2) copy of it that the step pulled with
+    until PR 32 cost 15.5 ms of a 52 ms device step). The learner is
+    built over a two-tile table: the step takes the table's size from
+    its arguments alone."""
+    from wormhole_tpu.models.linear import LinearConfig, LinearLearner
+    from wormhole_tpu.parallel.mesh import make_mesh
+
+    cfg = LinearConfig(minibatch=ROWS, nnz_per_row=NNZ,
+                       num_buckets=2 * ck.TILE, algo="ftrl", lr_eta=0.1,
+                       lambda_l1=4.0, kernel="pallas", kernel_dtype="bf16")
+    lrn = LinearLearner(cfg, make_mesh(1, 1))
+    lrn._build_tcoo(U_CAP_1TB)
+
+    def shaped(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    f32 = jnp.float32
+    state = {k: shaped((NB_1TB,), f32) for k in lrn.store.state}
+    assert sorted(state) == ["n", "w", "z"]
+    stream = [shaped(s, d) for s, d in coo_stream(CAP, U_CAP_1TB)]
+    step = lrn._kinds["tcoo"].train.lower(
+        state, shaped((U_CAP_1TB,)),
+        *[shaped(s, d) for s, d in slot_blocks(U_CAP_1TB, 3)], *stream,
+        shaped((ROWS,), f32), shaped((ROWS,), f32)).compile()
+    lines = hlo_lines(step)
+
+    calls = {}
+    for ln in lines:
+        m = re.match(r"(?:ROOT )?%([\w.\-]+) = .*custom_call_target="
+                     r'"tpu_custom_call"', ln)
+        if m:
+            calls.setdefault(re.sub(r"(\.\d+)*$", "", m.group(1)),
+                             []).append(ln)
+    assert {k: len(v) for k, v in calls.items()} == {
+        "tile_gather": 1, "coo_pull": 1, "coo_push": 1, "fused_update": 1}
+
+    for name, kernel in (("tcoo_pull_ms", "coo_pull"),
+                         ("coo_push_ms", "coo_push"),
+                         ("tile_gather_ms", "tile_gather"),
+                         ("fused_update_ms", "fused_update")):
+        rx = metric_pattern(name)
+        assert [ln for ln in lines if rx.search(ln)] == calls[kernel], name
+    rx = metric_pattern("kernel_ms_per_step")
+    assert len([ln for ln in lines if rx.search(ln)]) == 4
+
+    # operands of a call, in order: (tmap, first, ext, ...) for the pair
+    def operands(ln):
+        return re.findall(r"%([\w.\-]+)", ln.split("custom-call(", 1)[1]
+                          .split("), custom_call_target", 1)[0])
+
+    pull, push = operands(calls["coo_pull"][0]), operands(calls["coo_push"][0])
+    nblk = stream[3].shape[0]
+    ext = [ln for ln in lines
+           if re.match(rf"(?:ROOT )?%{re.escape(pull[2])} = s32\[{nblk}\]",
+                       ln)]
+    assert len(ext) == 1 and pull[2] == push[2], (pull[:3], push[:3])
+    assert pull[:2] == push[:2]          # tmap, first: the one stream
+
+    def gathered_rows(ln):
+        """Rows of the operand a `gather` instruction reads from (fused
+        computations are printed too), or None for any other line."""
+        if " gather(" not in ln:
+            return None
+        return int(re.search(r"\w+\[(\d+)", ln.split(" gather(", 1)[1])
+                   .group(1))
+
+    # the step's pull until PR 32, as the chip's compiler printed it
+    assert gathered_rows(
+        "%gather.3 = f32[2555904,2]{1,0:T(8,128)} gather(f32[12582913,2]"
+        "{0,1:T(2,128)S(1)} %param_0.2, s32[2555904]{0:T(1024)} "
+        "%transpose.5), offset_dims={1}") == U_CAP_1TB + 1
+    assert all(r is None or r < U_CAP_1TB for r in map(gathered_rows, lines))

@@ -365,9 +365,10 @@ class LinearLearner:
             # NOTE r5: a row-major xw (XLA row gather from a widened w
             # table) was tried for coo and measured ~50 ns/row — the
             # dense table (num_buckets x 8 B, 32 MB at the headline
-            # shape) is too large for the fast-gather regime, unlike the
-            # compact paths (PERF.md "Row-gather regimes"). The
-            # radix-image kernel stays.
+            # shape) is too large for the fast-gather regime (PERF.md
+            # "Row-gather regimes"), and so, measured again in PR 32, is
+            # the compact domain at any table that engages it. The
+            # radix-image kernel pulls for every Pallas kind.
             "coo": _Kind(
                 self._pack_coo,
                 _device_args(
@@ -545,21 +546,23 @@ class LinearLearner:
         cfg, dt = self.cfg, self._coo_dtype
         from wormhole_tpu.ops.fused_update import scatter_update
 
-        def pull_c(w, uniq, tmap_u, rm_slot, rm_val, rows):
-            w2 = w.reshape(-1, ck.LANES)
-            wc = ck.tile_gather(w2, uniq, tmap_u, dtype=dt)
-            # same row-major pull as the dense path, over the compact wc
-            wz = jnp.concatenate([wc, jnp.zeros((1,), wc.dtype)])
-            w2c = jnp.stack([wz, wz], axis=1)
-            got = jnp.take(w2c, rm_slot, axis=0)[:, 0]
-            return (rm_val * got).reshape(rows, -1).sum(1)
+        def pull_c(w, uniq, tmap_u, sidx, sseg, sval, tmap, first, rows):
+            # the touched weights into the compact domain, then the
+            # radix-image kernel over the COO stream the push walks. Not
+            # an XLA row gather from the compact domain: that takes 17.1
+            # ms against this kernel's 8.7 at 2^29 buckets (U = 12.6 M)
+            # and 12.0 against 8.3 at 2^26 (U = 1.6 M), v5e, 65,536 x 39
+            # (PERF.md §6, PR 32), so one pull serves every size
+            wc = ck.tile_gather(w.reshape(-1, ck.LANES), uniq, tmap_u,
+                                dtype=dt)
+            return ck.coo_spmv(wc, sidx, sseg, sval, tmap, first, rows,
+                               dtype=dt)
 
         @partial(jax.jit, donate_argnums=0)
         def train_step_tcoo(state, uniq, tmap_u, first_u, last_u,
-                            sidx, sseg, sval, tmap, first,
-                            rm_slot, rm_val, label, mask):
-            xw = pull_c(state["w"], uniq, tmap_u, rm_slot, rm_val,
-                        cfg.minibatch)
+                            sidx, sseg, sval, tmap, first, label, mask):
+            xw = pull_c(state["w"], uniq, tmap_u, sidx, sseg, sval, tmap,
+                        first, cfg.minibatch)
             obj, d = _loss_dual(cfg.loss, label, xw)
             d = d * mask
             g = ck.coo_spmv_t(d, sidx, sseg, sval, tmap, first, U, dtype=dt)
@@ -574,14 +577,13 @@ class LinearLearner:
             return new_state, _progress(obj, xw, label, mask, new_w)
 
         def arrays(tc, train):
-            # eval/predict read only the gathered compact w and the
-            # row-major (rm_slot, rm_val) pull: the COO stream and the
-            # update-block bounds feed the train step's gradient
-            # transpose and fused scatter alone, and go only with it
+            # every step pulls over the COO stream; the update-block
+            # bounds feed the train step's fused scatter alone, and go
+            # only with it
             p = tc.coo
-            mid = (tc.first_u, tc.last_u, p.idx, p.seg, p.val, p.tmap,
-                   p.first) if train else ()
-            return (tc.uniq, tc.tmap_u, *mid, tc.rm_slot, tc.rm_val)
+            mid = (tc.first_u, tc.last_u) if train else ()
+            return (tc.uniq, tc.tmap_u, *mid, p.idx, p.seg, p.val, p.tmap,
+                    p.first)
 
         self._kinds["tcoo"] = _Kind(
             self._pack_tcoo, _device_args(arrays, jnp.asarray),
@@ -648,9 +650,7 @@ class LinearLearner:
     def _pack_tcoo(self, db: DeviceBatch, train: bool):
         tc = ck.pack_tile_coo(db.idx, db.seg, db.val,
                               self.cfg.num_buckets, self._compact_cap,
-                              capacity=self.cfg.row_capacity,
-                              rm_rows=self.cfg.minibatch,
-                              rm_width=self.cfg.nnz_per_row)
+                              capacity=self.cfg.row_capacity)
         _PACK_BATCHES.inc()
         _PACK_NATIVE.inc(int(tc.packed_native))
         if tc.dropped_nnz:
@@ -658,9 +658,9 @@ class LinearLearner:
                 "compaction overflow: dropped %d unique keys "
                 "(%d nonzeros) — raise compact_cap (currently %d)",
                 tc.dropped_uniq, tc.dropped_nnz, self._compact_cap)
-        if train:  # tile_gather and the fused update; the push
+        if train:  # tile_gather and the fused update; pull and push
             _count_chunks(tc.uniq, self.cfg.num_buckets, ck.BLK_U, 2)
-            _count_chunks(tc.coo.val, 0, ck.BLK, 1)
+            _count_chunks(tc.coo.val, 0, ck.BLK, 2)
         return tc
 
     def _pack_coo(self, db: DeviceBatch, train: bool):
@@ -675,7 +675,7 @@ class LinearLearner:
 
     # -- epoch pack cache ----------------------------------------------------
     #: bump when prepare_batch's output layout changes for identical input
-    _PACK_VERSION = 1
+    _PACK_VERSION = 2
 
     def pack_cache_token(self, train: bool = True):
         """Everything (beyond the raw batch bytes) that decides what
@@ -704,8 +704,7 @@ class LinearLearner:
         eval_batch call it on whatever they are given, so every batch
         reaches its step this one way. Returns ("staged", kind, args,
         size, ids, train). The `train` flag must match the consuming step
-        (tcoo ships the COO stream + update-block bounds only for
-        training)."""
+        (tcoo ships the update-block bounds only for training)."""
         b = self._prepared(b)
         if b[0] == "staged":
             return b

@@ -159,10 +159,16 @@ def build_rm(seg, slot, val, num_rows: int, width: int,
     CSR-ordered COO batch: rm_slot[r*width + j] = slot of row r's j-th
     live nonzero (sentinel in padding), rm_val likewise (0.0 padding).
     The pull xw = X w then becomes ONE XLA row gather from the table
-    (widened to >= 8-byte rows) + a dense reshape-reduce — ~2.4 ns/row
-    vs the radix-image kernel's ~3 ns/nnz (PERF.md r5). Fast path: when
-    the batch is exactly width-per-row in row order (the fixed-field
-    Criteo shape), the layout IS the input and no packing runs.
+    (widened to >= 8-byte rows) + a dense reshape-reduce. That read
+    ~2.4 ns/row against the radix-image kernel's ~3 ns/nnz when the
+    table gathered from was a few MB (PERF.md r5); over the linear
+    learner's compact domain it reads 4.7 ns/row at 12.6 MB and 6.7 at
+    100 MB against the kernel's 3.2-3.4 ns/nnz, so since PR 32 that
+    learner pulls with coo_spmv and the FM learner's forward
+    (models/difacto.py, whose key table holds whole rows of dim floats)
+    is the layout's remaining caller. Fast path: when the batch is
+    exactly width-per-row in row order (the fixed-field Criteo shape),
+    the layout IS the input and no packing runs.
 
     `extra` carries further per-entry value channels laid out the same
     way (e.g. difacto's admitted V values next to the w values).
@@ -490,12 +496,13 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
 # compacted path is the TPU analog of the reference Localizer
 # (learn/base/localizer.h:42-221): map the batch's unique bucket ids to
 # a compact [0, u_cap) slot space and run the SAME kernels over the
-# compact domain (whose tile count is ~uniques/TILE instead of
-# num_buckets/TILE). A plain dense slot assignment would still pay XLA
-# element gather/scatter of the compact entries (~20 ns per random
-# access — latency-bound, ~22 ms per 64k-row step at 2^26 buckets), so
-# slots are instead grouped so each TOUCHED full-table tile's unique
-# keys occupy a BLK_U-aligned contiguous slot run. Then
+# compact domain, coo_spmv for the pull and coo_spmv_t for the push over
+# the one COO stream of the batch (its tile count is ~uniques/TILE
+# instead of num_buckets/TILE). A plain dense slot assignment would
+# still pay XLA element gather/scatter of the compact entries (~20 ns
+# per random access — latency-bound, ~22 ms per 64k-row step at 2^26
+# buckets), so slots are instead grouped so each TOUCHED full-table
+# tile's unique keys occupy a BLK_U-aligned contiguous slot run. Then
 # - pulling the touched entries is a Pallas kernel streaming only the
 #   touched table tiles (tile_gather below), and
 # - the optimizer update runs INSIDE a Pallas kernel that scatters the
@@ -514,7 +521,10 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
 # one-hot work follows its live extent (_live_chunks): with every block
 # built at full width the pull took 2.84 us a block where the tile's
 # DMA is 0.32 us; bounded by the extent it takes ~0.55 us and the fused
-# update runs near the bytes it moves.
+# update runs near the bytes it moves. ("The pull" of these figures is
+# tile_gather, the fetch into the compact domain; xw = X w over that
+# domain is coo_spmv, because an XLA row gather is latency-bound at any
+# domain over a few MB: models/linear._build_tcoo.)
 
 # slots per update block; 1024 is the minimum 1D block Mosaic accepts
 # against XLA's s32[...]{0:T(1024)} layout for large 1D operands
