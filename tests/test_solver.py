@@ -226,6 +226,58 @@ def test_solver_step_failure_no_thread_leak(data_dir, tmp_path):
     assert threading.active_count() <= before
 
 
+def test_a_loader_finishes_one_transfer_before_it_stages_the_next(
+        data_dir, tmp_path):
+    """Staging returns before the bytes are on the device, and transfers
+    under way share the link: a loader that stages batch after batch (a
+    pass's start, from the pack cache) would hold back the batch the
+    train thread needs first. So a loader waits for the batch it staged
+    last before it stages another (PR 37); the first it stages waits for
+    nothing, and every batch still reaches its step."""
+    import threading
+
+    cfg = _cfg(data_dir, tmp_path, model_out=None, max_data_pass=1,
+               val_data=None)
+    lrn = LinearLearner(cfg, make_mesh(1, 1))
+    real, lock, log = lrn.stage_batch, threading.Lock(), []
+
+    class Staged(tuple):
+        """A staged batch that says when it is waited for: no pytree
+        node, so a leaf to `jax.block_until_ready`."""
+
+        def block_until_ready(self):
+            with lock:
+                log.append(("waited", threading.get_ident(), id(self)))
+            return self
+
+    kept = []
+
+    def stage(b, train=True):
+        if isinstance(b, Staged):      # train_batch: staged already
+            return real(tuple(b), train)
+        out = Staged(real(b, train))
+        kept.append(out)               # ids stay distinct
+        with lock:
+            log.append(("staged", threading.get_ident(), id(out)))
+        return out
+
+    lrn.stage_batch = stage
+    solver = MinibatchSolver(lrn, cfg, verbose=False)
+    assert solver.run()["train"].value("nex") == 1200
+    by_thread = {}
+    for what, thread, batch in log:
+        by_thread.setdefault(thread, []).append((what, batch))
+    assert sum(len(v) for v in by_thread.values()) >= 2 * len(kept) - len(
+        by_thread)
+    for events in by_thread.values():
+        staged = [b for w, b in events if w == "staged"]
+        assert events[0] == ("staged", staged[0])
+        # between two stagings: a wait for the first of them
+        for a, b in zip(staged, staged[1:]):
+            i, j = events.index(("staged", a)), events.index(("staged", b))
+            assert ("waited", a) in events[i + 1:j], events
+
+
 def test_predict_missing_data_raises(data_dir, tmp_path):
     cfg = _cfg(data_dir, tmp_path)
     lrn = LinearLearner(cfg, make_mesh(1, 1))

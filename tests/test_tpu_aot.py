@@ -130,6 +130,17 @@ def metric_pattern(name):
         return re.compile(json.load(fh)["params"]["pattern"])
 
 
+def progress_outputs(compiled):
+    """Shapes of a compiled train step's float32 outputs beside its
+    tables (the dicts): the packed progress alone. No output of the
+    step, whatever its type, may be a scalar: each would be a
+    device-to-host read of its own."""
+    outs = compiled.out_info
+    assert all(o.shape != () for o in jax.tree_util.tree_leaves(outs)), outs
+    return [tuple(o.shape) for o in outs
+            if not isinstance(o, dict) and o.dtype == jnp.float32]
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_coo_pull_push_dense(v5e, dtype):
     stream = coo_stream(CAP, NB_DENSE)
@@ -320,6 +331,9 @@ def test_fm_step_carries_the_names_its_layer_metrics_match(v5e):
                        ("kernel_ms_per_step", 5)):
         hit = [ln for ln in lines if metric_pattern(name).search(ln)]
         assert len(hit) == want, (name, hit)
+    # one read a step (PR 37): the tables, the progress with the two
+    # counts' halves as one f32 vector, the next key; no scalar is left
+    assert progress_outputs(step) == [(13,)]
 
 
 def test_tcoo_step_pulls_and_pushes_over_one_stream(v5e):
@@ -364,6 +378,9 @@ def test_tcoo_step_pulls_and_pushes_over_one_stream(v5e):
                              []).append(ln)
     assert {k: len(v) for k, v in calls.items()} == {
         "tile_gather": 1, "coo_pull": 1, "coo_push": 1, "fused_update": 1}
+    # one read a step (PR 37): beside the tables the step returns its
+    # eight progress values as one f32 vector, and no scalar
+    assert progress_outputs(step) == [(8,)]
 
     for name, kernel in (("tcoo_pull_ms", "coo_pull"),
                          ("coo_push_ms", "coo_push"),

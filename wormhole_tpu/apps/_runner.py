@@ -270,11 +270,12 @@ def _global_train(cfg, env, make_learner, verbose, client) -> dict:
                 prog = train_fn(args, sub)
             else:
                 prog = eval_fn(args)
-            prog = {k: float(v) for k, v in prog.items()}
-            # nex is a GLOBAL sum (the batch mask is mesh-sharded): zero
-            # means every rank drained. The decision must be THE SAME on
-            # every rank (the next step is a collective), so it depends
-            # only on this global value — never on local state.
+            # prog holds Python floats: the step's one read is made
+            # (models/linear.read_progress). nex is a GLOBAL sum (the
+            # batch mask is mesh-sharded): zero means every rank drained.
+            # The decision must be THE SAME on every rank (the next step
+            # is a collective), so it depends only on this global value —
+            # never on local state.
             if prog["nex"] == 0:
                 break
             for k, v in prog.items():

@@ -62,6 +62,38 @@ _V_ROWS = REGISTRY.counter("difacto.v.rows")
 _STEP_LIVE = REGISTRY.counter("difacto.step.live_nnz")
 _STEP_ADMITTED = REGISTRY.counter("difacto.step.admitted_nnz")
 
+#: what a train step packs (linmod.pack_progress): the XLA step's
+#: progress, and after it the compact step's two nonzero counts
+XLA_TRAIN_KEYS = tuple(sorted(linmod.TRAIN_KEYS + ("objv_w",)))
+FM_TRAIN_KEYS = XLA_TRAIN_KEYS + (
+    "live_nnz.hi", "live_nnz.lo", "admitted_nnz.hi", "admitted_nnz.lo")
+
+
+def _halves(name: str, count) -> dict:
+    """An int32 count as its two 16-bit halves: f32, which the packed
+    progress is, holds each exactly and would round the whole past
+    2^24."""
+    return {name + ".hi": count >> 16, name + ".lo": count & 0xFFFF}
+
+
+def _whole(out: dict, name: str) -> int:
+    """Take _halves' two entries out of a read progress dict again."""
+    return (int(out.pop(name + ".hi")) << 16) | int(out.pop(name + ".lo"))
+
+
+def _keyed(step):
+    """`step(state, vstate, *batch, sub)` jitted as the step train_batch
+    launches: it takes the learner's key in place of the sub-key, splits
+    it as train_batch did on the host, and returns the next key after
+    the step's own outputs. One launch a step, and the chain of sub-keys
+    unchanged."""
+    @partial(jax.jit, donate_argnums=(0, 1))
+    def run(state, vstate, *args):
+        *batch, key = args
+        nxt, sub = jax.random.split(key)
+        return (*step(state, vstate, *batch, sub), nxt)
+    return run
+
 
 @dataclasses.dataclass
 class DifactoConfig(linmod.LinearConfig):
@@ -291,7 +323,6 @@ class DifactoLearner:
         self._touched_w: list[np.ndarray] = []
         self._touched_v: list[np.ndarray] = []
 
-        @partial(jax.jit, donate_argnums=(0, 1))
         def train_step(state, vstate, seg, idx, vidx, val, label, mask, rngkey):
             new_state = dict(state)
             new_vstate = dict(vstate)
@@ -359,7 +390,8 @@ class DifactoLearner:
             prog = linmod._progress(obj, margin, label, mask, new_w)
             obj_w, _ = linmod._loss_dual(cfg.loss, label, xw)
             prog["objv_w"] = jnp.sum(obj_w * mask)
-            return new_state, new_vstate, prog
+            return new_state, new_vstate, linmod.pack_progress(
+                prog, XLA_TRAIN_KEYS)
 
         @jax.jit
         def fwd(state, vstate, seg, idx, vidx, val, label, mask):
@@ -367,9 +399,14 @@ class DifactoLearner:
                 cfg, state["w"], vstate["V"], state["cnt"],
                 seg, idx, vidx, val, label.shape[0])
             obj, _ = linmod._loss_dual(cfg.loss, label, margin)
-            return margin, linmod._progress(obj, margin, label, mask)
+            return margin, linmod.pack_progress(
+                linmod._progress(obj, margin, label, mask),
+                linmod.EVAL_KEYS)
 
-        self._train_step = train_step
+        # the global SPMD loop hands every rank the same sub-key
+        # (global_step_protocol); train_batch chains the learner's own
+        self._train_step = jax.jit(train_step, donate_argnums=(0, 1))
+        self._train_keyed = _keyed(train_step)
         self._fwd = fwd
         self._rng = jax.random.PRNGKey(seed + 17)
 
@@ -634,7 +671,6 @@ class DifactoLearner:
             margin = xw + 0.5 * jnp.sum(xv * xv - x2, axis=-1)
             return xw, xv, margin, adm_key, Vk
 
-        @partial(jax.jit, donate_argnums=(0, 1))
         def train_fm(state, vstate, uniq_w, wtm, wfi, wla, wcnts,
                      widx, wseg, wval, wtmap, wfirst,
                      vidx, vseg, vval, vtmap, vfirst,
@@ -704,10 +740,13 @@ class DifactoLearner:
             prog = linmod._progress(obj, margin, label, mask, new_w)
             obj_w, _ = linmod._loss_dual(cfg.loss, label, xw)
             prog["objv_w"] = jnp.sum(obj_w * mask)
-            # for the step's two counters: fetched with the progress
-            nnz = {"live": jnp.sum(nnzK),
-                   "admitted": jnp.sum(nnzK * adm_key)}
-            return new_state, new_vstate, prog, nnz
+            # for the step's two counters: whole numbers, summed as such
+            prog.update(_halves("live_nnz",
+                                jnp.sum(nnzK.astype(jnp.int32))))
+            prog.update(_halves("admitted_nnz",
+                                jnp.sum((nnzK * adm_key).astype(jnp.int32))))
+            return new_state, new_vstate, linmod.pack_progress(
+                prog, FM_TRAIN_KEYS)
 
         @jax.jit
         def fwd_fm(state, vstate, uniq_w, wtm, vlines, key_slot,
@@ -719,9 +758,11 @@ class DifactoLearner:
             margin = forward_rm(wc, cc, Vl, key_slot, key_vslot, rm_key,
                                 rm_wval)[2]
             obj, _ = linmod._loss_dual(cfg.loss, label, margin)
-            return margin, linmod._progress(obj, margin, label, mask)
+            return margin, linmod.pack_progress(
+                linmod._progress(obj, margin, label, mask),
+                linmod.EVAL_KEYS)
 
-        self._fm_steps = (train_fm, fwd_fm)
+        self._fm_steps = (_keyed(train_fm), fwd_fm)
 
     def prepare_batch(self, blk: RowBlock, train: bool = True):
         """Host-side batch prep for the solver's loader threads: pad to
@@ -743,7 +784,7 @@ class DifactoLearner:
     def global_step_protocol(self):
         """(train_fn, eval_fn) over (seg, idx, val, label, mask) GLOBAL
         arrays; vidx derives on device. Both mutate learner state and
-        return a progress dict of device scalars."""
+        return the step's progress, read off the device in one read."""
         vb = self.cfg.vb
 
         def train_fn(args, rng):
@@ -752,14 +793,14 @@ class DifactoLearner:
             self.store.state, self.vstore.state, prog = self._train_step(
                 self.store.state, self.vstore.state, seg, idx, vidx, val,
                 label, mask, rng)
-            return prog
+            return linmod.read_progress(prog, XLA_TRAIN_KEYS)
 
         def eval_fn(args):
             seg, idx, val, label, mask = args
             vidx = idx % np.int32(vb)
             _, prog = self._fwd(self.store.state, self.vstore.state,
                                 seg, idx, vidx, val, label, mask)
-            return prog
+            return linmod.read_progress(prog, linmod.EVAL_KEYS)
 
         return train_fn, eval_fn
 
@@ -871,33 +912,28 @@ class DifactoLearner:
         return np.asarray(label)[:self.cfg.minibatch]
 
     def train_batch(self, blk) -> dict:
-        # two spans, as LinearLearner.train_batch has them: a device
-        # profile then tells a late dispatch from a late return out of
-        # the blocking fetch
+        # one launch and one read, under two spans, as
+        # LinearLearner.train_batch has them. The key the step drew
+        # from comes back advanced, and stays on the device
         with _trace.span("step.dispatch", cat="step") as sp:
             kind, args, _, st_train, ids = self.stage_batch(blk, True)
             assert st_train, "batch was staged for eval, not train"
-            self._rng, sub = jax.random.split(self._rng)
-            nnz = None
-            if kind == "fm_staged":
-                (self.store.state, self.vstore.state, prog,
-                 nnz) = self._fm_steps[0](
-                    self.store.state, self.vstore.state, *args, sub)
-            else:
-                self.store.state, self.vstore.state, prog = \
-                    self._train_step(self.store.state, self.vstore.state,
-                                     *args, sub)
+            fm = kind == "fm_staged"
+            step = self._fm_steps[0] if fm else self._train_keyed
+            (self.store.state, self.vstore.state, prog,
+             self._rng) = step(self.store.state, self.vstore.state,
+                               *args, self._rng)
             if self.track_touched:
                 self._note_touched(ids)
             self._step_count += 1
             sp.set(kind=kind)
         with _trace.span("step.fetch", cat="step"):
-            # one host round trip per scalar: blocks until the device
-            # has finished the step
-            out = jax.tree_util.tree_map(float, prog)
-            if nnz is not None:
-                _STEP_LIVE.inc(int(nnz["live"]))
-                _STEP_ADMITTED.inc(int(nnz["admitted"]))
+            # blocks until the device has finished the step
+            out = linmod.read_progress(
+                prog, FM_TRAIN_KEYS if fm else XLA_TRAIN_KEYS)
+            if fm:
+                _STEP_LIVE.inc(_whole(out, "live_nnz"))
+                _STEP_ADMITTED.inc(_whole(out, "admitted_nnz"))
             return out
 
     # -- sparse PS wire hints ------------------------------------------------
@@ -936,7 +972,7 @@ class DifactoLearner:
 
     def eval_batch(self, blk) -> dict:
         _, prog, _ = self._fwd_any(blk)
-        return jax.tree_util.tree_map(float, prog)
+        return linmod.read_progress(prog, linmod.EVAL_KEYS)
 
     def predict_batch(self, blk) -> np.ndarray:
         margin, _, size = self._fwd_any(blk)

@@ -57,6 +57,10 @@ _CHUNKS_RUN = REGISTRY.counter("linear.blocks.chunks_run")
 # tcoo batches packed, and those the native pass packed
 _PACK_BATCHES = REGISTRY.counter("linear.pack.batches")
 _PACK_NATIVE = REGISTRY.counter("linear.pack.native")
+# a step's progress read off the device: the steps read, and the
+# blocking device-to-host reads that took (read_progress)
+_FETCH_STEPS = REGISTRY.counter("step.fetch.steps")
+_FETCH_READS = REGISTRY.counter("step.fetch.reads")
 
 
 def _count_chunks(stream, dead, blk: int, kernels: int):
@@ -76,8 +80,9 @@ class _Kind:
 
     pack: Callable     # (db, train) -> packed: host side, loader thread
     args: Callable     # _device_args: what a step takes after the state
-    train: Callable    # (state, *args) -> (state, progress); donates state
-    eval: Callable     # (state, *args) -> progress
+    #: (state, *args) -> (state, packed progress); donates state
+    train: Callable
+    eval: Callable     # (state, *args) -> packed progress
     predict: Callable  # (state, *args less label and mask) -> margins
     #: packed -> the unique buckets it touches (the sparse PS push set;
     #: reference ZPush of the minibatch's keys, async_sgd.h:270-287), or
@@ -409,14 +414,18 @@ class LinearLearner:
 
     # -- global-mesh SPMD protocol (apps/_runner._global_train) ------------
     def global_step_protocol(self):
+        """(train_fn, eval_fn) over GLOBAL batch arrays: each runs the
+        XLA kind's step and returns its progress, read off the device
+        in one read."""
         xla = self._kinds["xla"]
 
         def train_fn(args, rng):
             self.store.state, prog = xla.train(self.store.state, *args)
-            return prog
+            return read_progress(prog, TRAIN_KEYS)
 
         def eval_fn(args):
-            return xla.eval(self.store.state, *args)
+            return read_progress(xla.eval(self.store.state, *args),
+                                 EVAL_KEYS)
 
         return train_fn, eval_fn
 
@@ -460,7 +469,7 @@ class LinearLearner:
             *batch, label, mask = args
             xw = pull(state["w"], *batch, label.shape[0])
             obj, _ = _loss_dual(cfg.loss, label, xw)
-            return _progress(obj, xw, label, mask)
+            return pack_progress(_progress(obj, xw, label, mask), EVAL_KEYS)
 
         @jax.jit
         def predict_step(state, *batch):
@@ -502,7 +511,8 @@ class LinearLearner:
             new_state = _update(cfg.algo, state, g, touched, cfg)
             new_w = (jnp.sum(new_state["w"] != 0)
                      - jnp.sum(w != 0)).astype(jnp.float32)
-            return new_state, _progress(obj, xw, label, mask, new_w)
+            return new_state, pack_progress(
+                _progress(obj, xw, label, mask, new_w), TRAIN_KEYS)
 
         return (train_step, *self._read_steps(pull))
 
@@ -574,7 +584,8 @@ class LinearLearner:
                 lr_eta=cfg.lr_eta, lr_beta=cfg.lr_beta,
                 lambda_l1=cfg.lambda_l1, lambda_l2=cfg.lambda_l2,
                 fixed_bytes=cfg.fixed_bytes, dtype=dt)
-            return new_state, _progress(obj, xw, label, mask, new_w)
+            return new_state, pack_progress(
+                _progress(obj, xw, label, mask, new_w), TRAIN_KEYS)
 
         def arrays(tc, train):
             # every step pulls over the COO stream; the update-block
@@ -752,9 +763,10 @@ class LinearLearner:
         return {k: u for k in self.store.state}
 
     def train_batch(self, blk) -> dict:
-        # two spans, so that a device profile can tell a late dispatch
-        # from a late return out of the blocking fetch (PERF.md §5: on
-        # the chip it is the fetch the device idles under)
+        # a step is one launch and one read. Two spans, so that a device
+        # profile can tell a late dispatch from a late return out of the
+        # blocking read (PERF.md §5: on the chip it is the read the
+        # device idles under)
         with _trace.span("step.dispatch", cat="step") as sp:
             _, kind, args, _, ids, st_train = self.stage_batch(blk, True)
             assert st_train, "batch was staged for eval, not train"
@@ -765,15 +777,14 @@ class LinearLearner:
                 self.store.state, *args)
             sp.set(kind=kind)
         with _trace.span("step.fetch", cat="step"):
-            # one host round trip per scalar of prog: blocks until the
-            # device has finished the step
-            return jax.tree_util.tree_map(float, prog)
+            # blocks until the device has finished the step
+            return read_progress(prog, TRAIN_KEYS)
 
     def eval_batch(self, blk) -> dict:
         _, kind, args, _, _, st_train = self.stage_batch(blk, False)
         assert not st_train, "batch was staged for train, not eval"
         prog = self._kinds[kind].eval(self.store.state, *args)
-        return jax.tree_util.tree_map(float, prog)
+        return read_progress(prog, EVAL_KEYS)
 
     def predict_batch(self, blk) -> np.ndarray:
         kind, packed, _, _, size = _split(self._prepared(blk))
@@ -806,3 +817,30 @@ def _progress(obj, xw, label, mask, new_w=None):
     if new_w is not None:
         p["new_w"] = new_w
     return p
+
+
+#: what _progress holds for an eval step and for a train step, in the
+#: order a step packs them and read_progress names them again: sorted,
+#: as a jitted step returns a dict
+EVAL_KEYS = ("acc", "auc", "clk", "logloss", "nex", "objv", "pclk")
+TRAIN_KEYS = tuple(sorted(EVAL_KEYS + ("new_w",)))
+
+
+def pack_progress(p: dict, keys) -> jax.Array:
+    """A step's progress scalars as one f32[len(keys)] vector, traced
+    inside the jitted step, so that the host reads a step's progress in
+    one transfer and not one a scalar."""
+    assert set(p) == set(keys), (sorted(p), keys)
+    return jnp.stack([jnp.asarray(p[k], jnp.float32) for k in keys])
+
+
+def read_progress(vec, keys) -> dict:
+    """pack_progress's inverse on the host: the one blocking
+    device-to-host read of a step, which returns when the device has
+    finished the step. A value is the Python float that float() of the
+    device scalar was."""
+    _FETCH_STEPS.inc()
+    _FETCH_READS.inc()
+    host = np.asarray(vec)
+    assert host.shape == (len(keys),), (host.shape, keys)
+    return dict(zip(keys, host.tolist()))

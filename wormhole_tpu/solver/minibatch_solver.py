@@ -22,6 +22,7 @@ import threading
 import time
 from typing import Callable, Optional
 
+import jax
 import numpy as np
 
 from wormhole_tpu.data.minibatch import MinibatchIter
@@ -373,6 +374,7 @@ class MinibatchSolver:
 
         def loader(node_id: int):
             _pyprof.tag_thread("loader")
+            staged = None   # the batch this loader staged last
             try:
                 while not stop.is_set():
                     got = pool.get(f"loader-{node_id}")
@@ -419,10 +421,18 @@ class MinibatchSolver:
                     for b in _pc.iter_part_cached(
                             self.pack_cache, part_key, raw_iter, prep):
                         if stage is not None:
+                            # one transfer in flight a loader: staging
+                            # returns before the bytes are over, and
+                            # transfers under way share the link. A
+                            # loader that runs ahead (a pass's start,
+                            # from the pack cache: a dozen batches in a
+                            # row) would hold back the two the train
+                            # thread needs first (PERF.md §6, PR 37)
+                            jax.block_until_ready(staged)
                             t0h = time.perf_counter()
                             with _trace.span("loader.h2d", cat="loader",
                                              part=part_id, i=i):
-                                b = stage(b, train=train)
+                                b = staged = stage(b, train=train)
                             if train:
                                 _ST_H2D.observe(
                                     time.perf_counter() - t0h)
