@@ -16,6 +16,7 @@ from wormhole_tpu.data import pack_cache as pc
 from wormhole_tpu.data.rowblock import RowBlock
 from wormhole_tpu.models.difacto import (DifactoConfig, DifactoLearner,
                                          row_stride)
+from wormhole_tpu.obs import trace as obs_trace
 from wormhole_tpu.ops import coo_kernels as ck
 from wormhole_tpu.parallel.mesh import make_mesh
 from wormhole_tpu.solver.minibatch_solver import MinibatchSolver
@@ -297,7 +298,10 @@ def test_difacto_learner_answers_the_harness_itself(kernel, kinds):
     lrn = _learner(50, rows, kernel)
     (keys, label), = _batches(rows, seed=1, steps=1)
     prepared = lrn.prepare_batch(_block(keys, label))
-    staged = lrn.stage_batch(prepared)
+    # staged under the solver's span, which the learner tells the bytes
+    with obs_trace._Span(None, "loader.h2d", "loader", {}) as h2d:
+        staged = lrn.stage_batch(prepared)
+    assert h2d.args == {"bytes": sum(a.nbytes for a in staged[1])}
     assert (lrn.batch_kind(prepared), lrn.batch_kind(staged)) == kinds
     for b in (prepared, staged):
         got = lrn.batch_label(b)

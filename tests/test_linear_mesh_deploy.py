@@ -384,7 +384,7 @@ def test_annotate_reaches_the_innermost_open_span_and_h2d_carries_bytes(
         one = LinearLearner(_cfg(model_shards=1, compact_cap=0),
                             make_mesh(1, 1))
         with obs_trace.span("loader.h2d", cat="loader", part=0, i=1):
-            one.stage_batch(one.prepare_batch(data.block(2)))
+            flat = one.stage_batch(one.prepare_batch(data.block(2)))
     finally:
         tracer.close()
         monkeypatch.delenv("WH_OBS_DIR")
@@ -399,5 +399,6 @@ def test_annotate_reaches_the_innermost_open_span_and_h2d_carries_bytes(
                                   mc.first, packed[2], packed[3]))
     assert by["loader.h2d", 0]["bytes"] == want == sum(
         a.nbytes for a in staged[2])
-    assert "bytes" not in by["loader.h2d", 1]       # one device: as it was
+    # one device: every batch kind says its bytes since PR 38
+    assert by["loader.h2d", 1]["bytes"] == sum(a.nbytes for a in flat[2]) > 0
     assert obs_trace.ACTIVE is None

@@ -354,6 +354,11 @@ def test_obs_smoke_linear_job(tmp_path, retrace):
                            "nodes"}
     assert {"load", "step", "metrics"} <= set(
         report["train_stages"]["stages"])
+    # a loader's whole cycle a batch, for an operator without a profiler
+    cycle = ("source", "pack", "h2d_wait", "h2d", "put")
+    assert set(cycle) <= set(report["train_stages"]["stages"])
+    for s in cycle:
+        assert grew(f"train.stage.{s}_s", "count") == 4, s
     # the pass loop's per-batch timings are the train.stage.* histograms
     # alone: it feeds no `perf.*` mirror beside them
     stages = [f"train.stage.{s}_s" for s in ("load", "step", "metrics")]

@@ -890,26 +890,24 @@ def _drain_round(solver, learner, pool: RemotePool, wtype, data_pass,
     while (got := pool.get()) is not None:
         part_id, f = got
         part_prog: dict = {}
-        with _trace.span("solver.part", cat="solver", part=part_id,
-                         data_pass=data_pass):
-            for blk in MinibatchIter(
-                f.filename, f.part, f.num_parts, f.format,
-                minibatch_size=cfg.minibatch,
-                shuf_buf=(cfg.rand_shuffle * cfg.minibatch if train else 0),
-                neg_sampling=(cfg.neg_sampling if train else 1.0),
-                seed=data_pass * 7919 + part_id,
-            ):
-                with _trace.span(span_name, cat="solver"):
-                    p = step(blk)
-                for k, v in p.items():
-                    part_prog[k] = part_prog.get(k, 0.0) + float(v)
-                if train and synced is not None:
-                    synced.maybe_sync()
+        for blk in MinibatchIter(
+            f.filename, f.part, f.num_parts, f.format,
+            minibatch_size=cfg.minibatch,
+            shuf_buf=(cfg.rand_shuffle * cfg.minibatch if train else 0),
+            neg_sampling=(cfg.neg_sampling if train else 1.0),
+            seed=data_pass * 7919 + part_id,
+        ):
+            with _trace.span(span_name, cat="solver"):
+                p = step(blk)
+            for k, v in p.items():
+                part_prog[k] = part_prog.get(k, 0.0) + float(v)
             if train and synced is not None:
-                # barrier, not plain sync: with async sync on there may
-                # be a round-trip still in flight — the finish RPC's
-                # contract is "every contribution already merged"
-                synced.flush()
+                synced.maybe_sync()
+        if train and synced is not None:
+            # barrier, not plain sync: with async sync on there may
+            # be a round-trip still in flight — the finish RPC's
+            # contract is "every contribution already merged"
+            synced.flush()
         prog.merge(part_prog)
         pool.finish(part_id, part_prog)
         if absorb is not None and pool.mepoch:

@@ -43,11 +43,13 @@ import os
 import pickle
 import tempfile
 import threading
+import time
 from collections import OrderedDict
 from typing import Any, Callable, Iterable, Iterator, Optional
 
 import numpy as np
 
+from wormhole_tpu.obs import trace as _trace
 from wormhole_tpu.obs.metrics import REGISTRY
 
 log = logging.getLogger(__name__)
@@ -219,13 +221,18 @@ class PackCache:
     def get(self, key: str):
         """The cached object or None. Memory first, then disk (a disk
         hit is promoted into the memory tier)."""
+        return self.lookup(key)[0]
+
+    def lookup(self, key: str) -> tuple:
+        """(the cached object or None, the tier that answered: "mem",
+        "disk" or "miss")."""
         with self._lock:
             got = self._mem.get(key)
             if got is not None:
                 self._mem.move_to_end(key)
                 self.hits += 1
                 _HITS.inc()
-                return got[0]
+                return got[0], "mem"
         if self.disk_dir:
             path = self._path(key)
             try:
@@ -237,7 +244,7 @@ class PackCache:
                     _HITS.inc()
                     _DISK_HITS.inc()
                     self._mem_insert(key, obj, nbytes_of(obj))
-                    return obj
+                    return obj, "disk"
             except Exception as e:
                 _CORRUPT.inc()
                 log.warning("pack cache: dropping corrupt entry %s (%s); "
@@ -249,7 +256,7 @@ class PackCache:
         with self._lock:
             self.misses += 1
         _MISSES.inc()
-        return None
+        return None, "miss"
 
     # ---------------------------------------------------------------- put
     def put(self, key: str, obj) -> bool:
@@ -339,7 +346,9 @@ def from_env() -> Optional[PackCache]:
 # ---------------------------------------------------- whole-part replay
 def iter_part_cached(cache: Optional[PackCache], part_key,
                      raw_iter_fn: Callable[[], Iterable],
-                     prepare_fn: Callable[[Any], Any]) -> Iterator:
+                     prepare_fn: Callable[[Any], Any], part: int = -1,
+                     fetched: Optional[Callable[[float], None]] = None
+                     ) -> Iterator:
     """Iterate one file part's prepared batches through the cache.
 
     ``part_key`` identifies the part AND the full pack configuration
@@ -354,6 +363,12 @@ def iter_part_cached(cache: Optional[PackCache], part_key,
     forwarded — already-served batches are re-parsed but NOT re-packed
     or re-yielded — and filling resumes from the gap.
 
+    A replayed batch's fetch lies under span ``loader.source``
+    (``part``, ``i``, ``cached=1``, the ``tier`` that answered), the
+    name the solver gives its wait for a parsed block: a loader's wait
+    for its next item, whichever the source. ``fetched`` is told the
+    seconds of every fetch that gave a batch.
+
     With ``cache`` or ``part_key`` None this is exactly the uncached
     loop (the default-off path)."""
     if cache is None or part_key is None:
@@ -364,9 +379,15 @@ def iter_part_cached(cache: Optional[PackCache], part_key,
     n = cache.get(fingerprint(part_key, "n"))
     if n is not None:
         for i in range(int(n)):
-            b = cache.get(fingerprint(part_key, i))
+            t0 = time.perf_counter()
+            with _trace.span("loader.source", cat="loader", part=part,
+                             i=i, cached=1) as fetch:
+                b, tier = cache.lookup(fingerprint(part_key, i))
+                fetch.set(tier=tier)
             if b is None:
                 break
+            if fetched is not None:
+                fetched(time.perf_counter() - t0)
             yield b
             start = i + 1
         else:

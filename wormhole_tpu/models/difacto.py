@@ -874,12 +874,17 @@ class DifactoLearner:
                 ids_w = pk[0][pk[0] < self.cfg.num_buckets].astype(np.int64)
                 ids = (ids_w, np.unique(ids_w % self.cfg.vb))
             args = tuple(jax.device_put(a) for a in (*pk, label, mask))
+            # what the batch moves to the device: on the solver's
+            # loader.h2d span round this call
+            _trace.annotate(bytes=sum(a.nbytes for a in args))
             return ("fm_staged", args, size, train, ids)
         db, size = b[1], b[2]
         if train and self.track_touched:
             ids_w = np.unique(db.idx[db.val != 0]).astype(np.int64)
             ids = (ids_w, ids_w % self.cfg.vb)
-        return ("xla_staged", self._xla_args(db), size, train, ids)
+        args = self._xla_args(db)
+        _trace.annotate(bytes=sum(a.nbytes for a in args))
+        return ("xla_staged", args, size, train, ids)
 
     def _prepared(self, blk, train: bool):
         if isinstance(blk, RowBlock):

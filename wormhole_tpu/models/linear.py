@@ -725,10 +725,9 @@ class LinearLearner:
         # after staging only device arrays remain
         ids = k.touched(packed) if (train and self.track_touched) else None
         args = k.args(packed, label, mask, train)
-        if self._mesh_coo:
-            # what the batch moves to the chips, a [1, M, P] slice a
-            # shard: on the solver's loader.h2d span round this call
-            _trace.annotate(bytes=sum(a.nbytes for a in args))
+        # what the batch moves to the device (on a mesh a [1, M, P]
+        # slice a shard): on the solver's loader.h2d span round this call
+        _trace.annotate(bytes=sum(a.nbytes for a in args))
         return ("staged", kind, args, size, ids, train)
 
     # -- what a harness asks of the learner (benchmark/check.py) -------------

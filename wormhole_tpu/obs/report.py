@@ -86,11 +86,13 @@ def serve_stage_table(aggregate: dict) -> dict:
 #: training-step stages, in batch order. The train thread's wall per
 #: batch is load (queue wait) + step (jitted call) + metrics
 #: (merge/print) — those three are the pipeline whose p50s must sum to
-#: the per-batch total. pack and h2d run in loader threads overlapped
-#: with compute, and sync is either inside step (synchronous mode's
-#: flush) or hidden behind it (async fold wait shows up as load/step
-#: stall), so they inform but don't sum.
-TRAIN_STAGES = ("load", "pack", "h2d", "step", "sync", "metrics")
+#: the per-batch total. A loader's cycle per batch is source + pack +
+#: h2d_wait + h2d + put, in loader threads overlapped with compute, and
+#: sync is either inside step (synchronous mode's flush) or hidden
+#: behind it (async fold wait shows up as load/step stall), so they
+#: inform but don't sum.
+TRAIN_STAGES = ("load", "source", "pack", "h2d_wait", "h2d", "put", "step",
+                "sync", "metrics")
 _TRAIN_PIPELINE = ("load", "step", "metrics")
 
 

@@ -16,6 +16,7 @@ Parity with reference learn/solver/minibatch_solver.h + iter_solver.h:
 
 from __future__ import annotations
 
+import contextlib
 import os
 import queue
 import threading
@@ -177,18 +178,48 @@ _POOL = REGISTRY.gauge("loader.pool_size")
 # training-step stage decomposition (the serve.stage.* contract for
 # the train plane — obs/report.train_stage_table): the train thread's
 # wall per batch splits into load (queue wait) + step (jitted call) +
-# metrics (merge/print); pack and h2d run in loader threads overlapped
-# with compute, and sync_s is observed by the PS client sync paths.
-# Each boundary is also a span (obs/names.py: loader.pack, loader.h2d,
-# solver.queue_wait, solver.*_step, solver.merge) that a running device
-# profile lays beside the chip's own operations; the spans of one batch
-# share (part, i), the part's id and the batch's index in it.
+# metrics (merge/print); a loader's cycle per batch is source (its wait
+# for the parser or the pack cache) + pack + h2d_wait (for the batch it
+# staged last) + h2d + put (on a full queue), overlapped with compute;
+# sync_s is observed by the PS client sync paths.
+# Each boundary is also a span (obs/names.py: loader.source, loader.pack,
+# loader.h2d_wait, loader.h2d, loader.put_wait, solver.queue_wait,
+# solver.*_step, solver.merge) that a running device profile lays beside
+# the chip's own operations; the spans of one batch share (part, i), the
+# part's id and the batch's index in it. What a pass costs outside its
+# steps lies under solver.pass_start and solver.pass_end.
 _ST_LOAD = REGISTRY.histogram("train.stage.load_s")
+_ST_SOURCE = REGISTRY.histogram("train.stage.source_s")
 _ST_PACK = REGISTRY.histogram("train.stage.pack_s")
+_ST_H2D_WAIT = REGISTRY.histogram("train.stage.h2d_wait_s")
 _ST_H2D = REGISTRY.histogram("train.stage.h2d_s")
+_ST_PUT = REGISTRY.histogram("train.stage.put_s")
 _ST_STEP = REGISTRY.histogram("train.stage.step_s")
 _ST_METRICS = REGISTRY.histogram("train.stage.metrics_s")
 _ST_TOTAL = REGISTRY.histogram("train.stage.total_s")
+
+
+_DONE = object()
+
+
+def _sourced(blocks, part: int, fetched):
+    """`blocks` (a part's raw iterator) with each `next` under span
+    `loader.source`: what a loader waits for its parser thread or its
+    file, the pack not in it. The span is closed before the block is
+    handed on; `fetched` is told the seconds of every block that came."""
+    it, i = iter(blocks), 0
+    while True:
+        t0 = time.perf_counter()
+        with _trace.span("loader.source", cat="loader", part=part, i=i,
+                         cached=0) as wait:
+            blk = next(it, _DONE)
+            if blk is _DONE:
+                wait.set(end=1)
+                return
+        if fetched is not None:
+            fetched(time.perf_counter() - t0)
+        yield blk
+        i += 1
 
 
 class MinibatchSolver:
@@ -284,7 +315,13 @@ class MinibatchSolver:
 
     def _flush(self) -> None:
         if self.sync_flush is not None:
-            self.sync_flush()
+            with _trace.span("solver.flush", cat="solver"):
+                self.sync_flush()
+
+    def _save(self, base: str, it: Optional[int] = None) -> None:
+        # how long a save stalls the passes: a span of its own
+        with _trace.span("solver.checkpoint", cat="solver"):
+            ckpt.save_model(self._ckpt_store, base, it)
 
     def _run_passes(self, cfg) -> dict:
         result = {}
@@ -299,13 +336,13 @@ class MinibatchSolver:
                 (dp + 1) % cfg.save_iter == 0 and dp + 1 < cfg.max_data_pass
             ):
                 self._flush()
-                ckpt.save_model(self._ckpt_store, cfg.model_out, dp)
+                self._save(cfg.model_out, dp)
             if self._should_stop(result, dp):
                 self._log(f"early stop after pass {dp}")
                 break
         self._flush()
         if cfg.model_out:
-            ckpt.save_model(self._ckpt_store, cfg.model_out)
+            self._save(cfg.model_out)
         if getattr(cfg, "predict_out", None):
             self.predict(cfg.val_data or cfg.train_data, cfg.predict_out)
         return result
@@ -333,6 +370,22 @@ class MinibatchSolver:
         return tok_fn(train=train)
 
     def iterate(self, data: str, wtype: WorkType, data_pass: int = 0) -> Progress:
+        """One pass. The train thread's time in it lies under
+        `solver.<mode>_pass`, and inside that under `solver.pass_start`
+        up to the first batch in hand, the loop's own spans (queue_wait,
+        step, merge a batch), and `solver.pass_end` from the loop's exit
+        on; `turn` holds whichever of the first and the last is open."""
+        mode = "train" if wtype == WorkType.TRAIN else "eval"
+        with _trace.span(f"solver.{mode}_pass", cat="solver",
+                         data_pass=data_pass), \
+                contextlib.ExitStack() as turn:
+            return self._iterate(data, wtype, data_pass, mode, turn)
+
+    def _iterate(self, data: str, wtype: WorkType, data_pass: int,
+                 mode: str, turn: contextlib.ExitStack) -> Progress:
+        start = turn.enter_context(_trace.span(
+            "solver.pass_start", cat="solver", mode=mode,
+            data_pass=data_pass))
         cfg = self.cfg
         hook = getattr(self.learner, "on_pass_start", None)
         if hook:
@@ -347,7 +400,9 @@ class MinibatchSolver:
             # sparsity column is cumulative across passes like the
             # reference log (progress.h:10-35), not per-pass deltas;
             # one host reduction per pass, not per row
-            prog.merge({"new_w": float(self.learner.nnz())})
+            with _trace.span("solver.nnz", cat="solver"):
+                nnz = float(self.learner.nnz())
+            prog.merge({"new_w": nnz})
             prog.take_increment()
         q: queue.Queue = queue.Queue(maxsize=self.max_queued)
         _END = object()
@@ -366,6 +421,8 @@ class MinibatchSolver:
             return False
 
         train = wtype == WorkType.TRAIN
+        # a loader's wait for a batch's source, a batch (train passes)
+        fetched = _ST_SOURCE.observe if train else None
         token = self._pass_cache_token(train)
         prepare = getattr(self.learner, "prepare_batch", None)
         # loader-side device staging (double-buffer): batch N+1's arrays
@@ -383,7 +440,7 @@ class MinibatchSolver:
                     part_id, f = got
 
                     def raw_iter(f=f, part_id=part_id):
-                        return MinibatchIter(
+                        return _sourced(MinibatchIter(
                             f.filename, f.part, f.num_parts, f.format,
                             minibatch_size=cfg.minibatch,
                             shuf_buf=(cfg.rand_shuffle * cfg.minibatch
@@ -391,7 +448,7 @@ class MinibatchSolver:
                             neg_sampling=(cfg.neg_sampling
                                           if train else 1.0),
                             seed=data_pass * 7919 + part_id,
-                        )
+                        ), part_id, fetched)
 
                     i = 0   # batches of this part delivered so far
 
@@ -419,7 +476,8 @@ class MinibatchSolver:
                             f.filename, f.part, f.num_parts, f.format,
                             cfg.minibatch, _pc.file_stamp(f.filename))
                     for b in _pc.iter_part_cached(
-                            self.pack_cache, part_key, raw_iter, prep):
+                            self.pack_cache, part_key, raw_iter, prep,
+                            part=part_id, fetched=fetched):
                         if stage is not None:
                             # one transfer in flight a loader: staging
                             # returns before the bytes are over, and
@@ -428,16 +486,28 @@ class MinibatchSolver:
                             # from the pack cache: a dozen batches in a
                             # row) would hold back the two the train
                             # thread needs first (PERF.md §6, PR 37)
-                            jax.block_until_ready(staged)
+                            t0w = time.perf_counter()
+                            if staged is not None:
+                                with _trace.span("loader.h2d_wait",
+                                                 cat="loader",
+                                                 part=part_id, i=i):
+                                    jax.block_until_ready(staged)
                             t0h = time.perf_counter()
                             with _trace.span("loader.h2d", cat="loader",
                                              part=part_id, i=i):
                                 b = staged = stage(b, train=train)
                             if train:
+                                _ST_H2D_WAIT.observe(t0h - t0w)
                                 _ST_H2D.observe(
                                     time.perf_counter() - t0h)
-                        if not _put((b, part_id, i)):
-                            return
+                        t0q = time.perf_counter()
+                        with _trace.span("loader.put_wait", cat="loader",
+                                         part=part_id, i=i,
+                                         depth=q.qsize()):
+                            if not _put((b, part_id, i)):
+                                return
+                        if train:
+                            _ST_PUT.observe(time.perf_counter() - t0q)
                         i += 1
                     pool.finish(part_id)
             except BaseException as e:
@@ -449,6 +519,7 @@ class MinibatchSolver:
 
         n_loaders = self.controller.n if self.controller else self.num_loaders
         _POOL.set(n_loaders)
+        start.set(loaders=n_loaders)
         threads = [
             threading.Thread(target=loader, args=(i,), daemon=True)
             for i in range(n_loaders)
@@ -456,7 +527,6 @@ class MinibatchSolver:
         for t in threads:
             t.start()
 
-        mode = ("train" if wtype == WorkType.TRAIN else "eval")
         step = (self.learner.train_batch if mode == "train"
                 else self.learner.eval_batch)
         done_loaders = 0
@@ -472,45 +542,53 @@ class MinibatchSolver:
             self._log(f"{mode} pass {data_pass}: {data}")
             self._log(Progress.header())
         try:
-            with _trace.span(f"solver.{mode}_pass", cat="solver",
-                             data_pass=data_pass):
-                while done_loaders < len(threads):
-                    depth = q.qsize()
-                    _QDEPTH.set(depth)
-                    gets += 1
-                    if depth >= max(1, self.max_queued // 2):
-                        high += 1
-                    t_w = time.perf_counter()
-                    with _trace.span("solver.queue_wait", cat="solver"):
-                        item = q.get()
-                    dw = time.perf_counter() - t_w
-                    stall_s += dw
-                    _STALL.set(stall_s)
+            while done_loaders < len(threads):
+                depth = q.qsize()
+                _QDEPTH.set(depth)
+                gets += 1
+                if depth >= max(1, self.max_queued // 2):
+                    high += 1
+                t_w = time.perf_counter()
+                with _trace.span("solver.queue_wait", cat="solver") as wait:
+                    item = q.get()
+                    if gets == 1:
+                        wait.set(first=1)
                     if item is _END:
-                        done_loaders += 1
-                        continue
-                    b, part_id, i = item
-                    t_s = time.perf_counter()
-                    with _trace.span(f"solver.{mode}_step", cat="solver",
-                                     part=part_id, i=i):
-                        out = step(b)
-                    dt = time.perf_counter() - t_s
-                    t_step += dt
-                    n_steps += 1
-                    t_m = time.perf_counter()
-                    with _trace.span("solver.merge", cat="solver"):
-                        prog.merge(out)
-                        if self.verbose and (time.time() - last_print
-                                             >= cfg.print_sec):
-                            self._log(prog.row(self.t0))
-                            last_print = time.time()
-                    if train:
-                        dm = time.perf_counter() - t_m
-                        _ST_LOAD.observe(dw)
-                        _ST_STEP.observe(dt)
-                        _ST_METRICS.observe(dm)
-                        _ST_TOTAL.observe(dw + dt + dm)
+                        wait.set(end=1)
+                dw = time.perf_counter() - t_w
+                stall_s += dw
+                _STALL.set(stall_s)
+                if item is _END:
+                    done_loaders += 1
+                    continue
+                if not n_steps:
+                    turn.close()   # a batch in hand: the start is over
+                b, part_id, i = item
+                t_s = time.perf_counter()
+                with _trace.span(f"solver.{mode}_step", cat="solver",
+                                 part=part_id, i=i):
+                    out = step(b)
+                dt = time.perf_counter() - t_s
+                t_step += dt
+                n_steps += 1
+                t_m = time.perf_counter()
+                with _trace.span("solver.merge", cat="solver"):
+                    prog.merge(out)
+                    if self.verbose and (time.time() - last_print
+                                         >= cfg.print_sec):
+                        self._log(prog.row(self.t0))
+                        last_print = time.time()
+                if train:
+                    dm = time.perf_counter() - t_m
+                    _ST_LOAD.observe(dw)
+                    _ST_STEP.observe(dt)
+                    _ST_METRICS.observe(dm)
+                    _ST_TOTAL.observe(dw + dt + dm)
         finally:
+            turn.close()
+            turn.enter_context(_trace.span(
+                "solver.pass_end", cat="solver", mode=mode,
+                data_pass=data_pass, steps=n_steps))
             stop.set()
             for t in threads:
                 t.join()
