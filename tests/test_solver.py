@@ -2,9 +2,13 @@
 with fake workloads, SURVEY §4), full solver loop, checkpoint/resume,
 predict output."""
 
+import gc
 import os
+import random
 import re
+import threading
 import time
+import weakref
 
 import numpy as np
 import pytest
@@ -203,8 +207,6 @@ def test_checkpoint_reshard_removes_stale_parts(data_dir, tmp_path):
 
 def test_solver_step_failure_no_thread_leak(data_dir, tmp_path):
     """A failing train step must not park loader threads forever."""
-    import threading
-
     cfg = _cfg(data_dir, tmp_path, model_out=None)
     lrn = LinearLearner(cfg, make_mesh(1, 1))
 
@@ -234,8 +236,6 @@ def test_a_loader_finishes_one_transfer_before_it_stages_the_next(
     train thread needs first. So a loader waits for the batch it staged
     last before it stages another (PR 37); the first it stages waits for
     nothing, and every batch still reaches its step."""
-    import threading
-
     cfg = _cfg(data_dir, tmp_path, model_out=None, max_data_pass=1,
                val_data=None)
     lrn = LinearLearner(cfg, make_mesh(1, 1))
@@ -383,31 +383,33 @@ def _inside(outer, spans):
             if outer["start"] <= s["start"] and s["end"] <= outer["end"]]
 
 
-def _loaders_of(pass_span, spans):
-    """The `loader.*` spans of one pass, a list a loader thread (a line
-    within the pass: the pass's loaders live side by side from its start,
-    so they hold different ids), each in order."""
+def _loaders_of(spans):
+    """The `loader.*` spans of a run, a list a loader thread (a line: the
+    run's loaders live side by side from its first pass to its last, so
+    they hold different ids), each in order."""
     out = {}
-    for s in _inside(pass_span, spans):
+    for s in spans:
         if s["name"].startswith("loader."):
             out.setdefault(s["thread"], []).append(s)
     return list(out.values())
 
 
 def _check_pass_turn(passes, by, spans):
-    """The new spans' nesting and order, pass by pass: `solver.pass_start`
-    holds `solver.nnz` and the pass's first queue wait and ends before the
-    first step; `solver.pass_end` follows the last; `first=1` once a
-    pass, `end=1` once a loader; a loader waits for its last transfer
-    before every staging but its first."""
+    """The turn's spans' nesting and order, pass by pass:
+    `solver.pass_start` holds `solver.nnz` and the pass's first queue wait
+    and ends before the first step; `solver.pass_end` follows the last;
+    `first=1` and `end=1` once a pass each (its end marker); `ahead` is 0
+    in a run's first pass; a loader waits for its last transfer before
+    every staging but its first of the run."""
     assert len(by["solver.pass_start"]) == len(passes) == len(
         by["solver.pass_end"]) == len(by["solver.nnz"])
     for dp, (p, head, tail) in enumerate(zip(
             passes, by["solver.pass_start"], by["solver.pass_end"])):
         assert p["args"]["data_pass"] == dp
-        loaders = head["args"]["loaders"]
+        loaders, ahead = head["args"]["loaders"], head["args"]["ahead"]
         assert head["args"] == {"mode": "train", "data_pass": dp,
-                                "loaders": loaders}
+                                "loaders": loaders, "ahead": ahead}
+        assert ahead >= 0 and (dp or ahead == 0)
         assert p["start"] <= head["start"] and tail["end"] <= p["end"]
         steps = _inside(p, by["solver.train_step"])
         assert tail["args"] == {"mode": "train", "data_pass": dp,
@@ -418,23 +420,25 @@ def _check_pass_turn(passes, by, spans):
         waits = _inside(p, by["solver.queue_wait"])
         (first,) = [w for w in waits if w["args"].get("first")]
         assert first is waits[0] and nnz["end"] <= first["start"]
-        assert first in _inside(head, waits)
-        # the waits inside the start but the last ended in an end marker
-        assert all(w["args"].get("end") for w in _inside(head, waits)[:-1])
-        ends = [w for w in waits if w["args"].get("end")]
-        assert len(ends) == loaders and ends[-1] is waits[-1]
-        assert len(waits) == len(steps) + loaders
-        assert 1 <= len(_loaders_of(p, spans)) <= loaders
-        for mine in _loaders_of(p, spans):
-            h2ds = [s for s in mine if s["name"] == "loader.h2d"]
-            wts = [s for s in mine if s["name"] == "loader.h2d_wait"]
-            assert len(wts) == len(h2ds) - 1
-            for prev, w, h in zip(h2ds, wts, h2ds[1:]):
-                assert prev["end"] <= w["start"] <= w["end"] <= h["start"]
-                assert (w["args"]["part"], w["args"]["i"]) == (
-                    h["args"]["part"], h["args"]["i"])
-            for h in h2ds:
-                assert h["args"]["bytes"] > 0
+        # the start holds the one wait that brought the first batch
+        assert _inside(head, waits) == [first]
+        (end,) = [w for w in waits if w["args"].get("end")]
+        assert end is waits[-1]
+        assert len(waits) == len(steps) + 1
+    # the loaders are the run's: a loader's last batch survives the turn,
+    # so it waits before every staging but its very first
+    assert 1 <= len(_loaders_of(spans)) <= max(
+        h["args"]["loaders"] for h in by["solver.pass_start"])
+    for mine in _loaders_of(spans):
+        h2ds = [s for s in mine if s["name"] == "loader.h2d"]
+        wts = [s for s in mine if s["name"] == "loader.h2d_wait"]
+        assert len(wts) == len(h2ds) - 1
+        for prev, w, h in zip(h2ds, wts, h2ds[1:]):
+            assert prev["end"] <= w["start"] <= w["end"] <= h["start"]
+            assert (w["args"]["part"], w["args"]["i"]) == (
+                h["args"]["part"], h["args"]["i"])
+        for h in h2ds:
+            assert h["args"]["bytes"] > 0
 
 
 def test_training_spans_in_the_device_profile(tmp_path, monkeypatch):
@@ -462,7 +466,7 @@ def test_training_spans_in_the_device_profile(tmp_path, monkeypatch):
     for name in ("loader.pack", "loader.h2d", "loader.put_wait",
                  "step.dispatch", "step.fetch", "solver.merge"):
         assert len(by[name]) == n, name
-    assert len(by["solver.queue_wait"]) == n + 2      # the end markers too
+    assert len(by["solver.queue_wait"]) == n + 1      # the end marker too
     # read + parse work in chunks, not batches: both parts were read and
     # every row came out of a parse span
     assert {s["args"]["part"] for s in by["data.parse"]} == {0, 1}
@@ -547,11 +551,16 @@ def test_the_train_thread_is_under_a_named_span_from_first_step_to_last(
     solver = MinibatchSolver(lrn, cfg, num_loaders=2, verbose=False)
 
     calls = []      # (what was called, the innermost span open there)
+    train_thread = threading.get_ident()
 
     def told(what, fn):
         def wrapper(*a, **kw):
             sp = getattr(obs_trace._TLS, "span", None)
-            calls.append((what, sp.name if sp is not None else None))
+            where = sp.name if sp is not None else None
+            if threading.get_ident() != train_thread and what in (
+                    "pool.add", "cache.stats"):
+                where = "a loader"
+            calls.append((what, where))
             return fn(*a, **kw)
         return wrapper
 
@@ -591,9 +600,14 @@ def test_the_train_thread_is_under_a_named_span_from_first_step_to_last(
     for what, span in calls:
         under.setdefault(what, set()).add(span)
     assert under == {
-        "nnz": {"solver.nnz"}, "pool.add": {"solver.pass_start"},
+        "nnz": {"solver.nnz"},
+        # the run's first pool by the train thread, the next passes' by
+        # the loader that turned the pass before
+        "pool.add": {"solver.pass_start", "a loader"},
         "train_batch": {"solver.train_step"},
-        "cache.stats": {"solver.pass_end"},
+        # where the cache stood at a pass's end, from the loader that
+        # turned the pass: the next pass's lookups are not in it
+        "cache.stats": {"a loader"},
         "controller": {"solver.pass_end"},
         "save_model": {"solver.checkpoint"},
         "sync_flush": {"solver.flush"},
@@ -604,6 +618,8 @@ def test_the_train_thread_is_under_a_named_span_from_first_step_to_last(
     assert [w for w, _ in calls
             if w in ("nnz", "sync_flush", "save_model")] == [
         "nnz", "sync_flush", "sync_flush", "save_model"] * 3
+    assert [where for w, where in calls if w == "pool.add"] == [
+        "solver.pass_start", "a loader", "a loader"]
 
     # the train thread's line, one level below the pass: the loop's order
     train = _one_role_at_a_time(spans)
@@ -630,12 +646,430 @@ def test_the_train_thread_is_under_a_named_span_from_first_step_to_last(
     assert level[0]["end"] <= first["start"] and level[-1]["start"] >= (
         last["end"])
 
-    # the first pass packed what the other two were handed from the cache
-    for dp, p_ in enumerate(passes):
-        src = [s for s in _inside(p_, by["loader.source"])
-               if not s["args"].get("end")]
-        assert len(src) == per
-        assert {s["args"]["cached"] for s in src} == {0 if dp == 0 else 1}
-        assert {s["args"].get("tier") for s in src} == {
+    # the first pass packed what the other two were handed from the
+    # cache. The loaders read ahead of the train thread, so a pass's
+    # fetches are told by their order, not by the pass's span: the feed
+    # reads one pass at a time
+    src = [s for s in by["loader.source"] if not s["args"].get("end")]
+    assert len(src) == n and len(by["loader.pack"]) == per
+    for dp in range(3):
+        mine = src[dp * per:(dp + 1) * per]
+        assert {s["args"]["cached"] for s in mine} == {0 if dp == 0 else 1}
+        assert {s["args"].get("tier") for s in mine} == {
             None if dp == 0 else "mem"}
-        assert len(_inside(p_, by["loader.pack"])) == (per if dp == 0 else 0)
+        assert sorted((s["args"]["part"], s["args"]["i"]) for s in mine) == (
+            sorted((s["args"]["part"], s["args"]["i"]) for s in src[:per]))
+    # and what was staged ahead of a pass is what its start says
+    for dp, head in enumerate(by["solver.pass_start"]):
+        mine = by["loader.h2d"][dp * per:(dp + 1) * per]
+        assert head["args"]["ahead"] <= sum(
+            h["end"] <= head["start"] for h in mine)
+
+
+# ------------------------------------------------- the run owns the feed
+def _within(seconds, fn, *args, **kw):
+    """`fn`'s result or its exception, and a failure where it is not over
+    in `seconds`: a feed that hangs fails its test, not the suite."""
+    box = {}
+
+    def run():
+        try:
+            box["out"] = fn(*args, **kw)
+        except BaseException as e:      # handed on below
+            box["err"] = e
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert not t.is_alive(), f"not over after {seconds}s"
+    if "err" in box:
+        raise box["err"]
+    return box["out"]
+
+
+class _Blk:
+    """What the fake part iterator yields: the seed says the pass."""
+
+    size = 4
+
+    def __init__(self, seed, name, part, i):
+        self.tag = (seed // 7919, name, part, i)
+
+
+class _Staged:
+    def __init__(self, packed, train):
+        self.packed, self.train = packed, train
+
+
+class _Recorder:
+    """A learner with no tables that records what the solver asks of it:
+    `events` in the train thread's order, `packs` and `staged` as the
+    loaders made them, and how many staged batches were alive at once
+    (staged and not yet through their step)."""
+
+    placement = "[recorder]"
+    store = None
+
+    def __init__(self, step_s=0.0, pack_s=None, boom_at=None):
+        self.step_s, self.pack_s, self.boom_at = step_s, pack_s or {}, boom_at
+        self.lock = threading.Lock()
+        self.events, self.packs, self.by_thread = [], [], {}
+        self.alive = self.peak = self.staged = 0
+        self.kept = weakref.WeakSet()
+        self.on_step = None
+
+    def on_pass_start(self):
+        self.events.append(("start",))
+
+    def nnz(self):
+        self.events.append(("nnz",))
+        return 0.0
+
+    def pack_cache_token(self, train=True):
+        return ("recorder", 1)
+
+    def prepare_batch(self, blk, train=True):
+        time.sleep(self.pack_s.get(blk.tag[1:3], 0.0))
+        with self.lock:
+            self.packs.append(blk.tag)
+        return ("packed", *blk.tag, np.zeros(4, np.float32))
+
+    def stage_batch(self, b, train=True):
+        out = _Staged(b, train)
+        with self.lock:
+            self.kept.add(out)
+            self.staged += 1
+            self.alive += 1
+            self.peak = max(self.peak, self.alive)
+            self.by_thread.setdefault(threading.get_ident(), []).append(b[1:])
+        return out
+
+    def _step(self, what, b):
+        assert isinstance(b, _Staged) and b.train == (what == "train")
+        self.events.append((what, *b.packed[1:5]))
+        if self.on_step is not None:
+            self.on_step(what, b)
+        if self.boom_at == len(self.events):
+            raise _Boom()
+        time.sleep(self.step_s)
+        with self.lock:
+            self.alive -= 1
+        return {"nex": 4.0, "logloss": 1.0}
+
+    def train_batch(self, b):
+        return self._step("train", b)
+
+    def eval_batch(self, b):
+        return self._step("eval", b)
+
+
+class _Boom(RuntimeError):
+    pass
+
+
+_PARTS, _PER_PART = 6, 2       # train parts (files) and batches a part
+_PER_PASS = _PARTS * _PER_PART
+
+
+def _fed(tmp_path, monkeypatch, lrn, cache=True, loaders=2, max_queued=3,
+         bad=None, **kw):
+    """A solver over `lrn` whose parts come from a fake iterator (no file
+    is parsed): `_PARTS` train files of `_PER_PART` batches and one val
+    file; `bad` = (data_pass, file, i) makes that batch's source raise."""
+    from wormhole_tpu.solver import minibatch_solver as ms
+
+    for k in range(_PARTS):
+        (tmp_path / f"train-{k}").write_text("")
+    (tmp_path / "val-0").write_text("")
+
+    def parts(filename, part, num_parts, fmt, minibatch_size, shuf_buf,
+              neg_sampling, seed):
+        for i in range(_PER_PART):
+            blk = _Blk(seed, os.path.basename(filename), part, i)
+            if bad == (blk.tag[0], blk.tag[1], i):
+                raise _Boom()
+            yield blk
+
+    monkeypatch.setattr(ms, "MinibatchIter", parts)
+    if cache:
+        monkeypatch.setenv("WH_PACK_CACHE", "1")
+    else:
+        monkeypatch.delenv("WH_PACK_CACHE", raising=False)
+    cfg = LinearConfig(**{**dict(
+        train_data=str(tmp_path / r"train-\d+"), val_data=None,
+        minibatch=4, num_buckets=64, max_data_pass=3, max_queued=max_queued,
+        num_parts_per_file=1), **kw})
+    made = []
+
+    class Kept(ms._Feed):
+        def __init__(self, *a):
+            super().__init__(*a)
+            made.append(self)
+
+    monkeypatch.setattr(ms, "_Feed", Kept)
+    solver = MinibatchSolver(lrn, cfg, num_loaders=loaders, verbose=False)
+    solver.feeds = made     # every feed the solver opens, for the test
+    return solver
+
+
+def _all_joined(solver, lrn, before):
+    """Every feed closed: its loaders ended, its queue empty, and no
+    staged batch left anywhere (what ran ahead was dropped)."""
+    assert solver._feed is None and solver.feeds
+    for feed in solver.feeds:
+        assert not any(t.is_alive() for t in feed.threads)
+        assert feed.q.empty() and feed.live == 0
+    assert threading.active_count() <= before
+    gc.collect()
+    assert len(lrn.kept) == 0
+
+
+@pytest.mark.parametrize("cache", [False, True], ids=["packing", "replay"])
+def test_a_batch_is_stepped_in_its_own_pass_only(tmp_path, monkeypatch,
+                                                 cache):
+    """(a) Three passes with a val pass, a barrier, a save and the stop
+    hook between: the train thread's calls come in `_run_passes`' order
+    and every pass steps its own batches, all of them, once, though the
+    loaders have staged the next pass's first ones by then. Packing, a
+    batch's seed names its pass; replayed from the cache the same packed
+    batch serves every pass, and a pass is told by its count."""
+    from wormhole_tpu.solver import minibatch_solver as ms
+
+    lrn = _Recorder(step_s=0.002)
+    solver = _fed(tmp_path, monkeypatch, lrn, cache=cache,
+                  val_data=str(tmp_path / r"val-\d+"),
+                  model_out=str(tmp_path / "m/out"), save_iter=1)
+    solver.sync_flush = lambda: lrn.events.append(("flush",))
+    monkeypatch.setattr(ms.ckpt, "save_model",
+                        lambda *a: lrn.events.append(("save",)))
+    solver.stop_hook = lambda prog, dp, key: lrn.events.append(
+        ("stop?", dp, key))
+    _within(60, solver.run)
+    want, at = [], 0
+    for dp in range(3):
+        want += ["start", "nnz"] + ["train"] * _PER_PASS + ["flush"]
+        want += ["start", "nnz"] + ["eval"] * _PER_PART
+        want += ["flush", "save"] * (dp < 2) + ["stop?"]
+    want += ["flush", "save"]
+    assert [e[0] for e in lrn.events] == want
+    every = sorted((f"train-{k}", 0, i) for k in range(_PARTS)
+                   for i in range(_PER_PART))
+    for dp in range(3):
+        at = want.index("train", at)
+        mine = lrn.events[at:at + _PER_PASS]
+        at += _PER_PASS
+        assert sorted(e[2:] for e in mine) == every
+        # the pass that packed them is in a batch's tag
+        assert {e[1] for e in mine} == {0 if cache else dp}
+    assert len(lrn.packs) == (1 if cache else 3) * (_PER_PASS + _PER_PART)
+
+
+@pytest.fixture
+def counted(monkeypatch):
+    """`solver.pass.turns` and `solver.pass.batches_ahead` as they stood
+    at every `on_pass_start` (the solver counts a turn before it calls
+    the hook), less where they stood when the test began."""
+    from wormhole_tpu.obs.metrics import REGISTRY
+
+    turns = REGISTRY.counter("solver.pass.turns")
+    ahead = REGISTRY.counter("solver.pass.batches_ahead")
+    base = turns.value(), ahead.value()
+    seen = []
+    real = _Recorder.on_pass_start
+
+    def hook(self):
+        seen.append((turns.value() - base[0], ahead.value() - base[1]))
+        real(self)
+
+    monkeypatch.setattr(_Recorder, "on_pass_start", hook)
+    return seen
+
+
+@pytest.mark.parametrize("loaders,max_queued", [(1, 3), (2, 3), (4, 2)])
+def test_staged_batches_alive_stay_under_the_pass_s_own_bound(
+        tmp_path, monkeypatch, counted, loaders, max_queued):
+    """(b) and (c): across two turns of a replayed run no more staged
+    batches are alive than inside a pass (the queue's `max_queued`, one
+    in each loader's hands, one in the step); every turn found batches
+    staged ahead; nothing was staged beyond the last pass."""
+    lrn = _Recorder(step_s=0.003)
+    solver = _fed(tmp_path, monkeypatch, lrn, loaders=loaders,
+                  max_queued=max_queued)
+    before = threading.active_count()
+    _within(60, solver.run)
+    assert max_queued <= lrn.peak <= max_queued + loaders + 1
+    assert lrn.staged == 3 * _PER_PASS == sum(
+        e[0] == "train" for e in lrn.events) and lrn.alive == 0
+    # pass 0 is the run's first: no turn; passes 1 and 2 each found some
+    assert [t for t, _ in counted] == [0, 1, 2]
+    gains = [b - a for (_, a), (_, b) in zip(counted, counted[1:])]
+    assert counted[0][1] == 0 and all(1 <= g <= max_queued + loaders
+                                      for g in gains), counted
+    _all_joined(solver, lrn, before)
+
+
+def test_one_pass_and_iterate_alone_stage_nothing_ahead(
+        tmp_path, monkeypatch, counted):
+    """(c) A run of one pass has no turn, and `iterate()` called alone
+    opens a feed of its own pass and closes it: nothing runs ahead."""
+    lrn = _Recorder()
+    solver = _fed(tmp_path, monkeypatch, lrn, max_data_pass=1)
+    before = threading.active_count()
+    _within(60, solver.run)
+    for dp in (5, 6):
+        prog = _within(60, solver.iterate, solver.cfg.train_data,
+                       WorkType.TRAIN, dp)
+        assert prog.value("nex") == 4.0 * _PER_PASS
+    assert counted == [(0, 0)] * 3
+    assert [f.turns for f in solver.feeds] == [1, 1, 1]
+    assert lrn.staged == 3 * _PER_PASS
+    _all_joined(solver, lrn, before)
+
+
+@pytest.mark.parametrize("loaders", [1, 2, 4])
+def test_a_pass_that_fills_the_cache_packs_every_part_once(
+        tmp_path, monkeypatch, loaders):
+    """(d) `iter_part_cached` writes a part's count entry when the part
+    ends: a loader let into part P of the next pass while P of this one
+    is still being packed would miss and pack it again. One part here
+    packs slowly, so every other loader is done long before it."""
+    lrn = _Recorder(pack_s={("train-3", 0): 0.15})
+    solver = _fed(tmp_path, monkeypatch, lrn, loaders=loaders)
+    _within(60, solver.run)
+    assert sorted(lrn.packs) == sorted(
+        (0, f"train-{k}", 0, i) for k in range(_PARTS)
+        for i in range(_PER_PART))
+    stats = solver.pack_cache.stats()
+    # a filling pass misses once a part (its count entry), no more
+    assert stats["misses"] == _PARTS
+    assert stats["hits"] == 2 * (_PARTS + _PER_PASS)
+
+
+@pytest.mark.parametrize("how", ["stop_hook", "step", "loader"])
+def test_a_run_that_ends_early_joins_its_loaders_and_drops_what_ran_ahead(
+        tmp_path, monkeypatch, how):
+    """(e) The stop hook after pass 0, an exception out of pass 1's third
+    step, a loader's error in pass 1: each ends the run with every loader
+    joined, the queue empty and no staged batch alive; the loader's error
+    is raised in the pass it belongs to."""
+    kw = {}
+    if how == "step":
+        # start, nnz, 12 steps; start, nnz, 3 steps
+        kw["boom_at"] = 2 + _PER_PASS + 2 + 3
+    lrn = _Recorder(step_s=0.002, **kw)
+    solver = _fed(tmp_path, monkeypatch, lrn, max_data_pass=4,
+                  bad=(1, "train-2", 1) if how == "loader" else None,
+                  cache=how != "loader")
+    before = threading.active_count()
+    if how == "stop_hook":
+        solver.stop_hook = lambda prog, dp, key: True
+        _within(60, solver.run)
+        done = 1
+    else:
+        with pytest.raises(_Boom):
+            _within(60, solver.run)
+        done = 2
+    starts = [k for k, e in enumerate(lrn.events) if e[0] == "start"]
+    assert len(starts) == done
+    steps = [e for e in lrn.events[starts[0]:] if e[0] == "train"]
+    assert len(steps[:_PER_PASS]) == _PER_PASS      # pass 0 whole
+    if how == "loader":
+        # pass 1 came as far as the loaders brought it
+        assert {e[1] for e in steps[_PER_PASS:]} <= {1}
+        assert len(steps) < 2 * _PER_PASS
+    elif how == "step":
+        assert len(steps) == _PER_PASS + 3
+    # the loaders had run ahead, and what they staged is gone
+    assert lrn.staged > len(steps) or how == "loader"
+    _all_joined(solver, lrn, before)
+
+
+def test_a_run_equals_its_passes_made_one_at_a_time(data_dir, tmp_path):
+    """(f) Tables and progress of a three-pass run with a val pass equal,
+    bit for bit, those of the same passes made by `iterate()` one at a
+    time: with one loader the parts come in the order the pool's
+    `random.choice` draws them, seeded alike."""
+    def tables(lrn):
+        return {k: np.asarray(v) for k, v in lrn.tables().items()}
+
+    cfg = _cfg(data_dir, tmp_path, max_data_pass=3, model_out=None)
+    a = LinearLearner(cfg, make_mesh(1, 1))
+    random.seed(11)
+    got = _within(120, MinibatchSolver(a, cfg, num_loaders=1,
+                                       verbose=False).run)
+    b = LinearLearner(cfg, make_mesh(1, 1))
+    solver = MinibatchSolver(b, cfg, num_loaders=1, verbose=False)
+    random.seed(11)
+    for dp in range(3):
+        tr = _within(60, solver.iterate, cfg.train_data, WorkType.TRAIN, dp)
+        vl = _within(60, solver.iterate, cfg.val_data, WorkType.VAL, dp)
+    assert got["train"].tot == tr.tot and got["val"].tot == vl.tot
+    ta, tb = tables(a), tables(b)
+    assert ta.keys() == tb.keys() and len(ta) >= 3
+    for k in ta:
+        assert np.array_equal(ta[k], tb[k]), k
+    assert np.count_nonzero(ta["w"]) > 0
+
+
+def test_the_controller_s_growth_and_shrink_reach_the_living_pool(
+        tmp_path, monkeypatch):
+    """(g) The controller decides at a pass's end, as before; the pool
+    lives on, so a growth starts threads at the next pass's start and a
+    shrink lets loaders retire, each at its next part."""
+    from wormhole_tpu.obs.metrics import REGISTRY
+
+    sizes = iter([4, 1, 1, 1])      # after passes 0, 1, 2, 3
+
+    class Scripted:
+        n, decisions = 2, []
+
+        def record_pass(self, stall_s, wall_s, n_steps, queue_high_frac):
+            new = next(sizes)
+            self.decisions.append({"from": self.n, "to": new, "why": "told",
+                                   "stall_frac": 0.0, "queue_high_frac": 0.0})
+            self.n = new
+            return new
+
+    lrn = _Recorder(step_s=0.002)
+    solver = _fed(tmp_path, monkeypatch, lrn, cache=False, max_data_pass=4)
+    solver.controller = Scripted()
+    seen = []       # at a pass's first step: (loaders wanted, gauge)
+
+    def on_step(what, b):
+        if sum(e[0] == "train" for e in lrn.events) % _PER_PASS == 1:
+            (feed,) = solver.feeds
+            with feed.turn:
+                seen.append((feed.live - feed.retire, sum(
+                    t.is_alive() for t in feed.threads),
+                    REGISTRY.gauge("loader.pool_size").value()))
+
+    lrn.on_step = on_step
+    _within(60, solver.run)
+    (feed,) = solver.feeds
+    assert [w for w, _, _ in seen] == [2, 4, 1, 1]
+    assert [g for _, _, g in seen] == [2.0, 4.0, 1.0, 1.0]
+    # the growth made two threads more, none was made after it
+    assert len(feed.threads) == 4 and seen[1][1] == 4
+    # by the fourth pass three had retired, each at a part's end
+    assert seen[3][1] == 1
+    # and the grown pool worked: pass 1's batches came from over two
+    # threads, though two loaders had read ahead into it
+    pass1 = {t for t, tags in lrn.by_thread.items()
+             if any(tag[0] == 1 for tag in tags)}
+    assert len(pass1) >= 3
+
+
+def test_a_run_reaches_its_passes_one_at_a_time(tmp_path, monkeypatch):
+    """The benchmark's window run names a million passes and a stop ends
+    it: the feed makes a pass when the one before it turns, not a million
+    at its start (which cost the four-chip cell 1.9 s of set-up)."""
+    lrn = _Recorder()
+    solver = _fed(tmp_path, monkeypatch, lrn, max_data_pass=10 ** 6)
+    solver.stop_hook = lambda prog, dp, key: dp >= 1
+    before = threading.active_count()
+    _within(60, solver.run)
+    (feed,) = solver.feeds
+    assert feed.turns == 2 and len(feed.open) <= 2
+    # the passes after the ones reached were never made
+    assert next(feed._rest)[2] <= 4
+    _all_joined(solver, lrn, before)
