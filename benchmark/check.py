@@ -26,6 +26,16 @@ batches in the same order and `numbers` sets the two side by side:
                   tables z after one step IS the first gradient as the
                   optimizer got it, sigma * w = 0, and every sum in it is
                   exact).
+  grad_off_share  share of g1's values (a touched row of the `GRADIENT`
+                  leaf after one step) off the reference's by more than
+                  2^-12 of it. Where the summed gradient is rounded to
+                  bfloat16 (`push_g`) a norm stands on the few hottest
+                  rows, and one rounding step of one of them moves it as
+                  far as a lane group of rows left out of the push does
+                  (PERF.md section 2); the share counts such a step as one
+                  row in some hundred thousand and the lane group as every
+                  row it touches. Compared where the configuration's
+                  limits name it.
   delta_norm_gap  worst leaf after the last step: gap of the norms of the
                   change from the start (zero, or what was read before the
                   first step), against the reference's norm of that leaf
@@ -378,6 +388,7 @@ def numbers(run: dict, ref: dict) -> dict:
         "loss_gap": max(loss),
         "grad_norm_gap": abs(_norm(run["grad1"]) - _norm(ref["grad1"]))
         / _norm(ref["grad1"]),
+        "grad_off_share": _off_share(run["grad1"], ref["grad1"]),
         "delta_norm_gap": max(abs(_norm(_change(run, k)) - rn[k])
                               / max(rn[k], floor) for k in leaves),
         "state_off_share": max(_off_share(run["final"][k], ref["final"][k])

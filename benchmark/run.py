@@ -186,7 +186,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
             plan = trace_plan(work, traffic, seconds) if trace else None
             tap = drive(config, traffic, conf, ds, seconds, plan,
                         tp.Tap(None, clog, warns, first, served))
-            out = result(tap, seconds, warns)
+            out = result(tap, seconds, warns, traffic)
             ok, lines, compared = correct(config, first, reference, tap,
                                           clog)
             for line in lines:
@@ -279,16 +279,24 @@ def drive(config, traffic, conf, ds, seconds, plan, tap):
                      "raise the traffic's min_pass_rows or window_passes")
 
 
-def result(tap, seconds, warns) -> dict:
-    """The result line but for `correct` and the metrics."""
+def result(tap, seconds, warns, traffic) -> dict:
+    """The result line but for `correct` and the metrics. A mix that sizes
+    its pass to hold the window (`min_pass_rows` over 0) gets no line from
+    a window that reached a further pass: a pass's turn changes the loader
+    pool, and the window would be two jobs."""
     peak = memory_peak_bytes()
     n = len(tap.ends)
     if n < 2:
         raise SystemExit(f"run.py: {n} step(s) completed in the window")
     say(f"window: {n} steps, {sum(tap.rows):.0f} rows in "
         f"{tap.ends[-1] - tap.t_open:.3f}s (nominal {seconds}s); pass "
-        f"{tap.pass_no} of the window run; {n} gaps; set-up "
+        f"{tap.pass_close} of the window run; {n} gaps; set-up "
         f"{tap.t_open - T_START:.1f}s")
+    if int(traffic["min_pass_rows"]) > 0 and tap.pass_close != tap.pass_open:
+        raise SystemExit(
+            f"run.py: the window opened in pass {tap.pass_open} of the "
+            f"window run and closed in pass {tap.pass_close}: the long pass "
+            "ended before the window did; raise the traffic's min_pass_rows")
     gaps = window.gaps_ms(tap.t_open, tap.ends)
     say(f"window: longest gap {max(gaps):.1f} ms, "
         f"{sum(g > 1e3 * tp.STALL_DUMP_S for g in gaps)} over "

@@ -122,6 +122,10 @@ class Tap:
         self.seconds = 0.0
         self.warmup_passes = 0
         self.pass_no = -1
+        # the window run's pass in which the window opened and the one in
+        # which its last step ran: a mix that says its window lies in one
+        # pass is held to it (run.py)
+        self.pass_open = self.pass_close = None
         self.t_open = None
         self.ends: list[float] = []
         self.rows: list[float] = []
@@ -216,7 +220,7 @@ class Tap:
         self.kinds.add(batch_kind(self._learner, b))
         if self.t_open is None:
             # the first step completed after warm-up opens the window
-            self.t_open = t1
+            self.t_open, self.pass_open = t1, self.pass_no
             self._clog.phase = self._warns.phase = "window"
             return
         self.ends.append(t1)
@@ -238,7 +242,7 @@ class Tap:
         if self.t_hist_close is None:
             self.t_hist_close = now     # the histograms: at the next entry
         self._clog.phase = self._warns.phase = "after"
-        self._closed = True
+        self._closed, self.pass_close = True, self.pass_no
 
     def _trace_tick(self, t1):
         """With --trace 1: the host-side layer metrics are taken from the

@@ -64,11 +64,15 @@ def test_the_cell_rehearses_to_correct_on_the_compact_path(tmp_path):
     assert out["correct"] is True and out["failed"] == 0
     assert set(out["metrics"]) == {"train_ex_per_s", "val_logloss", "setup_s"}
     lines = [ln for ln in r.stdout.splitlines() if "correct:" in ln]
-    for name in ("loss_gap", "grad_norm_gap", "delta_norm_gap",
-                 "state_off_share", "served_loss_gap", "served_delta_gap",
-                 "served_off_share"):
+    for name in ("loss_gap", "grad_norm_gap", "grad_off_share",
+                 "delta_norm_gap", "state_off_share", "served_loss_gap",
+                 "served_delta_gap", "served_off_share"):
         (ln,) = [x for x in lines if f" {name} = " in x]
         assert "(limit" in ln and ln.rstrip().endswith("ok"), ln
+    assert set(out["compared"]) == {
+        "loss_gap", "grad_norm_gap", "grad_off_share", "delta_norm_gap",
+        "state_off_share", "served_loss_gap", "served_delta_gap",
+        "served_off_share", "window_compiles", "val_logloss"}
     (ln,) = [x for x in lines if "reference: 3 steps" in x]
     assert "touched vrows of 65536" in ln
     assert any("staged batch kinds ['fm']  (expected 'fm')" in x

@@ -76,7 +76,9 @@ def test_every_configuration_resolves_and_is_used(bench):
             BENCH, "reference", cfg["reference"] + ".py"))
         for k in cfg["kernels"]:
             assert os.path.isfile(os.path.join(BENCH, "kernels", k + ".py"))
-        assert set(cfg["correct"]["limits"]) == {
+        # the four every configuration compares; `grad_off_share` where
+        # a norm of the first gradient cannot tell sound from fault
+        assert set(cfg["correct"]["limits"]) - {"grad_off_share"} == {
             "loss_gap", "grad_norm_gap", "delta_norm_gap", "state_off_share"}
         assert set(cfg["correct"]["served_limits"]) == {
             "served_loss_gap", "served_delta_gap", "served_off_share"}

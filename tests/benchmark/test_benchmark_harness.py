@@ -306,13 +306,33 @@ def test_run_py_fails_where_only_the_benchmark_is(tmp_path):
     assert "{" not in r.stdout
 
 
+# `criteo1tb.text-stream` was retired in PR 41 (its runs of one code and
+# one seed spread 6-9 % on the chip: PERF.md section 6); its mix and this
+# rehearsal of it stay: the cell's entry as it stood, laid into the
+# benchmark the rehearsal is given
+_TEXT_STREAM = """
+import json
+import sys
+from benchmark import run
+
+bench = run.load_json(run.ROOT, "BENCHMARK.json")
+if not any(w["name"] == "criteo1tb.text-stream" for w in bench["workloads"]):
+    bench["workloads"].append({
+        "name": "criteo1tb.text-stream", "config": "linear-ftrl-criteo1tb",
+        "traffic": "text-stream", "chips": 1,
+        "why": "one long pass over raw Criteo text, pack cache off"})
+out = run.run_cell(bench, "criteo1tb.text-stream", int(sys.argv[1]), 3.0,
+                   False, rehearsal=True)
+print(json.dumps(out))
+"""
+
+
 @pytest.fixture(scope="module")
 def sound_run(tmp_path_factory):
     tmp = tmp_path_factory.mktemp("sound")
     r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "rehearse.py"),
-         "--workload", "criteo1tb.text-stream", "--seed", "2147483659",
-         "--seconds", "3"], capture_output=True, text=True, timeout=900,
+        [sys.executable, "-c", _TEXT_STREAM, "2147483659"],
+        capture_output=True, text=True, timeout=900,
         env=_env(tmp, BENCH_RUN="3"), cwd=REPO)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     return r.stdout
@@ -341,6 +361,10 @@ def test_last_line_is_the_contracts_object(sound_run):
                                   "memory_peak_bytes"}
     # a rehearsal is named for what it is
     assert out["device"]["platform"] == "cpu"
+    # the mix says its window lies in one pass, and it did (PR 41)
+    (ln,) = [x for x in sound_run.splitlines() if "of the window run; " in x]
+    assert "pass 0 of the window run" in ln
+    assert "linked 381x more" in sound_run
 
 
 def test_reference_agrees_with_the_learner_and_every_number_is_printed(

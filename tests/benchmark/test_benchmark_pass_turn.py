@@ -1,10 +1,10 @@
 """The pass's turn and a loader's waits as layer metrics (PR 38): seven
 data files under `benchmark/layer_metrics/`, each through a reducer the
 benchmark already has, read from the profile of a real solver run on the
-CPU (three passes, the pack cache on). `BENCHMARK.json` gains no entry
-in this PR (an accepted test holds `per_layer`'s last entry, and a new
-entry may go nowhere else: PERF.md §7 (k) holds the seven entries whole
-for the `benchmark` issue that mends it). Nothing here is a speed."""
+CPU (three passes, the pack cache on). Since PR 41 `BENCHMARK.json`
+holds their entries, after `tcoo_pull_ms` (whose test finds its entry by
+name now); the four of a pass's turn list the cells whose window holds
+one. Nothing here is a speed."""
 
 import importlib
 import json
@@ -79,12 +79,33 @@ def test_the_file_loads_and_names_an_accepted_reducer(name):
     assert span in names.SPANS
 
 
-def test_the_benchmark_s_list_is_the_parent_s():
-    """No entry of this PR's in `per_layer`: the last is still the one an
-    accepted test holds there."""
+def test_the_benchmark_lists_the_seven_after_the_accepted_entries():
+    """Each is an entry of `per_layer`, after `tcoo_pull_ms` (that it
+    equals its file is test_benchmark_files.py's, for every entry). A
+    loader's waits are read in every cell (no
+    `workloads` list); the four of a pass's turn list the cells whose
+    window holds a turn: not a mix that says its window lies in one
+    pass (`min_pass_rows` over 0)."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
-        per_layer = [m["name"] for m in json.load(fh)["per_layer"]]
-    assert per_layer[-1] == "tcoo_pull_ms" and not set(per_layer) & set(SEVEN)
+        bench = json.load(fh)
+    names = [m["name"] for m in bench["per_layer"]]
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    one_pass = set()
+    for w in bench["workloads"]:
+        with open(os.path.join(REPO, "benchmark", "traffic",
+                               w["traffic"] + ".json")) as fh:
+            if json.load(fh)["min_pass_rows"] > 0:
+                one_pass.add(w["name"])
+    assert "criteo1tb.crb-stream" in one_pass
+    replay = {"criteo1tb.replay", "criteo1tb-2p30.replay-8",
+              "difacto1tb.replay"}
+    for name in SEVEN:
+        assert names.index(name) > names.index("tcoo_pull_ms")
+        if "pass_" in name:
+            listed = set(entries[name]["workloads"])
+            assert replay <= listed and not listed & one_pass, name
+        else:
+            assert "workloads" not in entries[name]
 
 
 @pytest.fixture(scope="module")
