@@ -105,7 +105,10 @@ def test_the_benchmark_lists_the_seven_after_the_accepted_entries():
             listed = set(entries[name]["workloads"])
             assert replay <= listed and not listed & one_pass, name
         else:
-            assert "workloads" not in entries[name]
+            # every cell: the replay cells under the name, the stream
+            # cell under its twin's (PR 43: its rate is a layer metric)
+            assert set(entries[name]["workloads"]) == replay, name
+            assert set(entries[name + ".stream"]["workloads"]) == one_pass
 
 
 @pytest.fixture(scope="module")

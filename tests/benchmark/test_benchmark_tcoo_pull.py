@@ -36,7 +36,12 @@ def test_the_metric_s_file_loads_and_lists_the_compact_cells_only():
     # the cells whose step is the compact one: those of the one-chip
     # linear configuration, whose kind is tcoo, and no other
     cells = {w["name"]: w for w in bench["workloads"]}
-    listed = set(entry["workloads"])
+    (twin,) = [m for m in bench["per_layer"]
+               if m["name"] == "tcoo_pull_ms.stream"]
+    assert _json(BENCH, "layer_metrics", "tcoo_pull_ms.stream.json")[
+        "params"] == spec["params"]
+    # (the stream cell reads it under the twin's name since PR 43)
+    listed = set(entry["workloads"]) | set(twin["workloads"])
     assert {"criteo1tb.crb-stream", "criteo1tb.replay"} <= listed
     assert all(cells[n]["config"] == CONFIG for n in listed)
     assert _json(BENCH, "configs", CONFIG + ".json")["expect_kind"] == "tcoo"
