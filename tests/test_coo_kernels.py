@@ -5,6 +5,7 @@ Mosaic on TPU (tests/test_tpu_aot.py compiles them for v5e; chip_smoke.py
 and the benchmark run them there).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -177,6 +178,9 @@ def test_tile_gather_equals_full_width(fill, dtype, full_width):
         np.testing.assert_allclose(got[live], w[ts.uniq[live]], rtol=1e-6)
 
 
+_HYPER = dict(lr_eta=0.3, lr_beta=1.0, lambda_l1=0.2, lambda_l2=0.01)
+
+
 def _update_case(algo, fill, with_add):
     nb = 3 * TILE
     ts, keys = _tile_slots(fill)
@@ -188,8 +192,10 @@ def _update_case(algo, fill, with_add):
              for k in names}
     live = ts.uniq != nb
     g = np.where(live, rng.normal(size=len(live)), 0).astype(np.float32)
-    kw = dict(lr_eta=0.3, lr_beta=1.0, lambda_l1=0.2, lambda_l2=0.01,
-              dtype=jnp.float32)
+    if algo == "ftrl":      # w is the derived table, as a run leaves it
+        state["w"] = np.array(fu.ftrl_weight(
+            state["z"], np.sqrt(state["n"]), **_HYPER))
+    kw = dict(_HYPER, dtype=jnp.float32)
     adds = None
     if with_add:   # counts over 256: the additive scatter stays f32
         state["cnt"] = rng.integers(0, 9, size=nb).astype(np.float32)
@@ -203,14 +209,14 @@ def _update_case(algo, fill, with_add):
             jnp.asarray(g), jnp.asarray(ts.uniq), jnp.asarray(ts.tmap_u),
             jnp.asarray(ts.first_u), jnp.asarray(ts.last_u), **kw)
         return {k: np.asarray(v) for k, v in st.items()}, float(nw)
-    return run, state, ts, adds
+    return run, state, ts, adds, g
 
 
 @pytest.mark.parametrize("algo,with_add", [
     ("ftrl", False), ("adagrad", False), ("sgd", False), ("ftrl", True)])
 @pytest.mark.parametrize("fill", U_FILLS)
 def test_fused_update_equals_full_width(fill, algo, with_add, full_width):
-    run, before, ts, adds = _update_case(algo, fill, with_add)
+    run, before, ts, adds, _ = _update_case(algo, fill, with_add)
     got, nw = run()
     full_width()
     want, nw_want = run()
@@ -228,6 +234,60 @@ def test_fused_update_equals_full_width(fill, algo, with_add, full_width):
         want_cnt = before["cnt"].copy()
         want_cnt[ts.uniq[live]] += adds[live]
         np.testing.assert_array_equal(got["cnt"], want_cnt)
+
+
+def _xla_update(before, ts, g, adds):
+    """models/linear._update over the whole tables, on the gradient the
+    kernel's scatter forms: the dense path's twin of the case."""
+    from wormhole_tpu.models.linear import LinearConfig, _update
+
+    nb = 3 * TILE
+    dense = np.zeros(nb + 1, np.float32)
+    dense[ts.uniq] = g          # the sentinel's slot is cut off below
+    state = {k: jnp.asarray(before[k]) for k in ("z", "n", "w")}
+    want, nw = jax.jit(lambda st, gd: _update(
+        "ftrl", st, gd, 1.0, LinearConfig(algo="ftrl", **_HYPER)))(
+            state, jnp.asarray(dense[:nb]))
+    want = {k: np.asarray(v) for k, v in want.items()}
+    if adds is not None:
+        want["cnt"] = before["cnt"].copy()
+        want["cnt"][ts.uniq[ts.uniq != nb]] += adds[ts.uniq != nb]
+    return want, float(nw)
+
+
+@pytest.mark.parametrize("with_add", [False, True])
+@pytest.mark.parametrize("fill", U_FILLS)
+def test_fused_ftrl_update_equals_the_dense_rule(fill, with_add):
+    """From a state a run can leave (w the derived table), the kernel
+    and models/linear._update write the same z, n, w and count the same
+    new_w: the two sites of the one rule."""
+    run, before, ts, adds, g = _update_case("ftrl", fill, with_add)
+    got, nw = run()
+    want, nw_want = _xla_update(before, ts, g, adds)
+    assert nw == nw_want
+    for k in want:      # f32 to the last place: XLA fuses the two apart
+        np.testing.assert_allclose(got[k], want[k], rtol=3e-7, atol=0,
+                                   err_msg=k)
+
+
+@pytest.mark.parametrize("with_add", [False, True])
+@pytest.mark.parametrize("fill", U_FILLS)
+def test_fused_ftrl_update_does_not_read_w(fill, with_add):
+    """FTRL's w is written and never read: garbage in the stored w of a
+    touched tile changes nothing the step writes there, and garbage in
+    an untouched tile comes through in place (the alias holds)."""
+    run, state, ts, _, _ = _update_case("ftrl", fill, with_add)
+    want, nw_want = run()
+    junk = np.random.default_rng(7).normal(size=TILE).astype(np.float32)
+    junk[::5] = 0
+    state["w"][2 * TILE:] = junk            # touched: keys 5, 77, 4000
+    state["w"][TILE:2 * TILE] = junk        # no key of the batch
+    got, nw = run()
+    assert nw == nw_want        # counted from the derived weight too
+    for k in want:
+        np.testing.assert_array_equal(got[k][2 * TILE:], want[k][2 * TILE:])
+        np.testing.assert_array_equal(got[k][:TILE], want[k][:TILE])
+    np.testing.assert_array_equal(got["w"][TILE:2 * TILE], junk)
 
 
 def _coo_case(fill, num_rows=256):

@@ -387,6 +387,42 @@ def test_every_form_of_a_batch_takes_the_one_path(kind):
 
 
 @pytest.mark.parametrize("kind", list(_KINDS))
+def test_ftrl_w_is_the_derived_table_of_z_and_n(kind):
+    """What lets FTRL's update write w without reading it: on every
+    bucket the stored w is `ftrl_weight` of the stored z and n, bit for
+    bit, after steps from zero tables and again after a checkpoint's
+    round trip through the host and a further step; and the learner
+    that took the round trip holds what one that never left the device
+    holds."""
+    import jax
+    import jax.numpy as jnp
+
+    from wormhole_tpu.ops.fused_update import ftrl_weight
+
+    def derived(t, cfg):
+        # under jit, as the step forms it: XLA folds the constants
+        return np.asarray(jax.jit(lambda z, n: ftrl_weight(
+            z, jnp.sqrt(n), cfg.lr_eta, cfg.lr_beta, cfg.lambda_l1,
+            cfg.lambda_l2))(t["z"], t["n"]))
+
+    b0, b1, b2 = _kind_blocks()
+    lrn = _kind_learner(kind)
+    lrn.train_batch(b0)
+    lrn.train_batch(b1)
+    t = lrn.store.to_numpy()
+    assert np.count_nonzero(t["w"]) > 100
+    np.testing.assert_array_equal(t["w"], derived(t, lrn.cfg))
+    back = _kind_learner(kind)
+    back.store.from_numpy(t)
+    progs = [x.train_batch(b2) for x in (lrn, back)]
+    assert progs[0] == progs[1]
+    t, tb = lrn.store.to_numpy(), back.store.to_numpy()
+    for k in t:
+        np.testing.assert_array_equal(tb[k], t[k], err_msg=k)
+    np.testing.assert_array_equal(t["w"], derived(t, lrn.cfg))
+
+
+@pytest.mark.parametrize("kind", list(_KINDS))
 def test_a_batch_staged_for_the_other_step_is_refused(kind):
     lrn = _kind_learner(kind)
     blk = _kind_blocks(1)[0]

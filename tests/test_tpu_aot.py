@@ -92,6 +92,27 @@ def aot(kernel, fn, sharding, *shapes):
                          for c in calls), (kernel, calls)
 
 
+def streamed_tables(fn, *shapes):
+    """How the fused update's one Pallas call takes its inputs: the
+    number the pipeline streams by (TILE_HI, LANES) tile, and the number
+    left whole in HBM (memory space ANY), which get no DMA."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    jaxpr = jax.make_jaxpr(fn)(*[jax.ShapeDtypeStruct(*s) for s in shapes])
+    (call,) = calls(jaxpr.jaxpr)
+    gm = call.params["grid_mapping"]
+    avals = [str(bm.transformed_block_aval)
+             for bm in gm.block_mappings[:gm.num_inputs]]
+    tile = f"float32[{ck.TILE_HI},{ck.LANES}]"
+    return (sum(a == f"Ref{{{tile}}}" for a in avals),
+            sum(a.startswith("Ref<any>") for a in avals))
+
+
 def coo_stream(capacity, num_buckets, tile=None, blk=None):
     """(idx, seg, val, tmap, first) shapes of a packed COO stream."""
     p = ck.packed_size(capacity, num_buckets, tile, blk)
@@ -167,8 +188,12 @@ def test_compacted_linear_kernels(v5e, dtype):
             lr_eta=0.1, lr_beta=1.0, lambda_l1=1.0, lambda_l2=0.0,
             dtype=dtype)
 
-    aot("fused_update", update, v5e, *[((NB_BIG,), f32)] * 3,
-        ((U_CAP,), f32), ((U_CAP,), i32), *slot_blocks(U_CAP, 3))
+    shapes = (*[((NB_BIG,), f32)] * 3, ((U_CAP,), f32), ((U_CAP,), i32),
+              *slot_blocks(U_CAP, 3))
+    aot("fused_update", update, v5e, *shapes)
+    # FTRL streams z and n in by tile and no w: w is derived, written
+    # through its alias and never read (ops/fused_update.apply_handle)
+    assert streamed_tables(update, *shapes) == (2, 1)
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
@@ -192,9 +217,10 @@ def test_compacted_linear_kernels_at_the_benchmarks_table(v5e, dtype):
             lr_eta=0.1, lr_beta=1.0, lambda_l1=4.0, lambda_l2=0.0,
             dtype=dtype)
 
-    aot("fused_update", update, v5e, *[((NB_1TB,), f32)] * 3,
-        ((U_CAP_1TB,), f32), ((U_CAP_1TB,), i32),
-        *slot_blocks(U_CAP_1TB, 3))
+    shapes = (*[((NB_1TB,), f32)] * 3, ((U_CAP_1TB,), f32),
+              ((U_CAP_1TB,), i32), *slot_blocks(U_CAP_1TB, 3))
+    aot("fused_update", update, v5e, *shapes)
+    assert streamed_tables(update, *shapes) == (2, 1)
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
@@ -230,8 +256,11 @@ def test_fused_update_other_handles(v5e, algo, tables):
                                  lr_beta=1.0, lambda_l1=1.0, lambda_l2=0.0,
                                  fixed_bytes=1, dtype=jnp.bfloat16)
 
-    aot("fused_update", update, v5e, *[((NB_BIG,), f32)] * tables,
-        ((U_CAP,), f32), ((U_CAP,), i32), *slot_blocks(U_CAP, 3))
+    shapes = (*[((NB_BIG,), f32)] * tables, ((U_CAP,), f32),
+              ((U_CAP,), i32), *slot_blocks(U_CAP, 3))
+    aot("fused_update", update, v5e, *shapes)
+    # their w is state, not derived: every table is read by tile
+    assert streamed_tables(update, *shapes) == (tables, 0)
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
@@ -273,9 +302,10 @@ def test_fm_kernels(v5e, dtype, dim, stride, rows):
             la, lr_eta=0.1, lr_beta=1.0, lambda_l1=1.0, lambda_l2=0.0,
             dtype=dtype, add_table="cnt", add_values=wcnts)
 
-    aot("fused_update", update, v5e, *[((NB_DENSE,), f32)] * 4,
-        ((UW_CAP,), f32), ((UW_CAP,), i32), *slot_blocks(UW_CAP, 3),
-        ((UW_CAP,), f32))
+    shapes = (*[((NB_DENSE,), f32)] * 4, ((UW_CAP,), f32),
+              ((UW_CAP,), i32), *slot_blocks(UW_CAP, 3), ((UW_CAP,), f32))
+    aot("fused_update", update, v5e, *shapes)
+    assert streamed_tables(update, *shapes) == (3, 1)    # z, n, cnt; w
 
 
 def test_gbdt_histogram(v5e):
@@ -418,3 +448,35 @@ def test_tcoo_step_pulls_and_pushes_over_one_stream(v5e):
         "{0,1:T(2,128)S(1)} %param_0.2, s32[2555904]{0:T(1024)} "
         "%transpose.5), offset_dims={1}") == U_CAP_1TB + 1
     assert all(r is None or r < U_CAP_1TB for r in map(gathered_rows, lines))
+
+
+def test_dense_ftrl_step_reads_w_for_the_pull_alone(v5e):
+    """The dense kinds' train step (`_dense_steps`: `coo` here, the same
+    `_update` under the mesh kinds), lowered for the chip at the
+    headline table: the stored w is the pull's operand and nothing
+    else's. The update forms the old weight from z and n, so its sweep
+    over the tables reads g, z, n and writes z, n, w, and the |w|_0
+    count rides in it; a second reader of `state['w']` would be the
+    seventh stream back."""
+    from wormhole_tpu.models.linear import LinearConfig, LinearLearner
+    from wormhole_tpu.parallel.mesh import make_mesh
+
+    cfg = LinearConfig(minibatch=ROWS, nnz_per_row=NNZ,
+                       num_buckets=NB_DENSE, algo="ftrl", lr_eta=0.1,
+                       lambda_l1=4.0, kernel="pallas", kernel_dtype="bf16",
+                       compact_cap=0)
+    lrn = LinearLearner(cfg, make_mesh(1, 1))
+
+    def shaped(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    f32 = jnp.float32
+    step = lrn._kinds["coo"].train.lower(
+        {k: shaped((NB_DENSE,), f32) for k in lrn.store.state},
+        *[shaped(s, d) for s, d in coo_stream(CAP, NB_DENSE)],
+        shaped((ROWS,), f32), shaped((ROWS,), f32)).compile()
+    readers = [ln for ln in hlo_lines(step)
+               if re.search(r"\(.*%state__w__", ln)
+               and not ln.startswith("ENTRY")]
+    assert len(readers) == 1 and readers[0].startswith("%coo_pull"), readers
+    assert progress_outputs(step) == [(8,)]

@@ -376,7 +376,8 @@ class DifactoLearner:
 
             # ---- updates: w by FTRL, V by AdaGrad ------------------------
             lin_state = {"w": state["w"], "z": state["z"], "n": state["n"]}
-            lin_new = linmod._update("ftrl", lin_state, gw, touched_w, cfg)
+            lin_new, new_w = linmod._update("ftrl", lin_state, gw,
+                                            touched_w, cfg)
             new_state.update(lin_new)
 
             nV = vstate["nV"] + touched_v * gV * gV
@@ -385,8 +386,6 @@ class DifactoLearner:
             new_vstate["V"] = jnp.where(touched_v > 0, V_new, V)
             new_vstate["nV"] = nV
 
-            new_w = (jnp.sum(new_state["w"] != 0)
-                     - jnp.sum(w != 0)).astype(jnp.float32)
             prog = linmod._progress(obj, margin, label, mask, new_w)
             obj_w, _ = linmod._loss_dual(cfg.loss, label, xw)
             prog["objv_w"] = jnp.sum(obj_w * mask)

@@ -38,7 +38,7 @@ from wormhole_tpu.obs import trace as _trace
 from wormhole_tpu.obs.metrics import REGISTRY
 from wormhole_tpu.ops import coo_kernels as ck
 from wormhole_tpu.ops import metrics as M
-from wormhole_tpu.ops.penalty import l1l2_solve
+from wormhole_tpu.ops.fused_update import apply_handle, scatter_update
 from wormhole_tpu.ops.spmv import spmv, spmv_t
 from wormhole_tpu.parallel.kvstore import KVStore, TableSpec, quantize_push
 from wormhole_tpu.parallel.mesh import (batch_sharding, describe_placement,
@@ -257,41 +257,29 @@ def _loss_dual(loss: str, y01, xw):
 
 
 def _update(algo: str, state, g, touched, cfg: LinearConfig):
-    """Per-bucket update rules (reference async_sgd.h:71-180 handles).
-
-    touched masks buckets that received a push this step, so regularizer
-    shrinkage applies exactly when the reference's per-key Push would run.
-    """
-    out = dict(state)
-    if algo == "ftrl":
-        w, z, n = state["w"], state["z"], state["n"]
-        sigma = (jnp.sqrt(n + g * g) - jnp.sqrt(n)) / cfg.lr_eta
-        z = z + touched * (g - sigma * w)
-        n = n + touched * g * g
-        eta = (cfg.lr_beta + jnp.sqrt(n)) / cfg.lr_eta
-        w_new = l1l2_solve(-z, eta, cfg.lambda_l1, cfg.lambda_l2)
-        out["w"] = jnp.where(touched > 0, w_new, w)
-        out["z"], out["n"] = z, n
-    elif algo == "adagrad":
-        w, n = state["w"], state["n"]
-        n = n + touched * g * g
-        eta = (cfg.lr_beta + jnp.sqrt(n)) / cfg.lr_eta
-        w_new = l1l2_solve(eta * w - g, eta, cfg.lambda_l1, cfg.lambda_l2)
-        out["w"] = jnp.where(touched > 0, w_new, w)
-        out["n"] = n
-    elif algo == "sgd":
-        w = state["w"]
-        eta = 1.0 / cfg.lr_eta  # constant step size lr_eta
-        w_new = l1l2_solve(eta * w - g, eta, cfg.lambda_l1, cfg.lambda_l2)
-        out["w"] = jnp.where(touched > 0, w_new, w)
-    else:
-        raise ValueError(f"unknown algo {algo!r}")
-    return out
+    """Per-bucket update rules over whole tables: the handle math the
+    fused kernel applies to a tile (ops/fused_update.apply_handle).
+    Returns (new_state, new_w), new_w the step's |w|_0 delta. FTRL's
+    state["w"] is written and not read: it is the derived table."""
+    z2, n2, w2, w_old = apply_handle(
+        algo, state.get("z"), state.get("n"),
+        None if algo == "ftrl" else state["w"], g, touched,
+        lr_eta=cfg.lr_eta, lr_beta=cfg.lr_beta, lambda_l1=cfg.lambda_l1,
+        lambda_l2=cfg.lambda_l2)
+    out = dict(state, w=w2)
+    if z2 is not None:
+        out["z"] = z2
+    if n2 is not None:
+        out["n"] = n2
+    new_w = (jnp.sum(w2 != 0) - jnp.sum(w_old != 0)).astype(jnp.float32)
+    return out, new_w
 
 
 def _tables_for(algo: str) -> dict[str, TableSpec]:
     t = {"w": TableSpec()}
     if algo == "ftrl":
+        # z, n are the state; w is their derived table (derived_tables),
+        # written by the update and read by the pull alone
         t["z"] = TableSpec()
         t["n"] = TableSpec(wire_cap="bf16")  # second moment: see TableSpec
     elif algo == "adagrad":
@@ -485,8 +473,7 @@ class LinearLearner:
         @partial(jax.jit, donate_argnums=0)
         def train_step(state, *args):
             *batch, label, mask = args
-            w = state["w"]
-            xw = pull(w, *batch, label.shape[0])
+            xw = pull(state["w"], *batch, label.shape[0])
             obj, d = _loss_dual(cfg.loss, label, xw)
             d = d * mask
             g = push(d, *batch, cfg.num_buckets)
@@ -508,9 +495,7 @@ class LinearLearner:
                 touched = 1.0
             else:
                 touched = (raw_g != 0).astype(jnp.float32)
-            new_state = _update(cfg.algo, state, g, touched, cfg)
-            new_w = (jnp.sum(new_state["w"] != 0)
-                     - jnp.sum(w != 0)).astype(jnp.float32)
+            new_state, new_w = _update(cfg.algo, state, g, touched, cfg)
             return new_state, pack_progress(
                 _progress(obj, xw, label, mask, new_w), TRAIN_KEYS)
 
@@ -554,8 +539,6 @@ class LinearLearner:
 
     def _build_tcoo(self, U: int):
         cfg, dt = self.cfg, self._coo_dtype
-        from wormhole_tpu.ops.fused_update import scatter_update
-
         def pull_c(w, uniq, tmap_u, sidx, sseg, sval, tmap, first, rows):
             # the touched weights into the compact domain, then the
             # radix-image kernel over the COO stream the push walks. Not

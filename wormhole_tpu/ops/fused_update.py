@@ -13,17 +13,23 @@ tiles are never streamed.
 
 Cost: the scatter's one-hot operands are built only over a block's live
 slots (a prefix of the block, whose extent scatter_update derives on the
-device; coo_kernels._live_chunks), so a touched tile costs its three
-tiles in and out plus the handle math, whatever the block's capacity:
-at 2^29 buckets, ~30 keys in each of 8,192 tiles, ~2.6 us a tile for
-1.5 MB of traffic (measured on v5e, PERF.md §5). A block that both
-opens and closes its tile's run skips the copy-through: the apply
-overwrites every output tile anyway.
+device; coo_kernels._live_chunks), so a touched tile costs its tiles in
+and out plus the handle math, whatever the block's capacity. FTRL
+streams two tiles in (z, n) and three out (z, n, w): at 2^29 buckets,
+~30 keys in each of 8,192 tiles, ~2.3 us a tile for 1.25 MB of traffic,
+of it ~2.2 the streams' and the scatter's and the rest handle math that
+the DMA does not hide (measured on v5e, PERF.md §5, §6 PR 44; ~2.6 us
+for 1.5 MB while w was read). A block that both opens and closes its
+tile's run skips the copy-through: the apply overwrites every output
+tile anyway.
 
-Semantics match models/linear._update exactly:
-- FTRL: w is a pure function of (z, n); entries with zero gradient
-  round-trip unchanged, so updating the whole tile is a no-op exactly
-  where the reference would not receive a push.
+Semantics are models/linear._update's, which runs the same
+apply_handle over whole tables:
+- FTRL: w is a pure function of (z, n), the derived table: the update
+  forms the old weight from the z and n it holds and writes w without
+  reading it. Entries with zero gradient round-trip unchanged, so
+  updating the whole tile is a no-op exactly where the reference would
+  not receive a push.
 - AdaGrad/SGD: repeated L1 shrinkage must only hit pushed keys, so the
   tile update is masked by g != 0 (the touched mask).
 - fixed_bytes: the push-quantization filter applies to the scattered
@@ -52,6 +58,14 @@ from wormhole_tpu.ops.coo_kernels import (_VMEM_LIMIT, BLK_U, LANES,
 from wormhole_tpu.ops.penalty import l1l2_solve
 
 
+# Rows of a tile the apply takes at a time, unrolled. Measured on v5e at
+# 2^29 buckets (PERF.md §6, PR 44), ms a step / seconds the step's first
+# call takes with the compile cache warm (every unrolled group is
+# lowered again, in Python): 32 rows 18.88 / 1.40, 128 rows 18.95 /
+# 1.01, 256 rows 19.53, the whole tile 19.96 / 0.87
+APPLY_ROWS = 128
+
+
 def _quantize(g, fixed_bytes: int, qscale):
     """In-kernel mirror of parallel.kvstore.quantize_push: bf16 rounding
     for fixed_bytes >= 2, global-absmax int8 for fixed_bytes == 1."""
@@ -64,28 +78,45 @@ def _quantize(g, fixed_bytes: int, qscale):
     return q * qscale
 
 
-def _apply(algo: str, z, n, w, g, touched, *, lr_eta, lr_beta,
-           lambda_l1, lambda_l2):
-    """The per-entry handle math of models/linear._update, on a tile."""
+def ftrl_weight(z, sq, lr_eta, lr_beta, lambda_l1, lambda_l2):
+    """FTRL's weight from z and sq = sqrt(n): the derived table."""
+    return l1l2_solve(-z, (lr_beta + sq) / lr_eta, lambda_l1, lambda_l2)
+
+
+def apply_handle(algo: str, z, n, w, g, touched, *, lr_eta, lr_beta,
+                 lambda_l1, lambda_l2):
+    """The per-entry handle math (reference async_sgd.h:71-180), on
+    whatever shape the tables come in: a kernel's tile here, a whole
+    table in models/linear._update. touched masks entries that received
+    a push, so that regularizer shrinkage applies exactly when the
+    reference's per-key Push would run. Returns (z2, n2, w2, w_old),
+    None for a table the algo lacks; w_old is the weight before the
+    update.
+
+    FTRL does not read w (pass None): the stored weight is
+    ftrl_weight of the stored z and n, written by this same rule one
+    update earlier (or zero over zero tables), and forming it again is
+    the same operations on the same values, the stored weight bit for
+    bit. With lr_beta = 0 = lambda_l2 an entry with n = 0 divides by
+    zero (docs/linear.md)."""
     if algo == "ftrl":
-        sigma = (jnp.sqrt(n + g * g) - jnp.sqrt(n)) / lr_eta
+        hyper = (lr_eta, lr_beta, lambda_l1, lambda_l2)
+        sq = jnp.sqrt(n)
+        w = ftrl_weight(z, sq, *hyper)
+        sigma = (jnp.sqrt(n + g * g) - sq) / lr_eta
         z2 = z + touched * (g - sigma * w)
         n2 = n + touched * g * g
-        eta = (lr_beta + jnp.sqrt(n2)) / lr_eta
-        w2 = l1l2_solve(-z2, eta, lambda_l1, lambda_l2)
-        w2 = jnp.where(touched > 0, w2, w)
-        return z2, n2, w2
+        w2 = ftrl_weight(z2, jnp.sqrt(n2), *hyper)
+        return z2, n2, jnp.where(touched > 0, w2, w), w
     if algo == "adagrad":
         n2 = n + touched * g * g
         eta = (lr_beta + jnp.sqrt(n2)) / lr_eta
         w2 = l1l2_solve(eta * w - g, eta, lambda_l1, lambda_l2)
-        w2 = jnp.where(touched > 0, w2, w)
-        return None, n2, w2
+        return None, n2, jnp.where(touched > 0, w2, w), w
     if algo == "sgd":
-        eta = 1.0 / lr_eta
+        eta = 1.0 / lr_eta  # constant step size lr_eta
         w2 = l1l2_solve(eta * w - g, eta, lambda_l1, lambda_l2)
-        w2 = jnp.where(touched > 0, w2, w)
-        return None, None, w2
+        return None, None, jnp.where(touched > 0, w2, w), w
     raise ValueError(f"unknown algo {algo!r}")
 
 
@@ -94,12 +125,15 @@ def _kernel(tmap_ref, first_ref, last_ref, ext_ref, qscale_ref, g_ref,
             hyper: dict, n_state: int, with_add: bool):
     # refs = [add values (if with_add)] + state-in tiles (n_state, plus
     # the additive table last if with_add), then the matching out tiles,
-    # then nw_out, then the g_acc scratch (+ add_acc scratch)
+    # then nw_out, then the g_acc scratch (+ add_acc scratch). FTRL's w
+    # comes in as the whole table in HBM, aliased onto its output and
+    # never read: the ref at `derived`
     add_ref = refs[0] if with_add else None
     refs = refs[1:] if with_add else refs
     n_tabs = n_state + (1 if with_add else 0)
     in_refs = refs[:n_tabs]
     out_refs = refs[n_tabs:2 * n_tabs]
+    derived = 2 if algo == "ftrl" else None     # w among z, n, w
     nw_ref = refs[2 * n_tabs]
     acc_ref = refs[2 * n_tabs + 1]
     add_acc = refs[2 * n_tabs + 2] if with_add else None
@@ -120,8 +154,12 @@ def _kernel(tmap_ref, first_ref, last_ref, ext_ref, qscale_ref, g_ref,
     # its tile's run overwrites all of them below
     @pl.when((first_ref[b] == 1) & (last_ref[b] == 0))
     def _():
-        for i_ref, o_ref in zip(in_refs, out_refs):
-            o_ref[:] = i_ref[:]
+        for k, (i_ref, o_ref) in enumerate(zip(in_refs, out_refs)):
+            if k == derived:
+                o_ref[:] = ftrl_weight(in_refs[0][:],
+                                       jnp.sqrt(in_refs[1][:]), **hyper)
+            else:
+                o_ref[:] = i_ref[:]
 
     base = tmap_ref[b] * TILE
 
@@ -160,27 +198,39 @@ def _kernel(tmap_ref, first_ref, last_ref, ext_ref, qscale_ref, g_ref,
 
     @pl.when(last_ref[b] == 1)
     def _():
-        raw_g = acc_ref[:]
-        g = _quantize(raw_g, fixed_bytes, qscale_ref[0])
-        if algo == "ftrl":
-            touched = 1.0
-            z, n, w = in_refs[0][:], in_refs[1][:], in_refs[2][:]
-        else:
-            touched = (raw_g != 0).astype(jnp.float32)
-            z = None
-            n = in_refs[0][:] if algo == "adagrad" else None
-            w = in_refs[n_state - 1][:]
-        w_old = w if algo != "ftrl" else in_refs[2][:]
-        z2, n2, w2 = _apply(algo, z, n, w, g, touched, **hyper)
-        outs = {"ftrl": (z2, n2, w2), "adagrad": (n2, w2),
-                "sgd": (w2,)}[algo]
-        for o_ref, v in zip(out_refs[:n_state], outs):
-            o_ref[:] = v
-        if with_add:
-            out_refs[n_state][:] = in_refs[n_state][:] + add_acc[:]
-        delta = (jnp.sum((w2 != 0).astype(jnp.float32))
-                 - jnp.sum((w_old != 0).astype(jnp.float32)))
-        nw_ref[:] += delta
+        # the handle math APPLY_ROWS rows at a time, so that a row
+        # group's values stay near the registers from its loads to its
+        # stores: over the whole tile at once every intermediate is the
+        # size of the register file and goes through VMEM
+        def rows(i, count):
+            r = pl.ds(pl.multiple_of(i * APPLY_ROWS, APPLY_ROWS),
+                      APPLY_ROWS)
+            raw_g = acc_ref[r, :]
+            g = _quantize(raw_g, fixed_bytes, qscale_ref[0])
+            if algo == "ftrl":
+                touched = 1.0
+                z, n, w = in_refs[0][r, :], in_refs[1][r, :], None
+            else:
+                touched = (raw_g != 0).astype(jnp.float32)
+                z = None
+                n = in_refs[0][r, :] if algo == "adagrad" else None
+                w = in_refs[n_state - 1][r, :]
+            z2, n2, w2, w_old = apply_handle(algo, z, n, w, g, touched,
+                                             **hyper)
+            outs = {"ftrl": (z2, n2, w2), "adagrad": (n2, w2),
+                    "sgd": (w2,)}[algo]
+            for o_ref, v in zip(out_refs[:n_state], outs):
+                o_ref[r, :] = v
+            if with_add:
+                out_refs[n_state][r, :] = (in_refs[n_state][r, :]
+                                           + add_acc[r, :])
+            return count + ((w2 != 0).astype(jnp.float32)
+                            - (w_old != 0).astype(jnp.float32))
+
+        count = jax.lax.fori_loop(
+            0, TILE_HI // APPLY_ROWS, rows,
+            jnp.zeros((APPLY_ROWS, LANES), jnp.float32), unroll=True)
+        nw_ref[:] += jnp.sum(count)
 
 
 # ------------------------------------------------ vector rows, by lane line
@@ -264,6 +314,12 @@ def scatter_update(algo: str, state: dict, g, uniq, tmap_u, first_u,
         return (tmap[b], 0)
 
     ext = block_extents(uniq != num_buckets, BLK_U)
+    # FTRL's w is derived from (z, n) and never read (apply_handle): it
+    # stays in HBM, no DMA is issued for it, and the alias onto its
+    # tile-mapped output brings untouched tiles through in place.
+    # AdaGrad's and SGD's w is state, streamed in like the rest
+    w_in = (pl.BlockSpec(memory_space=pl.ANY) if algo == "ftrl"
+            else pl.BlockSpec((TILE_HI, LANES), tile_map))
     add_specs = ([pl.BlockSpec((BLK_U,), lambda b, *_: (b,))]
                  if with_add else [])
     add_args = [add_values] if with_add else []
@@ -274,7 +330,8 @@ def scatter_update(algo: str, state: dict, g, uniq, tmap_u, first_u,
             pl.BlockSpec((BLK_U,), lambda b, *_: (b,)),   # g
             pl.BlockSpec((BLK_U,), lambda b, *_: (b,)),   # uniq
         ] + add_specs
-        + [pl.BlockSpec((TILE_HI, LANES), tile_map) for _ in tabs],
+        + [w_in if k == "w" else pl.BlockSpec((TILE_HI, LANES), tile_map)
+           for k in order],
         out_specs=[pl.BlockSpec((TILE_HI, LANES), tile_map)
                    for _ in tabs] + [
             pl.BlockSpec((8, LANES), lambda b, *_: (0, 0))],
