@@ -6,7 +6,9 @@ cell: it shows that `run.py` carries a job that is no minibatch-solver
 run by a module its configuration names, and what such a module needs of
 `run.py` (nothing but `T_START`; `say` and `memory_peak_bytes` come from
 `benchmark.drivers`). tests/benchmark lays it into a copy of the
-benchmark as `benchmark/drivers/batch_driver.py`.
+benchmark as `benchmark/drivers/batch_driver.py`. Since PR 46
+test_benchmark_takes_a_cell.py also lays it into a copy of the real
+`BENCHMARK.json`, beside which the copy's own tests stay green.
 
 Set-up: rows from the seed (`gen.Dataset`), folded into `num_feature`
 raw ids (`load_batches` takes int32 ids and Criteo keys are 64 bits:

@@ -277,6 +277,8 @@ def test_a_new_cell_is_new_files_and_one_entry_each(tmp_path, bench):
         "moves": "train_ex_per_s", "workloads": ["kaggle.replay-small"]})
     # since PR 43 the rate lists its cells (the stream cell's is a layer
     # metric): a cell that reports it appends its name, as to any list
+    # (which lists a cell appends to, and that the tests beside this one
+    # then stay green: test_benchmark_takes_a_cell.py)
     (rate,) = [m for m in new["end_to_end"] if m["name"] == "train_ex_per_s"]
     rate["workloads"].append("kaggle.replay-small")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(new))

@@ -33,9 +33,12 @@ def test_the_metric_s_file_loads_and_lists_the_compact_cells_only():
         "kernels", "train_ex_per_s", "lower")
     assert os.path.isfile(os.path.join(BENCH, "reducers",
                                        spec["reducer"] + ".py"))
-    # the cells whose step is the compact one: those of the one-chip
-    # linear configuration, whose kind is tcoo, and no other
+    # the cells whose step is the compact one: those of a configuration
+    # whose kind is tcoo (by rule since PR 46: the one-chip linear
+    # configuration's, and a later one's of that kind), and no other
     cells = {w["name"]: w for w in bench["workloads"]}
+    kinds = {c["name"]: _json(REPO, c["file"])["expect_kind"]
+             for c in bench["configs"]}
     (twin,) = [m for m in bench["per_layer"]
                if m["name"] == "tcoo_pull_ms.stream"]
     assert _json(BENCH, "layer_metrics", "tcoo_pull_ms.stream.json")[
@@ -43,8 +46,9 @@ def test_the_metric_s_file_loads_and_lists_the_compact_cells_only():
     # (the stream cell reads it under the twin's name since PR 43)
     listed = set(entry["workloads"]) | set(twin["workloads"])
     assert {"criteo1tb.crb-stream", "criteo1tb.replay"} <= listed
-    assert all(cells[n]["config"] == CONFIG for n in listed)
-    assert _json(BENCH, "configs", CONFIG + ".json")["expect_kind"] == "tcoo"
+    assert all(kinds[cells[n]["config"]] == "tcoo" for n in listed)
+    assert kinds[CONFIG] == "tcoo"
+    assert cells["criteo1tb.replay"]["config"] == CONFIG
     # the pull and nothing else: the op as a device trace names it, not
     # the mesh cell's metric of the same kernel's push, nor a fusion
     rx = re.compile(spec["params"]["pattern"])
