@@ -26,7 +26,8 @@ from wormhole_tpu.solver.lbfgs import LBFGSConfig, LBFGSSolver
 @dataclasses.dataclass
 class LbfgsLinearConfig:
     """Key surface of the reference lbfgs.cc SetParam loop (:236-241):
-    reg_L1, max_lbfgs_iter, lbfgs_stop_tol, model_in/out, task."""
+    reg_L1, max_lbfgs_iter, lbfgs_stop_tol, max_linesearch_iter,
+    model_in/out, task."""
 
     data: str = ""
     test_data: Optional[str] = None
@@ -39,10 +40,18 @@ class LbfgsLinearConfig:
     reg_L2: float = 0.0
     max_lbfgs_iter: int = 30
     lbfgs_stop_tol: float = 1e-7
+    # backtracking trials an iteration before the job gives up: from
+    # w = 0 the first step along -g has to shrink by about 1 / rows
+    # (22 halvings at a million rows)
+    max_linesearch_iter: int = 20
     m: int = 10
     minibatch: int = 4096
     nnz_per_row: int = 64
     num_parts_per_file: int = 1
+    # 0 discovers the dimension as the reference does (max id + 1,
+    # lbfgs.cc:107-113); over 0 it is the dimension, and column ids are
+    # folded `mod num_feature` (64-bit keys, e.g. the Criteo format's)
+    num_feature: int = 0
     # multi-process SPMD over one jax.distributed mesh: the weight vector
     # and history shard over every process's devices (the reference's
     # rank partition, lbfgs.h:127-136) and all dot products ride the
@@ -53,6 +62,28 @@ class LbfgsLinearConfig:
     # partitioned, gradient/loss reduced over the ring — the reference's
     # rabit layout, fault-tolerant via version checkpoints
     bsp: bool = False
+
+
+def _solver_config(cfg) -> LBFGSConfig:
+    return LBFGSConfig(
+        max_iter=cfg.max_lbfgs_iter, m=cfg.m, reg_l1=cfg.reg_L1,
+        reg_l2=cfg.reg_L2, min_rel_decrease=cfg.lbfgs_stop_tol,
+        max_linesearch=cfg.max_linesearch_iter)
+
+
+def make_solver(cfg, mesh=None):
+    """The single-process training job of `cfg`, ready to run: its rows
+    resident on the device, the objective over them and the solver.
+    Returns (solver, obj, batches, num_feature). `main` runs the job
+    through this, and so does anything that drives it from outside (the
+    benchmark's batch driver steps `solver.run(on_iter=...)`)."""
+    mesh = make_mesh() if mesh is None else mesh
+    batches, num_feature = load_batches(
+        cfg.data, mesh, cfg.data_format, cfg.minibatch, cfg.nnz_per_row,
+        cfg.num_parts_per_file, cfg.num_feature)
+    obj = LinearObjFunction(batches, num_feature, mesh)
+    return (LBFGSSolver(obj, _solver_config(cfg)), obj, batches,
+            num_feature)
 
 
 def _global_worker_body(cfg, env, client) -> int:
@@ -68,9 +99,7 @@ def _global_worker_body(cfg, env, client) -> int:
         cfg.data, mesh, env, cfg.data_format, cfg.minibatch,
         cfg.nnz_per_row, cfg.num_parts_per_file)
     obj = LinearObjFunction(batches, num_feature, mesh)
-    solver = LBFGSSolver(obj, LBFGSConfig(
-        max_iter=cfg.max_lbfgs_iter, m=cfg.m, reg_l1=cfg.reg_L1,
-        reg_l2=cfg.reg_L2, min_rel_decrease=cfg.lbfgs_stop_tol))
+    solver = LBFGSSolver(obj, _solver_config(cfg))
     # every rank drives the identical host loop on identical global
     # scalars, so all jitted collectives stay in lockstep
     w, objv = solver.run(verbose=(rank == 0))
@@ -103,10 +132,7 @@ def _bsp_worker_body(cfg, env, client, comm) -> int:
         cfg.data, mesh, env, client, cfg.data_format, cfg.minibatch,
         cfg.nnz_per_row, cfg.num_parts_per_file)
     obj = LinearObjFunction(batches, num_feature, mesh)
-    solver = LBFGSSolver(obj, LBFGSConfig(
-        max_iter=cfg.max_lbfgs_iter, m=cfg.m, reg_l1=cfg.reg_L1,
-        reg_l2=cfg.reg_L2, min_rel_decrease=cfg.lbfgs_stop_tol),
-        comm=comm)
+    solver = LBFGSSolver(obj, _solver_config(cfg), comm=comm)
     # every rank drives the identical host loop on identical reduced
     # scalars; w is replicated, so rank 0 alone saves it
     w, objv = solver.run(verbose=(rank == 0))
@@ -165,13 +191,7 @@ def main(argv=None) -> int:
         print(f"wrote {n} predictions to {cfg.pred_out}")
         return 0
 
-    batches, num_feature = load_batches(
-        cfg.data, mesh, cfg.data_format, cfg.minibatch, cfg.nnz_per_row,
-        cfg.num_parts_per_file)
-    obj = LinearObjFunction(batches, num_feature, mesh)
-    solver = LBFGSSolver(obj, LBFGSConfig(
-        max_iter=cfg.max_lbfgs_iter, m=cfg.m, reg_l1=cfg.reg_L1,
-        reg_l2=cfg.reg_L2, min_rel_decrease=cfg.lbfgs_stop_tol))
+    solver, _, _, num_feature = make_solver(cfg, mesh)
     w, objv = solver.run()
     print(f"final objective: {objv:.6f}")
     if cfg.model_out:

@@ -30,31 +30,53 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from wormhole_tpu.data.rowblock import to_device_batch
 from wormhole_tpu.parallel.mesh import batch_sharding
+from wormhole_tpu.solver.lbfgs import fetch
 from wormhole_tpu.solver.workload import iter_rowblocks
+
+
+# a modulus that leaves every int32 id as it is: "no fold"
+_RAW_IDS = 2 ** 31 - 1
+
+
+def _max_id(blk) -> int:
+    """The largest raw column id of a block (-1 for an empty one)."""
+    return int(blk.index.max()) if blk.nnz else -1
+
+
+def _device_batch(blk, rows: int, cap: int, num_feature: int = 0):
+    """A block as a fixed-shape DeviceBatch of the batch solvers: the one
+    place their column ids are made. `num_feature` 0 keeps the raw ids,
+    no hash kernel (batch solvers use the true feature space like the
+    reference's RowBlockIter path); over 0 folds them `mod num_feature`
+    through `to_device_batch`'s `num_buckets`, the way 64-bit keys (the
+    Criteo format's) become columns. Either way the ids have to fit the
+    device's int32 index."""
+    top = num_feature - 1 if num_feature else _max_id(blk)
+    assert top < _RAW_IDS, "batch objectives need int32 ids"
+    return to_device_batch(blk, rows, cap, num_feature or _RAW_IDS)
+
+
+def _put(db, bsh):
+    """A DeviceBatch's five arrays resident on the device under `bsh`."""
+    return tuple(jax.device_put(x, bsh) for x in (
+        db.seg, db.idx, db.val, db.label, db.row_mask))
 
 
 def load_batches(pattern: str, mesh, fmt: str = "libsvm",
                  minibatch: int = 4096, nnz_per_row: int = 64,
-                 num_parts_per_file: int = 1):
+                 num_parts_per_file: int = 1, num_feature: int = 0):
     """Read all data into device-resident fixed-shape batches; returns
-    (batches, num_feature) with num_feature = max id + 1 over all shards
-    (the Allreduce<Max> of lbfgs.cc:107-113)."""
+    (batches, num_feature). `num_feature` 0 discovers it as max id + 1
+    over all shards (the Allreduce<Max> of lbfgs.cc:107-113); over 0 it
+    is the dimension given, and ids are folded into it."""
     bsh = batch_sharding(mesh, 1)
     batches = []
     max_id = -1
     for blk in iter_rowblocks(pattern, num_parts_per_file, fmt, minibatch):
-        if blk.nnz:
-            max_id = max(max_id, int(blk.index.max()))
-        # raw column ids, no hash kernel (batch solvers use the true
-        # feature space like the reference's RowBlockIter path); ids
-        # must fit the device index dtype
-        assert max_id < 2 ** 31 - 1, "batch objectives need int32 ids"
-        db = to_device_batch(blk, minibatch, minibatch * nnz_per_row,
-                             2 ** 31 - 1)
-        put = lambda x: jax.device_put(x, bsh)
-        batches.append((put(db.seg), put(db.idx), put(db.val),
-                        put(db.label), put(db.row_mask)))
-    return batches, max_id + 1
+        max_id = max(max_id, _max_id(blk))
+        batches.append(_put(_device_batch(
+            blk, minibatch, minibatch * nnz_per_row, num_feature), bsh))
+    return batches, num_feature or max_id + 1
 
 
 def load_batches_global(pattern: str, mesh, env, fmt: str = "libsvm",
@@ -78,8 +100,7 @@ def load_batches_global(pattern: str, mesh, env, fmt: str = "libsvm",
     for f, k in mh.rank_parts(pattern, num_parts_per_file, env):
         for blk in MinibatchIter(f, k, num_parts_per_file, fmt,
                                  minibatch_size=local_rows):
-            if blk.nnz:
-                max_id = max(max_id, int(blk.index.max()))
+            max_id = max(max_id, _max_id(blk))
             local.append(blk)
     n_batches = mh.global_scalar_max(len(local))
     num_feature = mh.global_scalar_max(max_id) + 1
@@ -88,7 +109,7 @@ def load_batches_global(pattern: str, mesh, env, fmt: str = "libsvm",
     out = []
     for i in range(n_batches):
         blk = local[i] if i < len(local) else empty
-        db = to_device_batch(blk, local_rows, local_cap, 2 ** 31 - 1)
+        db = _device_batch(blk, local_rows, local_cap)
         out.append(mh.global_coo_batch(bsh, db, rank, local_rows,
                                        minibatch, nnz_per_row))
     return out, num_feature
@@ -112,10 +133,8 @@ def load_batches_bsp(pattern: str, mesh, env, client, fmt: str = "libsvm",
     for f, k in mh.rank_parts(pattern, num_parts_per_file, env):
         for blk in MinibatchIter(f, k, num_parts_per_file, fmt,
                                  minibatch_size=minibatch):
-            if blk.nnz:
-                max_id = max(max_id, int(blk.index.max()))
+            max_id = max(max_id, _max_id(blk))
             local.append(blk)
-    assert max_id < 2 ** 31 - 1, "batch objectives need int32 ids"
     client.blob_put(f"{key}_{env.rank}", np.int64(max_id))
     if env.rank == 0 and not client.call(op="blob_get", key=key)["ok"]:
         dims = [int(client.blob_get(f"{key}_{r}", timeout=120))
@@ -123,13 +142,9 @@ def load_batches_bsp(pattern: str, mesh, env, client, fmt: str = "libsvm",
         client.blob_put(key, np.int64(max(dims)))
     num_feature = int(client.blob_get(key, timeout=120)) + 1
     bsh = batch_sharding(mesh, 1)
-    batches = []
-    for blk in local:  # a zero-part rank simply holds no batches
-        db = to_device_batch(blk, minibatch, minibatch * nnz_per_row,
-                             2 ** 31 - 1)
-        put = lambda x: jax.device_put(x, bsh)
-        batches.append((put(db.seg), put(db.idx), put(db.val),
-                        put(db.label), put(db.row_mask)))
+    # a zero-part rank simply holds no batches
+    batches = [_put(_device_batch(blk, minibatch, minibatch * nnz_per_row),
+                    bsh) for blk in local]
     return batches, num_feature
 
 
@@ -168,7 +183,9 @@ class _BatchObjBase:
         tot = jnp.zeros(())
         for b in self.batches:
             tot = tot + self._eval_batch(p, *b)
-        return float(tot)
+        # the pass's one blocking read, which waits for every batch's
+        # program: counted and spanned like the solver's own
+        return fetch(tot)
 
     def grad(self, p):
         g = jnp.zeros_like(p)

@@ -24,17 +24,56 @@ trials).
 OWL-QN specifics (lbfgs.h:358-407): pseudo-gradient at w=0, direction
 sign-fix against the pseudo-gradient, and orthant projection of each
 line-search trial point.
+
+What an iteration's time is made of is under spans (obs/names.py,
+`lbfgs.*`): a pass over the rows is dispatched without waiting and ends
+in a blocking read, so `lbfgs.grad_pass` and `lbfgs.obj_pass` each close
+on the read that waits for their device work, and `lbfgs.combine` on the
+read of pg . d where OWL-QN makes one. Nothing waits for a span's sake:
+the job's first gradient, at its starting point, is under none (the first
+objective pass's read waits for it). With no tracer and no profiler
+session a span is the shared no-op.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import os
+import threading
 from typing import Callable, Optional, Protocol
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from wormhole_tpu.obs import trace as _trace
+from wormhole_tpu.obs.metrics import REGISTRY
+
+_ITERS = REGISTRY.counter("lbfgs.iters")
+_PASSES = REGISTRY.counter("lbfgs.passes")
+_TRIALS = REGISTRY.counter("lbfgs.linesearch_trials")
+_HOST_SYNCS = REGISTRY.counter("lbfgs.host_syncs")
+
+# elements of each basis vector a step of the Gram matrix and of the
+# combine take, where a device holds the vectors whole
+_CHUNK = 1 << 22
+
+# .solver = the LBFGSSolver whose run() this thread is inside, so that a
+# read the objective makes on its behalf lands in its `host_syncs` too
+_RUNNING = threading.local()
+
+
+def fetch(x, read=float):
+    """One blocking device-to-host read (`read`: float for a scalar,
+    np.asarray for the Gram matrix), counted and under `lbfgs.fetch`:
+    the one way the solver and the batch objectives read the device, so
+    that `lbfgs.host_syncs` counts every read a pass makes."""
+    _HOST_SYNCS.inc()
+    solver = getattr(_RUNNING, "solver", None)
+    if solver is not None:
+        solver.host_syncs += 1
+    with _trace.span("lbfgs.fetch", cat="lbfgs"):
+        return read(x)
 
 
 class ObjFunction(Protocol):
@@ -129,18 +168,54 @@ class LBFGSSolver:
             m_ = self.obj.l1_mask()
             return jnp.where(keep | (m_ == 0), w_new, 0.0)
 
+        hi = jax.lax.Precision.HIGHEST  # float32 products on the MXU too
+        # a device that holds the vectors whole (one-device mesh) works
+        # through them _CHUNK elements at a time: the stacked basis is
+        # then a temporary of (2m + 1) x _CHUNK, not a second copy of
+        # 2m + 1 vectors (at 2^26 columns that copy does not fit a chip).
+        # Vectors no longer than a chunk are the one chunk. Sharded
+        # vectors are stacked whole, a shard's part a device.
+        whole = getattr(getattr(obj, "mesh", None), "size", 1) == 1
+
+        def pieces(vs, start, size):
+            return jnp.stack([jax.lax.dynamic_slice(v, (start,), (size,))
+                              for v in vs])
+
         @jax.jit
         def gram(*vs):
             """B Bᵀ for the stacked basis [S..., Y..., pg]: every dot
             product the two-loop recursion needs, in ONE device program /
             ONE host fetch (the reference's single Allreduce<Sum> of the
             5n dot-product vector, lbfgs.h:235-252)."""
-            B = jnp.stack(vs)
-            return B @ B.T
+            def block(B):
+                return jnp.dot(B, B.T, precision=hi)
+
+            if not whole:
+                return block(jnp.stack(vs))
+            n, k = vs[0].shape[0], len(vs)
+            c = min(_CHUNK, n)
+            G = jax.lax.fori_loop(
+                0, n // c, lambda i, G: G + block(pieces(vs, i * c, c)),
+                jnp.zeros((k, k), jnp.float32))
+            return G + block(pieces(vs, n - n % c, n % c)) if n % c else G
 
         @jax.jit
         def combine(coef, *vs):
-            return jnp.einsum("i,in->n", coef, jnp.stack(vs))
+            def block(B):
+                return jnp.einsum("i,in->n", coef, B, precision=hi)
+
+            if not whole:
+                return block(jnp.stack(vs))
+            n = vs[0].shape[0]
+            c = min(_CHUNK, n)
+            d = jax.lax.fori_loop(
+                0, n // c, lambda i, d: jax.lax.dynamic_update_slice(
+                    d, block(pieces(vs, i * c, c)), (i * c,)),
+                jnp.zeros((n,), jnp.float32))
+            if n % c:
+                d = jax.lax.dynamic_update_slice(
+                    d, block(pieces(vs, n - n % c, n % c)), (n - n % c,))
+            return d
 
         self._gram = gram
         self._combine = combine
@@ -148,60 +223,82 @@ class LBFGSSolver:
         self._pseudo_gradient = pseudo_gradient
         self._fix_dir_sign = fix_dir_sign
         self._orthant_project = orthant_project
-        # host-sync counter: every device->host scalar/array fetch the
-        # solver makes (the quantity the reference minimizes by batching
-        # dots into one allreduce; tests assert the fused path stays lean)
+        # host-sync counter: every device->host scalar/array fetch made
+        # inside run(), the objective's one a pass included (the quantity
+        # the reference minimizes by batching dots into one allreduce;
+        # tests assert the fused path stays lean). The registry's
+        # `lbfgs.host_syncs` is the same count over every solver.
         self.host_syncs = 0
 
-    def _fetch(self, x) -> float:
-        self.host_syncs += 1
-        return float(x)
+    def reset(self) -> None:
+        """Forget the job: the next run() starts from w = 0 with no
+        history, on the programs this solver has already compiled."""
+        self.S.clear()
+        self.Y.clear()
+        self.iter = 0
+        self.objv_history.clear()
 
     # -- two-loop recursion in basis coordinates (lbfgs.h:216-318) ----------
     def _direction(self, pg: jax.Array):
-        """Returns (d, pg_dot_d_or_None). The search direction is computed
-        vector-free: one Gram matrix of the [S..., Y..., pg] basis comes
-        back to the host (ONE sync per iteration instead of ~4m), the
-        two-loop recursion runs on (2m+1)-sized host vectors, and the
-        result is a single device linear combination of the basis."""
+        """Returns (d, pg . d): the search direction, restricted to the
+        descent orthant where OWL-QN is on. It is computed vector-free:
+        one Gram matrix of the [S..., Y..., pg] basis comes back to the
+        host (ONE sync per iteration instead of ~4m), the two-loop
+        recursion runs on (2m+1)-sized host vectors, and the result is a
+        single device linear combination of the basis. pg . d falls out
+        of the same Gram matrix (d = sum coef_i B_i) except where the
+        sign fix altered d: it is then read, and `lbfgs.combine` closes
+        on that read, which waits for the combination."""
+        l1 = self.cfg.reg_l1 > 0
         if not self.S:
-            return -pg, None
+            d = self._fix_dir_sign(-pg, pg)
+            return d, fetch(jnp.vdot(pg, d))
         k = len(self.S)
         basis = self.S + self.Y + [pg]
-        G = np.asarray(self._gram(*basis))
-        self.host_syncs += 1
-        coef = np.zeros(2 * k + 1)
-        coef[2 * k] = -1.0  # q = -pg
-        alphas = np.zeros(k)
-        rhos = np.zeros(k)
-        for i in range(k - 1, -1, -1):
-            rhos[i] = 1.0 / G[i, k + i]            # 1 / (s_i . y_i)
-            alphas[i] = rhos[i] * float(G[i] @ coef)   # rho (s_i . q)
-            coef[k + i] -= alphas[i]               # q -= a y_i
-        gamma = G[k - 1, 2 * k - 1] / G[2 * k - 1, 2 * k - 1]
-        coef *= gamma
-        for i in range(k):
-            b = rhos[i] * float(G[k + i] @ coef)   # rho (y_i . q)
-            coef[i] += alphas[i] - b               # q += (a - b) s_i
-        d = self._combine(jnp.asarray(coef, jnp.float32), *basis)
-        # pg . d is free from the same Gram: d = sum coef_i B_i
-        return d, float(G[2 * k] @ coef)
+        with _trace.span("lbfgs.gram", cat="lbfgs", vectors=len(basis)):
+            G = fetch(self._gram(*basis), np.asarray)
+        with _trace.span("lbfgs.two_loop", cat="lbfgs"):
+            coef = np.zeros(2 * k + 1)
+            coef[2 * k] = -1.0  # q = -pg
+            alphas = np.zeros(k)
+            rhos = np.zeros(k)
+            for i in range(k - 1, -1, -1):
+                rhos[i] = 1.0 / G[i, k + i]            # 1 / (s_i . y_i)
+                alphas[i] = rhos[i] * float(G[i] @ coef)   # rho (s_i . q)
+                coef[k + i] -= alphas[i]               # q -= a y_i
+            gamma = G[k - 1, 2 * k - 1] / G[2 * k - 1, 2 * k - 1]
+            coef *= gamma
+            for i in range(k):
+                b = rhos[i] * float(G[k + i] @ coef)   # rho (y_i . q)
+                coef[i] += alphas[i] - b               # q += (a - b) s_i
+        with _trace.span("lbfgs.combine", cat="lbfgs"):
+            d = self._fix_dir_sign(
+                self._combine(jnp.asarray(coef, jnp.float32), *basis), pg)
+            gd = fetch(jnp.vdot(pg, d)) if l1 else float(G[2 * k] @ coef)
+        return d, gd
 
     # -- one iteration (UpdateOneIter, lbfgs.h:168-196) ----------------------
     def _eval_full(self, w) -> float:
-        """Full objective at w. The RAW data loss reduces over the ring
-        BEFORE regularization: the reg terms are functions of the
-        replicated w and must be added exactly once, not `world` times
-        (the reference reduces sum_loss the same way, lbfgs.h:321-340)."""
-        raw = self.obj.eval(w)
-        if self.comm is not None:
-            raw = np.float32(self.comm.allreduce(np.float32(raw)))
-        return self._fetch(self._full_obj(w, raw))
+        """Full objective at w: one pass over the rows, from its launch
+        to the read that waits for it. The RAW data loss reduces over
+        the ring BEFORE regularization: the reg terms are functions of
+        the replicated w and must be added exactly once, not `world`
+        times (the reference reduces sum_loss the same way,
+        lbfgs.h:321-340)."""
+        _PASSES.inc()
+        with _trace.span("lbfgs.obj_pass", cat="lbfgs"):
+            raw = self.obj.eval(w)
+            if self.comm is not None:
+                raw = np.float32(self.comm.allreduce(np.float32(raw)))
+            return fetch(self._full_obj(w, raw))
 
     def _grad(self, w):
-        """Gradient of the data loss: local accumulation, then one ring
-        allreduce, re-placed under the objective's sharding (the single
-        Allreduce<Sum> per iteration of lbfgs.h:194)."""
+        """Gradient of the data loss, dispatched and not waited for:
+        local accumulation, then one ring allreduce, re-placed under the
+        objective's sharding (the single Allreduce<Sum> per iteration of
+        lbfgs.h:194). The caller's `lbfgs.grad_pass` span closes on the
+        read that waits for it."""
+        _PASSES.inc()
         g = self.obj.grad(w)
         if self.comm is not None:
             g = np.asarray(self.comm.allreduce(np.asarray(g)))
@@ -210,7 +307,25 @@ class LBFGSSolver:
                 jnp.asarray(g, jnp.float32))
         return g
 
-    def run(self, verbose: bool = True) -> tuple[jax.Array, float]:
+    def run(self, verbose: bool = True,
+            on_iter: Optional[Callable] = None) -> tuple[jax.Array, float]:
+        """The job from where the solver stands: from w = 0 (or the
+        checkpoint) until the stop rule, `max_iter`, a failed line search
+        or `on_iter` ends it. `on_iter(iter, objv, trials, state)` is
+        called at each iteration's end, outside its span, with the
+        iteration's number, its objective, the line search's trial count
+        and the state a checkpoint holds as it lies on the device (`w`,
+        `g`, `S`, `Y`, `objv`: the history); a true return ends the run
+        there (as `gbdt.fit_prepared` has `on_round`). Call `reset()`
+        before running another job on the same solver."""
+        outer = getattr(_RUNNING, "solver", None)
+        _RUNNING.solver = self
+        try:
+            return self._run(verbose, on_iter)
+        finally:
+            _RUNNING.solver = outer
+
+    def _run(self, verbose, on_iter):
         cfg = self.cfg
         w, g, objv = self._try_resume()
         resumed = w is not None
@@ -221,6 +336,8 @@ class LBFGSSolver:
         # required in BSP mode for counter alignment, a free speedup
         # otherwise. Old file checkpoints (no g) just recompute.
         if g is None:
+            # not waited for and under no span: the first objective
+            # pass's read, next, waits for both
             g = self._grad(w)
         if objv is None:
             objv = self._eval_full(w)
@@ -242,56 +359,75 @@ class LBFGSSolver:
                     if verbose:
                         print("lbfgs: converged", flush=True)
                     break
-            pg = self._pseudo_gradient(w, g)
-            d_raw, gd_raw = self._direction(pg)
-            d = self._fix_dir_sign(d_raw, pg)
-
-            # orthant for this step: sign(w), or -sign(pg) where w == 0
-            orthant = jnp.where(w != 0, jnp.sign(w), -jnp.sign(pg))
-
-            # backtracking line search (lbfgs.h:321-356). pg.d falls out
-            # of the direction's Gram matrix except when the OWL-QN
-            # sign-fix altered d
-            if cfg.reg_l1 > 0 or gd_raw is None:
-                gd = self._fetch(jnp.vdot(pg, d))
-            else:
-                gd = gd_raw
-            if gd >= 0:  # not a descent direction: reset history
-                self.S.clear()
-                self.Y.clear()
-                d = -pg
-                gd = self._fetch(jnp.vdot(pg, d))
-            alpha = cfg.alpha0
-            w_new, objv_new, ok = w, objv, False
-            for _ in range(cfg.max_linesearch):
-                trial = self._orthant_project(w + alpha * d, orthant)
-                o = self._eval_full(trial)
-                if o <= objv + cfg.c1 * alpha * gd:
-                    w_new, objv_new, ok = trial, o, True
-                    break
-                alpha *= cfg.rho
-            if not ok:
+            with _trace.span("lbfgs.iter", cat="lbfgs",
+                             iter=self.iter + 1) as sp:
+                step = self._iterate(w, g, objv)
+                sp.set(trials=step[-1])
+            if step[0] is None:
                 if verbose:
                     print("lbfgs: line search failed, stopping", flush=True)
                 break
-
-            g_new = self._grad(w_new)
-            s = w_new - w
-            y = (g_new + cfg.reg_l2 * w_new) - (g + cfg.reg_l2 * w)
-            if self._fetch(jnp.vdot(s, y)) > 1e-10:
-                self.S.append(s)
-                self.Y.append(y)
-                if len(self.S) > cfg.m:
-                    self.S.pop(0)
-                    self.Y.pop(0)
-            w, g, objv = w_new, g_new, objv_new
+            w, g, objv, alpha, trials = step
             self.iter += 1
+            _ITERS.inc()
             self.objv_history.append(objv)
             if verbose:
                 print(f"lbfgs iter {self.iter}: objv {objv:.6f} "
                       f"alpha {alpha:.3g}", flush=True)
             self._checkpoint(w, g)
+            if on_iter is not None and on_iter(
+                    self.iter, objv, trials,
+                    dict(w=w, g=g, S=list(self.S), Y=list(self.Y),
+                         objv=list(self.objv_history))):
+                break
         return w, objv
+
+    def _iterate(self, w, g, objv):
+        """One iteration from (w, g, objv): (w, g, objv, alpha, trials)
+        after it, the first three None where the line search failed."""
+        cfg = self.cfg
+        pg = self._pseudo_gradient(w, g)
+        with _trace.span("lbfgs.direction", cat="lbfgs"):
+            d, gd = self._direction(pg)
+
+        # orthant for this step: sign(w), or -sign(pg) where w == 0
+        orthant = jnp.where(w != 0, jnp.sign(w), -jnp.sign(pg))
+
+        # backtracking line search (lbfgs.h:321-356)
+        if gd >= 0:  # not a descent direction: reset history
+            self.S.clear()
+            self.Y.clear()
+            d = -pg
+            gd = fetch(jnp.vdot(pg, d))
+        alpha = cfg.alpha0
+        w_new, trials = None, 0
+        with _trace.span("lbfgs.linesearch", cat="lbfgs") as sp:
+            for _ in range(cfg.max_linesearch):
+                trial = self._orthant_project(w + alpha * d, orthant)
+                trials += 1
+                o = self._eval_full(trial)
+                if o <= objv + cfg.c1 * alpha * gd:
+                    w_new, objv_new = trial, o
+                    break
+                alpha *= cfg.rho
+            _TRIALS.inc(trials)
+            sp.set(trials=trials)
+        if w_new is None:
+            return None, None, None, alpha, trials
+
+        # the pass ends in the read of s.y, which waits for it
+        with _trace.span("lbfgs.grad_pass", cat="lbfgs"):
+            g_new = self._grad(w_new)
+            s = w_new - w
+            y = (g_new + cfg.reg_l2 * w_new) - (g + cfg.reg_l2 * w)
+            sy = fetch(jnp.vdot(s, y))
+        if sy > 1e-10:
+            self.S.append(s)
+            self.Y.append(y)
+            if len(self.S) > cfg.m:
+                self.S.pop(0)
+                self.Y.pop(0)
+        return w_new, g_new, objv_new, alpha, trials
 
     # -- elastic state (rabit CheckPoint parity, lbfgs.h:120,194) -----------
     def _state(self, w, g) -> dict:
