@@ -172,6 +172,39 @@ def test_coo_pull_push_dense(v5e, dtype):
         v5e, ((ROWS,), jnp.float32), *stream)
 
 
+def test_batch_solver_passes_at_the_benchmarks_columns(v5e):
+    """The batch solver's packed passes (models/batch_objectives.py, PR
+    48) as `lbfgs1tb.resident` runs them: a row chunk over all 1,024
+    tiles of 2^26 columns, float32 (the three-pass bodies of
+    `_onehot_dot`). The objective's chunk is `coo_pull` alone; the
+    gradient's is `coo_pull` then `coo_push`, and adds into gw in place."""
+    from wormhole_tpu.models import batch_objectives as bo
+
+    obj = object.__new__(bo.LinearObjFunction)
+    obj.num_feature = NB_BIG
+    obj._build_packed(bo.ROW_CHUNK)
+    f32 = jnp.float32
+    # the pack gives a chunk the blocks its fullest one needs: a block a
+    # tile and a few more for the hot columns' tiles
+    need = NB_BIG // ck.TILE + 16
+    stream = coo_stream((need - NB_BIG // ck.TILE) * ck.BLK, NB_BIG)
+    rows = [((bo.ROW_CHUNK,), f32)] * 2
+    aot("coo_pull", obj._eval_chunk, v5e, ((), f32), ((NB_BIG,), f32),
+        ((), f32), *stream, *rows)
+    args = [jax.ShapeDtypeStruct(s[0], s[1], sharding=v5e)
+            for s in [((NB_BIG,), f32), ((), f32), ((NB_BIG,), f32),
+                      ((), f32), *stream, *rows]]
+    compiled = obj._grad_chunk.lower(*args).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%([\w.\-]+) = .*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert [c.split(".")[0] for c in calls] == ["coo_pull", "coo_push"]
+    # gw is donated and the kernel adds into it tile by tile (`acc`): the
+    # sum is written where the old one lay, with no table-sized temporary
+    assert "input_output_alias" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_compacted_linear_kernels(v5e, dtype):
     f32, i32 = jnp.float32, jnp.int32

@@ -71,6 +71,13 @@ def _solver_config(cfg) -> LBFGSConfig:
         max_linesearch=cfg.max_linesearch_iter)
 
 
+def _state_path(obj) -> None:
+    """The start-up statement of which lowering the passes take and,
+    where it is the `segment_sum` fall-back, why: on stderr, so that
+    what `main` prints is the same on every path."""
+    print(obj.placement, file=sys.stderr, flush=True)
+
+
 def make_solver(cfg, mesh=None):
     """The single-process training job of `cfg`, ready to run: its rows
     resident on the device, the objective over them and the solver.
@@ -82,6 +89,7 @@ def make_solver(cfg, mesh=None):
         cfg.data, mesh, cfg.data_format, cfg.minibatch, cfg.nnz_per_row,
         cfg.num_parts_per_file, cfg.num_feature)
     obj = LinearObjFunction(batches, num_feature, mesh)
+    _state_path(obj)
     return (LBFGSSolver(obj, _solver_config(cfg)), obj, batches,
             num_feature)
 
@@ -99,6 +107,8 @@ def _global_worker_body(cfg, env, client) -> int:
         cfg.data, mesh, env, cfg.data_format, cfg.minibatch,
         cfg.nnz_per_row, cfg.num_parts_per_file)
     obj = LinearObjFunction(batches, num_feature, mesh)
+    if rank == 0:
+        _state_path(obj)
     solver = LBFGSSolver(obj, _solver_config(cfg))
     # every rank drives the identical host loop on identical global
     # scalars, so all jitted collectives stay in lockstep
@@ -132,6 +142,8 @@ def _bsp_worker_body(cfg, env, client, comm) -> int:
         cfg.data, mesh, env, client, cfg.data_format, cfg.minibatch,
         cfg.nnz_per_row, cfg.num_parts_per_file)
     obj = LinearObjFunction(batches, num_feature, mesh)
+    if rank == 0:
+        _state_path(obj)
     solver = LBFGSSolver(obj, _solver_config(cfg), comm=comm)
     # every rank drives the identical host loop on identical reduced
     # scalars; w is replicated, so rank 0 alone saves it

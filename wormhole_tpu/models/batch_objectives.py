@@ -12,15 +12,23 @@ Parity targets:
 
 TPU design: the dataset is loaded once into fixed-shape device batches
 sharded over the data axis (the reference's per-rank RowBlockIter cache);
-the flat parameter vector is sharded over all devices; each objective is a
-pure per-batch loss and jax.grad produces the exact gradient — the
-per-thread gradient buffers and hand-written backward passes of the
-reference (fm.cc:209-242) are unnecessary under XLA.
+the flat parameter vector is sharded over all devices. The general
+formulation is a pure per-batch loss over `segment_sum` margins with
+jax.grad for the gradient: it runs on any backend, mesh and dimension and
+needs no backward pass written out (fm.cc:209-242), but on a TPU XLA
+lowers its gather and scatter to a sort and serial fusions (0.07 % of a
+gradient pass's roofline at 2^26 columns, PERF_LEDGER.jsonl PR 47). Where
+one TPU device holds the whole vector and the dimension is a whole number
+of table tiles, the linear objective's two products, X w and X^T d, run
+instead on the packed-COO Pallas kernels (ops/coo_kernels.py) at float32,
+over a layout of the resident rows made once, at construction
+(`LinearObjFunction`, `packed_rule`; docs/lbfgs.md "The passes").
 """
 
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional
 
 import jax
@@ -29,7 +37,9 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from wormhole_tpu.data.rowblock import to_device_batch
-from wormhole_tpu.parallel.mesh import batch_sharding
+from wormhole_tpu.obs.metrics import REGISTRY
+from wormhole_tpu.ops import coo_kernels as ck
+from wormhole_tpu.parallel.mesh import batch_sharding, describe_placement
 from wormhole_tpu.solver.lbfgs import fetch
 from wormhole_tpu.solver.workload import iter_rowblocks
 
@@ -211,13 +221,169 @@ class _BatchObjBase:
         return m
 
 
-class LinearObjFunction(_BatchObjBase):
-    """Logistic regression, layout [w(d); bias]."""
+# Rows of a resident batch that one call of the packed-COO kernels takes.
+# The kernels' row one-hot is rows / 128 wide, so a whole batch in one call
+# is not an option; each chunk is packed over all the columns (a block a
+# table tile at least), so a smaller chunk means more and emptier blocks.
+ROW_CHUNK = 65536
 
-    def __init__(self, batches, num_feature: int, mesh):
+
+def packed_rule(batches, num_feature: int, mesh, row_chunk: int,
+                any_backend: bool = False) -> str:
+    """Why these resident batches cannot take the packed-COO kernels, ""
+    where they can: everything here is what the code can observe of the
+    backend, the placement and the shapes (as `kernel: auto` of
+    models/linear.py), no key of a configuration. `any_backend` is the
+    tests' way to the interpreted kernels off the chip."""
+    dev = mesh.devices.flat[0]
+    if dev.platform != "tpu" and not any_backend:
+        return f"backend is {dev.platform}, not tpu"
+    if mesh.size != 1:
+        return f"the vector is sharded over {mesh.size} devices"
+    if num_feature <= 0 or num_feature % ck.TILE:
+        return f"num_feature {num_feature} is not a multiple of {ck.TILE}"
+    if not batches:
+        return "no resident batch"
+    for b in batches:
+        rows = b[3].shape[0]
+        if rows % row_chunk or row_chunk % ck.LANES:
+            return (f"a batch of {rows} rows is not a multiple of the row "
+                    f"chunk {row_chunk} and of {ck.LANES}")
+    return ""
+
+
+def pack_row_chunks(batches, num_feature: int, row_chunk: int):
+    """The resident batches as row chunks packed for the COO kernels, or
+    (None, why) where a column id lies outside the table. Each batch is
+    read back once; its live triples (val != 0: padding and explicit
+    zeros add nothing to either product) are cut into chunks of
+    `row_chunk` rows, and each chunk is sorted by column and laid in
+    BLK-padded per-tile runs over all `num_feature` columns
+    (ck.pack_sorted_coo, the dense layout). Every chunk gets the block
+    count of the one that needs most, so one compiled program runs them
+    all. A chunk is (sidx, sseg, sval, tmap, first, label, mask), all on
+    the batch's device; sseg counts rows from the chunk's first."""
+    tiles = num_feature // ck.TILE
+    cut, need = [], 0
+    for seg, idx, val, label, mask in batches:
+        seg, idx, val = (np.asarray(x) for x in (seg, idx, val))
+        live = val != 0
+        if not live.all():
+            seg, idx, val = seg[live], idx[live], val[live]
+        if idx.size and not 0 <= idx.min() <= idx.max() < num_feature:
+            return None, (f"a column id outside [0, {num_feature}): "
+                          f"{idx.min()}..{idx.max()}")
+        if (seg[1:] < seg[:-1]).any():    # to_device_batch gives CSR order
+            order = np.argsort(seg, kind="stable")
+            seg, idx, val = seg[order], idx[order], val[order]
+        rows = label.shape[0]
+        ends = np.searchsorted(
+            seg, np.arange(0, rows + 1, row_chunk, dtype=seg.dtype))
+        for c, (a, b) in enumerate(zip(ends[:-1], ends[1:])):
+            r0 = c * row_chunk
+            per_tile = np.bincount(idx[a:b] // ck.TILE, minlength=tiles)
+            need = max(need, int(np.maximum(
+                -(-per_tile // ck.BLK), 1).sum()))
+            cut.append((idx[a:b], seg[a:b] - r0, val[a:b],
+                        label[r0:r0 + row_chunk], mask[r0:r0 + row_chunk]))
+    # rounded up (by 64 blocks at 1,024 tiles) so that another sample of
+    # the same data gets the same shapes, and the compile cache's programs
+    step = max(tiles // 16, 1)
+    need = -(-need // step) * step
+    # packed_size(capacity, num_feature) == need * BLK
+    capacity = (need - tiles) * ck.BLK
+    chunks = []
+    for idx, seg, val, label, mask in cut:
+        p = ck.pack_sorted_coo(idx, seg, val, num_feature, capacity)
+        dev = label.sharding
+        chunks.append(tuple(jax.device_put(x, dev) for x in (
+            p.idx, p.seg, p.val, p.tmap, p.first)) + (label, mask))
+    return chunks, ""
+
+
+class LinearObjFunction(_BatchObjBase):
+    """Logistic regression, layout [w(d); bias].
+
+    Two lowerings of the same two sparse products, chosen at construction
+    by `packed_rule`: on one TPU device with `num_feature` a whole number
+    of table tiles and the batches' rows a multiple of `ROW_CHUNK`, a pass
+    is `coo_spmv` (X w) a row chunk, the loss or the dual d = (sigmoid(xw)
+    - y) * mask in XLA, and for the gradient `coo_spmv_t` (X^T d) into
+    table layout with the bias's sum(d) beside it, all at float32
+    (`self.packed`; `self.placement` says which and why; every such pass
+    counts itself in `lbfgs.passes.packed`, which a packed objective
+    registers and no other process lists). Anywhere else the
+    `segment_sum` programs of `_BatchObjBase` run as they always did.
+    `_packed` is for tests alone: a row chunk, which also admits the
+    interpreted kernels off the chip; no app's configuration reaches it."""
+
+    def __init__(self, batches, num_feature: int, mesh,
+                 _packed: Optional[int] = None):
         self.num_feature = num_feature
         self.num_dim = num_feature + 1
         super().__init__(batches, mesh)
+        row_chunk = _packed or ROW_CHUNK
+        why = packed_rule(batches, num_feature, mesh, row_chunk,
+                          any_backend=_packed is not None)
+        self._chunks = None
+        if not why:
+            self._chunks, why = pack_row_chunks(batches, num_feature,
+                                                row_chunk)
+        self.packed = self._chunks is not None
+        self.placement = describe_placement(mesh, "lbfgs", self.packed, why)
+        if self.packed:
+            self.placement += (f" chunks={len(self._chunks)}x{row_chunk} "
+                               f"rows, {self._chunks[0][3].shape[0]} blocks")
+            self._passes = REGISTRY.counter("lbfgs.passes.packed")
+            self._build_packed(row_chunk)
+
+    def _build_packed(self, rows: int):
+        nf, f32 = self.num_feature, jnp.float32
+
+        def margin(w, bias, sidx, sseg, sval, tmap, first):
+            return ck.coo_spmv(w, sidx, sseg, sval, tmap, first, rows,
+                               dtype=f32) + bias
+
+        @jax.jit
+        def eval_chunk(tot, w, bias, *c):
+            *coo, label, mask = c
+            xw = margin(w, bias, *coo)
+            return tot + jnp.sum((jax.nn.softplus(xw) - label * xw) * mask)
+
+        @partial(jax.jit, donate_argnums=(0, 1))
+        def grad_chunk(gw, gb, w, bias, *c):
+            *coo, label, mask = c
+            d = (jax.nn.sigmoid(margin(w, bias, *coo)) - label) * mask
+            return (ck.coo_spmv_t(d, *coo, nf, dtype=f32, acc=gw),
+                    gb + jnp.sum(d))
+
+        # once a pass: w as the kernels' table, the sums' zeros (programs'
+        # outputs, so that a pass's first chunk and its later ones give
+        # the chunk's program the same kind of argument: one compilation)
+        # and g back in p's layout
+        self._split = jax.jit(lambda p: (p[:nf], p[nf], jnp.zeros(())))
+        self._split_g = jax.jit(lambda p: (
+            p[:nf], p[nf], jnp.zeros(()), jnp.zeros((nf,), f32)))
+        self._join = jax.jit(lambda gw, gb: jnp.concatenate([gw, gb[None]]))
+        self._eval_chunk, self._grad_chunk = eval_chunk, grad_chunk
+
+    def eval(self, p) -> float:
+        if not self.packed:
+            return super().eval(p)
+        self._passes.inc()
+        w, bias, tot = self._split(p)
+        for c in self._chunks:
+            tot = self._eval_chunk(tot, w, bias, *c)
+        return fetch(tot)
+
+    def grad(self, p):
+        if not self.packed:
+            return super().grad(p)
+        self._passes.inc()
+        w, bias, gb, gw = self._split_g(p)
+        for c in self._chunks:
+            gw, gb = self._grad_chunk(gw, gb, w, bias, *c)
+        return self._join(gw, gb)
 
     def _margin(self, p, seg, idx, val, num_rows: int):
         w, bias = p[: self.num_feature], p[self.num_feature]

@@ -290,12 +290,57 @@ def pack_sorted_coo(idx, seg, val, num_buckets: int,
 
 
 def _prec(dtype):
-    """MXU precision for the kernel matmuls: at f32 request HIGHEST
-    (bf16x3 decomposition) so the "exact" kernel_dtype=f32 path really
-    matches the XLA segment-op numerics — the default single-pass mode
-    rounds f32 operands to bf16 on the way into the systolic array."""
+    """MXU precision of `tile_gather`'s and `fm_push_contrib`'s matmuls:
+    at f32 request HIGHEST (bf16x6: both operands decomposed) so the
+    "exact" kernel_dtype=f32 path really matches the XLA segment-op
+    numerics — the default single-pass mode rounds f32 operands to bf16
+    on the way into the systolic array. The two product kernels
+    (`coo_pull`, `coo_push`) decompose the values' side alone
+    (_onehot_dot, three passes for these six); these two keep HIGHEST
+    because the DiFacto step runs them at f32 whatever its kernel_dtype
+    (the count table's fetch, models/difacto.py) and that step's programs
+    are to stay as they are until a change is measured in its own cell
+    (ROADMAP.md A4 (2))."""
     return (jax.lax.Precision.HIGHEST if dtype == jnp.float32 else
             jax.lax.Precision.DEFAULT)
+
+
+def _split3(x):
+    """x (float32) as three bfloat16 addends, (hi + mid) + lo == x to the
+    bit: each takes the next 8 of the 24 mantissa bits (a value so small
+    that lo leaves bfloat16's exponent range loses those bits)."""
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    return hi, mid, (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _mxu(dtype):
+    """The dtype of a one-hot the MXU multiplies by: bfloat16 at f32 too
+    (a one-hot is exact in it; the values' side is split, _onehot_dot)."""
+    return jnp.bfloat16 if dtype == jnp.float32 else dtype
+
+
+def _onehot_dot(e, x, dtype):
+    """One-hot e (dtype _mxu(dtype)) times the values x (f32) on the MXU,
+    e's columns against x's rows; f32 out. At bf16 the values round to
+    it: one pass. At f32 they go in as their three bfloat16 addends and
+    every product is exact, summed in float32: three passes where
+    Precision.HIGHEST (bf16x6) also multiplies the one-hot's zero mid
+    and lo parts, so a fetch is exact and a scatter an f32 sum, at half
+    HIGHEST's MXU cost."""
+    def dot(v):
+        return jax.lax.dot_general(
+            e, v,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT,
+        )
+
+    if dtype != jnp.float32:
+        return dot(x.astype(dtype))
+    hi, mid, lo = _split3(x)
+    return (dot(hi) + dot(mid)) + dot(lo)
 
 
 def _row_fetch(table2, hi, dtype):
@@ -310,6 +355,14 @@ def _row_fetch(table2, hi, dtype):
         preferred_element_type=jnp.float32,
         precision=_prec(dtype),
     )
+
+
+def _row_fetch3(table2, hi, dtype):
+    """_row_fetch for the two product kernels: the same rows, at f32 from
+    the table's three bf16 addends against a bf16 one-hot (_onehot_dot)
+    where _row_fetch asks HIGHEST; at bf16 the same matmul."""
+    e = _onehot(hi, table2.shape[0], _mxu(dtype))
+    return _onehot_dot(e, table2, dtype)
 
 
 def _lane_pick(rows, lane_onehot):
@@ -363,18 +416,13 @@ def _pull_kernel(tmap_ref, first_ref, ext_ref, w_ref, idx_ref, seg_ref,
         lo = local & (LANES - 1)
         w2 = w_ref[:].reshape(TILE_HI, LANES)
         c_lo = _onehot(lo, LANES, dtype)
-        p = _lane_pick(_row_fetch(w2, hi, dtype), c_lo) * val_ref[sl]
+        p = _lane_pick(_row_fetch3(w2, hi, dtype), c_lo) * val_ref[sl]
 
         rhi = seg_ref[sl] >> 7
         rlo = seg_ref[sl] & (LANES - 1)
-        e_rt = _onehot_t(rhi, num_rows // LANES, dtype)
+        e_rt = _onehot_t(rhi, num_rows // LANES, _mxu(dtype))
         c_r = _onehot(rlo, LANES, dtype)
-        out_ref[:] += jax.lax.dot_general(
-            e_rt, (p[:, None] * c_r).astype(dtype),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=_prec(dtype),
-        )
+        out_ref[:] += _onehot_dot(e_rt, p[:, None] * c_r, dtype)
 
     # a block past whose extent every val is 0 adds nothing there
     _live_chunks(ext_ref[blk], BLK, body)
@@ -384,8 +432,9 @@ def coo_spmv(w, sidx, sseg, sval, tmap, first, num_rows: int, dtype=None):
     """xw = X w over the sorted/padded COO batch; returns (num_rows,) f32.
     num_rows must be a multiple of 128. dtype is the MXU compute dtype:
     bf16 (default on TPU; one-hots stay exact, table values round — the
-    reference's compressing-filter tradeoff) or f32 (exact, ~4x the MXU
-    cost; default off-TPU so CPU tests compare bit-tight)."""
+    reference's compressing-filter tradeoff) or f32 (every gathered value
+    exact and every sum a float32 one, at three MXU passes for bf16's
+    one, _onehot_dot; default off-TPU so CPU tests compare bit-tight)."""
     if dtype is None:
         dtype = jnp.bfloat16 if not _use_interpret() else jnp.float32
     assert num_rows % LANES == 0
@@ -418,12 +467,14 @@ def coo_spmv(w, sidx, sseg, sval, tmap, first, num_rows: int, dtype=None):
 
 # --------------------------------------------------------------------- push
 def _push_kernel(tmap_ref, first_ref, ext_ref, d_ref, idx_ref, seg_ref,
-                 val_ref, out_ref, *, dtype):
+                 val_ref, *acc_out, dtype):
+    *acc_ref, out_ref = acc_out
     blk = pl.program_id(0)
 
+    # a tile's sum starts from zero, or from the caller's tile of `acc`
     @pl.when(first_ref[blk] == 1)
     def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
+        out_ref[:] = acc_ref[0][:] if acc_ref else jnp.zeros_like(out_ref)
 
     base = tmap_ref[blk] * TILE
 
@@ -431,28 +482,28 @@ def _push_kernel(tmap_ref, first_ref, ext_ref, d_ref, idx_ref, seg_ref,
         rhi = seg_ref[sl] >> 7
         rlo = seg_ref[sl] & (LANES - 1)
         c_r = _onehot(rlo, LANES, dtype)
-        c = _lane_pick(_row_fetch(d_ref[:], rhi, dtype), c_r) * val_ref[sl]
+        c = _lane_pick(_row_fetch3(d_ref[:], rhi, dtype), c_r) * val_ref[sl]
 
         local = idx_ref[sl] - base
         hi = local >> 7
         lo = local & (LANES - 1)
-        e_hit = _onehot_t(hi, TILE_HI, dtype)
+        e_hit = _onehot_t(hi, TILE_HI, _mxu(dtype))
         c_lo = _onehot(lo, LANES, dtype)
-        out_ref[:] += jax.lax.dot_general(
-            e_hit, (c[:, None] * c_lo).astype(dtype),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=_prec(dtype),
-        )
+        out_ref[:] += _onehot_dot(e_hit, c[:, None] * c_lo, dtype)
 
     # an empty block only zeroes its tile (above) when it opens one
     _live_chunks(ext_ref[blk], BLK, body)
 
 
 def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
-               dtype=None):
+               dtype=None, acc=None):
     """g = Xᵀ d in table layout; returns (num_buckets,) f32. d is the
-    per-row dual vector, len(d) a multiple of 128."""
+    per-row dual vector, len(d) a multiple of 128. With `acc`, a
+    (num_buckets,) f32 sum so far, returns acc + Xᵀ d written where acc
+    lay: every tile is read once and written once, where adding the
+    product afterwards reads two tables and writes a third (18 ms of a
+    624 ms gradient pass of 16 chunks at 2^26 buckets, PERF.md §6, PR
+    48). For the dense layout, whose every tile has one run."""
     if dtype is None:
         dtype = jnp.bfloat16 if not _use_interpret() else jnp.float32
     num_rows = d.shape[0]
@@ -461,6 +512,10 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
     nblk = tmap.shape[0]
     d2 = d.reshape(num_rows // LANES, LANES)
     ext = block_extents(sval != 0, BLK)
+    tile_spec = pl.BlockSpec(
+        (TILE_HI, LANES), lambda b, tmap, *_: (tmap[b], 0))
+    # the sum so far rides as one more input, tiled and aliased as the output
+    sums = () if acc is None else (acc.reshape(num_buckets // LANES, LANES),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nblk,),
@@ -469,9 +524,8 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
             pl.BlockSpec((BLK,), lambda b, *_: (b,)),
             pl.BlockSpec((BLK,), lambda b, *_: (b,)),
             pl.BlockSpec((BLK,), lambda b, *_: (b,)),
-        ],
-        out_specs=pl.BlockSpec(
-            (TILE_HI, LANES), lambda b, tmap, *_: (tmap[b], 0)),
+        ] + [tile_spec] * len(sums),
+        out_specs=tile_spec,
     )
     out = pl.pallas_call(
         partial(_push_kernel, dtype=dtype),
@@ -482,7 +536,8 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_use_interpret(),
         name="coo_push",
-    )(tmap, first, ext, d2, sidx, sseg, sval)
+        input_output_aliases={7: 0} if sums else {},
+    )(tmap, first, ext, d2, sidx, sseg, sval, *sums)
     return out.reshape(num_buckets)
 
 

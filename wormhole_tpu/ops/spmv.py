@@ -1,10 +1,17 @@
 """Sparse matrix x vector/matrix products on device.
 
 The reference's OpenMP CSR kernels (learn/base/spmv.h:72-119, spmm.h:41-123)
-become XLA gather + segment-sum on a fixed-shape COO DeviceBatch: that is
-the TPU-idiomatic formulation — both directions compile to fused
-gather/scatter-add programs, and the transposed product lands directly in
-the (sharded) parameter table layout.
+become XLA gather + segment-sum on a fixed-shape COO DeviceBatch: the
+general formulation, which runs on any backend, mesh and table size, and
+whose transposed product lands directly in the (sharded) parameter table
+layout. It is not the fast one on a TPU: XLA lowers a gather or a
+scatter-add over millions of unsorted ids to a sort and serial fusions
+(23.7 and 43.3 ns a nonzero for the batch solver's two passes at 2^26
+columns, 0.1 % and 0.07 % of their rooflines: PERF_LEDGER.jsonl, PR 47,
+`lbfgs1tb.resident`). Where the batch can be sorted by column ahead of
+time the same two products run on the packed-COO Pallas kernels of
+ops/coo_kernels.py (the minibatch learners on a TPU, and the batch
+solver's linear objective since PR 48).
 
 All functions are jit-safe (static shapes, no Python branching on values).
 """
