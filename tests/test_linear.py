@@ -316,19 +316,22 @@ def test_compacted_predict_and_eval(synth_file):
 # ------------------------------------------- one record a kind, one path
 # kind -> (config, mesh shape, pack_cache_token written out). The token's
 # tail is the block geometry (TILE, BLK, BLK_U, LANES): a data format,
-# constant beside the kernels
+# constant beside the kernels; the mesh pack's block rides after the
+# shard's capacity, like it a function of the table and the mesh that
+# only an `mcoo` batch is packed by (ck.mesh_block: BLK here, where a
+# shard has one to four tiles for its 4,096 nonzeros)
 _KINDS = {
     "xla": (dict(kernel="xla"), (1, 1),
-            ("linear", 2, False, False, 0, 4096, 256, 8, 262144, 1, 1,
+            ("linear", 2, False, False, 0, 4096, 4096, 256, 8, 262144, 1, 1,
              65536, 4096, 1024, 128)),
     "coo": (dict(kernel="pallas", compact_cap=0), (1, 1),
-            ("linear", 2, True, False, 0, 4096, 256, 8, 262144, 1, 1,
+            ("linear", 2, True, False, 0, 4096, 4096, 256, 8, 262144, 1, 1,
              65536, 4096, 1024, 128)),
     "tcoo": (dict(kernel="pallas", compact_cap=1), (1, 1),
-             ("linear", 2, True, False, 65536, 4096, 256, 8, 262144, 1, 1,
-              65536, 4096, 1024, 128)),
+             ("linear", 2, True, False, 65536, 4096, 4096, 256, 8, 262144,
+              1, 1, 65536, 4096, 1024, 128)),
     "mcoo": (dict(kernel="pallas", model_shards=2), (2, 2),
-             ("linear", 2, True, True, 0, 4096, 256, 8, 262144, 2, 2,
+             ("linear", 2, True, True, 0, 4096, 4096, 256, 8, 262144, 2, 2,
               65536, 4096, 1024, 128)),
 }
 

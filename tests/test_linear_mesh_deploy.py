@@ -176,10 +176,15 @@ def _packed(data, part):
     return idx, seg, np.ones(len(idx), np.float32)
 
 
+def _mesh_pack(idx, seg, val):
+    cap = ck.mesh_capacity(ROWS * gen.NNZ, 1, SHARDS)
+    return ck.pack_mesh_coo(idx, seg, val, NB, ROWS, 1, SHARDS, cap,
+                            ck.mesh_block(cap, NB // SHARDS))
+
+
 def test_one_shard_is_hotter_than_the_rest(data):
     idx, seg, val = _packed(data, 0)
-    mc = ck.pack_mesh_coo(idx, seg, val, NB, ROWS, 1, SHARDS,
-                          ck.mesh_capacity(ROWS * gen.NNZ, 1, SHARDS))
+    mc = _mesh_pack(idx, seg, val)
     cells = mc.cell_nnz[0]
     assert cells.sum() == len(idx) and mc.dropped_nnz == 0
     assert [int((mc.sval[0, m] != 0).sum()) for m in range(SHARDS)] == list(
@@ -195,8 +200,7 @@ def test_partial_margins_of_the_four_shards_sum_to_the_one_device_margins(
     want = np.asarray(ck.coo_spmv(
         jnp.asarray(w), *(jnp.asarray(x) for x in (
             one.idx, one.seg, one.val, one.tmap, one.first)), ROWS))
-    mc = ck.pack_mesh_coo(idx, seg, val, NB, ROWS, 1, SHARDS,
-                          ck.mesh_capacity(ROWS * gen.NNZ, 1, SHARDS))
+    mc = _mesh_pack(idx, seg, val)
     nb_m = NB // SHARDS
     parts = [np.asarray(ck.coo_spmv(
         jnp.asarray(w[m * nb_m:(m + 1) * nb_m]), *(jnp.asarray(x[0, m])

@@ -266,9 +266,12 @@ def test_mesh_coo_pull_push_at_a_quarter_of_2p30(topo, dtype):
     cell = NamedSharding(mesh, P(DATA_AXIS, MODEL_AXIS, None))
     table = NamedSharding(mesh, P(MODEL_AXIS))
     rows = NamedSharding(mesh, P(DATA_AXIS))
+    cap = ck.mesh_capacity(CAP, 1, M)
+    # the pack's own rule gives the shard's block (1,024 since PR 49)
     stream = [((1, M) + s, d, cell) for s, d in coo_stream(
-        ck.mesh_capacity(CAP, 1, M), NB_MESH // M)]
-    assert stream[0][0] == (1, 4, 18055168)   # PERF.md §4
+        cap, NB_MESH // M, blk=ck.mesh_block(cap, NB_MESH // M))]
+    assert stream[0][0] == (1, 4, 5472256)   # PERF.md §4
+    assert stream[3][0] == (1, 4, 5344)
     aot("coo_pull",
         lambda w, *s: ck.mesh_coo_spmv(mesh, w, *s, ROWS, dtype=dtype),
         None, ((NB_MESH,), jnp.float32, table), *stream)

@@ -52,6 +52,9 @@ _log = logging.getLogger(__name__)
 _MESH_DROPPED = REGISTRY.counter("linear.mesh.dropped_nnz")
 _MESH_NNZ_MAX = REGISTRY.counter("linear.mesh.shard_nnz_max")
 _MESH_NNZ_SUM = REGISTRY.counter("linear.mesh.shard_nnz_sum")
+# slots the packed mesh batches hold, D x M x P a batch: what the loader
+# copies for those nonzeros
+_MESH_SLOTS = REGISTRY.counter("linear.mesh.slots")
 _CHUNKS = REGISTRY.counter("linear.blocks.chunks")
 _CHUNKS_RUN = REGISTRY.counter("linear.blocks.chunks_run")
 # tcoo batches packed, and those the native pass packed
@@ -325,6 +328,9 @@ class LinearLearner:
             self.placement += (" tcoo_pack="
                                + ("native" if native.available() else "numpy"))
         self._shard_cap = ck.mesh_capacity(cfg.row_capacity, D, M)
+        # the block a shard's runs are padded to, from the same geometry
+        self._shard_blk = ck.mesh_block(self._shard_cap,
+                                        cfg.num_buckets // M)
         if self.use_pallas:
             assert cfg.num_buckets % (M * ck.TILE) == 0, (
                 f"pallas kernel needs num_buckets % {M * ck.TILE} == 0")
@@ -629,16 +635,17 @@ class LinearLearner:
         M = self.mesh.shape.get("model", 1)
         mc = ck.pack_mesh_coo(db.idx, db.seg, db.val,
                               self.cfg.num_buckets, self.cfg.minibatch,
-                              D, M, self._shard_cap)
+                              D, M, self._shard_cap, self._shard_blk)
         _MESH_NNZ_MAX.inc(int(mc.cell_nnz.max()))
         _MESH_NNZ_SUM.inc(int(mc.cell_nnz.sum()))
+        _MESH_SLOTS.inc(mc.sval.size)
         if mc.dropped_nnz:
             _MESH_DROPPED.inc(mc.dropped_nnz)
             _log.warning(
                 "mesh shard overflow: dropped %d nonzeros — raise "
                 "nnz_per_row or mesh_capacity slack", mc.dropped_nnz)
         if train:  # pull and push walk the same COO blocks
-            _count_chunks(mc.sval, 0, ck.BLK, 2)
+            _count_chunks(mc.sval, 0, self._shard_blk, 2)
         return mc
 
     def _pack_tcoo(self, db: DeviceBatch, train: bool):
@@ -684,7 +691,8 @@ class LinearLearner:
         cfg = self.cfg
         return ("linear", self._PACK_VERSION, self.use_pallas,
                 self._mesh_coo, self._compact_cap, self._shard_cap,
-                cfg.minibatch, cfg.nnz_per_row, cfg.num_buckets,
+                self._shard_blk, cfg.minibatch, cfg.nnz_per_row,
+                cfg.num_buckets,
                 self.mesh.shape.get("data", 1),
                 self.mesh.shape.get("model", 1),
                 ck.TILE, ck.BLK, ck.BLK_U, ck.LANES)

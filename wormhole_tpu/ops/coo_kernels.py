@@ -53,7 +53,10 @@ import os
 # full, so the kernels build those operands only over a block's live
 # prefix (CHUNK and _live_chunks below), and a block costs what it
 # holds. The geometry decides the layout of every packed batch and
-# pack-cache entry (models/linear.pack_cache_token), so it is constant.
+# pack-cache entry (models/linear.pack_cache_token), so it is constant;
+# BLK is the block of the one-chip layouts, a stream carries its own
+# (pack_sorted_coo's `blk`: FM_BLK for the FM kernels, mesh_block for a
+# shard of a mesh) and the product kernels read it off the stream.
 TILE_HI = 512  # sublanes per tile
 LANES = 128
 TILE = TILE_HI * LANES  # buckets per table tile
@@ -76,6 +79,12 @@ _VMEM_LIMIT = int(os.environ.get("WORMHOLE_VMEM", 96 * 2**20))
 # pack_sorted_coo: a tile's run is written at d0:d0+n and padded after
 # it), so one number a block, the extent of that prefix, bounds its work.
 CHUNK = 128
+# The least block a 1-D stream goes by: XLA lays a 1-D 32-bit array out
+# in tiles of 1,024 elements, and inside a jitted step Mosaic refuses a
+# block under its operand's tile ("XLA layout {0:T(1024)} does not match
+# Mosaic layout {0:T(512)}": PERF.md §6, PR 49; the kernel compiled
+# alone gets the layout it asks for, so only the chip's step says it).
+STREAM_TILE = 1024
 
 
 def _use_interpret() -> bool:
@@ -91,6 +100,15 @@ def block_extents(live, blk: int):
     live2 = live.reshape(-1, blk)
     pos = jax.lax.broadcasted_iota(jnp.int32, live2.shape, 1) + 1
     return jnp.max(jnp.where(live2, pos, 0), axis=1)
+
+
+def stream_block(sidx, tmap) -> int:
+    """Slots a grid block of a packed COO stream holds: a layout carries
+    its own block (pack_sorted_coo's `blk`), one tmap entry a block, so
+    the product kernels take it from the shapes they are handed."""
+    blk, rest = divmod(sidx.shape[0], tmap.shape[0])
+    assert rest == 0 and blk % STREAM_TILE == 0, (sidx.shape, tmap.shape)
+    return blk
 
 
 def chunks_run(ext, blk: int):
@@ -425,7 +443,7 @@ def _pull_kernel(tmap_ref, first_ref, ext_ref, w_ref, idx_ref, seg_ref,
         out_ref[:] += _onehot_dot(e_rt, p[:, None] * c_r, dtype)
 
     # a block past whose extent every val is 0 adds nothing there
-    _live_chunks(ext_ref[blk], BLK, body)
+    _live_chunks(ext_ref[blk], idx_ref.shape[0], body)
 
 
 def coo_spmv(w, sidx, sseg, sval, tmap, first, num_rows: int, dtype=None):
@@ -439,15 +457,16 @@ def coo_spmv(w, sidx, sseg, sval, tmap, first, num_rows: int, dtype=None):
         dtype = jnp.bfloat16 if not _use_interpret() else jnp.float32
     assert num_rows % LANES == 0
     nblk = tmap.shape[0]
-    ext = block_extents(sval != 0, BLK)
+    blk = stream_block(sidx, tmap)
+    ext = block_extents(sval != 0, blk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((TILE,), lambda b, tmap, *_: (tmap[b],)),
-            pl.BlockSpec((BLK,), lambda b, *_: (b,)),
-            pl.BlockSpec((BLK,), lambda b, *_: (b,)),
-            pl.BlockSpec((BLK,), lambda b, *_: (b,)),
+            pl.BlockSpec((blk,), lambda b, *_: (b,)),
+            pl.BlockSpec((blk,), lambda b, *_: (b,)),
+            pl.BlockSpec((blk,), lambda b, *_: (b,)),
         ],
         out_specs=pl.BlockSpec(
             (num_rows // LANES, LANES), lambda b, *_: (0, 0)),
@@ -492,7 +511,7 @@ def _push_kernel(tmap_ref, first_ref, ext_ref, d_ref, idx_ref, seg_ref,
         out_ref[:] += _onehot_dot(e_hit, c[:, None] * c_lo, dtype)
 
     # an empty block only zeroes its tile (above) when it opens one
-    _live_chunks(ext_ref[blk], BLK, body)
+    _live_chunks(ext_ref[blk], idx_ref.shape[0], body)
 
 
 def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
@@ -511,7 +530,8 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
     assert num_buckets % TILE == 0
     nblk = tmap.shape[0]
     d2 = d.reshape(num_rows // LANES, LANES)
-    ext = block_extents(sval != 0, BLK)
+    blk = stream_block(sidx, tmap)
+    ext = block_extents(sval != 0, blk)
     tile_spec = pl.BlockSpec(
         (TILE_HI, LANES), lambda b, tmap, *_: (tmap[b], 0))
     # the sum so far rides as one more input, tiled and aliased as the output
@@ -521,9 +541,9 @@ def coo_spmv_t(d, sidx, sseg, sval, tmap, first, num_buckets: int,
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((num_rows // LANES, LANES), lambda b, *_: (0, 0)),
-            pl.BlockSpec((BLK,), lambda b, *_: (b,)),
-            pl.BlockSpec((BLK,), lambda b, *_: (b,)),
-            pl.BlockSpec((BLK,), lambda b, *_: (b,)),
+            pl.BlockSpec((blk,), lambda b, *_: (b,)),
+            pl.BlockSpec((blk,), lambda b, *_: (b,)),
+            pl.BlockSpec((blk,), lambda b, *_: (b,)),
         ] + [tile_spec] * len(sums),
         out_specs=tile_spec,
     )
@@ -946,8 +966,8 @@ class MeshCOO:
     sidx: np.ndarray   # [D, M, P]
     sseg: np.ndarray   # [D, M, P] row ids local to the data shard
     sval: np.ndarray   # [D, M, P]
-    tmap: np.ndarray   # [D, M, P/BLK]
-    first: np.ndarray  # [D, M, P/BLK]
+    tmap: np.ndarray   # [D, M, P/blk], blk = mesh_block(capacity, nb_m)
+    first: np.ndarray  # [D, M, P/blk]
     dropped_nnz: int   # nonzeros beyond a shard's capacity (overflow)
     cell_nnz: np.ndarray  # [D, M] live nonzeros a cell got, before the cut
 
@@ -962,17 +982,36 @@ def mesh_capacity(capacity: int, D: int, M: int, slack: float = 2.0) -> int:
     return max((per + BLK - 1) // BLK, 1) * BLK
 
 
+def mesh_block(capacity_per_shard: int, nb_m: int) -> int:
+    """Slots a shard's runs are padded to. Every tile of a shard's
+    nb_m buckets gets at least one block, so where a shard has many
+    tiles for its nonzeros the blocks are mostly padding, and padding
+    is bytes the loader copies to the chip each batch: at 2^28 buckets
+    a shard and 1,277,952 nonzeros at capacity, 312 a tile, BLK-sized
+    blocks make a batch of 867 MB, 28 slots a nonzero. Where
+    STREAM_TILE slots hold twice a tile's mean at capacity the shard
+    packs at STREAM_TILE (263 MB there); every other shard keeps the
+    one-chip layout's BLK. The two values the chip measured (PERF.md
+    §6, PR 49: 512 is refused, 2,048 read worse than BLK and is not
+    understood, so the rule never gives it)."""
+    fits = STREAM_TILE * (nb_m // TILE) >= 2 * capacity_per_shard
+    return STREAM_TILE if fits else BLK
+
+
 def pack_mesh_coo(idx, seg, val, num_buckets: int, num_rows: int,
-                  D: int, M: int, capacity_per_shard: int) -> MeshCOO:
+                  D: int, M: int, capacity_per_shard: int,
+                  blk: int) -> MeshCOO:
     """Split COO triples into (data, model) mesh cells and pack each cell
-    (host-side, loader threads). Zero-valued entries (padding) are
-    dropped before splitting — they contribute nothing."""
+    (host-side, loader threads) at the capacity and the block the caller
+    took from the shard's geometry (mesh_capacity, mesh_block).
+    Zero-valued entries (padding) are dropped before splitting — they
+    contribute nothing."""
     nb_m = num_buckets // M
     rows_d = num_rows // D
     assert nb_m % TILE == 0, (num_buckets, M)
     assert rows_d % LANES == 0, (num_rows, D)
-    P = packed_size(capacity_per_shard, nb_m)
-    nblk = P // BLK
+    P = packed_size(capacity_per_shard, nb_m, blk=blk)
+    nblk = P // blk
     idx = np.asarray(idx, np.int64)
     seg = np.asarray(seg, np.int64)
     val = np.asarray(val, np.float32)
@@ -1000,7 +1039,7 @@ def pack_mesh_coo(idx, seg, val, num_buckets: int, num_rows: int,
                 cs = cs[:capacity_per_shard]
                 cv = cv[:capacity_per_shard]
             p = pack_sorted_coo(ci, cs, cv, nb_m,
-                                capacity=capacity_per_shard)
+                                capacity=capacity_per_shard, blk=blk)
             sidx[d, m] = p.idx
             sseg[d, m] = p.seg
             sval[d, m] = p.val
