@@ -350,8 +350,7 @@ def test_pull_and_push_equal_full_width(fill, full_width, dtype=jnp.float32):
 # ------------------------------------------- the native tcoo pack (PR 29)
 # pack_tile_coo has two bodies: one call of the native core
 # (native/src/pack.cc: one radix sort, every array written in its order)
-# and the numpy body (localize, assign_tile_slots, build_rm,
-# pack_sorted_coo). The second is the oracle: every field bit-equal.
+# and the numpy body (localize, assign_tile_slots, pack_sorted_coo). The second is the oracle: every field bit-equal.
 
 def _rows_batch(rng, live_per_row, num_buckets, pad_to=None):
     """CSR-ordered triples with `live_per_row[r]` entries in row r, then
@@ -384,22 +383,22 @@ def _tile_pack_case(name):
     nb = 128 * TILE
     U = ck.BLK_U
     roomy = 4 * TILE               # 256 update blocks: nothing is cut
-    if name == "fixed_width":      # exactly rm_width a row: build_rm's
-        idx, seg, val = _rows_batch(rng, [8] * 64, nb)    # fast path
+    if name == "fixed_width":      # the same count a row, the capacity
+        idx, seg, val = _rows_batch(rng, [8] * 64, nb)    # filled
         return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=roomy,
-                    capacity=512, rm_rows=64, rm_width=8)
+                    capacity=512)
     if name == "ragged_padded":
         live = rng.integers(0, 9, 64)
         idx, seg, val = _rows_batch(rng, live, nb, pad_to=512)
         val[rng.random(512) < 0.1] = 0.0          # explicit zeros too
         return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=roomy,
-                    capacity=512, rm_rows=64, rm_width=8)
-    if name == "row_over_width":   # rows 3 and 9 hold 7 and 5 live
-        live = [2] * 16            # entries against a width of 4
+                    capacity=512)
+    if name == "long_rows":        # rows 3 and 9 hold 7 and 5 live
+        live = [2] * 16            # entries where the others hold 2
         live[3], live[9] = 7, 5
         idx, seg, val = _rows_batch(rng, live, nb, pad_to=64)
         return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=roomy,
-                    capacity=64, rm_rows=16, rm_width=4)
+                    capacity=64)
     if name in ("u_cap_truncates_boundary_tile", "u_cap_cuts_at_a_tile"):
         # u_cap = TILE is 64 update blocks. 63 tiles of 3 keys take 63;
         # tile 63's 1,500 keys want 2 and get 1 (1,024 kept), tile 64
@@ -413,23 +412,22 @@ def _tile_pack_case(name):
         idx, seg, val = _keys_batch(keys, rng)
         val[rng.random(len(val)) < 0.2] = 0.0     # not counted as dropped
         return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=TILE,
-                    capacity=len(idx), rm_rows=len(idx), rm_width=2)
+                    capacity=len(idx))
     if name == "cut_leaves_exact_rows":
-        # the kept entries alone are exactly rm_width a row in row order
-        # (the fast path), with cut entries lying between them
+        # the kept entries alone are exactly two a row in row order,
+        # with cut entries lying between them
         keys = np.repeat(np.arange(64) * TILE + 5, 2)
         seg = np.repeat(np.arange(64, dtype=np.int32), 2)
         at = np.sort(rng.integers(0, 129, 9))
         idx = np.insert(keys, at, 70 * TILE + np.arange(9)).astype(np.int32)
         seg = np.insert(seg, at, 7).astype(np.int32)
         return dict(idx=idx, seg=seg, val=np.ones(len(idx), np.float32),
-                    num_buckets=nb, u_cap=TILE, capacity=len(idx),
-                    rm_rows=64, rm_width=2)
-    if name in ("empty", "empty_no_rm"):
+                    num_buckets=nb, u_cap=TILE, capacity=len(idx))
+    if name in ("empty", "empty_with_capacity"):
         z = np.zeros(0, np.int32)
-        rm = dict(rm_rows=4, rm_width=2) if name == "empty" else {}
         return dict(idx=z, seg=z, val=np.zeros(0, np.float32),
-                    num_buckets=nb, u_cap=TILE, capacity=None, **rm)
+                    num_buckets=nb, u_cap=TILE,
+                    capacity=None if name == "empty" else 2 * BLK)
     if name == "tile_edges_and_block_runs":
         # a tile's first and last bucket (the table's too), a run of
         # exactly BLK_U keys in one tile and of BLK_U + 1 in another
@@ -439,7 +437,7 @@ def _tile_pack_case(name):
         keys += list(20 * TILE + TILE - 1 - 7 * np.arange(U + 1))
         idx, seg, val = _keys_batch(keys, rng)
         return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=roomy,
-                    capacity=2 * len(idx), rm_rows=len(idx), rm_width=1)
+                    capacity=2 * len(idx))
     if name == "duplicate_keys_across_rows":
         # five keys, 600 entries: equal keys keep their input order
         live = [6] * 100
@@ -447,13 +445,12 @@ def _tile_pack_case(name):
         idx = rng.choice(np.array([3, TILE + 1, TILE + 2, 9 * TILE, nb - 1],
                                   np.int32), 600)
         return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=roomy,
-                    capacity=600, rm_rows=100, rm_width=6)
+                    capacity=600)
     if name == "one_key":          # every digit constant: no sort pass
         idx, seg, val = _rows_batch(rng, [4] * 32, nb)
         return dict(idx=np.full_like(idx, 3 * TILE + 17), seg=seg, val=val,
-                    num_buckets=nb, u_cap=roomy, capacity=128, rm_rows=32,
-                    rm_width=4)
-    if name == "no_row_major":
+                    num_buckets=nb, u_cap=roomy, capacity=128)
+    if name == "ragged_unpadded":
         idx, seg, val = _rows_batch(rng, rng.integers(0, 9, 64), nb)
         return dict(idx=idx, seg=seg, val=val, num_buckets=nb, u_cap=roomy,
                     capacity=512)
@@ -463,7 +460,7 @@ def _tile_pack_case(name):
         idx[::2] += top - 64 * TILE               # the table's two ends
         idx[:3] = top - 1, 0, top - 1
         return dict(idx=idx, seg=seg, val=val, num_buckets=top, u_cap=roomy,
-                    capacity=512, rm_rows=64, rm_width=8)
+                    capacity=512)
     if name == "criteo_shape":
         # skewed keys over 64 tiles, a compact domain of 16 tiles, spare
         # capacity: what a batch of the benchmark looks like, smaller
@@ -474,17 +471,16 @@ def _tile_pack_case(name):
         seg = np.repeat(np.arange(rows, dtype=np.int32), width)
         return dict(idx=idx, seg=seg, val=np.ones(rows * width, np.float32),
                     num_buckets=64 * TILE, u_cap=16 * TILE,
-                    capacity=rows * width + 3 * BLK, rm_rows=rows,
-                    rm_width=width)
+                    capacity=rows * width + 3 * BLK)
     raise KeyError(name)
 
 
 _TILE_PACK_CASES = [
-    "fixed_width", "ragged_padded", "row_over_width",
+    "fixed_width", "ragged_padded", "long_rows",
     "u_cap_truncates_boundary_tile", "u_cap_cuts_at_a_tile",
-    "cut_leaves_exact_rows", "empty", "empty_no_rm",
+    "cut_leaves_exact_rows", "empty", "empty_with_capacity",
     "tile_edges_and_block_runs", "duplicate_keys_across_rows", "one_key",
-    "no_row_major", "ids_up_to_2p31", "criteo_shape"]
+    "ragged_unpadded", "ids_up_to_2p31", "criteo_shape"]
 
 
 def _numpy_body(monkeypatch, **kw):
@@ -532,31 +528,53 @@ def test_native_tile_pack_is_bit_equal_to_the_numpy_body(case, monkeypatch):
             case == "u_cap_truncates_boundary_tile")
     else:
         assert want.dropped_uniq == 0
-    if case == "row_over_width":   # both sides lost the same 3 + 1
-        live = np.count_nonzero(kw["val"])
-        assert np.count_nonzero(want.rm_val) == live - 4
-        assert np.count_nonzero(want.coo.val) == live - 4
-    if case in ("fixed_width", "cut_leaves_exact_rows"):
-        assert (want.rm_slot != kw["u_cap"]).all()     # the fast path
+    if case == "long_rows":        # one stream holds a row of any length
+        assert np.count_nonzero(want.coo.val) == np.count_nonzero(kw["val"])
+        # where the row-major layout the FM pack still makes (build_rm)
+        # cuts a row at its width: rows 3 and 9 lose 3 + 1 at width 4
+        _, rm_val, over = ck.build_rm(kw["seg"], kw["idx"], kw["val"], 16,
+                                      4, kw["num_buckets"])
+        assert len(over) == 4 and set(kw["seg"][over]) == {3, 9}
+        assert np.count_nonzero(rm_val) == np.count_nonzero(kw["val"]) - 4
+    if case == "fixed_width":      # build_rm's fast path: the input's
+        rm_slot, rm_val, over = ck.build_rm(
+            kw["seg"], kw["idx"], kw["val"], 64, 8, kw["num_buckets"])
+        assert np.array_equal(rm_slot, kw["idx"]) and len(over) == 0
+        assert np.shares_memory(rm_val, kw["val"])
+    if case.startswith("empty"):   # a block a compact tile, all padding
+        assert want.coo.val.shape == ((kw["capacity"] or 0) + BLK,)
+        assert not want.coo.val.any()
 
 
 @pytest.mark.parametrize("case", ["int64_ids", "rows_not_grouped",
                                   "over_capacity"])
-def test_tile_pack_outside_the_native_domain_runs_the_numpy_body(case):
+def test_tile_pack_outside_the_native_domain_runs_the_numpy_body(
+        case, monkeypatch):
     """What the native pass cannot take, it hands to the numpy body,
     which decides what such a batch means: the same answer or the same
     refusal as before."""
+    from wormhole_tpu import native
+
     kw = _tile_pack_case("fixed_width")
     if case == "int64_ids":
         tc = ck.pack_tile_coo(**{**kw, "idx": kw["idx"].astype(np.int64)})
         assert not tc.packed_native
         _assert_same_bits(tc, ck.pack_tile_coo(**kw))
     elif case == "rows_not_grouped":
+        # no layout that is left asks for the rows in order: the batch
+        # is inside the native domain, and the stream holds the entry
+        # under the row it names
         kw = _tile_pack_case("ragged_padded")
         live = np.flatnonzero(kw["val"])
         kw["seg"][live[0]] = 63
-        with pytest.raises(ValueError, match="row-grouped"):
-            ck.pack_tile_coo(**kw)
+        tc = ck.pack_tile_coo(**kw)
+        assert tc.packed_native == native.available()
+        _assert_same_bits(tc, _numpy_body(monkeypatch, **kw))
+        at = np.flatnonzero(tc.coo.val == kw["val"][live[0]])
+        assert 63 in tc.coo.seg[at]
+        with pytest.raises(ValueError, match="row-grouped"):    # as ever
+            ck.build_rm(kw["seg"], kw["idx"], kw["val"], 64, 8,
+                        kw["num_buckets"])
     else:
         # two blocks of entries in one compact tile, room for one
         idx, seg, val = _rows_batch(np.random.default_rng(1), [8] * 1024,

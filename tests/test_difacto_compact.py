@@ -184,7 +184,7 @@ def test_train_pack_is_pure_and_cacheable(tmp_path):
     batches = _batches(rows, seed=5)
     first = lrn.prepare_batch(_block(*batches[0]))
     token = lrn.pack_cache_token(train=True)
-    assert token is not None and token[1] == lrn._PACK_VERSION == 2
+    assert token is not None and token[1] == lrn._PACK_VERSION == 3
     assert lrn._fm_caps in token and lrn.pack_cache_token(False) == token
     assert _same_bytes(first, lrn.prepare_batch(_block(*batches[0])))
     for b in batches[1:]:
@@ -250,11 +250,12 @@ def test_pack_cache_round_trip_with_three_loaders(tmp_path, monkeypatch):
 # ---------------------------------------------- what the linear path keeps
 @pytest.mark.parametrize("body", ["native", "numpy"])
 def test_tcoo_pack_is_bit_for_bit_the_one_before_this_pr(body, monkeypatch):
-    """The vector-row work shares `assign_tile_slots`, `pack_sorted_coo`
-    and `build_rm` with the linear learner's `tcoo` pack. A seeded batch
-    (a hot bucket, padding entries) packs to the bytes it packed to at
-    the parent commit (PR 30; the digest was taken there), by either
-    body of `pack_tile_coo`."""
+    """The vector-row work shares `assign_tile_slots` and
+    `pack_sorted_coo` with the linear learner's `tcoo` pack. A seeded
+    batch (a hot bucket, padding entries) packs to the bytes it packed to
+    at the parent commit (PR 30; the digest was taken again at PR 50's
+    parent over the arrays that remain, without the row-major companion
+    that pack made until then), by either body of `pack_tile_coo`."""
     import hashlib
 
     from wormhole_tpu import native
@@ -271,19 +272,18 @@ def test_tcoo_pack_is_bit_for_bit_the_one_before_this_pr(body, monkeypatch):
     val = np.ones(rows * nnz, np.float32)
     val[::11] = 0.0
     tc = ck.pack_tile_coo(idx, seg, val, nb, 2 * ck.TILE,
-                          capacity=rows * nnz, rm_rows=rows, rm_width=nnz)
+                          capacity=rows * nnz)
     assert tc.packed_native == (body == "native")
     h = hashlib.sha256()
     for a in (tc.uniq, tc.coo.idx, tc.coo.seg, tc.coo.val, tc.coo.tmap,
-              tc.coo.first, tc.tmap_u, tc.first_u, tc.last_u, tc.rm_slot,
-              tc.rm_val):
+              tc.coo.first, tc.tmap_u, tc.first_u, tc.last_u):
         h.update(np.ascontiguousarray(a).tobytes())
     ts = ck.assign_tile_slots(np.unique(idx), ck.TILE, 2 * ck.TILE, nb)
     for a in (ts.uniq, ts.tmap_u, ts.first_u, ts.last_u, ts.slot_of_uniq):
         h.update(np.ascontiguousarray(a).tobytes())
     assert (tc.num_uniq, tc.dropped_uniq) == (16826, 0)
-    assert h.hexdigest() == ("c400a51338ad0d7547435ece878f52a5c50dcaee6e26f"
-                             "1e8e502127178a00bf5")
+    assert h.hexdigest() == ("1a97c6bcee009d1cbe60ea6fd5bd50ca8720b07ecb6d8"
+                             "7dbb03446f2cafb16aa")
 
 
 # -------------------------------------------- the learner's three answers
@@ -301,7 +301,7 @@ def test_difacto_learner_answers_the_harness_itself(kernel, kinds):
     # staged under the solver's span, which the learner tells the bytes
     with obs_trace._Span(None, "loader.h2d", "loader", {}) as h2d:
         staged = lrn.stage_batch(prepared)
-    assert h2d.args == {"bytes": sum(a.nbytes for a in staged[1])}
+    assert h2d.args == {"bytes": sum(a.nbytes for a in staged[2])}
     assert (lrn.batch_kind(prepared), lrn.batch_kind(staged)) == kinds
     for b in (prepared, staged):
         got = lrn.batch_label(b)
@@ -359,3 +359,48 @@ def test_compact_step_with_grad_normalization_matches_the_reference():
     # it is no small thing: nV is a sum of squares, 200^2 times smaller
     assert plain["states"][-1]["nV"].sum() > 1e4 * want["states"][-1][
         "nV"].sum() > 0
+
+
+# ------------------------------------------------- the sparse PS push set
+def _digest(a) -> tuple:
+    import hashlib
+
+    return (len(a), hashlib.sha256(np.ascontiguousarray(a).tobytes())
+            .hexdigest()[:16])
+
+
+@pytest.mark.parametrize("kernel,want_w,want_v", [
+    ("pallas", (292, "f5a5016e289a6e95"), (292, "d1499ce3ce8173de")),
+    ("xla", (291, "d286d720eeb0b891"), (291, "7880a0fb3363f091"))])
+def test_collect_touched_gives_the_sets_it_gave_before_the_one_protocol(
+        kernel, want_w, want_v):
+    """After three train steps `collect_touched` names, for every table
+    of the w id space and of the V id space, the sorted unique rows the
+    steps wrote (the compact pack's live slots with the padding's
+    bucket, or the XLA batch's nonzero ids; their V rows): lengths and
+    digests taken at the parent commit of PR 50, where the learner kept
+    two lists of its own. A drained learner answers empty sets, and a
+    step whose batch was staged without the hint answers None."""
+    rows = 200         # of a 256-row minibatch: the pack pads
+    lrn = _learner(8, 256, kernel)
+    lrn.track_touched = True
+    batches = _batches(rows, seed=50, steps=4)
+    for keys, label in batches[:3]:
+        lrn.train_batch(_block(keys, label))
+    got = lrn.collect_touched()
+    assert sorted(got) == ["V", "cnt", "n", "nV", "w", "z"]
+    for k in ("w", "z", "n", "cnt"):
+        assert got[k] is got["w"]
+    assert got["nV"] is got["V"]
+    for a, nb in ((got["w"], NB), (got["V"], VB)):
+        assert a.dtype == np.int64 and np.all(np.diff(a) > 0) and a[-1] < nb
+    assert np.array_equal(got["V"], np.unique(got["w"] % VB))
+    assert (_digest(got["w"]), _digest(got["V"])) == (want_w, want_v)
+    again = lrn.collect_touched()
+    assert sorted(again) == sorted(got) and all(
+        len(a) == 0 for a in again.values())
+    lrn.track_touched = False
+    staged = lrn.stage_batch(_block(*batches[3]), True)
+    lrn.track_touched = True
+    lrn.train_batch(staged)
+    assert lrn.collect_touched() is None

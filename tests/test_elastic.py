@@ -22,11 +22,11 @@ import pytest
 from tests.conftest import synth_libsvm_text
 from wormhole_tpu.runtime.allreduce import BspWorker
 from wormhole_tpu.runtime.tracker import (
+    MembershipController,
     RemotePool,
     Scheduler,
     SchedulerClient,
 )
-from wormhole_tpu.solver.minibatch_solver import MembershipController
 from wormhole_tpu.solver.workload import WorkloadPool
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

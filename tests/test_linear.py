@@ -314,34 +314,55 @@ def test_compacted_predict_and_eval(synth_file):
 
 
 # ------------------------------------------- one record a kind, one path
-# kind -> (config, mesh shape, pack_cache_token written out). The token's
-# tail is the block geometry (TILE, BLK, BLK_U, LANES): a data format,
-# constant beside the kernels; the mesh pack's block rides after the
-# shard's capacity, like it a function of the table and the mesh that
-# only an `mcoo` batch is packed by (ck.mesh_block: BLK here, where a
-# shard has one to four tiles for its 4,096 nonzeros)
+# case -> (config, mesh shape, pack_cache_token written out): the linear
+# learner's four kinds under their own names, DiFacto's two as
+# "difacto-<kind>"; one protocol (models/minibatch_learner.py) for both.
+# The token's tail is the block geometry (TILE, BLK, BLK_U, LANES, and
+# for the compact FM pack its capacities, stride, TILE_HI and FM_BLK): a
+# data format, constant beside the kernels; the mesh pack's block rides
+# after the shard's capacity, like it a function of the table and the
+# mesh that only an `mcoo` batch is packed by (ck.mesh_block: BLK here,
+# where a shard has one to four tiles for its 4,096 nonzeros)
+_VB = 4096
 _KINDS = {
     "xla": (dict(kernel="xla"), (1, 1),
-            ("linear", 2, False, False, 0, 4096, 4096, 256, 8, 262144, 1, 1,
+            ("linear", 3, False, False, 0, 4096, 4096, 256, 8, 262144, 1, 1,
              65536, 4096, 1024, 128)),
     "coo": (dict(kernel="pallas", compact_cap=0), (1, 1),
-            ("linear", 2, True, False, 0, 4096, 4096, 256, 8, 262144, 1, 1,
+            ("linear", 3, True, False, 0, 4096, 4096, 256, 8, 262144, 1, 1,
              65536, 4096, 1024, 128)),
     "tcoo": (dict(kernel="pallas", compact_cap=1), (1, 1),
-             ("linear", 2, True, False, 65536, 4096, 4096, 256, 8, 262144,
+             ("linear", 3, True, False, 65536, 4096, 4096, 256, 8, 262144,
               1, 1, 65536, 4096, 1024, 128)),
     "mcoo": (dict(kernel="pallas", model_shards=2), (2, 2),
-             ("linear", 2, True, True, 0, 4096, 4096, 256, 8, 262144, 2, 2,
+             ("linear", 3, True, True, 0, 4096, 4096, 256, 8, 262144, 2, 2,
               65536, 4096, 1024, 128)),
+    "difacto-xla": (dict(kernel="xla"), (1, 1),
+                    ("difacto", 3, False, 256, 8, 262144, _VB, 4)),
+    "difacto-fm": (dict(kernel="pallas"), (1, 1),
+                   ("difacto", 3, True, 256, 8, 262144, _VB, 4,
+                    (65536, 3072, 512), 4, 65536, 4096, 1024, 512, 1024,
+                    128)),
 }
+_LINEAR_KINDS = [c for c in _KINDS if "-" not in c]
 
 
-def _kind_learner(kind, **kw):
-    conf, mesh, _ = _KINDS[kind]
-    cfg = LinearConfig(minibatch=256, num_buckets=1 << 18, nnz_per_row=8,
-                       algo="ftrl", lr_eta=0.5, lambda_l1=0.1,
-                       kernel_dtype="f32", **conf, **kw)
-    return LinearLearner(cfg, make_mesh(*mesh))
+def _kind_learner(case, **kw):
+    conf, mesh, _ = _KINDS[case]
+    sizes = dict(minibatch=256, num_buckets=1 << 18, nnz_per_row=8,
+                 algo="ftrl", lr_eta=0.5, lambda_l1=0.1, kernel_dtype="f32")
+    if case.startswith("difacto-"):
+        from wormhole_tpu.models.difacto import DifactoConfig, DifactoLearner
+
+        return DifactoLearner(DifactoConfig(
+            v_buckets=_VB, dim=4, threshold=2, **sizes, **conf, **kw),
+            make_mesh(*mesh))
+    return LinearLearner(LinearConfig(**sizes, **conf, **kw),
+                         make_mesh(*mesh))
+
+
+def _kind_tables(lrn) -> dict:
+    return (getattr(lrn, "ckpt_store", None) or lrn.store).to_numpy()
 
 
 def _kind_blocks(n=3, rows=200, nnz=8, nb=1 << 18):
@@ -376,7 +397,7 @@ def test_every_form_of_a_batch_takes_the_one_path(kind):
                  lrn.eval_batch(form(lrn, b2, False))]
         if name != "staged":    # a staged batch was never a predict input
             progs.append(lrn.predict_batch(form(lrn, b2, False)))
-        got[name] = (progs, lrn.store.to_numpy())
+        got[name] = (progs, _kind_tables(lrn))
     assert got["rowblock"][0][0]["nex"] == 200.0
     assert got["rowblock"][0][0]["new_w"] > 0
     ref_progs, ref_tables = got["rowblock"]
@@ -389,7 +410,7 @@ def test_every_form_of_a_batch_takes_the_one_path(kind):
     assert ref_progs[3].shape == (200,) and np.any(ref_progs[3] != 0)
 
 
-@pytest.mark.parametrize("kind", list(_KINDS))
+@pytest.mark.parametrize("kind", _LINEAR_KINDS)
 def test_ftrl_w_is_the_derived_table_of_z_and_n(kind):
     """What lets FTRL's update write w without reading it: on every
     bucket the stored w is `ftrl_weight` of the stored z and n, bit for
@@ -433,33 +454,42 @@ def test_a_batch_staged_for_the_other_step_is_refused(kind):
         lrn.train_batch(lrn.stage_batch(blk, train=False))
     with pytest.raises(AssertionError, match="staged for train"):
         lrn.eval_batch(lrn.stage_batch(blk, train=True))
+    if kind == "difacto-fm":    # the one kind whose packs differ
+        for train in (True, False):
+            with pytest.raises(AssertionError, match="packed for the other"):
+                lrn.stage_batch(lrn.prepare_batch(blk, not train), train)
 
 
-@pytest.mark.parametrize("kind", list(_KINDS))
-def test_batch_layouts_and_pack_cache_token_are_pinned(kind):
+@pytest.mark.parametrize("case", list(_KINDS))
+def test_batch_layouts_and_pack_cache_token_are_pinned(case):
     """The pack cache's entries, benchmark/check.py and chip_smoke.py read
-    these tuples by position, and the token keys the cache's entries."""
+    these tuples by position, and the token keys the cache's entries:
+    one layout for both learners. The touched ids are an array an id
+    space: the buckets, and for DiFacto the V rows they hash to."""
     from wormhole_tpu.data.rowblock import DeviceBatch
 
-    lrn = _kind_learner(kind)
+    kind = case.rsplit("-", 1)[-1]
+    lrn = _kind_learner(case)
     lrn.track_touched = True
     blk = _kind_blocks(1)[0]
-    assert lrn._PACK_VERSION == 2
-    if kind == "tcoo":      # undecided until the first batch sizes it
+    assert lrn._PACK_VERSION == 3
+    if kind in ("tcoo", "fm"):  # undecided until the first batch sizes it
         assert lrn.pack_cache_token() is None
     b = lrn.prepare_batch(blk)
-    assert lrn.pack_cache_token() == _KINDS[kind][2]
+    assert lrn.pack_cache_token() == _KINDS[case][2]
     assert b[0] == kind and b[-1] == 200
-    if kind == "xla":
-        assert len(b) == 3 and isinstance(b[1], DeviceBatch)
-        label, mask = b[1].label, b[1].row_mask
-    else:
-        assert len(b) == 5
-        label, mask = b[2], b[3]
-    assert label.shape == mask.shape == (256,)
-    np.testing.assert_array_equal(label[:200], blk.label)
-    assert mask.sum() == 200
+    assert lrn.batch_kind(b) == kind
     for train in (True, False):
+        b = lrn.prepare_batch(blk, train)
+        if kind == "xla":
+            assert len(b) == 3 and isinstance(b[1], DeviceBatch)
+            label, mask = b[1].label, b[1].row_mask
+        else:
+            assert len(b) == 5
+            label, mask = b[2], b[3]
+        assert label.shape == mask.shape == (256,)
+        np.testing.assert_array_equal(label[:200], blk.label)
+        assert mask.sum() == 200
         st = lrn.stage_batch(b, train)
         assert len(st) == 6 and st[:2] == ("staged", kind)
         assert st[3] == 200 and st[5] is train
@@ -468,14 +498,19 @@ def test_batch_layouts_and_pack_cache_token_are_pinned(kind):
         assert isinstance(args, tuple)
         np.testing.assert_array_equal(np.asarray(args[-2]), label)
         np.testing.assert_array_equal(np.asarray(args[-1]), mask)
+        for form in (b, st):
+            np.testing.assert_array_equal(lrn.batch_label(form), label)
         ids = st[4]
         if not train or kind == "mcoo":   # mcoo: left to the delta scan
             assert ids is None
-        else:
-            want = np.unique(blk.index.astype(np.int64) % (1 << 18))
-            if kind == "tcoo":      # the padding's bucket rides along
-                want = np.union1d(want, [0])
-            np.testing.assert_array_equal(ids, want)
+            continue
+        want = np.unique(blk.index.astype(np.int64) % (1 << 18))
+        if kind in ("tcoo", "fm"):  # the padding's bucket rides along
+            want = np.union1d(want, [0])
+        spaces = (want, np.unique(want % _VB))[:len(lrn._id_spaces())]
+        assert len(ids) == len(spaces) == 1 + case.startswith("difacto-")
+        for got, ref in zip(ids, spaces):
+            np.testing.assert_array_equal(got, ref)
 
 
 # ------------------------- the compact step pulls over its own COO stream
@@ -606,7 +641,11 @@ def test_compact_batches_ship_one_stream_and_no_row_major_companion():
     lrn = _kind_learner("tcoo")
     b = lrn.prepare_batch(_kind_blocks(1)[0])
     tc = b[1]
-    assert tc.rm_slot is None and tc.rm_val is None
+    import dataclasses
+
+    assert [f.name for f in dataclasses.fields(tc)] == [
+        "uniq", "coo", "tmap_u", "first_u", "last_u", "num_uniq",
+        "dropped_uniq", "dropped_nnz"]
     p = tc.coo
     stream = (p.idx, p.seg, p.val, p.tmap, p.first)
     for train, head in ((True, (tc.uniq, tc.tmap_u, tc.first_u, tc.last_u)),

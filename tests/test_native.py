@@ -254,7 +254,7 @@ def test_native_concurrent_stress():
         h = native.cityhash64(b"stress-%d" % i)
         # each thread keeps its own work space between its packs
         tc = native.pack_tile_coo(ids, rows, vals, 1 << 22, 1 << 19,
-                                  200000, 5000, 64, 1 << 16, 4096, 1024)
+                                  200000, 1 << 16, 4096, 1024)
         return (blk.size, int(order[0]), float(got[0]), h,
                 tc["uniq"].tobytes() + tc["val"].tobytes())
 

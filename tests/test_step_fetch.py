@@ -16,6 +16,7 @@ import pytest
 from wormhole_tpu.data.rowblock import RowBlock
 from wormhole_tpu.models import difacto as df
 from wormhole_tpu.models import linear as lin
+from wormhole_tpu.models import minibatch_learner as mbl
 from wormhole_tpu.obs.metrics import REGISTRY
 from wormhole_tpu.ops import coo_kernels as ck
 from wormhole_tpu.parallel.mesh import make_mesh
@@ -85,10 +86,10 @@ def _unpacked(monkeypatch):
     """Take the packing out, for learners built from here on: their steps
     return the dict `_progress` made, and it is read a scalar at a time
     (`tree_map(float, prog)` was the parent's line)."""
-    monkeypatch.setattr(lin, "pack_progress",
+    monkeypatch.setattr(mbl, "pack_progress",
                         lambda p, keys: {k: p[k] for k in keys})
     monkeypatch.setattr(
-        lin, "read_progress",
+        mbl, "read_progress",
         lambda prog, keys: jax.tree_util.tree_map(float, prog))
 
 
@@ -135,15 +136,15 @@ def test_pack_and_read_are_inverse_and_hold_the_order():
     import jax.numpy as jnp
 
     p = {"b": jnp.float32(0.1), "a": jnp.float32(3.0)}
-    vec = lin.pack_progress(p, ("a", "b"))
+    vec = mbl.pack_progress(p, ("a", "b"))
     assert vec.shape == (2,) and vec.dtype == jnp.float32
-    out = lin.read_progress(vec, ("a", "b"))
+    out = mbl.read_progress(vec, ("a", "b"))
     assert out == {"a": 3.0, "b": float(np.float32(0.1))}
     assert list(out) == ["a", "b"]
     with pytest.raises(AssertionError):
-        lin.pack_progress(p, ("a",))
+        mbl.pack_progress(p, ("a",))
     with pytest.raises(AssertionError):
-        lin.read_progress(vec, ("a", "b", "c"))
+        mbl.read_progress(vec, ("a", "b", "c"))
 
 
 def test_fm_step_counts_arrive_exact_past_two_to_the_sixteen():
@@ -220,7 +221,7 @@ def test_dropout_draws_the_masks_of_the_parents_key_chain(case):
     if case == "difacto-xla":
         ref = _learner(case, dropout=0.3)
         for blk, sub in zip(blocks, subs):
-            _, args, _, _, _ = ref.stage_batch(blk, True)
+            args = ref.stage_batch(blk, True)[2]
             ref.store.state, ref.vstore.state, _ = ref._train_step(
                 ref.store.state, ref.vstore.state, *args, sub)
         want = tables(ref)

@@ -271,7 +271,7 @@ def _global_train(cfg, env, make_learner, verbose, client) -> dict:
             else:
                 prog = eval_fn(args)
             # prog holds Python floats: the step's one read is made
-            # (models/linear.read_progress). nex is a GLOBAL sum (the
+            # (models/minibatch_learner.read_progress). nex is a GLOBAL sum (the
             # batch mask is mesh-sharded): zero means every rank drained.
             # The decision must be THE SAME on every rank (the next step
             # is a collective), so it depends only on this global value —

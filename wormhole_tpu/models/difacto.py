@@ -38,9 +38,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from wormhole_tpu.data.rowblock import DeviceBatch, RowBlock, to_device_batch
+from wormhole_tpu.data.rowblock import DeviceBatch
 from wormhole_tpu.models import linear as linmod
-from wormhole_tpu.obs import trace as _trace
+from wormhole_tpu.models import minibatch_learner as mbl
 from wormhole_tpu.obs.metrics import REGISTRY
 from wormhole_tpu.ops import coo_kernels as ck
 from wormhole_tpu.ops.fused_update import (row_gather, scatter_update,
@@ -48,8 +48,7 @@ from wormhole_tpu.ops.fused_update import (row_gather, scatter_update,
 from wormhole_tpu.ops.localizer import localize
 from wormhole_tpu.ops.spmv import row_squares, spmm, spmv, spmv_t
 from wormhole_tpu.parallel.kvstore import KVStore, TableSpec, quantize_push
-from wormhole_tpu.parallel.mesh import (batch_sharding, describe_placement,
-                                        make_mesh)
+from wormhole_tpu.parallel.mesh import describe_placement
 
 _log = logging.getLogger(__name__)
 
@@ -62,9 +61,9 @@ _V_ROWS = REGISTRY.counter("difacto.v.rows")
 _STEP_LIVE = REGISTRY.counter("difacto.step.live_nnz")
 _STEP_ADMITTED = REGISTRY.counter("difacto.step.admitted_nnz")
 
-#: what a train step packs (linmod.pack_progress): the XLA step's
+#: what a train step packs (mbl.pack_progress): the XLA step's
 #: progress, and after it the compact step's two nonzero counts
-XLA_TRAIN_KEYS = tuple(sorted(linmod.TRAIN_KEYS + ("objv_w",)))
+XLA_TRAIN_KEYS = tuple(sorted(mbl.TRAIN_KEYS + ("objv_w",)))
 FM_TRAIN_KEYS = XLA_TRAIN_KEYS + (
     "live_nnz.hi", "live_nnz.lo", "admitted_nnz.hi", "admitted_nnz.lo")
 
@@ -253,8 +252,11 @@ class _Tables(Mapping):
         return len(self._store.state)
 
 
-class DifactoLearner:
+class DifactoLearner(mbl.MinibatchLearner):
     """Jitted FM train/eval/predict over sharded w and V tables."""
+
+    # one forward program serves eval and predict: it takes the rows
+    _predict_rows = True
 
     def __init__(self, cfg: DifactoConfig, mesh=None, seed: int = 0):
         assert 0 < cfg.vb <= cfg.num_buckets, (
@@ -262,8 +264,7 @@ class DifactoLearner:
         assert cfg.algo == "ftrl", (
             "difacto trains w with FTRL (reference async_sgd.h:262-286); "
             f"algo={cfg.algo!r} is not supported here")
-        self.cfg = cfg
-        self.mesh = mesh if mesh is not None else make_mesh(num_model=1)
+        super().__init__(cfg, mesh)
         # compact Pallas FM path (see the block comment above _pack_fm);
         # l1_shrk needs w != 0 beside the count in the admission test,
         # sharded meshes use the XLA collectives path
@@ -295,6 +296,8 @@ class DifactoLearner:
         #: rows of a compact batch: the minibatch padded to the kernels'
         #: lane multiple; rows past cfg.minibatch are masked and empty
         self._rows = -(-cfg.minibatch // ck.LANES) * ck.LANES
+        if self._use_fm_pallas:
+            self._batch_rows = self._rows
         specs = _tables_for(cfg, self._stride)
         self.store = KVStore(self.mesh, cfg.num_buckets,
                              {k: v for k, v in specs.items()
@@ -304,9 +307,6 @@ class DifactoLearner:
         self.vstore = KVStore(self.mesh, cfg.vb,
                               {k: v for k, v in specs.items()
                                if v.tail != ()}, seed=seed + 1)
-        self._bsh1 = batch_sharding(self.mesh, 1)
-        self._dropped_rows = 0
-        self._step_count = 0
         self.ckpt_store = _CombinedStore(self.store, self.vstore)
         #: start-up statement of where and how this learner runs
         self.placement = describe_placement(
@@ -316,12 +316,6 @@ class DifactoLearner:
         self._fm_caps = None
         self._fm_steps = None
         self._fm_lock = threading.Lock()
-        # sparse PS wire hints: unique w-space / V-space rows touched by
-        # trained batches since the last collect_touched() drain
-        self.track_touched = False
-        self._touched_lock = threading.Lock()
-        self._touched_w: list[np.ndarray] = []
-        self._touched_v: list[np.ndarray] = []
 
         def train_step(state, vstate, seg, idx, vidx, val, label, mask, rngkey):
             new_state = dict(state)
@@ -389,7 +383,7 @@ class DifactoLearner:
             prog = linmod._progress(obj, margin, label, mask, new_w)
             obj_w, _ = linmod._loss_dual(cfg.loss, label, xw)
             prog["objv_w"] = jnp.sum(obj_w * mask)
-            return new_state, new_vstate, linmod.pack_progress(
+            return new_state, new_vstate, mbl.pack_progress(
                 prog, XLA_TRAIN_KEYS)
 
         @jax.jit
@@ -398,9 +392,9 @@ class DifactoLearner:
                 cfg, state["w"], vstate["V"], state["cnt"],
                 seg, idx, vidx, val, label.shape[0])
             obj, _ = linmod._loss_dual(cfg.loss, label, margin)
-            return margin, linmod.pack_progress(
+            return margin, mbl.pack_progress(
                 linmod._progress(obj, margin, label, mask),
-                linmod.EVAL_KEYS)
+                mbl.EVAL_KEYS)
 
         # the global SPMD loop hands every rank the same sub-key
         # (global_step_protocol); train_batch chains the learner's own
@@ -409,14 +403,63 @@ class DifactoLearner:
         self._fwd = fwd
         self._rng = jax.random.PRNGKey(seed + 17)
 
-    def derived_tables(self) -> dict:
-        """w trains by FTRL (async_sgd.h:262-286): non-additive prox of
-        the additive (z, n), recomputed server-side (see
-        LinearLearner.derived_tables)."""
-        cfg = self.cfg
-        return {"w": {"kind": "ftrl_prox", "lr_eta": cfg.lr_eta,
-                      "lr_beta": cfg.lr_beta, "lambda_l1": cfg.lambda_l1,
-                      "lambda_l2": cfg.lambda_l2}}
+        nb, vb = cfg.num_buckets, cfg.vb
+
+        def with_v(ids_w):
+            # a batch's rows in w's id space, and the V rows they hash to
+            return ids_w, np.unique(ids_w % vb)
+
+        self._kinds = {
+            "xla": mbl._Kind(
+                lambda db, train: db,
+                mbl._device_args(
+                    lambda db, train: (
+                        db.seg, db.idx,
+                        (db.idx % np.int32(vb)).astype(np.int32), db.val),
+                    partial(jax.device_put, device=self._bsh1)),
+                *self._steps(lambda: (self._train_keyed, self._fwd)),
+                lambda db: with_v(linmod._nonzero_ids(db)[0]),
+                XLA_TRAIN_KEYS),
+            # the compact path (see the block comment above _pack_fm);
+            # sentinel slots are no rows of the table
+            "fm": mbl._Kind(
+                self._pack_fm,
+                mbl._device_args(self._fm_arrays, jax.device_put),
+                *self._steps(lambda: self._fm_steps),
+                lambda pk: with_v(pk[0][pk[0] < nb].astype(np.int64)),
+                FM_TRAIN_KEYS),
+        }
+
+    def _id_spaces(self) -> tuple:
+        return (self.store.state, self.vstore.state)
+
+    def _steps(self, programs):
+        """A kind's (train, eval, predict) over `programs()`, its jitted
+        (keyed train step, forward) pair, looked up a call because the
+        compact pair is built with the capacities, after its record:
+        thin wrappers outside the jit, one launch a step. Beside the w
+        tables a step reads the V tables where they live and writes them
+        back; the key it drew from comes back advanced, and stays on the
+        device."""
+        def train(state, *args):
+            state, self.vstore.state, prog, self._rng = programs()[0](
+                state, self.vstore.state, *args, self._rng)
+            return state, prog
+
+        def forward(out):   # the forward gives (margins, progress)
+            return lambda state, *args: programs()[1](
+                state, self.vstore.state, *args)[out]
+
+        return train, forward(1), forward(0)
+
+    def _trained(self, out: dict) -> dict:
+        if "live_nnz.hi" in out:    # the compact step's two counts
+            _STEP_LIVE.inc(_whole(out, "live_nnz"))
+            _STEP_ADMITTED.inc(_whole(out, "admitted_nnz"))
+        return out
+
+    def _choose_kind(self, db: DeviceBatch) -> str:
+        return "fm" if self._use_fm_pallas else "xla"
 
     # -- compact Pallas FM path ---------------------------------------------
     # The XLA segment-op step makes dense temporaries of both tables'
@@ -457,14 +500,6 @@ class DifactoLearner:
         "wfirst_u", "wlast_u", "wcnts", "widx", "wseg", "wval", "wtmap",
         "wfirst", "vidx", "vseg", "vval", "vtmap", "vfirst",
     ) + _FM_EVAL[2:]
-
-    def _fm_dtype_of(self):
-        cfg = self.cfg
-        if cfg.kernel_dtype == "f32":
-            return jnp.float32
-        if cfg.kernel_dtype == "auto" and cfg.fixed_bytes == 0:
-            return jnp.float32
-        return None  # kernel default (bf16 on TPU, f32 in interpret)
 
     def _size_fm(self, uniq, live_counts, nnz_live: int) -> tuple:
         """The permanent capacities, from the first batch packed: compact
@@ -551,7 +586,7 @@ class DifactoLearner:
         # and xv / x2 sums are row gathers from U (see _build_fm). Key
         # uk_cap is the appended zero row.
         W = cfg.nnz_per_row
-        rm_key, (rm_wval,), over = ck.build_rm(
+        rm_key, rm_wval, over = ck.build_rm(
             seg, key_nz, val, cfg.minibatch, W, uk_cap)
         rm_dropped = 0
         if len(over):
@@ -613,7 +648,7 @@ class DifactoLearner:
         cfg = self.cfg
         S, rows = self._stride, self._rows
         uvr_cap = ul_cap * (ck.LANES // S)
-        dt = self._fm_dtype_of()
+        dt = mbl.kernel_dtype(cfg)
         # wire dtype for the XLA gather operands (U, xvd): dt resolves
         # to None in bf16 mode (the kernels pick bf16 internally), but
         # astype(None) is a float32 no-op — so name the gather dtype
@@ -744,7 +779,7 @@ class DifactoLearner:
                                 jnp.sum(nnzK.astype(jnp.int32))))
             prog.update(_halves("admitted_nnz",
                                 jnp.sum((nnzK * adm_key).astype(jnp.int32))))
-            return new_state, new_vstate, linmod.pack_progress(
+            return new_state, new_vstate, mbl.pack_progress(
                 prog, FM_TRAIN_KEYS)
 
         @jax.jit
@@ -757,27 +792,11 @@ class DifactoLearner:
             margin = forward_rm(wc, cc, Vl, key_slot, key_vslot, rm_key,
                                 rm_wval)[2]
             obj, _ = linmod._loss_dual(cfg.loss, label, margin)
-            return margin, linmod.pack_progress(
+            return margin, mbl.pack_progress(
                 linmod._progress(obj, margin, label, mask),
-                linmod.EVAL_KEYS)
+                mbl.EVAL_KEYS)
 
         self._fm_steps = (_keyed(train_fm), fwd_fm)
-
-    def prepare_batch(self, blk: RowBlock, train: bool = True):
-        """Host-side batch prep for the solver's loader threads: pad to
-        the fixed device shape and, on the compact path, pack. Returns
-        ("xla", db, size) or ("fm", packed host arrays, label, mask,
-        size, train); stage_batch moves either to the device."""
-        cfg = self.cfg
-        fm = self._use_fm_pallas
-        db = to_device_batch(blk, self._rows if fm else cfg.minibatch,
-                             cfg.row_capacity, cfg.num_buckets)
-        if db.dropped_rows:
-            self._dropped_rows += db.dropped_rows
-        if not fm:
-            return ("xla", db, blk.size)
-        return ("fm", self._pack_fm(db, train), db.label, db.row_mask,
-                blk.size, train)
 
     # -- global-mesh SPMD protocol (apps/_runner._global_train) ------------
     def global_step_protocol(self):
@@ -792,26 +811,21 @@ class DifactoLearner:
             self.store.state, self.vstore.state, prog = self._train_step(
                 self.store.state, self.vstore.state, seg, idx, vidx, val,
                 label, mask, rng)
-            return linmod.read_progress(prog, XLA_TRAIN_KEYS)
+            return mbl.read_progress(prog, XLA_TRAIN_KEYS)
 
         def eval_fn(args):
             seg, idx, val, label, mask = args
             vidx = idx % np.int32(vb)
             _, prog = self._fwd(self.store.state, self.vstore.state,
                                 seg, idx, vidx, val, label, mask)
-            return linmod.read_progress(prog, linmod.EVAL_KEYS)
+            return mbl.read_progress(prog, mbl.EVAL_KEYS)
 
         return train_fn, eval_fn
 
     def global_predict_protocol(self):
         """pred_fn over (seg, idx, val, mask) GLOBAL arrays — see
         LinearLearner.global_predict_protocol."""
-        import jax.numpy as jnp
-
-        from wormhole_tpu.parallel.mesh import batch_sharding
-
-        vb = self.cfg.vb
-        bsh = batch_sharding(self.mesh, 1)
+        vb, bsh = self.cfg.vb, self._bsh1
 
         @jax.jit
         def pred(state, vstate, seg, idx, val, mask):
@@ -830,7 +844,7 @@ class DifactoLearner:
 
     # -- epoch pack cache ----------------------------------------------------
     #: bump when prepare_batch's output layout changes for identical input
-    _PACK_VERSION = 2
+    _PACK_VERSION = 3
 
     def pack_cache_token(self, train: bool = True):
         """See LinearLearner.pack_cache_token. Both paths pack with no
@@ -849,52 +863,18 @@ class DifactoLearner:
         return base + (self._fm_caps, self._stride, ck.TILE, ck.BLK,
                        ck.BLK_U, ck.TILE_HI, ck.FM_BLK, ck.LANES)
 
-    # -- double-buffered device feed -----------------------------------------
-    def stage_batch(self, b, train: bool = True):
-        """Loader-side device placement of a prepared batch (a RowBlock
-        is prepared first; a staged batch comes back as it is). Returns
-        ("fm_staged" | "xla_staged", device args, size, train, ids)."""
-        b = self._prepared(b, train)
-        if b[0] in ("fm_staged", "xla_staged"):
-            return b
-        ids = None
-        if b[0] == "fm":
-            _, pk, label, mask, size, train = b
-            with self._fm_lock:
-                if self._fm_caps is None:
-                    # a pack this learner did not make (the pack cache's
-                    # disk tier): the capacities are the lengths of its
-                    # uniq_w, key_slot and vlines (_FM_EVAL's order)
-                    self._fm_caps = (len(pk[0]), len(pk[-4]), len(pk[-5]))
-                    self._build_fm(*self._fm_caps)
-            if train and self.track_touched:
-                # touched rows for the sparse PS wire, from the host
-                # arrays while they are at hand (sentinel slots filtered)
-                ids_w = pk[0][pk[0] < self.cfg.num_buckets].astype(np.int64)
-                ids = (ids_w, np.unique(ids_w % self.cfg.vb))
-            args = tuple(jax.device_put(a) for a in (*pk, label, mask))
-            # what the batch moves to the device: on the solver's
-            # loader.h2d span round this call
-            _trace.annotate(bytes=sum(a.nbytes for a in args))
-            return ("fm_staged", args, size, train, ids)
-        db, size = b[1], b[2]
-        if train and self.track_touched:
-            ids_w = np.unique(db.idx[db.val != 0]).astype(np.int64)
-            ids = (ids_w, ids_w % self.cfg.vb)
-        args = self._xla_args(db)
-        _trace.annotate(bytes=sum(a.nbytes for a in args))
-        return ("xla_staged", args, size, train, ids)
-
-    def _prepared(self, blk, train: bool):
-        if isinstance(blk, RowBlock):
-            return self.prepare_batch(blk, train=train)
-        return blk
-
-    def _xla_args(self, db):
-        vidx = (db.idx % np.int32(self.cfg.vb)).astype(np.int32)
-        put = lambda x: jax.device_put(x, self._bsh1)
-        return (put(db.seg), put(db.idx), put(vidx), put(db.val),
-                put(db.label), put(db.row_mask))
+    def _fm_arrays(self, pk, train: bool) -> tuple:
+        """A compact pack on its way to the device, as it is. One this
+        learner did not make (the pack cache's disk tier) brings the
+        capacities, the lengths of its uniq_w, key_slot and vlines
+        (_FM_EVAL's order), and the steps are built for them."""
+        assert len(pk) == len(self._FM_TRAIN if train else self._FM_EVAL), (
+            "batch was packed for the other step")
+        with self._fm_lock:
+            if self._fm_caps is None:
+                self._fm_caps = (len(pk[0]), len(pk[-4]), len(pk[-5]))
+                self._build_fm(*self._fm_caps)
+        return pk
 
     # -- what a harness asks of the learner (benchmark/check.py) -------------
     def tables(self) -> Mapping:
@@ -902,98 +882,22 @@ class DifactoLearner:
         (v_buckets, dim), however it is stored."""
         return _Tables(self.ckpt_store)
 
-    @staticmethod
-    def batch_kind(b) -> str:
-        """What step a batch takes: "fm" (the compact path, prepared or
-        staged), "xla" or, once staged, "xla_staged"."""
-        return "fm" if b[0] == "fm_staged" else b[0]
+    def batch_kind(self, b) -> str:
+        # a staged XLA batch answers "xla_staged", the tuple head it had
+        # until PR 50: tests/benchmark/fixtures/difacto-fixture.json
+        # (expect_kind), test_benchmark_fetch_reads.py and
+        # test_benchmark_vector_rows.py pin it (ROADMAP C15)
+        kind = super().batch_kind(b)
+        return "xla_staged" if (b[0], kind) == ("staged", "xla") else kind
 
-    def batch_label(self, b) -> np.ndarray:
-        """A prepared or staged batch's labels on the host: the
-        minibatch's rows, without the rows the compact path pads on."""
-        label = b[1].label if b[0] == "xla" else (
-            b[2] if b[0] == "fm" else b[1][-2])
-        return np.asarray(label)[:self.cfg.minibatch]
-
-    def train_batch(self, blk) -> dict:
-        # one launch and one read, under two spans, as
-        # LinearLearner.train_batch has them. The key the step drew
-        # from comes back advanced, and stays on the device
-        with _trace.span("step.dispatch", cat="step") as sp:
-            kind, args, _, st_train, ids = self.stage_batch(blk, True)
-            assert st_train, "batch was staged for eval, not train"
-            fm = kind == "fm_staged"
-            step = self._fm_steps[0] if fm else self._train_keyed
-            (self.store.state, self.vstore.state, prog,
-             self._rng) = step(self.store.state, self.vstore.state,
-                               *args, self._rng)
-            if self.track_touched:
-                self._note_touched(ids)
-            self._step_count += 1
-            sp.set(kind=kind)
-        with _trace.span("step.fetch", cat="step"):
-            # blocks until the device has finished the step
-            out = linmod.read_progress(
-                prog, FM_TRAIN_KEYS if fm else XLA_TRAIN_KEYS)
-            if fm:
-                _STEP_LIVE.inc(_whole(out, "live_nnz"))
-                _STEP_ADMITTED.inc(_whole(out, "admitted_nnz"))
-            return out
-
-    # -- sparse PS wire hints ------------------------------------------------
-    def _note_touched(self, ids) -> None:
-        if ids is None:
-            ids = (None, None)
-        with self._touched_lock:
-            self._touched_w.append(ids[0])
-            self._touched_v.append(ids[1])
-
-    def collect_touched(self):
-        """Sorted-unique global rows touched since the last call, per
-        table (the sparse PS push set; reference ZPush of the
-        minibatch's keys, async_sgd.h:270-287). Returns None if any
-        trained batch lacked a hint (SyncedStore then falls back to a
-        full delta scan for this sync)."""
-        with self._touched_lock:
-            tw, tv = self._touched_w, self._touched_v
-            self._touched_w, self._touched_v = [], []
-        if any(a is None for a in tw):
-            return None
-        uw = (np.unique(np.concatenate(tw)) if tw
-              else np.empty(0, np.int64))
-        uv = (np.unique(np.concatenate(tv)) if tv
-              else np.empty(0, np.int64))
-        out = {k: uw for k in self.store.state}
-        out.update({k: uv for k in self.vstore.state})
-        return out
-
-    def _fwd_any(self, blk):
-        kind, args, size, st_train, _ = self.stage_batch(blk, False)
-        assert not st_train, "batch was staged for train, not eval"
-        fwd = self._fm_steps[1] if kind == "fm_staged" else self._fwd
-        margin, prog = fwd(self.store.state, self.vstore.state, *args)
-        return margin, prog, size
-
-    def eval_batch(self, blk) -> dict:
-        _, prog, _ = self._fwd_any(blk)
-        return linmod.read_progress(prog, linmod.EVAL_KEYS)
-
-    def predict_batch(self, blk) -> np.ndarray:
-        margin, _, size = self._fwd_any(blk)
-        out = np.asarray(margin)[:size]
-        if self.cfg.prob_predict:
-            out = 1.0 / (1.0 + np.exp(-out))
-        return out
-
-    def nnz(self) -> int:
-        return self.store.nnz("w")
-
-    def num_admitted(self) -> int:
-        cnt = np.asarray(self.store.state["cnt"])
-        admit = cnt >= self.cfg.threshold
+    def _admitted(self) -> np.ndarray:
+        admit = np.asarray(self.store.state["cnt"]) >= self.cfg.threshold
         if self.cfg.l1_shrk:
             admit &= np.asarray(self.store.state["w"]) != 0
-        return int(admit.sum())
+        return admit
+
+    def num_admitted(self) -> int:
+        return int(self._admitted().sum())
 
     def v_collision_rate(self) -> float:
         """Fraction of ADMITTED keys whose V bucket (key % v_buckets) is
@@ -1003,11 +907,7 @@ class DifactoLearner:
         aliasing it introduces — size v_buckets so this stays small
         (rate ~ n_admitted / v_buckets for a uniform hash; see
         docs/difacto.md)."""
-        cnt = np.asarray(self.store.state["cnt"])
-        admit = cnt >= self.cfg.threshold
-        if self.cfg.l1_shrk:
-            admit &= np.asarray(self.store.state["w"]) != 0
-        keys = np.flatnonzero(admit)
+        keys = np.flatnonzero(self._admitted())
         if len(keys) == 0:
             return 0.0
         vb_of = keys % self.cfg.vb

@@ -24,7 +24,7 @@ import dataclasses
 import logging
 import threading
 from functools import partial
-from typing import Callable, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,16 +33,15 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from wormhole_tpu import native
-from wormhole_tpu.data.rowblock import DeviceBatch, RowBlock, to_device_batch
-from wormhole_tpu.obs import trace as _trace
+from wormhole_tpu.data.rowblock import DeviceBatch
+from wormhole_tpu.models import minibatch_learner as mbl
 from wormhole_tpu.obs.metrics import REGISTRY
 from wormhole_tpu.ops import coo_kernels as ck
 from wormhole_tpu.ops import metrics as M
 from wormhole_tpu.ops.fused_update import apply_handle, scatter_update
 from wormhole_tpu.ops.spmv import spmv, spmv_t
 from wormhole_tpu.parallel.kvstore import KVStore, TableSpec, quantize_push
-from wormhole_tpu.parallel.mesh import (batch_sharding, describe_placement,
-                                        make_mesh)
+from wormhole_tpu.parallel.mesh import describe_placement
 
 _log = logging.getLogger(__name__)
 
@@ -60,10 +59,6 @@ _CHUNKS_RUN = REGISTRY.counter("linear.blocks.chunks_run")
 # tcoo batches packed, and those the native pass packed
 _PACK_BATCHES = REGISTRY.counter("linear.pack.batches")
 _PACK_NATIVE = REGISTRY.counter("linear.pack.native")
-# a step's progress read off the device: the steps read, and the
-# blocking device-to-host reads that took (read_progress)
-_FETCH_STEPS = REGISTRY.counter("step.fetch.steps")
-_FETCH_READS = REGISTRY.counter("step.fetch.reads")
 
 
 def _count_chunks(stream, dead, blk: int, kernels: int):
@@ -75,50 +70,8 @@ def _count_chunks(stream, dead, blk: int, kernels: int):
     _CHUNKS_RUN.inc(run * kernels)
 
 
-@dataclasses.dataclass(frozen=True)
-class _Kind:
-    """One kind of batch. `LinearLearner._choose_kind` picks it; every
-    other method looks the record up by the name the batch's tuple
-    carries."""
-
-    pack: Callable     # (db, train) -> packed: host side, loader thread
-    args: Callable     # _device_args: what a step takes after the state
-    #: (state, *args) -> (state, packed progress); donates state
-    train: Callable
-    eval: Callable     # (state, *args) -> packed progress
-    predict: Callable  # (state, *args less label and mask) -> margins
-    #: packed -> the unique buckets it touches (the sparse PS push set;
-    #: reference ZPush of the minibatch's keys, async_sgd.h:270-287), or
-    #: None = unknown, which forces a full delta scan
-    touched: Callable
-
-
-def _device_args(arrays, put, put_rows=None):
-    """A kind's `args`: the host arrays `arrays(packed, train)` names go
-    to the device through `put`, label and mask after them through
-    `put_rows` (`put` where the rows go the same way); predict passes
-    neither."""
-    put_rows = put_rows or put
-
-    def args(packed, label=None, mask=None, train=False):
-        out = [put(x) for x in arrays(packed, train)]
-        if label is not None:
-            out += [put_rows(label), put_rows(mask)]
-        return tuple(out)
-    return args
-
-
-def _split(b):
-    """(kind, packed, label, mask, size) of a prepared batch: the short
-    ("xla", db, size) form carries its label and mask inside db."""
-    if len(b) == 3:
-        kind, db, size = b
-        return kind, db, db.label, db.row_mask, size
-    return b
-
-
-def _nonzero_ids(packed) -> np.ndarray:
-    return np.unique(packed.idx[packed.val != 0]).astype(np.int64)
+def _nonzero_ids(packed) -> tuple:
+    return (np.unique(packed.idx[packed.val != 0]).astype(np.int64),)
 
 
 @dataclasses.dataclass
@@ -290,15 +243,12 @@ def _tables_for(algo: str) -> dict[str, TableSpec]:
     return t
 
 
-class LinearLearner:
+class LinearLearner(mbl.MinibatchLearner):
     """Jitted train/eval/predict steps over a sharded weight table."""
 
     def __init__(self, cfg: LinearConfig, mesh=None):
-        self.cfg = cfg
-        self.mesh = mesh if mesh is not None else make_mesh(num_model=1)
+        super().__init__(cfg, mesh)
         self.store = KVStore(self.mesh, cfg.num_buckets, _tables_for(cfg.algo))
-        self._bsh1 = batch_sharding(self.mesh, 1)
-        self._dropped_rows = 0
         D = self.mesh.shape.get("data", 1)
         M = self.mesh.shape.get("model", 1)
         # per-shard kernel constraints: each model shard owns whole tiles,
@@ -336,26 +286,17 @@ class LinearLearner:
                 f"pallas kernel needs num_buckets % {M * ck.TILE} == 0")
             assert cfg.minibatch % (D * ck.LANES) == 0, (
                 f"pallas kernel needs minibatch % {D * ck.LANES} == 0")
-        # MXU compute dtype for the COO kernels. None defers to the kernel
-        # default (bf16 on TPU, f32 in interpret mode); "auto" keeps f32
-        # whenever fixed_bytes == 0 so disabling gradient quantization also
-        # disables the kernels' bf16 rounding (ADVICE r1).
-        if cfg.kernel_dtype == "f32":
-            self._coo_dtype = jnp.float32
-        elif cfg.kernel_dtype == "auto" and cfg.fixed_bytes == 0:
-            self._coo_dtype = jnp.float32
-        else:
-            self._coo_dtype = None
+        self._coo_dtype = mbl.kernel_dtype(cfg)
 
         mesh, dt = self.mesh, self._coo_dtype
         rows = partial(jax.device_put, device=self._bsh1)
         cells = partial(jax.device_put, device=NamedSharding(
             mesh, P("data", "model", None)))
-        self._kinds: dict[str, _Kind] = {
-            "xla": _Kind(
+        self._kinds = {
+            "xla": mbl._Kind(
                 lambda db, train: db,
-                _device_args(lambda db, train: (db.seg, db.idx, db.val),
-                             rows),
+                mbl._device_args(lambda db, train: (db.seg, db.idx, db.val),
+                                 rows),
                 *self._dense_steps(
                     lambda w, seg, idx, val, n: spmv(seg, idx, val, w, n),
                     lambda d, seg, idx, val, n: self.store.constrain(
@@ -368,9 +309,9 @@ class LinearLearner:
             # "Row-gather regimes"), and so, measured again in PR 32, is
             # the compact domain at any table that engages it. The
             # radix-image kernel pulls for every Pallas kind.
-            "coo": _Kind(
+            "coo": mbl._Kind(
                 self._pack_coo,
-                _device_args(
+                mbl._device_args(
                     lambda p, train: (p.idx, p.seg, p.val, p.tmap, p.first),
                     jnp.asarray),
                 *self._dense_steps(partial(ck.coo_spmv, dtype=dt),
@@ -380,9 +321,9 @@ class LinearLearner:
             # axis; psum plays ZPull/ZPush (async_sgd.h:277-287). The
             # packed batch holds shard-local layouts, so its touched set
             # is left to the full delta scan
-            "mcoo": _Kind(
+            "mcoo": mbl._Kind(
                 self._pack_mcoo,
-                _device_args(
+                mbl._device_args(
                     lambda mc, train: (mc.sidx, mc.sseg, mc.sval, mc.tmap,
                                        mc.first),
                     cells, rows),
@@ -400,11 +341,6 @@ class LinearLearner:
         self._compact_lock = threading.Lock()
         if self._mesh_coo or not self.use_pallas or cfg.compact_cap == 0:
             self._compact_cap = 0
-        # sparse PS wire hints: unique buckets touched by trained batches
-        # since the last collect_touched() drain (runtime/ps_server)
-        self.track_touched = False
-        self._touched_lock = threading.Lock()
-        self._touched: list[Optional[np.ndarray]] = []
 
     # -- global-mesh SPMD protocol (apps/_runner._global_train) ------------
     def global_step_protocol(self):
@@ -415,11 +351,11 @@ class LinearLearner:
 
         def train_fn(args, rng):
             self.store.state, prog = xla.train(self.store.state, *args)
-            return read_progress(prog, TRAIN_KEYS)
+            return mbl.read_progress(prog, mbl.TRAIN_KEYS)
 
         def eval_fn(args):
-            return read_progress(xla.eval(self.store.state, *args),
-                                 EVAL_KEYS)
+            return mbl.read_progress(xla.eval(self.store.state, *args),
+                                     mbl.EVAL_KEYS)
 
         return train_fn, eval_fn
 
@@ -441,17 +377,6 @@ class LinearLearner:
 
         return pred_fn
 
-    def derived_tables(self) -> dict:
-        """Tables that are non-additive pure functions of additive ones,
-        for server-side recomputation in the multi-process PS data plane
-        (runtime/ps_server.ServerNode._recompute_derived)."""
-        cfg = self.cfg
-        if cfg.algo != "ftrl":
-            return {}
-        return {"w": {"kind": "ftrl_prox", "lr_eta": cfg.lr_eta,
-                      "lr_beta": cfg.lr_beta, "lambda_l1": cfg.lambda_l1,
-                      "lambda_l2": cfg.lambda_l2}}
-
     # -- the jitted steps ----------------------------------------------------
     def _read_steps(self, pull):
         """(eval, predict) of a kind whose `pull(w, *batch, rows)` is
@@ -463,7 +388,8 @@ class LinearLearner:
             *batch, label, mask = args
             xw = pull(state["w"], *batch, label.shape[0])
             obj, _ = _loss_dual(cfg.loss, label, xw)
-            return pack_progress(_progress(obj, xw, label, mask), EVAL_KEYS)
+            return mbl.pack_progress(_progress(obj, xw, label, mask),
+                                     mbl.EVAL_KEYS)
 
         @jax.jit
         def predict_step(state, *batch):
@@ -502,8 +428,8 @@ class LinearLearner:
             else:
                 touched = (raw_g != 0).astype(jnp.float32)
             new_state, new_w = _update(cfg.algo, state, g, touched, cfg)
-            return new_state, pack_progress(
-                _progress(obj, xw, label, mask, new_w), TRAIN_KEYS)
+            return new_state, mbl.pack_progress(
+                _progress(obj, xw, label, mask, new_w), mbl.TRAIN_KEYS)
 
         return (train_step, *self._read_steps(pull))
 
@@ -573,8 +499,8 @@ class LinearLearner:
                 lr_eta=cfg.lr_eta, lr_beta=cfg.lr_beta,
                 lambda_l1=cfg.lambda_l1, lambda_l2=cfg.lambda_l2,
                 fixed_bytes=cfg.fixed_bytes, dtype=dt)
-            return new_state, pack_progress(
-                _progress(obj, xw, label, mask, new_w), TRAIN_KEYS)
+            return new_state, mbl.pack_progress(
+                _progress(obj, xw, label, mask, new_w), mbl.TRAIN_KEYS)
 
         def arrays(tc, train):
             # every step pulls over the COO stream; the update-block
@@ -585,25 +511,12 @@ class LinearLearner:
             return (tc.uniq, tc.tmap_u, *mid, p.idx, p.seg, p.val, p.tmap,
                     p.first)
 
-        self._kinds["tcoo"] = _Kind(
-            self._pack_tcoo, _device_args(arrays, jnp.asarray),
+        self._kinds["tcoo"] = mbl._Kind(
+            self._pack_tcoo, mbl._device_args(arrays, jnp.asarray),
             train_step_tcoo, *self._read_steps(pull_c),
-            lambda tc: tc.uniq[tc.uniq < cfg.num_buckets].astype(np.int64))
+            lambda tc: (tc.uniq[tc.uniq < cfg.num_buckets].astype(np.int64),))
 
-    # -- device batch plumbing ---------------------------------------------
-    def make_device_batch(self, blk: RowBlock) -> DeviceBatch:
-        db = to_device_batch(
-            blk, self.cfg.minibatch, self.cfg.row_capacity, self.cfg.num_buckets
-        )
-        if db.dropped_rows:
-            self._dropped_rows += db.dropped_rows
-            _log.warning(
-                "minibatch overflow: dropped %d rows (total %d) — raise "
-                "nnz_per_row or minibatch capacity",
-                db.dropped_rows, self._dropped_rows,
-            )
-        return db
-
+    # -- batch kinds and their packs ---------------------------------------
     def _choose_kind(self, db: DeviceBatch) -> str:
         """The one place that decides what kind of batch this learner
         makes: everything downstream looks `self._kinds` up by the name
@@ -615,20 +528,6 @@ class LinearLearner:
         if self.ensure_compact(db.idx):
             return "tcoo"
         return "coo"
-
-    def prepare_batch(self, blk: RowBlock, train: bool = True):
-        """Host-side batch prep (runs in loader threads): pad to the fixed
-        device shape, and for the pallas path additionally tile-sort the
-        COO triples (the Localizer role). Returns an opaque prepared batch
-        accepted by train/eval/predict_batch: (kind, packed, label, mask,
-        size), or ("xla", db, size) where the padded batch is the packed
-        one."""
-        db = self.make_device_batch(blk)
-        kind = self._choose_kind(db)
-        packed = self._kinds[kind].pack(db, train)
-        if packed is db:
-            return (kind, db, blk.size)
-        return (kind, packed, db.label, db.row_mask, blk.size)
 
     def _pack_mcoo(self, db: DeviceBatch, train: bool):
         D = self.mesh.shape.get("data", 1)
@@ -669,14 +568,9 @@ class LinearLearner:
                                   self.cfg.num_buckets,
                                   capacity=self.cfg.row_capacity)
 
-    def _prepared(self, x):
-        if isinstance(x, RowBlock):
-            x = self.prepare_batch(x)
-        return x
-
     # -- epoch pack cache ----------------------------------------------------
     #: bump when prepare_batch's output layout changes for identical input
-    _PACK_VERSION = 2
+    _PACK_VERSION = 3
 
     def pack_cache_token(self, train: bool = True):
         """Everything (beyond the raw batch bytes) that decides what
@@ -697,97 +591,6 @@ class LinearLearner:
                 self.mesh.shape.get("model", 1),
                 ck.TILE, ck.BLK, ck.BLK_U, ck.LANES)
 
-    # -- double-buffered device feed -----------------------------------------
-    def stage_batch(self, b, train: bool = True):
-        """Move a batch's arrays to the device (a RowBlock is prepared
-        first; a staged batch comes back as it is). The solver calls this
-        from the loader thread, so the host->device transfer of batch N+1
-        overlaps the main thread's step on batch N; train_batch /
-        eval_batch call it on whatever they are given, so every batch
-        reaches its step this one way. Returns ("staged", kind, args,
-        size, ids, train). The `train` flag must match the consuming step
-        (tcoo ships the update-block bounds only for training)."""
-        b = self._prepared(b)
-        if b[0] == "staged":
-            return b
-        kind, packed, label, mask, size = _split(b)
-        k = self._kinds[kind]
-        # the touched ids need the host arrays; grab them now because
-        # after staging only device arrays remain
-        ids = k.touched(packed) if (train and self.track_touched) else None
-        args = k.args(packed, label, mask, train)
-        # what the batch moves to the device (on a mesh a [1, M, P]
-        # slice a shard): on the solver's loader.h2d span round this call
-        _trace.annotate(bytes=sum(a.nbytes for a in args))
-        return ("staged", kind, args, size, ids, train)
-
-    # -- what a harness asks of the learner (benchmark/check.py) -------------
-    def tables(self) -> dict:
-        """Every table by name, each readable by row."""
-        return self.store.state
-
-    @staticmethod
-    def batch_kind(b) -> str:
-        """A prepared or staged batch's kind: a key of `_kinds`."""
-        return b[1] if b[0] == "staged" else b[0]
-
-    @staticmethod
-    def batch_label(b) -> np.ndarray:
-        """A prepared or staged batch's labels, on the host."""
-        if b[0] == "staged":
-            return np.asarray(b[2][-2])
-        return np.asarray(b[1].label if b[0] == "xla" else b[-3])
-
-    # -- sparse PS wire hints ------------------------------------------------
-    def collect_touched(self):
-        """Sorted-unique global rows touched since the last call, per
-        table, or None if any batch lacked a hint (SyncedStore then
-        falls back to a full delta scan for this sync)."""
-        with self._touched_lock:
-            acc = self._touched
-            self._touched = []
-        if any(a is None for a in acc):
-            return None
-        u = (np.unique(np.concatenate(acc)) if acc
-             else np.empty(0, np.int64))
-        return {k: u for k in self.store.state}
-
-    def train_batch(self, blk) -> dict:
-        # a step is one launch and one read. Two spans, so that a device
-        # profile can tell a late dispatch from a late return out of the
-        # blocking read (PERF.md §5: on the chip it is the read the
-        # device idles under)
-        with _trace.span("step.dispatch", cat="step") as sp:
-            _, kind, args, _, ids, st_train = self.stage_batch(blk, True)
-            assert st_train, "batch was staged for eval, not train"
-            if self.track_touched:
-                with self._touched_lock:
-                    self._touched.append(ids)
-            self.store.state, prog = self._kinds[kind].train(
-                self.store.state, *args)
-            sp.set(kind=kind)
-        with _trace.span("step.fetch", cat="step"):
-            # blocks until the device has finished the step
-            return read_progress(prog, TRAIN_KEYS)
-
-    def eval_batch(self, blk) -> dict:
-        _, kind, args, _, _, st_train = self.stage_batch(blk, False)
-        assert not st_train, "batch was staged for train, not eval"
-        prog = self._kinds[kind].eval(self.store.state, *args)
-        return read_progress(prog, EVAL_KEYS)
-
-    def predict_batch(self, blk) -> np.ndarray:
-        kind, packed, _, _, size = _split(self._prepared(blk))
-        k = self._kinds[kind]
-        xw = k.predict(self.store.state, *k.args(packed))
-        out = np.asarray(xw)[:size]
-        if self.cfg.prob_predict:
-            out = 1.0 / (1.0 + np.exp(-out))
-        return out
-
-    def nnz(self) -> int:
-        return self.store.nnz("w")
-
 
 def _progress(obj, xw, label, mask, new_w=None):
     """Per-batch mergeable progress vector (reference linear/progress.h:
@@ -807,30 +610,3 @@ def _progress(obj, xw, label, mask, new_w=None):
     if new_w is not None:
         p["new_w"] = new_w
     return p
-
-
-#: what _progress holds for an eval step and for a train step, in the
-#: order a step packs them and read_progress names them again: sorted,
-#: as a jitted step returns a dict
-EVAL_KEYS = ("acc", "auc", "clk", "logloss", "nex", "objv", "pclk")
-TRAIN_KEYS = tuple(sorted(EVAL_KEYS + ("new_w",)))
-
-
-def pack_progress(p: dict, keys) -> jax.Array:
-    """A step's progress scalars as one f32[len(keys)] vector, traced
-    inside the jitted step, so that the host reads a step's progress in
-    one transfer and not one a scalar."""
-    assert set(p) == set(keys), (sorted(p), keys)
-    return jnp.stack([jnp.asarray(p[k], jnp.float32) for k in keys])
-
-
-def read_progress(vec, keys) -> dict:
-    """pack_progress's inverse on the host: the one blocking
-    device-to-host read of a step, which returns when the device has
-    finished the step. A value is the Python float that float() of the
-    device scalar was."""
-    _FETCH_STEPS.inc()
-    _FETCH_READS.inc()
-    host = np.asarray(vec)
-    assert host.shape == (len(keys),), (host.shape, keys)
-    return dict(zip(keys, host.tolist()))

@@ -98,8 +98,8 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.wh_cityhash64.argtypes = [ctypes.c_char_p, ctypes.c_int64]
     lib.wh_pack_tile_coo.restype = ctypes.c_int32
     lib.wh_pack_tile_coo.argtypes = ([ctypes.c_void_p] * 3
-                                     + [ctypes.c_int64] * 9
-                                     + [ctypes.c_void_p] * 12)
+                                     + [ctypes.c_int64] * 7
+                                     + [ctypes.c_void_p] * 10)
     return lib
 
 
@@ -265,28 +265,23 @@ def gather(src, order):
 
 
 def pack_tile_coo(idx, seg, val, num_buckets: int, u_cap: int,
-                  capacity, rm_rows, rm_width, tile: int, blk: int,
-                  blk_u: int):
+                  capacity, tile: int, blk: int, blk_u: int):
     """The whole tcoo pack in one call that holds no interpreter lock
     (src/pack.cc): one radix sort of the ids, then every array of
     ops/coo_kernels.TileCOO written in that order, bit-equal to the numpy
     body of ops/coo_kernels.pack_tile_coo. Returns a dict of those
-    arrays and counts (`over`: nonzeros dropped from rows over
-    rm_width), or None when the library is missing or the batch is
-    outside the pass's domain (ids not int32 in [0, num_buckets), sizes
-    of 2^31 or more, rows the numpy body would refuse): the caller then
-    runs the numpy body."""
+    arrays and counts, or None when the library is missing or the batch
+    is outside the pass's domain (ids not int32 in [0, num_buckets),
+    sizes of 2^31 or more): the caller then runs the numpy body."""
     lib = get_lib()
     if (lib is None or not isinstance(idx, np.ndarray)
             or idx.dtype != np.int32 or idx.ndim != 1):
         return None
     n = idx.shape[0]
-    rm = rm_rows is not None
-    n_rm = rm_rows * rm_width if rm else 0
     # capacity None: that of the entries kept, at most n
     P = ((n if capacity is None else capacity) // blk + u_cap // tile) * blk
     if (not 0 < num_buckets < 2 ** 31 or not 0 < u_cap < 2 ** 31
-            or max(n, P, n_rm) >= 2 ** 31 or min(P, n_rm) < 0):
+            or max(n, P) >= 2 ** 31 or P < 0):
         return None
     idx = np.ascontiguousarray(idx)
     seg = np.ascontiguousarray(seg, np.int32)
@@ -300,25 +295,22 @@ def pack_tile_coo(idx, seg, val, num_buckets: int, u_cap: int,
         last_u=np.empty(u_cap // blk_u, i32),
         idx=np.empty(P, i32), seg=np.empty(P, i32),
         val=np.empty(P, np.float32), tmap=np.empty(P // blk, i32),
-        first=np.empty(P // blk, i32),
-        rm_slot=np.empty(n_rm, i32) if rm else None,
-        rm_val=np.empty(n_rm, np.float32) if rm else None)
-    counts = np.zeros(5, np.int64)
+        first=np.empty(P // blk, i32))
+    counts = np.zeros(4, np.int64)
 
     def ptr(a):
-        return None if a is None else a.ctypes.data_as(ctypes.c_void_p)
+        return a.ctypes.data_as(ctypes.c_void_p)
 
     rc = lib.wh_pack_tile_coo(
         ptr(idx), ptr(seg), ptr(val), n, num_buckets, u_cap,
-        -1 if capacity is None else capacity,
-        rm_rows if rm else -1, rm_width if rm else 0, tile, blk, blk_u,
+        -1 if capacity is None else capacity, tile, blk, blk_u,
         *(ptr(a) for a in out.values()), ptr(counts))
     if rc != 0:
         return None
-    if counts[4] != P:  # capacity None and entries cut: a shorter stream
+    if counts[3] != P:  # capacity None and entries cut: a shorter stream
         for k, per in (("idx", 1), ("seg", 1), ("val", 1), ("tmap", blk),
                        ("first", blk)):
-            out[k] = out[k][:counts[4] // per]
-    out.update(zip(("num_uniq", "dropped_uniq", "dropped_nnz", "over"),
+            out[k] = out[k][:counts[3] // per]
+    out.update(zip(("num_uniq", "dropped_uniq", "dropped_nnz"),
                    counts.tolist()))
     return out

@@ -7,8 +7,7 @@
 // (assign_tile_slots) and then sorts the slots again (pack_sorted_coo).
 // Slot order is key order, so one stable sort serves both: the sorted
 // (key, position) pairs are swept once for the unique keys, their
-// BLK_U-aligned slots and the update-block maps, once in input order
-// for the row-major companion (build_rm), and once more for the
+// BLK_U-aligned slots and the update-block maps, and once more for the
 // BLK-padded COO stream. Every array comes out bit-equal to the numpy
 // body's, which stays as the fallback and as the tests' oracle. No
 // OpenMP here: each loader thread packs its own batch, and the call
@@ -26,8 +25,6 @@ namespace {
 // the sweeps that fill them
 struct Scratch {
   std::vector<uint64_t> a, b;  // (key or slot) << 32 | position
-  std::vector<int32_t> slot;   // slot of each entry, input order
-  std::vector<float> val;      // val with over-width entries zeroed
 };
 thread_local Scratch scratch;
 
@@ -94,19 +91,16 @@ extern "C" {
 
 // Returns 0, or a reason the batch is outside this pass's domain (the
 // caller then runs the numpy body, which decides what such a batch
-// means): 1 an id outside [0, num_buckets), 2 live rows not grouped or
-// outside [0, rm_rows), 3 more entries than `capacity` has blocks for.
-// rm_rows < 0: no row-major companion. capacity < 0: that of the entries
-// kept (the COO arrays then have room for all n, and the length used
-// comes back). counts: num_uniq, dropped_uniq, dropped_nnz, nonzeros
-// dropped from rows over rm_width, length of the COO stream.
+// means): 1 an id outside [0, num_buckets), 3 more entries than
+// `capacity` has blocks for. capacity < 0: that of the entries kept (the
+// COO arrays then have room for all n, and the length used comes back).
+// counts: num_uniq, dropped_uniq, dropped_nnz, length of the COO stream.
 int32_t wh_pack_tile_coo(
     const int32_t* idx, const int32_t* seg, const float* val, int64_t n,
-    int64_t num_buckets, int64_t u_cap, int64_t capacity, int64_t rm_rows,
-    int64_t rm_width, int64_t tile, int64_t blk, int64_t blk_u,
-    int32_t* uniq, int32_t* tmap_u, int32_t* first_u, int32_t* last_u,
-    int32_t* coo_idx, int32_t* coo_seg, float* coo_val, int32_t* tmap,
-    int32_t* first, int32_t* rm_slot, float* rm_val, int64_t* counts) {
+    int64_t num_buckets, int64_t u_cap, int64_t capacity, int64_t tile,
+    int64_t blk, int64_t blk_u, int32_t* uniq, int32_t* tmap_u,
+    int32_t* first_u, int32_t* last_u, int32_t* coo_idx, int32_t* coo_seg,
+    float* coo_val, int32_t* tmap, int32_t* first, int64_t* counts) {
   for (int64_t i = 0; i < n; ++i)
     if (idx[i] < 0 || idx[i] >= num_buckets) return 1;
 
@@ -114,19 +108,16 @@ int32_t wh_pack_tile_coo(
   if (static_cast<int64_t>(w.a.size()) < n) {
     w.a.resize(n);
     w.b.resize(n);
-    w.slot.resize(n);
   }
   int bits = 0;
   while (bits < 32 && (int64_t{1} << bits) < num_buckets) ++bits;
   uint64_t* s = sort_pairs(idx, n, bits, w.a.data(), w.b.data());
-  int32_t* slot_of = w.slot.data();
 
   // --- the sorted keys, once: unique keys, their slots (a tile's run
   // starts on a BLK_U boundary), the update-block maps, and each
-  // entry's slot in both orders. Keys are kept while their slot is
-  // under u_cap: whole tiles, then the boundary tile's first blocks.
+  // entry's slot. Keys are kept while their slot is under u_cap: whole
+  // tiles, then the boundary tile's first blocks.
   const int32_t sentinel = static_cast<int32_t>(num_buckets);
-  const int32_t cut = static_cast<int32_t>(u_cap);
   const int64_t nb = u_cap / blk_u;
   std::memset(first_u, 0, nb * sizeof(int32_t));
   std::memset(last_u, 0, nb * sizeof(int32_t));
@@ -144,9 +135,6 @@ int32_t wh_pack_tile_coo(
   for (; j < n; ++j) {
     const uint64_t x = s[j];
     const int64_t key = static_cast<int64_t>(x >> 32);
-    const uint32_t i = static_cast<uint32_t>(x);
-    if (j + kAhead < n)  // the scatter below misses the cache otherwise
-      __builtin_prefetch(slot_of + static_cast<uint32_t>(s[j + kAhead]), 1);
     if (key != prev) {
       const int64_t t = key / tile;
       if (t != cur_tile) {
@@ -164,8 +152,7 @@ int32_t wh_pack_tile_coo(
       uniq[next] = static_cast<int32_t>(key);
       slot = static_cast<int32_t>(next++);
     }
-    slot_of[i] = slot;
-    s[j] = pair(slot, i);
+    s[j] = pair(slot, static_cast<uint32_t>(x));
   }
   if (open) close_tile();
   const int64_t kept_n = j, kept_uniq = total_uniq;
@@ -177,7 +164,6 @@ int32_t wh_pack_tile_coo(
       prev = key;
       ++total_uniq;
     }
-    slot_of[i] = cut;
     dropped_nnz += val[i] != 0.0f;
   }
   const int64_t used = next / blk_u;
@@ -186,53 +172,6 @@ int32_t wh_pack_tile_coo(
   std::fill(tmap_u + used, tmap_u + nb,
             static_cast<int32_t>(cur_tile < 0 ? 0 : cur_tile));
   if (used == 0) first_u[0] = last_u[0] = 1;
-
-  // --- input order, once: the row-major companion (build_rm)
-  const float* cval = val;
-  int64_t over = 0;
-  if (rm_rows >= 0) {
-    const int64_t n_rm = rm_rows * rm_width;
-    // exactly rm_width a row, in row order: the layout is the input's
-    // (copied while that still holds; the general fill below overwrites)
-    bool fast = kept_n == n_rm;
-    for (int64_t i = 0, q = 0, row = 0, col = 0; fast && i < n; ++i) {
-      if (slot_of[i] == cut) continue;
-      fast = seg[i] == row;
-      rm_slot[q] = slot_of[i];
-      rm_val[q++] = val[i];
-      if (++col == rm_width) {
-        col = 0;
-        ++row;
-      }
-    }
-    if (!fast) {
-      std::fill(rm_slot, rm_slot + n_rm, cut);
-      std::memset(rm_val, 0, n_rm * sizeof(float));
-      int64_t row = -1, pos = 0;
-      for (int64_t i = 0; i < n; ++i) {
-        if (slot_of[i] == cut || val[i] == 0.0f) continue;
-        const int64_t r = seg[i];
-        if (r < row || r < 0 || r >= rm_rows) return 2;
-        if (r != row) {
-          row = r;
-          pos = 0;
-        }
-        if (pos < rm_width) {
-          rm_slot[r * rm_width + pos] = slot_of[i];
-          rm_val[r * rm_width + pos] = val[i];
-        } else {
-          // over the width: pull and push must agree on the nonzeros
-          if (over == 0) {
-            w.val.assign(val, val + n);
-            cval = w.val.data();
-          }
-          w.val[i] = 0.0f;
-          ++over;
-        }
-        ++pos;
-      }
-    }
-  }
 
   // --- the sorted slots, once: the COO stream over the compact domain,
   // each compact tile's run padded to whole BLK blocks (one at least),
@@ -252,12 +191,12 @@ int32_t wh_pack_tile_coo(
       if (j + kAhead < kept_n) {
         const uint32_t ahead = static_cast<uint32_t>(s[j + kAhead]);
         __builtin_prefetch(seg + ahead);
-        __builtin_prefetch(cval + ahead);
+        __builtin_prefetch(val + ahead);
       }
       const uint32_t i = static_cast<uint32_t>(s[j]);
       coo_idx[p] = static_cast<int32_t>(s[j] >> 32);
       coo_seg[p] = seg[i];
-      coo_val[p] = cval[i];
+      coo_val[p] = val[i];
     }
     int64_t end = p == start ? start + blk : (p + blk - 1) / blk * blk;
     if (end > P) return 3;
@@ -272,8 +211,7 @@ int32_t wh_pack_tile_coo(
   counts[0] = kept_uniq;
   counts[1] = total_uniq - kept_uniq;
   counts[2] = dropped_nnz;
-  counts[3] = over;
-  counts[4] = P;
+  counts[3] = P;
   return 0;
 }
 

@@ -170,9 +170,8 @@ class SortedCOO:
         return self.tmap.shape[0]
 
 
-def build_rm(seg, slot, val, num_rows: int, width: int,
-             sentinel: int, extra: tuple = ()
-             ) -> tuple[np.ndarray, tuple, np.ndarray]:
+def build_rm(seg, slot, val, num_rows: int, width: int, sentinel: int
+             ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Row-major (num_rows x width) padded companion layout of a
     CSR-ordered COO batch: rm_slot[r*width + j] = slot of row r's j-th
     live nonzero (sentinel in padding), rm_val likewise (0.0 padding).
@@ -188,27 +187,22 @@ def build_rm(seg, slot, val, num_rows: int, width: int,
     exactly width-per-row in row order (the fixed-field Criteo shape),
     the layout IS the input and no packing runs.
 
-    `extra` carries further per-entry value channels laid out the same
-    way (e.g. difacto's admitted V values next to the w values).
-
-    Returns (rm_slot, rm_vals, overflow_pos): rm_vals is the rm image
-    of val followed by one image per extra channel; overflow_pos are
-    input positions of live entries beyond `width` per row — the CALLER
+    Returns (rm_slot, rm_val, overflow_pos): overflow_pos are input
+    positions of live entries beyond `width` per row — the CALLER
     must zero their val in the scatter-side stream(s) too, so pull and
     push agree about which nonzeros exist (empty on the fast path)."""
     seg = np.asarray(seg, np.int32)
     slot = np.asarray(slot)
-    vals = [np.asarray(val, np.float32)] + [np.asarray(x, np.float32)
-                                            for x in extra]
+    val = np.asarray(val, np.float32)
     empty = np.empty(0, np.int64)
     n = num_rows * width
     if len(seg) == n:
         expect = np.repeat(np.arange(num_rows, dtype=np.int32), width)
         if np.array_equal(seg, expect):
-            return slot.astype(np.int32, copy=False), tuple(vals), empty
+            return slot.astype(np.int32, copy=False), val, empty
     rm_slot = np.full(n, sentinel, np.int32)
-    rm_vals = [np.zeros(n, np.float32) for _ in vals]
-    live = vals[0] != 0
+    rm_val = np.zeros(n, np.float32)
+    live = val != 0
     seg_nz, slot_nz = seg[live], slot[live]
     if seg_nz.size and not (np.diff(seg_nz) >= 0).all():
         raise ValueError("build_rm expects row-grouped (CSR order) input")
@@ -225,9 +219,8 @@ def build_rm(seg, slot, val, num_rows: int, width: int,
             "than %d live entries", len(over), width)
     rm_index = seg_nz[fit] * width + pos[fit]
     rm_slot[rm_index] = slot_nz[fit]
-    for rv, v in zip(rm_vals, vals):
-        rv[rm_index] = v[live][fit]
-    return rm_slot, tuple(rm_vals), over
+    rm_val[rm_index] = val[live][fit]
+    return rm_slot, rm_val, over
 
 
 def packed_size(capacity: int, num_buckets: int,
@@ -621,9 +614,6 @@ class TileCOO:
     num_uniq: int
     dropped_uniq: int   # unique keys cut on u_cap overflow
     dropped_nnz: int    # their nonzeros, dropped with them
-    # optional row-major companion layout over the compact slot domain
-    rm_slot: np.ndarray | None = None
-    rm_val: np.ndarray | None = None
     #: which body of pack_tile_coo made this batch. Not a field: it is
     #: not part of the batch (compared, stored in the pack cache), only
     #: the packer's word to its caller's counter
@@ -718,14 +708,10 @@ def assign_tile_slots(uniq, rows_per_tile: int, u_cap: int,
 
 
 def pack_tile_coo(idx, seg, val, num_buckets: int, u_cap: int,
-                  capacity: int | None = None,
-                  rm_rows: int | None = None,
-                  rm_width: int | None = None) -> TileCOO:
+                  capacity: int | None = None) -> TileCOO:
     """Localize bucket ids (the reference Localizer's sort+unique+remap,
     localizer.h:98-221) into tile-run-aligned compact slots and pack the
-    COO triples over that domain (host-side, loader threads). With
-    rm_rows/rm_width, also emit the row-major companion layout (see
-    build_rm) over the compact slot domain, with u_cap as sentinel.
+    COO triples over that domain (host-side, loader threads).
 
     Where the native core is loaded and the batch is what
     to_device_batch makes (int32 ids in [0, num_buckets)), the whole pack
@@ -738,22 +724,14 @@ def pack_tile_coo(idx, seg, val, num_buckets: int, u_cap: int,
     from wormhole_tpu import native
     from wormhole_tpu.ops.localizer import localize
 
-    got = native.pack_tile_coo(
-        idx, seg, val, num_buckets, u_cap, capacity, rm_rows, rm_width,
-        TILE, BLK, BLK_U)
+    got = native.pack_tile_coo(idx, seg, val, num_buckets, u_cap, capacity,
+                               TILE, BLK, BLK_U)
     if got is not None:
-        if got["over"]:
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "row-major pack: dropped %d nonzeros from rows with more "
-                "than %d live entries", got["over"], rm_width)
         tc = TileCOO(
             got["uniq"], SortedCOO(*(got[k] for k in (
                 "idx", "seg", "val", "tmap", "first"))),
             got["tmap_u"], got["first_u"], got["last_u"], got["num_uniq"],
-            got["dropped_uniq"], got["dropped_nnz"], got["rm_slot"],
-            got["rm_val"])
+            got["dropped_uniq"], got["dropped_nnz"])
         tc.packed_native = True
         return tc
 
@@ -768,18 +746,10 @@ def pack_tile_coo(idx, seg, val, num_buckets: int, u_cap: int,
     # count only real (nonzero-valued) dropped entries: padding triples
     # carry val == 0 and losing them loses nothing (ADVICE r2)
     dropped_nnz = int(np.count_nonzero(~keep & (val != 0)))
-    seg_k, val_k, slot_k = seg[keep], val[keep], new_slot[keep]
-    rm_slot = rm_val = None
-    if rm_rows is not None:
-        rm_slot, (rm_val,), over = build_rm(seg_k, slot_k, val_k,
-                                            rm_rows, rm_width, u_cap)
-        if len(over):
-            val_k = val_k.copy()
-            val_k[over] = 0.0  # pull/push must agree on the nnz set
-    p = pack_sorted_coo(slot_k, seg_k, val_k, u_cap, capacity=capacity)
+    p = pack_sorted_coo(new_slot[keep], seg[keep], val[keep], u_cap,
+                        capacity=capacity)
     return TileCOO(ts.uniq, p, ts.tmap_u, ts.first_u, ts.last_u,
-                   ts.num_uniq, ts.dropped_uniq, dropped_nnz,
-                   rm_slot, rm_val)
+                   ts.num_uniq, ts.dropped_uniq, dropped_nnz)
 
 
 def _tile_gather_kernel(tmap_ref, ext_ref, w_ref, uniq_ref, out_ref, *,

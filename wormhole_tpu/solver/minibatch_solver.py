@@ -108,75 +108,6 @@ class LoaderController:
         return new
 
 
-class MembershipController:
-    """Stall-driven sizing of the WORKER SET — LoaderController's policy
-    one level up: where that one adds loader threads inside a process,
-    this one asks the scheduler for whole worker processes. Inputs are
-    the cluster-merged gauges the tracker already aggregates
-    (``queue.depth``, ``loader.stall_s``); the output is a target worker
-    count the scheduler publishes through its membership machinery
-    (Scheduler.set_elastic_target -> retire flags / launcher spawns).
-
-    Policy, deliberately conservative (a worker join costs a process
-    spawn + PS init, so flapping is worse than lagging):
-    - sustained stall (``grow_after`` consecutive starved observations)
-      => grow by 1, up to ``hi``;
-    - sustained idle (stall ~ 0 AND a well-stocked queue for
-      ``shrink_after`` observations) => shrink by 1, down to ``lo``;
-    - anything mixed resets the streaks (hysteresis).
-    Every decision is recorded like LoaderController's, so the run
-    report can show WHY the worker set moved."""
-
-    def __init__(self, initial: int, lo: int = 1, hi: Optional[int] = None,
-                 grow_stall: float = 0.5, shrink_stall: float = 0.05,
-                 grow_after: int = 3, shrink_after: int = 6):
-        self.target = max(int(initial), lo)
-        self.lo = max(int(lo), 1)
-        self.hi = hi if hi is not None else 2 * self.target
-        self.grow_stall = grow_stall
-        self.shrink_stall = shrink_stall
-        self.grow_after = max(int(grow_after), 1)
-        self.shrink_after = max(int(shrink_after), 1)
-        self._starved = 0
-        self._idle = 0
-        self.decisions: list[dict] = []
-
-    def record(self, queue_depth: float, stall_s: float,
-               live: Optional[int] = None) -> int:
-        """Fold one observation window in; returns the worker-count
-        target. `live` (the currently registered worker count) re-bases
-        the target so a crash-shrunk cluster is grown back toward the
-        target rather than the controller shrinking to match it."""
-        new = self.target
-        why = "steady"
-        if stall_s > self.grow_stall:
-            self._starved += 1
-            self._idle = 0
-            if self._starved >= self.grow_after:
-                new = min(self.target + 1, self.hi)
-                why = "starved"
-                self._starved = 0
-        elif stall_s < self.shrink_stall and queue_depth >= 1.0:
-            self._idle += 1
-            self._starved = 0
-            if self._idle >= self.shrink_after:
-                new = max(self.target - 1, self.lo)
-                why = "overfed"
-                self._idle = 0
-        else:
-            self._starved = 0
-            self._idle = 0
-        if new != self.target or why != "steady":
-            self.decisions.append({
-                "from": self.target, "to": new, "why": why,
-                "stall_s": round(float(stall_s), 3),
-                "queue_depth": round(float(queue_depth), 1),
-                "live": live,
-            })
-        self.target = new
-        return new
-
-
 _QDEPTH = REGISTRY.gauge("queue.depth")
 _STALL = REGISTRY.gauge("loader.stall_s")
 _POOL = REGISTRY.gauge("loader.pool_size")
